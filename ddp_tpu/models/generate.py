@@ -765,10 +765,10 @@ def slot_decode_step(
         ksc, vsc = _lane_scales(cache, i)
         if isinstance(cache, PagedSlotCache):
             # Same banded math over the table's gathered view
-            # (ops/decode.paged_decode_attention, block_k =
-            # page_size) — scratch/stale entries sit past ``pos`` and
-            # are masked, so the paged step is token-identical to the
-            # fixed-lane one (pinned by tests/test_paged.py).
+            # (ops/decode.paged_decode_attention) — scratch/stale
+            # entries sit past ``pos`` and are masked, so the paged
+            # step is token-identical to the fixed-lane one (pinned
+            # by tests/test_paged.py).
             attn = paged_decode_attention(
                 q[:, 0], cache.k[i], cache.v[i], cache.table, pos,
                 ksc, vsc, impl=attn_impl,

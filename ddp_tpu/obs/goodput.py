@@ -8,11 +8,10 @@ this module owns — a per-model **train-FLOPs-per-example estimator**
 (matmul/conv arithmetic only, the community convention; fwd ≈ the
 model's matmuls, train ≈ 3× fwd for fwd+bwd) and a **per-chip peak**.
 
-Peaks come from public spec sheets for TPU generations. Off-TPU there
-is no honest peak, so a nominal ``FALLBACK_PEAK_FLOPS`` (1e12) keeps
-the field populated as a *trend line* — CPU MFU values are comparable
-run-to-run, never a hardware-efficiency claim (the record's
-``platform`` field disambiguates, as bench.py's always has).
+Peaks come from public spec sheets for TPU generations. A CPU has no
+peak, so off-TPU MFU is absent (never a number under a device
+metric's name); a TPU ``device_kind`` the table does not list is an
+error, not a default.
 
 Goodput is the restart-aware companion: productive training seconds
 divided by wall seconds since the FIRST launch, persisted in a
@@ -42,28 +41,32 @@ TPU_BF16_PEAK = {
     "TPU v6e": 918e12,
 }
 
-# Nominal off-TPU peak: keeps MFU a stable run-to-run trend line on
-# dev boxes/CI where no spec-sheet number exists. Deliberately high so
-# fallback MFU can never exceed a real machine's (mfu <= 1 stays true).
-FALLBACK_PEAK_FLOPS = 1e12
 
+def peak_flops_per_chip(device=None) -> Optional[float]:
+    """Per-chip bf16 peak for MFU. ``device`` defaults to
+    jax.devices()[0].
 
-def peak_flops_per_chip(device=None) -> float:
-    """Per-chip peak for MFU. ``device`` defaults to jax.devices()[0].
-
-    TPU kinds use the bf16 spec-sheet peak (the compute dtype every
-    perf config here runs); unknown kinds and CPU/GPU fall back to the
-    nominal constant.
+    A listed TPU kind → its spec-sheet peak (the compute dtype every
+    perf config here runs). Any other platform → None: it has no peak
+    here, and ``mfu`` then reports None. A TPU kind the table does not
+    list raises — add the kind with its source rather than print a
+    utilization against a made-up peak.
     """
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind
     for prefix, peak in TPU_BF16_PEAK.items():
         if kind.startswith(prefix):
             return peak
-    return FALLBACK_PEAK_FLOPS
+    raise ValueError(
+        f"no bf16 peak for TPU device_kind {kind!r}: add it to "
+        "ddp_tpu.obs.goodput.TPU_BF16_PEAK with its spec-sheet source "
+        f"(known: {sorted(TPU_BF16_PEAK)})"
+    )
 
 
 def mfu(

@@ -63,24 +63,37 @@ def snapshot_env() -> dict:
 def build_info() -> dict:
     """The provenance block every long-lived process should publish.
 
-    Package version, jax version, backend and platform — the fields
-    bench.py's ``_env_fields()`` made load-bearing for the perf
-    trajectory (a CPU-fallback capture must never be read as an
-    on-chip one), now stamped on trainer ``run_start`` records,
-    ``/statusz``, and the linted ``ddp_tpu_build_info`` gauge on both
-    ``/metricsz`` exporters, so an aggregator scraping a fleet can
-    tell a version-skewed endpoint at a glance.
+    Package, jax, jaxlib and libtpu versions plus the device as JAX
+    reports it (platform, ``device_kind``, count): a CPU run must
+    never be read as an on-chip one, and a number is only comparable
+    with another from the same ``device_kind``. Stamped on trainer
+    ``run_start`` records, the server's startup JSON and ``/statusz``,
+    and the linted ``ddp_tpu_build_info`` gauge on both ``/metricsz``
+    exporters, so an aggregator scraping a fleet can tell a
+    version-skewed endpoint at a glance.
     """
+    import importlib.metadata
+
     import jax
+    import jaxlib
 
     import ddp_tpu
 
-    return {
+    devices = jax.devices()
+    info = {
         "version": ddp_tpu.__version__,
         "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
-        "platform": jax.devices()[0].platform,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
     }
+    try:
+        info["libtpu"] = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        pass  # a CPU/GPU-only installation
+    return info
 
 
 def _sanitize(obj):

@@ -91,6 +91,10 @@ _COLLECTIVE_RE = re.compile(
 _DEF_NAME_RE = re.compile(r"%([\w.\-]+)\s*$")
 _OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _SHAPE_RE = re.compile(r"(pred|[suf]\d+|bf16|c\d+)\[([0-9,]*)\]")
+# XLA annotates long tuples with position comments (`/*index=5*/`),
+# inside result shapes and operand lists alike; they carry nothing
+# the parser reads and their `/`, `=` would stop the shapes group.
+_HLO_COMMENT_RE = re.compile(r"/\*.*?\*/")
 # The two HLO spellings of group membership: explicit nested braces
 # (`replica_groups={{0,1},{2,3}}`) and the iota/v2 form
 # (`replica_groups=[2,2]<=[4]` — reshape iota(4) to [2,2], each row a
@@ -160,6 +164,7 @@ def parse_hlo_collectives(hlo_text: str) -> dict[str, dict]:
     """
     out: dict[str, dict] = {}
     pending: dict[str, dict] = {}
+    hlo_text = _HLO_COMMENT_RE.sub("", hlo_text)
     for m in _COLLECTIVE_RE.finditer(hlo_text):
         shapes, op, suffix = m.group(1), m.group(2), m.group(3)
         bol = hlo_text.rfind("\n", 0, m.start()) + 1
@@ -345,6 +350,10 @@ class ProgramProfile:
     label: str
     signature: str
     compile_time_s: float
+    # The part of compile_time_s spent tracing and lowering (Python,
+    # never cached); the rest is XLA's compile — what a persistent
+    # compile-cache hit removes. None for observe-only entries.
+    lower_time_s: Optional[float] = None
     flops: Optional[float] = None
     bytes_accessed: Optional[float] = None
     memory: dict = field(default_factory=dict)
@@ -363,6 +372,8 @@ class ProgramProfile:
             "compile_time_s": round(self.compile_time_s, 4),
             "calls": self.calls,
         }
+        if self.lower_time_s is not None:
+            out["lower_time_s"] = round(self.lower_time_s, 4)
         if self.notes:
             out["notes"] = dict(self.notes)
         if self.flops is not None:
@@ -383,37 +394,29 @@ class ProgramProfile:
 def _introspect(compiled) -> tuple[Optional[float], Optional[float], dict]:
     """(flops, bytes_accessed, memory breakdown) from a Compiled.
 
-    Every accessor is best-effort: introspection must never turn a
-    working program into a crash (backends may return None or raise
-    on any of these)."""
-    flops = bytes_accessed = None
-    try:
-        ca = compiled.cost_analysis()
-        ca0 = ca[0] if isinstance(ca, (list, tuple)) else ca
-        if ca0:
-            f = ca0.get("flops")
-            flops = float(f) if f is not None else None
-            b = ca0.get("bytes accessed")
-            bytes_accessed = float(b) if b is not None else None
-    except Exception:  # noqa: BLE001 — introspection is best-effort
-        pass
+    Both analyses answer on the CPU and on the TPU (PR 21's chip
+    runs); a field a backend leaves out stays None/absent."""
+    ca = compiled.cost_analysis() or {}
+    flops = ca.get("flops")
+    bytes_accessed = ca.get("bytes accessed")
     memory: dict = {}
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            for key in (
-                "argument_size_in_bytes",
-                "output_size_in_bytes",
-                "temp_size_in_bytes",
-                "alias_size_in_bytes",
-                "generated_code_size_in_bytes",
-            ):
-                v = getattr(ma, key, None)
-                if v is not None:
-                    memory[key.replace("_size_in_bytes", "_bytes")] = int(v)
-    except Exception:  # noqa: BLE001
-        pass
-    return flops, bytes_accessed, memory
+    ma = compiled.memory_analysis()
+    if ma is not None:
+        for key in (
+            "argument_size_in_bytes",
+            "output_size_in_bytes",
+            "temp_size_in_bytes",
+            "alias_size_in_bytes",
+            "generated_code_size_in_bytes",
+        ):
+            v = getattr(ma, key, None)
+            if v is not None:
+                memory[key.replace("_size_in_bytes", "_bytes")] = int(v)
+    return (
+        float(flops) if flops is not None else None,
+        float(bytes_accessed) if bytes_accessed is not None else None,
+        memory,
+    )
 
 
 class _Instrumented:
@@ -483,9 +486,13 @@ class _Instrumented:
             self._compiled[key] = (None, profile)
             return out
         t0 = time.perf_counter()
-        compiled = self._fn.lower(*args).compile()
+        lowered = self._fn.lower(*args)
+        lower_s = time.perf_counter() - t0
+        compiled = lowered.compile()
         dt = time.perf_counter() - t0
-        profile = self._xprof._record_compile(self, args, dt, compiled=compiled)
+        profile = self._xprof._record_compile(
+            self, args, dt, compiled=compiled, lower_s=lower_s
+        )
         self._compiled[key] = (compiled, profile)
         return compiled(*args)
 
@@ -520,6 +527,7 @@ class Xprof:
         self._ledger: deque[ProgramProfile] = deque(maxlen=self.MAX_LEDGER)
         self._program_count = 0
         self._total_compile_s = 0.0
+        self._total_lower_s = 0.0
         # Last signature per label, for shape_diff on recompile.
         self._last_sig: dict[str, str] = {}
         # Per-label annotation dicts (see :meth:`annotate`).
@@ -558,15 +566,13 @@ class Xprof:
                     p.notes.update(fields)
 
     def _record_compile(
-        self, inst: _Instrumented, args: tuple, dt: float, *, compiled
+        self, inst: _Instrumented, args: tuple, dt: float, *, compiled,
+        lower_s: Optional[float] = None,
     ) -> ProgramProfile:
         sig = shape_signature(args)
         if compiled is not None:
             flops, bytes_accessed, memory = _introspect(compiled)
-            try:
-                collectives = parse_hlo_collectives(compiled.as_text())
-            except Exception:  # noqa: BLE001
-                collectives = {}
+            collectives = parse_hlo_collectives(compiled.as_text())
         else:
             flops = bytes_accessed = None
             memory, collectives = {}, {}
@@ -576,6 +582,7 @@ class Xprof:
                 label=inst.label,
                 signature=sig,
                 compile_time_s=dt,
+                lower_time_s=lower_s,
                 flops=flops,
                 bytes_accessed=bytes_accessed,
                 memory=memory,
@@ -588,6 +595,7 @@ class Xprof:
             self._ledger.append(profile)
             self._program_count += 1
             self._total_compile_s += dt
+            self._total_lower_s += lower_s or 0.0
             self._last_sig[inst.label] = sig
             self.event_seq += 1
             self._events.append((self.event_seq, profile.record()))
@@ -618,6 +626,13 @@ class Xprof:
     def total_compile_s(self) -> float:
         with self._lock:
             return self._total_compile_s
+
+    @property
+    def total_lower_s(self) -> float:
+        """Seconds of ``total_compile_s`` spent tracing and lowering;
+        the remainder is XLA's compile (or its persistent-cache load)."""
+        with self._lock:
+            return self._total_lower_s
 
     def measured_flops(self, label: str) -> Optional[float]:
         """XLA-counted FLOPs of the label's most recent compile (the
@@ -785,22 +800,24 @@ class DeviceMemorySampler:
     def sample(self) -> dict:
         """One sample → ``{hbm_used_bytes, hbm_high_water_bytes,
         hbm_limit_bytes?, hbm_headroom_frac?, hbm_source}`` (max over
-        local devices). ``{}`` when disabled."""
+        local devices), plus ``hbm_peak_bytes_by_device`` where the
+        runtime reports ``memory_stats`` — a replica that holds
+        nothing, or one that holds everyone's batch, shows there and
+        not in a max. ``{}`` when disabled."""
         if not self.enabled:
             return {}
         import jax
 
         devices = self._devices if self._devices is not None else jax.local_devices()
         used = peak = limit = None
+        by_device = []
         for d in devices:
-            try:
-                stats = d.memory_stats()
-            except Exception:  # noqa: BLE001 — backend without stats
-                stats = None
+            stats = d.memory_stats()  # None on backends without stats
             if not stats:
                 continue
             u = int(stats.get("bytes_in_use", 0))
             p = int(stats.get("peak_bytes_in_use", u))
+            by_device.append(p)
             lim = stats.get("bytes_limit")
             used = u if used is None else max(used, u)
             peak = p if peak is None else max(peak, p)
@@ -818,6 +835,8 @@ class DeviceMemorySampler:
             "hbm_high_water_bytes": int(self._high_water),
             "hbm_source": self._source,
         }
+        if by_device:
+            out["hbm_peak_bytes_by_device"] = by_device
         if limit:
             out["hbm_limit_bytes"] = int(limit)
             out["hbm_headroom_frac"] = round(

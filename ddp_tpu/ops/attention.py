@@ -12,8 +12,6 @@ this exists for the ViT extension config and the long-context path.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -33,6 +31,23 @@ MASK_VALUE = -0.5 * jnp.finfo(jnp.float32).max
 FLASH_MIN_LEN = 1024
 
 
+def use_flash(key_len: int) -> bool:
+    """THE platform-and-size rule every default attention path shares
+    (``best_attention``, the GSPMD island, the ring's per-hop block):
+    the compiled Pallas flash kernel on TPU for at least
+    ``FLASH_MIN_LEN`` keys, dense XLA otherwise."""
+    return jax.default_backend() == "tpu" and key_len >= FLASH_MIN_LEN
+
+
+def describe_attention(key_len: int) -> dict:
+    """What ``use_flash`` builds for ``key_len`` keys, for the records
+    that must say so (trainer ``run_start``): the choice is made from
+    the platform, so it has to be visible, not inferred."""
+    if use_flash(key_len):
+        return {"impl": "flash", "kernel": "pallas-compiled"}
+    return {"impl": "dense", "kernel": "xla"}
+
+
 def best_attention(*, causal: bool = False, block_q: int = 512,
                    block_k: int = 512):
     """Platform- and SIZE-resolved default attention.
@@ -46,14 +61,10 @@ def best_attention(*, causal: bool = False, block_q: int = 512,
     platform). The model factories (vit/lm/seq/moe) call this when no
     explicit ``attention_fn`` is given.
     """
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not on_tpu:
-        return partial(dot_product_attention, causal=causal)
-
     from ddp_tpu.ops.flash import flash_attention
 
     def fn(q, k, v):
-        if k.shape[1] >= FLASH_MIN_LEN:
+        if use_flash(k.shape[1]):
             return flash_attention(q, k, v, causal, block_q, block_k, False)
         return dot_product_attention(q, k, v, causal=causal)
 
@@ -79,7 +90,6 @@ def gspmd_flash_attention(mesh, *, causal: bool = False, block_q: int = 512,
     """
     from ddp_tpu.runtime.mesh import data_axes
 
-    on_tpu = jax.devices()[0].platform == "tpu"
     # Same axis set AND same size-1 filter as spmd.batch_spec, so the
     # island's specs always match the GSPMD step's activation layout.
     batch_axes = tuple(
@@ -88,7 +98,10 @@ def gspmd_flash_attention(mesh, *, causal: bool = False, block_q: int = 512,
     tp = mesh.shape.get("model", 1)
 
     def fn(q, k, v):
-        if (not on_tpu and not interpret) or k.shape[1] < FLASH_MIN_LEN:
+        if not (
+            use_flash(k.shape[1])
+            or (interpret and k.shape[1] >= FLASH_MIN_LEN)
+        ):
             return dot_product_attention(q, k, v, causal=causal)
         from jax.sharding import PartitionSpec as P
 

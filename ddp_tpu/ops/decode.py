@@ -55,19 +55,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; absent on CPU-only builds of pallas
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from ddp_tpu.ops.flash import pick_block
 
 # Per-row stats ride broadcast across the minor 128-lane dim (the
 # ops/flash.py layout convention — [.., 1] would be lane-padded in
 # VMEM anyway and 2-D one-row blocks are not tileable).
 LANES = 128
+
+# KV rows streamed per grid step unless the caller asks otherwise.
+DEFAULT_BLOCK_K = 128
 
 # int8 quantization range: symmetric, NaN-free at zero rows (the amax
 # floor below keeps the scale strictly positive).
@@ -146,28 +144,6 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None):
 # ---- the Pallas kernel ----------------------------------------------
 
 
-def pick_block_k(L: int, block_k: int) -> int:
-    """Effective KV block for a length-``L`` lane.
-
-    The grid needs ``block_k | L``. When the requested size doesn't
-    divide, fall back to the **largest divisor of L ≤ requested** —
-    never to ``L`` itself (a single full-length block would defeat the
-    ``pl.when`` dead-block skip that makes young lanes O(pos)). Worst
-    case (prime ``L``) degrades to 1-wide blocks, which is still
-    banded; the tuner and the xprof ledger surface the effective value
-    so a pathological ``L`` is visible, not silent.
-    """
-    block_k = min(block_k, L)
-    while L % block_k:
-        block_k -= 1
-    return block_k
-
-
-# Pre-rename private spelling; kept so external callers (and the
-# tuner's site-version hash) have one canonical name to import.
-_pick_block_k = pick_block_k
-
-
 def flash_decode_attention(
     q,
     k,
@@ -176,21 +152,25 @@ def flash_decode_attention(
     k_scale=None,
     v_scale=None,
     *,
-    block_k: int = 128,
+    block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
 ):
     """Pallas flash-decode → [S, H, Dh] fp32 (the reference's contract).
 
     Same signature/semantics as :func:`decode_attention_reference`;
     ``interpret=None`` auto-detects (compiled Mosaic on TPU, the
-    interpreter elsewhere so one engine config runs anywhere).
+    interpreter elsewhere so one engine config runs anywhere). The
+    effective KV block is ``ops.flash.pick_block(L, block_k, k.dtype)``
+    — tile-aligned, never one full-length block for a long lane (that
+    would defeat the ``pl.when`` dead-block skip that makes young
+    lanes O(pos)); a lane length with no such block raises.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.default_backend() != "tpu"
     S, H, Dh = q.shape
     L, H_kv = k.shape[1], k.shape[2]
     G = H // H_kv
-    block_k = pick_block_k(L, block_k)
+    block_k = pick_block(L, block_k, k.dtype)
     quantized = k.dtype == jnp.int8
     # One grid row per (slot, kv-head): q regrouped kv-head-major
     # (exactly the engine's qg = q.reshape(S, H_kv, G, Dh) grouping),
@@ -198,93 +178,79 @@ def flash_decode_attention(
     qt = q.reshape(S * H_kv, G, Dh)
     kt = k.transpose(0, 2, 1, 3).reshape(S * H_kv, L, Dh)
     vt = v.transpose(0, 2, 1, 3).reshape(S * H_kv, L, Dh)
-    # Per-row lane position, broadcast across the minor 128 lanes
-    # (the ops/flash.py per-row-stat layout).
-    pos_l = jnp.broadcast_to(
-        jnp.repeat(pos.astype(jnp.int32), H_kv)[:, None, None],
-        (S * H_kv, 1, LANES),
-    )
-    kw = {} if _VMEM is None or interpret else {"memory_space": _VMEM}
-    qmap = lambda b, j: (b, 0, 0)
-    kmap = lambda b, j: (b, j, 0)
+    # Per-row lane position: a scalar the kernel branches on, so it
+    # rides scalar prefetch into SMEM (Mosaic reads no scalars out of
+    # VMEM blocks).
+    pos_rows = jnp.repeat(pos.astype(jnp.int32), H_kv)  # [S·H_kv]
+    vmem = {"memory_space": pltpu.VMEM}
+    qmap = lambda b, j, pos_ref: (b, 0, 0)
+    kmap = lambda b, j, pos_ref: (b, j, 0)
     in_specs = [
-        pl.BlockSpec((1, G, Dh), qmap, **kw),
-        pl.BlockSpec((1, block_k, Dh), kmap, **kw),
-        pl.BlockSpec((1, block_k, Dh), kmap, **kw),
+        pl.BlockSpec((1, G, Dh), qmap, **vmem),
+        pl.BlockSpec((1, block_k, Dh), kmap, **vmem),
+        pl.BlockSpec((1, block_k, Dh), kmap, **vmem),
     ]
     args = [qt, kt, vt]
     if quantized:
         ksc = k_scale.transpose(0, 2, 1).reshape(S * H_kv, L, 1)
         vsc = v_scale.transpose(0, 2, 1).reshape(S * H_kv, L, 1)
         in_specs += [
-            pl.BlockSpec((1, block_k, 1), kmap, **kw),
-            pl.BlockSpec((1, block_k, 1), kmap, **kw),
+            pl.BlockSpec((1, block_k, 1), kmap, **vmem),
+            pl.BlockSpec((1, block_k, 1), kmap, **vmem),
         ]
         args += [ksc.astype(jnp.float32), vsc.astype(jnp.float32)]
-    in_specs.append(pl.BlockSpec((1, 1, LANES), qmap, **kw))
-    args.append(pos_l)
 
-    def scratch(shape):
-        if pltpu is None:  # pragma: no cover
-            # No pallas.tpu module → no VMEM scratch spec to build.
-            # `auto` never routes here off-TPU; a forced `flash` on
-            # such a build gets a clear error, not a Mosaic crash.
-            raise RuntimeError(
-                "flash_decode_attention needs jax.experimental"
-                ".pallas.tpu for its scratch buffers; this jax build "
-                "lacks it — use impl='reference'"
-            )
-        return pltpu.VMEM(shape, jnp.float32)
-
-    kernel = (
-        _quantized_kernel if quantized else _plain_kernel
-    )
     out = pl.pallas_call(
         functools.partial(
-            kernel, scale=Dh**-0.5, block_k=block_k,
+            _quantized_kernel if quantized else _plain_kernel,
+            scale=Dh**-0.5, block_k=block_k,
         ),
-        grid=(S * H_kv, L // block_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, Dh), qmap, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S * H_kv, L // block_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, G, Dh), qmap, **vmem),
+            scratch_shapes=[
+                pltpu.VMEM((G, Dh), jnp.float32),
+                pltpu.VMEM((G, LANES), jnp.float32),
+                pltpu.VMEM((G, LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((S * H_kv, G, Dh), jnp.float32),
-        scratch_shapes=[
-            scratch((G, Dh)),
-            scratch((G, LANES)),
-            scratch((G, LANES)),
-        ],
         interpret=interpret,
-    )(*args)
+    )(pos_rows, *args)
     return out.reshape(S, H, Dh)
 
 
 def _plain_kernel(
-    q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref,
+    pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     *, scale, block_k,
 ):
     _decode_body(
-        q_ref, k_ref, v_ref, None, None, pos_ref, o_ref,
+        pos_ref, q_ref, k_ref, v_ref, None, None, o_ref,
         acc_ref, m_ref, l_ref, scale=scale, block_k=block_k,
     )
 
 
 def _quantized_kernel(
-    q_ref, k_ref, v_ref, ksc_ref, vsc_ref, pos_ref, o_ref,
+    pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
     acc_ref, m_ref, l_ref, *, scale, block_k,
 ):
     _decode_body(
-        q_ref, k_ref, v_ref, ksc_ref, vsc_ref, pos_ref, o_ref,
+        pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
         acc_ref, m_ref, l_ref, scale=scale, block_k=block_k,
     )
 
 
 def _decode_body(
-    q_ref, k_ref, v_ref, ksc_ref, vsc_ref, pos_ref, o_ref,
+    pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
     acc_ref, m_ref, l_ref, *, scale, block_k,
 ):
-    """Shared online-softmax body (see :func:`_decode_kernel` docs)."""
+    """Shared online-softmax body; ``pos_ref`` is the scalar-prefetched
+    [S·H_kv] position vector (SMEM)."""
     j = pl.program_id(1)
     n_kb = pl.num_programs(1)
-    pos = pos_ref[0, 0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -342,7 +308,9 @@ def _decode_body(
 # paths one definition: the jnp reference runs the EXACT fixed-lane
 # einsum math over the gathered view (bit-identical off-TPU — the
 # token-identity pin), and the flash path streams the gathered lanes
-# through the same Pallas kernel with block_k = page_size. The gather
+# through the same Pallas kernel (the gathered view is contiguous, so
+# the KV block is the fixed-lane one, not the page — a 16-row int8
+# page is below Mosaic's 32-row int8 tile). The gather
 # itself is one XLA dynamic-gather over int32 ids — static shape
 # arithmetic, no host sync (lint TN fixture ddp002_tn.py pins the
 # pattern).
@@ -381,11 +349,10 @@ def paged_decode_attention(
     fixed-lane call over the table's gathered view — positions past
     ``pos[s]`` (including every scratch-page line) are masked, so a
     stale or zero table entry above the live region can never leak
-    into the softmax. ``block_k = page_size`` aligns the flash
-    kernel's dead-block skip with page boundaries (compute-side only
-    here — see the module's cost note: the gather materializes the
-    full lane views first; in-kernel table indexing is the on-chip
-    follow-up).
+    into the softmax. The flash kernel's dead-block skip is
+    compute-side only here — see the module's cost note: the gather
+    materializes the full lane views first; in-kernel table indexing
+    is the on-chip follow-up.
     """
     k = gather_paged_kv(k_pages, table)
     v = gather_paged_kv(v_pages, table)
@@ -393,7 +360,7 @@ def paged_decode_attention(
     vs = gather_paged_kv(v_scale, table) if v_scale is not None else None
     return decode_attention(
         q, k, v, pos, ks, vs,
-        impl=impl, block_k=int(k_pages.shape[1]), interpret=interpret,
+        impl=impl, interpret=interpret,
     )
 
 
@@ -402,7 +369,7 @@ def paged_decode_attention(
 
 def decode_attention(
     q, k, v, pos, k_scale=None, v_scale=None, *,
-    impl: str = "reference", block_k: int = 128,
+    impl: str = "reference", block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
 ):
     """The engine-facing entry: ``impl`` picks the path at trace time.
@@ -414,9 +381,7 @@ def decode_attention(
     beats XLA's fused einsums, and the PR-3 numerics stay untouched).
     """
     if impl == "auto":
-        impl = (
-            "flash" if jax.devices()[0].platform == "tpu" else "reference"
-        )
+        impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "flash":
         return flash_decode_attention(
             q, k, v, pos, k_scale, v_scale,
@@ -431,7 +396,7 @@ def decode_attention(
 
 
 def shard_decode_attention(
-    mesh, *, impl: str = "auto", block_k: int = 128,
+    mesh, *, impl: str = "auto", block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
 ):
     """Mesh-composable flash-decode: shard_map over the ``model`` axis.

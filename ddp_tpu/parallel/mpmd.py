@@ -1123,10 +1123,7 @@ def _stage_entry(
     import jax
 
     jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    try:
-        jax.config.update("jax_enable_compilation_cache", False)
-    except Exception:  # older jax: env var alone covers it
-        pass
+    jax.config.update("jax_enable_compilation_cache", False)
     logging.basicConfig(level=logging.INFO)
     cfg = MPMDConfig.from_json(cfg_json)
     StageRunner(cfg, stage, workdir, metrics_path, ctrl_port).run()

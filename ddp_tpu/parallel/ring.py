@@ -69,9 +69,9 @@ def _default_block_fn(q, k, v, causal: bool):
     """Per-hop block attention: Pallas flash kernel on TPU for blocks
     past the crossover length, XLA elsewhere (short blocks lose to one
     fused einsum chain — ops.attention.FLASH_MIN_LEN)."""
-    from ddp_tpu.ops.attention import FLASH_MIN_LEN
+    from ddp_tpu.ops.attention import use_flash
 
-    if jax.default_backend() == "tpu" and k.shape[1] >= FLASH_MIN_LEN:
+    if use_flash(k.shape[1]):
         from ddp_tpu.ops.flash import flash_attention_with_lse
 
         return flash_attention_with_lse(q, k, v, causal, 512, 512, False)

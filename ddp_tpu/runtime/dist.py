@@ -51,14 +51,58 @@ def _ensure_host_device_count(n: int) -> None:
 def force_cpu_backend(num_devices: int | None = None) -> None:
     """Select the CPU platform (optionally with emulated devices).
 
-    Call before any JAX computation. Overrides platform plugins that
-    pin ``jax_platforms`` at import time.
+    Call before any JAX computation.
     """
     if num_devices is not None:
         _ensure_host_device_count(num_devices)
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+
+
+# Where the persistent XLA compile cache lives when
+# JAX_COMPILATION_CACHE_DIR does not place it: one fixed directory
+# inside the checkout (.gitignore lists it). Fixed on purpose — a
+# cache under a temporary name, a pid or a timestamp is never found
+# again by the next process.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str | None:
+    """Place the persistent compile cache; returns its directory, or
+    None when it stays off.
+
+    THE one place the cache directory is decided, called by every
+    entry point that compiles for the chip (``setup`` — so the trainer
+    and every launcher worker — ``scripts/serve.py``, ``bench.py``,
+    ``scripts/check_kernels.py``):
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set → nothing is set in code; JAX
+      reads the variable itself.
+    - unset, on an accelerator → ``COMPILE_CACHE_DIR``.
+    - unset, on a CPU backend → off. XLA:CPU AOT deserialization is
+      machine-feature-sensitive: cache-loaded executables SIGSEGV /
+      SIGABRT on resumed runs (tests/conftest.py, round 6), and a CPU
+      compile is seconds, not the minute the cache exists to save.
+
+    Reads ``jax.default_backend()``, which initializes the backend: in
+    a multi-host run call it after ``jax.distributed.initialize``
+    (``setup`` does).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,12 +183,9 @@ def setup(
             # and what lets the whole multihost test tier (and the
             # --spawn restart loop) run on a dev box. Must be set
             # before initialize(); harmless when already set.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            except (AttributeError, ValueError):  # older jaxlib
-                pass
+            jax.config.update(
+                "jax_cpu_collectives_implementation", "gloo"
+            )
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
@@ -158,6 +199,7 @@ def setup(
             f"requested backend {backend!r} but JAX resolved platform "
             f"{actual!r} — refusing to run on the wrong hardware silently"
         )
+    enable_compile_cache()
     ctx = DistContext(
         backend=actual,
         process_id=jax.process_index(),

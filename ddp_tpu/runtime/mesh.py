@@ -187,14 +187,15 @@ def make_mesh(
 ):
     """Build a ``jax.sharding.Mesh`` from a logical spec.
 
-    Uses ``mesh_utils.create_device_mesh`` when possible so axis order
-    maps onto the physical ICI torus (innermost axes get the
-    fastest-varying/nearest chips); falls back to a plain reshape for
-    emulated CPU devices. A ``dcn`` axis > 1 orders devices slice-major
-    first (``slice_index`` on real pods, process on emulated worlds) so
-    the outermost axis genuinely separates the DCN fabric.
+    ``mesh_utils.create_device_mesh`` lays the axes on the physical
+    ICI torus on TPU (innermost axes get the nearest chips) and is a
+    plain reshape elsewhere; a topology it cannot lay the mesh on
+    raises. A ``dcn`` axis > 1 orders devices slice-major first
+    (``slice_index`` on real pods, process on emulated worlds) so the
+    outermost axis genuinely separates the DCN fabric.
     """
     import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     if devices is None:
@@ -209,34 +210,20 @@ def make_mesh(
     if sizes["dcn"] > 1:
         devices = _slice_major(devices, sizes["dcn"])
         per = len(devices) // sizes["dcn"]
-        if devices[0].platform == "tpu":
-            try:
-                from jax.experimental import mesh_utils
-
-                # Torus-aware layout per slice, stacked along dcn —
-                # ICI adjacency is a within-slice property.
-                mesh_devices = np.stack(
-                    [
-                        mesh_utils.create_device_mesh(
-                            shape[1:], devices=devices[i * per : (i + 1) * per]
-                        )
-                        for i in range(sizes["dcn"])
-                    ]
+        # Torus-aware layout per slice, stacked along dcn — ICI
+        # adjacency is a within-slice property.
+        mesh_devices = np.stack(
+            [
+                mesh_utils.create_device_mesh(
+                    shape[1:], devices=devices[i * per : (i + 1) * per]
                 )
-                return Mesh(mesh_devices, AXIS_ORDER)
-            except Exception:  # non-standard topology: plain reshape
-                pass
-        return Mesh(np.asarray(devices).reshape(shape), AXIS_ORDER)
-
-    if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
-
-            mesh_devices = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(mesh_devices, AXIS_ORDER)
-        except Exception:  # non-standard topology: fall through to reshape
-            pass
-    return Mesh(np.asarray(devices).reshape(shape), AXIS_ORDER)
+                for i in range(sizes["dcn"])
+            ]
+        )
+        return Mesh(mesh_devices, AXIS_ORDER)
+    return Mesh(
+        mesh_utils.create_device_mesh(shape, devices=devices), AXIS_ORDER
+    )
 
 
 def live_world_spec(

@@ -75,6 +75,7 @@ from ddp_tpu.models.generate import (
 from ddp_tpu.models.generate import slot_decode_step as _decode_step
 from ddp_tpu.models.generate import slot_verify_step as _verify_step
 from ddp_tpu.models.lm import LMSpec
+from ddp_tpu.ops.decode import DEFAULT_BLOCK_K
 from ddp_tpu.obs.tracer import Tracer
 from ddp_tpu.serve.pages import PrefixCache, page_demand
 from ddp_tpu.serve.scheduler import (
@@ -259,14 +260,34 @@ def resolve_engine_knobs(
         )
     if decode_attn == "auto":
         decode_attn = (
-            "flash"
-            if jax.devices()[0].platform == "tpu"
-            else "reference"
+            "flash" if jax.default_backend() == "tpu" else "reference"
         )
     if kv_dtype not in ("fp32", "int8"):
         raise ValueError(
             f"kv_dtype must be fp32|int8, got {kv_dtype!r}"
         )
+    # How the resolved impl runs here — chosen from the platform, so
+    # reported (startup JSON), never left to inference.
+    decode_kernel = "xla"
+    decode_block_k = None
+    if decode_attn == "flash":
+        from ddp_tpu.ops.flash import pallas_kernel_mode, pick_block
+
+        decode_kernel = pallas_kernel_mode()
+        # The kernel's effective KV block, resolved here so a lane
+        # length with no tile-aligned block fails at construction with
+        # the shape named (ops/flash.pick_block), not inside Mosaic.
+        try:
+            decode_block_k = pick_block(
+                spec.total_len, DEFAULT_BLOCK_K,
+                jnp.int8 if kv_dtype == "int8" else jnp.float32,
+            )
+        except ValueError as e:
+            raise ValueError(
+                f"decode_attn=flash cannot tile total_len "
+                f"{spec.total_len} with a {kv_dtype} cache ({e}); use "
+                "decode_attn=reference"
+            ) from e
     if kv_pages is not None and not page_size:
         raise ValueError(
             "--kv_pages needs --page_size (the page pool only "
@@ -378,6 +399,8 @@ def resolve_engine_knobs(
         "slots": slots,
         "prefill_len": prefill_len,
         "decode_attn": decode_attn,
+        "decode_kernel": decode_kernel,
+        "decode_block_k": decode_block_k,
         "kv_dtype": kv_dtype,
         "paged": paged,
         "page_size": page_size,
@@ -474,6 +497,7 @@ class ServeEngine:
         )
         prefill_len = knobs["prefill_len"]
         self.decode_attn = knobs["decode_attn"]
+        self.decode_kernel = knobs["decode_kernel"]
         self.kv_dtype = knobs["kv_dtype"]
         # Paged KV + radix prefix reuse (PR 12, serve/pages.py):
         # --page_size > 0 flips the cache to the page-pool layout
@@ -494,14 +518,22 @@ class ServeEngine:
         # may overshoot its context by up to K-2 positions — reserved
         # rather than clamp-shifted over live lines).
         self.spec_tokens = knobs["spec_tokens"]
+        # The engine drives ONE device; it has no mesh (ROADMAP C9).
+        # Weights and every piece of engine state are COMMITTED to it
+        # up front: a restored checkpoint's arrays are committed, jit
+        # keys its cache on that, and a fresh uncommitted cache made
+        # warmup's first program compile a second time on the first
+        # request (the static-shape pin, broken only under restored
+        # weights).
+        self._device = jax.local_devices()[0]
         self.draft_spec = draft_spec
-        self.draft_params = draft_params
+        self.draft_params = self._put(draft_params)
         ctx_len = knobs["ctx_len"]
         tokens_per_decode = knobs["tokens_per_decode"]
         chunk = knobs["chunk"]
         min_bucket = knobs["min_bucket"]
         self.spec = spec
-        self.params = params
+        self.params = self._put(params)
         # Model lifecycle (serve/lifecycle.py): the serving version
         # label (None keeps every surface byte-identical to the
         # pre-lifecycle engine), hot-swap counters, and the admission
@@ -607,11 +639,11 @@ class ServeEngine:
         self._slots = [_Slot(index=i) for i in range(slots)]
         cache_dtype = jnp.int8 if kv_dtype == "int8" else jnp.float32
         if self.paged:
-            self._cache = init_paged_slot_cache(
+            self._cache = self._put(init_paged_slot_cache(
                 spec, slots,
                 num_pages=self.kv_pages, page_size=self.page_size,
                 dtype=cache_dtype,
-            )
+            ))
             self._prefix = PrefixCache(self.kv_pages, self.page_size)
             # Host mirror of the device page table: mutated at
             # bind/retire, uploaded (one [S, lane_pages] int32 array)
@@ -625,24 +657,24 @@ class ServeEngine:
             # outran the pool (requeued, retried next step).
             self.page_starved_binds = 0
         else:
-            self._cache = init_slot_cache(
+            self._cache = self._put(init_slot_cache(
                 spec, slots, dtype=cache_dtype,
-            )
+            ))
         # Device-resident token vector: output of the last decode (or
         # chunk splice), input to the next — the decode loop never
         # routes tokens through the host. NOT donated anywhere: the
         # host still owes an async read of the previous step's values.
-        self._toks = jnp.zeros((slots,), jnp.int32)
+        self._toks = self._put(jnp.zeros((slots,), jnp.int32))
         # Per-slot sampling state, ALSO device-resident: the chunk
         # program installs a request's (seed, temperature, top_p) at
         # its lane and the decode program advances the fold_in step
         # counters — the steady-state loop uploads NOTHING per step.
         # Seeds are int32 to hit exactly generate()'s
         # jax.random.key(seed) path.
-        self._seeds = jnp.zeros((slots,), jnp.int32)
-        self._sample_steps = jnp.zeros((slots,), jnp.int32)
-        self._temps = jnp.zeros((slots,), jnp.float32)
-        self._top_ps = jnp.ones((slots,), jnp.float32)
+        self._seeds = self._put(jnp.zeros((slots,), jnp.int32))
+        self._sample_steps = self._put(jnp.zeros((slots,), jnp.int32))
+        self._temps = self._put(jnp.zeros((slots,), jnp.float32))
+        self._top_ps = self._put(jnp.ones((slots,), jnp.float32))
         # Device values dispatched but not yet read back:
         # ("first", scalar, slot) | ("decode", [S] array, lanes).
         self._pending: list[tuple[str, Any, Any]] = []
@@ -724,20 +756,15 @@ class ServeEngine:
             "serve.flash_decode" if impl == "flash" else "serve.decode",
         )
         if impl == "flash":
-            # The kernel snaps block_k to the largest divisor of the
-            # lane length (ops/decode.pick_block_k) — a host-side
-            # decision XLA introspection can't see. Ledger it so the
-            # tuner and humans read the EFFECTIVE block, not the
-            # requested default. Paged lanes gather [pages ·
-            # page_size] keys and block on the page itself.
-            from ddp_tpu.ops.decode import pick_block_k
-
-            requested = self.page_size if self.paged else 128
-            lane_len = spec.total_len
+            # The kernel snaps block_k to the largest tile-aligned
+            # divisor of the lane length (ops/flash.pick_block) — a
+            # host-side decision XLA introspection can't see. Ledger
+            # it so the tuner and humans read the EFFECTIVE block, not
+            # the requested default.
             self._xprof.annotate(
                 "serve.flash_decode",
-                block_k_requested=requested,
-                block_k=pick_block_k(lane_len, requested),
+                block_k_requested=DEFAULT_BLOCK_K,
+                block_k=knobs["decode_block_k"],
             )
         if self.spec_tokens:
             dspec = draft_spec
@@ -745,19 +772,19 @@ class ServeEngine:
             # the same token history at its own width) plus dummy
             # per-slot sampling state for the chunk signature — the
             # draft always proposes greedily, so none of it is read.
-            self._draft_cache = init_slot_cache(dspec, slots)
-            self._d_toks = jnp.zeros((slots,), jnp.int32)
-            self._d_seeds = jnp.zeros((slots,), jnp.int32)
-            self._d_steps = jnp.zeros((slots,), jnp.int32)
-            self._d_temps = jnp.zeros((slots,), jnp.float32)
-            self._d_top_ps = jnp.ones((slots,), jnp.float32)
+            self._draft_cache = self._put(init_slot_cache(dspec, slots))
+            self._d_toks = self._put(jnp.zeros((slots,), jnp.int32))
+            self._d_seeds = self._put(jnp.zeros((slots,), jnp.int32))
+            self._d_steps = self._put(jnp.zeros((slots,), jnp.int32))
+            self._d_temps = self._put(jnp.zeros((slots,), jnp.float32))
+            self._d_top_ps = self._put(jnp.ones((slots,), jnp.float32))
             # Device-resident sync flags: the first draft step of a
             # round adopts the TARGET cache's per-lane positions (the
             # draft advanced spec_tokens last round, the target only
             # as far as acceptance went). Prebuilt so the sanitized
             # hot loop uploads nothing.
-            self._sync_pos = jnp.asarray(True)
-            self._keep_pos = jnp.asarray(False)
+            self._sync_pos = self._put(jnp.asarray(True))
+            self._keep_pos = self._put(jnp.asarray(False))
 
             def _draft_propose(p, c, t, pos, sync):
                 c = c._replace(pos=jnp.where(sync, pos, c.pos))
@@ -790,6 +817,11 @@ class ServeEngine:
         self.spec_drafted_total = 0
         self.spec_accepted_total = 0
         self.accept_rate = StatSummary()
+
+    def _put(self, tree):
+        """Commit a pytree of arrays to the engine's device (a no-op
+        for leaves already there)."""
+        return jax.device_put(tree, self._device)
 
     # ---- frontend surface ------------------------------------------
 
@@ -1034,7 +1066,7 @@ class ServeEngine:
                     f"spec_skew: leaf {tuple(new.shape)}/{new.dtype} "
                     f"!= serving {tuple(old.shape)}/{old.dtype}"
                 )
-        self.params = params
+        self.params = self._put(params)
         if model_version is not None:
             self.model_version = model_version
         self.reloads_total += 1
@@ -1472,7 +1504,7 @@ class ServeEngine:
         # step, nothing on the steady-state path.
         if self.paged and self._table_dirty:
             self._cache = self._cache._replace(
-                table=jnp.asarray(self._table_np)
+                table=self._put(self._table_np)
             )
             self._table_dirty = False
 

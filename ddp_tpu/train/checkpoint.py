@@ -376,7 +376,6 @@ class CheckpointManager:
         always preserved.
         """
         self._dir = os.path.abspath(directory)
-        self._keep_best_fallback: tuple | None = None
         opts_kwargs: dict = dict(
             max_to_keep=None if keep_best_metric else max_to_keep,
             create=True,
@@ -384,36 +383,26 @@ class CheckpointManager:
             step_prefix="epoch",
         )
         if keep_best_metric:
-            try:
-                from orbax.checkpoint.checkpoint_managers import (
-                    AnyPreservationPolicy,
-                    BestN,
-                    LatestN,
-                )
+            from orbax.checkpoint.checkpoint_managers import (
+                AnyPreservationPolicy,
+                BestN,
+                LatestN,
+            )
 
-                opts_kwargs["preservation_policy"] = AnyPreservationPolicy(
-                    [
-                        LatestN(1),  # auto-resume anchor
-                        BestN(
-                            get_metric_fn=lambda m: m[keep_best_metric],
-                            # reverse=False keeps the HIGHEST metric
-                            # values (empirically: reverse=True retains
-                            # the lowest)
-                            reverse=False,
-                            n=max_to_keep,
-                            keep_checkpoints_without_metrics=True,
-                        ),
-                    ]
-                )
-            except ImportError:
-                # orbax < 0.11: no preservation policies, and the old
-                # best_fn API cannot express best-N PLUS the latest
-                # anchor. Emulate with explicit deletes after each
-                # save (_prune_keep_best); metrics are tracked
-                # in-process, and saves whose metric was never seen
-                # are kept — the keep_checkpoints_without_metrics
-                # behaviour.
-                self._keep_best_fallback = (keep_best_metric, max_to_keep, {})
+            opts_kwargs["preservation_policy"] = AnyPreservationPolicy(
+                [
+                    LatestN(1),  # auto-resume anchor
+                    BestN(
+                        get_metric_fn=lambda m: m[keep_best_metric],
+                        # reverse=False keeps the HIGHEST metric
+                        # values (empirically: reverse=True retains
+                        # the lowest)
+                        reverse=False,
+                        n=max_to_keep,
+                        keep_checkpoints_without_metrics=True,
+                    ),
+                ]
+            )
         opts = ocp.CheckpointManagerOptions(**opts_kwargs)
         # Explicit handler so item_metadata works before any save/
         # restore call registered one (the template-free inference path
@@ -472,6 +461,40 @@ class CheckpointManager:
                 logger.warning(
                     "manifest write for epoch %d failed: %s", epoch, e
                 )
+        self._sweep_orphan_manifests()
+
+    def _sweep_orphan_manifests(self) -> None:
+        """Remove sidecars whose epoch Orbax's own retention deleted.
+
+        ``max_to_keep`` and the keep-best preservation policy garbage-
+        collect ``epoch_<N>`` directories inside Orbax, which knows
+        nothing of our ``epoch_<N>.manifest.json`` beside them. A
+        manifest with no directory verifies nothing and reads as a
+        kept checkpoint to anything that lists the directory. Epochs
+        still pending (async save in flight: no final directory YET)
+        are left alone.
+        """
+        if not self._is_manifest_writer():
+            return
+        try:
+            names = os.listdir(self._dir)
+        except OSError:
+            return
+        for name in names:
+            if not (
+                name.startswith("epoch_") and name.endswith(MANIFEST_SUFFIX)
+            ):
+                continue
+            stem = name[: -len(MANIFEST_SUFFIX)]
+            if not stem[len("epoch_"):].isdigit():
+                continue
+            if int(stem[len("epoch_"):]) in self._manifest_pending:
+                continue
+            if not os.path.isdir(os.path.join(self._dir, stem)):
+                try:
+                    os.remove(os.path.join(self._dir, name))
+                except OSError:
+                    pass
 
     def _drop_manifest(self, epoch: int) -> None:
         self._manifest_pending.discard(epoch)
@@ -660,36 +683,7 @@ class CheckpointManager:
         # committed by this point) and at wait()/close().
         self._manifest_pending.add(epoch)
         self._flush_manifests()
-        if self._keep_best_fallback is not None:
-            self._prune_keep_best(epoch, metrics)
         return True
-
-    def _prune_keep_best(self, epoch: int, metrics: dict | None) -> None:
-        """best-N ∪ latest retention for orbax versions without
-        preservation policies (see __init__). Runs after each save;
-        under async saving the in-flight step is not yet listed, so
-        the previous latest survives one extra round — pruned by the
-        next save, never the auto-resume anchor."""
-        metric_name, n, seen = self._keep_best_fallback
-        if metrics and metric_name in metrics:
-            seen[epoch] = metrics[metric_name]
-        steps = self._mgr.all_steps() or []
-        if not steps:
-            return
-        best = sorted(
-            (s for s in steps if s in seen),
-            key=lambda s: seen[s],
-            reverse=True,
-        )
-        # n=None means unbounded (the new-orbax path keeps every
-        # metric-bearing save then too) — only slice for a real bound.
-        if n is not None:
-            best = best[:n]
-        keep = set(best) | {max(steps)}
-        keep |= {s for s in steps if s not in seen}  # metric-less saves
-        for s in steps:
-            if s not in keep:
-                self._delete_epoch(s)
 
     def restore(
         self,
@@ -849,21 +843,11 @@ class CheckpointManager:
                 options=ocp.CheckpointManagerOptions(step_prefix="epoch"),
                 item_handlers=ocp.PyTreeCheckpointHandler(),
             )
-        try:
-            args = ocp.args.PyTreeRestore(
-                item=abstract,
-                restore_args=restore_args,
-                partial_restore=True,
-            )
-        except TypeError:
-            # orbax < 0.9: no partial_restore kwarg — an empty
-            # transforms dict is the era's partial-restore idiom
-            # (checkpoint keys absent from ``item`` are dropped).
-            args = ocp.args.PyTreeRestore(
-                item=abstract,
-                restore_args=restore_args,
-                transforms={},
-            )
+        args = ocp.args.PyTreeRestore(
+            item=abstract,
+            restore_args=restore_args,
+            partial_restore=True,
+        )
         return dict(self._pytree_mgr.restore(epoch, args=args))
 
     def params_metadata(self, epoch: int):

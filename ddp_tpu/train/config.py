@@ -149,10 +149,6 @@ class TrainConfig:
     # (resnet*, vit*, vit_moe*); simple_cnn has no block stack to remat.
     remat: bool = False
     emulate_devices: int | None = None  # N virtual CPU devices (dev box)
-    # Persistent XLA compilation cache: repeat runs skip the 20-40s
-    # first-compile on TPU. "" disables; env JAX_COMPILATION_CACHE_DIR
-    # takes precedence when set.
-    compile_cache_dir: str = "~/.cache/ddp_tpu/xla"
     compute_dtype: str = "float32"  # "bfloat16" for mixed precision
     eval_every: int = 1  # epochs between test-split evals (0 = only final)
     # Compiled-epoch fast path (train/fast.py): dataset device-resident,
@@ -396,9 +392,6 @@ class TrainConfig:
         )
         p.add_argument("--remat", action="store_true")
         p.add_argument("--emulate_devices", type=int, default=None)
-        p.add_argument(
-            "--compile_cache_dir", default=cls.compile_cache_dir,
-        )
         p.add_argument(
             "--compute_dtype", default=cls.compute_dtype,
             choices=("float32", "bfloat16"),

@@ -213,34 +213,6 @@ class Trainer:
             else {}
         )
 
-        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-            # Repeat CLI runs skip the first-compile wait (~20-40s on
-            # TPU). Compiled programs are keyed by HLO+flags, so a
-            # config change recompiles correctly. "" explicitly
-            # disables — including un-setting a cache a previous
-            # Trainer in this process enabled (the config is
-            # process-global).
-            #
-            # CPU backends leave the DEFAULT cache off: XLA:CPU AOT
-            # deserialization is machine-feature-sensitive (the
-            # tests/conftest.py round-6 finding — cache-loaded
-            # executables SIGSEGV/SIGABRT on mismatched hosts;
-            # reproduced on resumed --health runs, whose larger step
-            # crosses the 1s persistence threshold), and a CPU
-            # compile is seconds, not the 20-40s the cache exists to
-            # save. An explicit --compile_cache_dir (≠ the default)
-            # or the env var still opts in anywhere.
-            cache_dir = config.compile_cache_dir
-            if (
-                cache_dir == TrainConfig.compile_cache_dir
-                and jax.default_backend() == "cpu"
-            ):
-                cache_dir = ""
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.path.expanduser(cache_dir) if cache_dir else None,
-            )
-
         devices = jax.devices()
         if config.num_devices > 0:
             devices = devices[: config.num_devices]
@@ -1506,10 +1478,11 @@ class Trainer:
         )
         # Analytic train-FLOPs per example (None for unknown models —
         # MFU is then absent, never silently zero) against the mesh's
-        # aggregate peak.
+        # aggregate peak (None off-TPU: a CPU has no peak, so no MFU).
         self._flops_per_example = self._estimate_flops_per_example()
+        chip_peak = peak_flops_per_chip(devices[0])
         self._peak_flops = (
-            peak_flops_per_chip(devices[0]) * self.mesh.devices.size
+            chip_peak * self.mesh.devices.size if chip_peak else None
         )
         # Runtime sanitizer (--sanitize, runtime/sanitize.py): the
         # transfer guard arms around the hot loop in _train_epoch
@@ -1726,6 +1699,21 @@ class Trainer:
             depth=cfg.model_depth,
         )
 
+    def _attention_info(self) -> dict | None:
+        """The attention the token/sequence families' step was built
+        with (``ops.attention.use_flash`` decides from the platform
+        and the key count one call sees); None for the registry image
+        models, whose token count the trainer does not know."""
+        if not (self.seq_mode or self.pipe_lm_mode):
+            return None
+        from ddp_tpu.ops.attention import describe_attention
+
+        cfg = self.config
+        keys = cfg.seq_len
+        if cfg.mesh_seq > 1 and cfg.seq_strategy == "ring":
+            keys //= cfg.mesh_seq  # one ring hop attends one block
+        return describe_attention(keys)
+
     def _step_obs_fields(self, timing) -> dict:
         """JSONL fields for one attributed step ({} when attribution
         is off — the step record's schema only widens under
@@ -1783,10 +1771,17 @@ class Trainer:
                 k: ev[k]
                 for k in (
                     "label", "signature", "shape_diff",
-                    "compile_time_s", "flops",
+                    "compile_time_s", "lower_time_s", "flops", "memory",
                 )
                 if ev.get(k) is not None
             }
+            if ev.get("collectives"):
+                # Per-kind totals of what the compiled program moves
+                # (the per-instance list stays in the ledger).
+                rec["collectives"] = {
+                    op: {"count": c["count"], "result_bytes": c["result_bytes"]}
+                    for op, c in ev["collectives"].items()
+                }
             self.metrics_writer.write("compile", **rec)
             self._recorder.record("compile", **rec)
         # Hand-ledger vs compiled-program collectives, once per run:
@@ -2289,6 +2284,15 @@ class Trainer:
         tuning_fields = (
             {"tuning": self._tuning} if self._tuning else {}
         )
+        # What was built from what the platform offers — the attention
+        # implementation and where compiled programs persist — is
+        # chosen from observation, so the generation anchor says it.
+        # (enable_compile_cache is idempotent — setup() already ran it —
+        # and returns the directory in effect.)
+        built_fields = {"compile_cache": dist.enable_compile_cache()}
+        attention = self._attention_info()
+        if attention:
+            built_fields["attention"] = attention
         self._recorder.record(
             "run_start", start_epoch=start_epoch,
             restarts=self._goodput.restarts,
@@ -2303,6 +2307,7 @@ class Trainer:
             build_info=self._build_info,
             **world_fields,
             **tuning_fields,
+            **built_fields,
         )
         if self._tuning:
             self.metrics_writer.write(
@@ -2828,7 +2833,13 @@ class Trainer:
             for k in ("hbm_high_water_bytes", "hbm_headroom_frac"):
                 if k in xf:
                     extra[k] = xf[k]
+            # Per replica, not the max: one that holds nothing (or
+            # everyone's share) must show. Epoch records only.
+            by_device = self._hbm.sample().get("hbm_peak_bytes_by_device")
+            if by_device:
+                extra["hbm_peak_bytes_by_device"] = by_device
             extra["compile_s"] = round(self._xprof.total_compile_s, 4)
+            extra["lower_s"] = round(self._xprof.total_lower_s, 4)
             extra["compiled_programs"] = self._xprof.program_count
         self.metrics_writer.write(
             "epoch",

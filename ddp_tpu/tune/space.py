@@ -220,17 +220,23 @@ DECODE_BLOCKS = (32, 64, 128, 256, 512)
 def decode_block_space(total_len: int) -> SpaceReport:
     """``block_k`` for ``ops/decode.flash_decode_attention``.
 
-    Every request is constructible (``pick_block_k`` snaps to the
-    largest divisor), so validity never rejects — but the snap makes
-    grids alias hard (all requests ≥ L collapse to L's largest
-    divisor), and the dedupe is what keeps TPU measurement cheap.
+    ``ops.flash.pick_block`` snaps each request to the largest
+    tile-aligned divisor (rejecting lane lengths that have none), and
+    the snap makes grids alias hard (all requests ≥ L collapse to L):
+    the dedupe is what keeps TPU measurement cheap.
     """
-    from ddp_tpu.ops.decode import pick_block_k
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.flash import pick_block
 
     report = SpaceReport(site="decode_block")
     for bk in DECODE_BLOCKS:
         report.proposed += 1
-        eff = {"block_k": pick_block_k(total_len, bk)}
+        try:
+            eff = {"block_k": pick_block(total_len, bk, jnp.float32)}
+        except ValueError:
+            report.rejected += 1
+            continue
         knobs = {"block_k": bk}
         cand = _cand("decode_block", knobs)
         if any(r == eff for r in report.resolved.values()):

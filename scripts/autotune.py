@@ -22,9 +22,8 @@ load by default (``--tuned auto``; explicit flags always win).
 Prints one JSON report line per site. A warm cache is a pure hit:
 ``cache_hit: true, measured: 0`` (re-tune with ``--force``).
 
-TPU runbook: the first TPU-reachable session runs this against the
-production checkpoint, then refreshes BENCH_LKG in the same session —
-see docs/TUNING.md.
+TPU runbook: the first on-chip session runs this against the
+production checkpoint — see docs/TUNING.md.
 """
 
 from __future__ import annotations

@@ -1,0 +1,225 @@
+#!/usr/bin/env python
+"""Compile the Pallas kernels for the device JAX finds and compare each
+with its reference.
+
+    python scripts/check_kernels.py           # the shapes chip_smoke.py trains and serves
+    python scripts/check_kernels.py --all     # + the variants the server exposes:
+                                              #   GQA, int8 KV, paged lanes
+    python scripts/check_kernels.py --tiny    # small shapes (CPU rehearsal: the
+                                              #   Pallas interpreter, not Mosaic)
+
+On a TPU the kernels compile under Mosaic; elsewhere they run in the
+Pallas interpreter, which proves the program and nothing about the
+chip. References run under ``jax.default_matmul_precision("highest")``
+on fp32 copies of the inputs. One JSON line per case —
+``{"case", "kernel", "max_abs_err", "tol", "ok"}`` — then one summary
+line; exit status 1 when any case is outside its tolerance or failed
+to build. Nothing here is timed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import traceback
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# Max |kernel − reference| accepted per case family: a few times what
+# a TPU v5 lite showed (CHANGES.md, PR 21 — flash 7.5e-3 forward and
+# 2.2e-2 backward, decode 8.5e-3 to 1.2e-2). Flash is bounded by
+# bf16's 2^-8 rounding of its outputs. The decode kernel's fp32 dots
+# run at the MXU's default precision (bf16 passes), like any fp32
+# einsum XLA compiles for the chip without a precision request; each
+# decode case also reports what the XLA reference itself loses at
+# that default (``xla_default_max_abs_err``) for comparison.
+TOL = {
+    "flash": 2e-2,
+    "flash_grad": 6e-2,
+    "decode": 3e-2,
+}
+
+
+def _max_err(a, b) -> float:
+    import jax.numpy as jnp
+
+    return float(
+        jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
+    )
+
+
+def check_flash(B: int, T: int, H: int, D: int, block: int) -> dict:
+    """Flash forward AND backward (the trainer's causal bf16 call)
+    against dense attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.attention import dot_product_attention
+    from ddp_tpu.ops.flash import flash_attention
+
+    interpret = jax.default_backend() != "tpu"
+    kq, kk, kv, kw = jax.random.split(jax.random.key(0), 4)
+    shape = (B, T, H, D)
+    q, k, v = (
+        jax.random.normal(key, shape, jnp.bfloat16) for key in (kq, kk, kv)
+    )
+    w = jax.random.normal(kw, shape, jnp.float32)  # the cotangent
+
+    def flash_loss(q, k, v):
+        out = flash_attention(q, k, v, True, block, block, interpret)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    def dense_loss(q, k, v):
+        out = dot_product_attention(q, k, v, causal=True)
+        return (out * w).sum(), out
+
+    (_, out), grads = jax.jit(
+        jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True)
+    )(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, ref), ref_grads = jax.jit(
+            jax.value_and_grad(dense_loss, argnums=(0, 1, 2), has_aux=True)
+        )(*(x.astype(jnp.float32) for x in (q, k, v)))
+    err = _max_err(out, ref)
+    grad_err = max(_max_err(g, r) for g, r in zip(grads, ref_grads))
+    finite = bool(
+        jnp.isfinite(out.astype(jnp.float32)).all()
+        and all(jnp.isfinite(g.astype(jnp.float32)).all() for g in grads)
+    )
+    return {
+        "max_abs_err": err,
+        "tol": TOL["flash"],
+        "grad_max_abs_err": grad_err,
+        "grad_tol": TOL["flash_grad"],
+        "ok": finite and err <= TOL["flash"] and grad_err <= TOL["flash_grad"],
+    }
+
+
+def check_decode(
+    S: int, H: int, H_kv: int, Dh: int, L: int,
+    *, int8: bool = False, page_size: int = 0,
+) -> dict:
+    """Flash-decode (fixed-lane, or paged through a shuffled page
+    table) against ``decode_attention_reference`` on the same cache."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_tpu.ops.decode import (
+        decode_attention,
+        decode_attention_reference,
+        gather_paged_kv,
+        paged_decode_attention,
+        quantize_kv,
+    )
+
+    kq, kk, kv = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(kq, (S, H, Dh), jnp.float32)
+    k = jax.random.normal(kk, (S, L, H_kv, Dh), jnp.float32)
+    v = jax.random.normal(kv, (S, L, H_kv, Dh), jnp.float32)
+    # Every lane age the band must handle: the first key alone, a
+    # block edge either side, the full lane.
+    pos = jnp.asarray(
+        np.resize([0, 127, 128, L // 2, L - 1, 1, L // 3, L - 2], S),
+        jnp.int32,
+    ) % L
+    ks = vs = None
+    if int8:
+        k, ks = quantize_kv(k)
+        v, vs = quantize_kv(v)
+    if page_size:
+        # Scatter every lane's pages over a shuffled pool (page 0 is
+        # the engine's scratch page and stays unmapped).
+        n = L // page_size
+        table = jnp.asarray(
+            1 + np.random.default_rng(2).permutation(S * n).reshape(S, n),
+            jnp.int32,
+        )
+
+        def to_pool(x):
+            pages = x.reshape(S * n, page_size, *x.shape[2:])
+            pool = jnp.zeros((S * n + 1, *pages.shape[1:]), x.dtype)
+            return pool.at[table.reshape(-1)].set(pages)
+
+        pools = [to_pool(x) if x is not None else None for x in (k, v, ks, vs)]
+        out = jax.jit(
+            lambda q, kp, vp, ksp, vsp: paged_decode_attention(
+                q, kp, vp, table, pos, ksp, vsp, impl="flash"
+            )
+        )(q, *pools)
+        k, v, ks, vs = (
+            gather_paged_kv(x, table) if x is not None else None
+            for x in pools
+        )
+    else:
+        out = jax.jit(
+            lambda q, k, v, ks, vs: decode_attention(
+                q, k, v, pos, ks, vs, impl="flash"
+            )
+        )(q, k, v, ks, vs)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(decode_attention_reference)(q, k, v, pos, ks, vs)
+    xla_default = jax.jit(decode_attention_reference)(q, k, v, pos, ks, vs)
+    err = _max_err(out, ref)
+    return {
+        "max_abs_err": err,
+        "tol": TOL["decode"],
+        "xla_default_max_abs_err": _max_err(xla_default, ref),
+        "ok": bool(jnp.isfinite(out).all()) and err <= TOL["decode"],
+    }
+
+
+def cases(tiny: bool, every: bool):
+    if tiny:
+        flash = dict(B=1, T=128, H=2, D=128, block=64)
+        dec = dict(S=2, H=4, H_kv=4, Dh=128, L=256)
+    else:
+        # chip_smoke.py's model: d1024 / 8 heads, T=2048, 8 slots.
+        flash = dict(B=2, T=2048, H=8, D=128, block=512)
+        dec = dict(S=8, H=8, H_kv=8, Dh=128, L=2048)
+    yield "flash_fwd_bwd_bf16_causal", lambda: check_flash(**flash)
+    yield "decode_fp32_g1", lambda: check_decode(**dec)
+    if every:
+        gqa = dict(dec, H_kv=dec["H"] // 4)
+        yield "decode_fp32_g4", lambda: check_decode(**gqa)
+        yield "decode_fp32_g8", lambda: check_decode(
+            **dict(dec, H=8 * dec["H_kv"])
+        )
+        yield "decode_int8_g1", lambda: check_decode(**dec, int8=True)
+        yield "decode_int8_g4", lambda: check_decode(**gqa, int8=True)
+        yield "decode_paged16_fp32", lambda: check_decode(**dec, page_size=16)
+        yield "decode_paged16_int8_g4", lambda: check_decode(
+            **gqa, int8=True, page_size=16
+        )
+
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--all", action="store_true")
+    p.add_argument("--tiny", action="store_true")
+    args = p.parse_args()
+
+    from ddp_tpu.obs.recorder import build_info
+    from ddp_tpu.ops.flash import pallas_kernel_mode
+    from ddp_tpu.runtime.dist import enable_compile_cache
+
+    enable_compile_cache()
+    kernel = pallas_kernel_mode()
+    print(json.dumps({"build_info": build_info(), "kernel": kernel}), flush=True)
+    failed = []
+    for name, run in cases(args.tiny, args.all):
+        try:
+            rec = run()
+        except Exception:  # noqa: BLE001 — report every case, fail at the end
+            rec = {"ok": False, "error": traceback.format_exc(limit=6)}
+        print(json.dumps({"case": name, "kernel": kernel, **rec}), flush=True)
+        if not rec["ok"]:
+            failed.append(name)
+    print(json.dumps({"kernels_ok": not failed, "failed": failed}), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

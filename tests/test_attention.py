@@ -53,16 +53,11 @@ class TestBestAttentionDispatch:
     positional kernel call can't silently regress."""
 
     def _fake_tpu(self, monkeypatch):
-        import types
-
         import jax
 
         from ddp_tpu.ops import attention as attn_mod
 
-        monkeypatch.setattr(
-            jax, "devices",
-            lambda *a, **k: [types.SimpleNamespace(platform="tpu")],
-        )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         calls = []
 
         def fake_flash(q, k, v, causal, block_q, block_k, interpret):

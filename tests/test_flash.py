@@ -83,7 +83,8 @@ def test_flash_in_vit():
 
     model = ViT(
         num_classes=10, patch_size=7, embed_dim=32, depth=1, num_heads=4,
-        attention_fn=make_flash_attention(block_q=16, block_k=16, interpret=True),
+        # 17 tokens (16 patches + cls): one whole-sequence block.
+        attention_fn=make_flash_attention(block_q=32, block_k=32, interpret=True),
     )
     x = jnp.zeros((2, 28, 28, 1), jnp.float32)
     params = model.init(jax.random.key(0), x)["params"]
@@ -124,7 +125,7 @@ def test_flash_grads_causal_rectangular():
     _, k, v = _qkv(1, 32, 2, 8, seed=8)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, True, 4, 8, True) ** 2).mean()
+        return (flash_attention(q, k, v, True, 8, 8, True) ** 2).mean()
 
     def loss_dense(q, k, v):
         return (_dense_causal(q, k, v) ** 2).mean()

@@ -70,7 +70,7 @@ class TestKernel:
         [
             (3, 4, 4, 8, 16, 8),    # MHA, two key blocks
             (2, 8, 2, 16, 32, 8),   # GQA group 4, four blocks
-            (4, 4, 2, 8, 24, 16),   # block_k does not divide L → one block
+            (4, 4, 2, 8, 24, 16),   # 16 does not divide 24 → three blocks of 8
             (1, 2, 1, 4, 8, 128),   # block_k > L → clamped to L
         ],
     )
@@ -116,12 +116,13 @@ class TestKernel:
         """Dequantize-in-kernel computes the same attention as the
         dequantize-then-reference path over the SAME int8 cache."""
         rng = np.random.default_rng(11)
-        q, k, v = _rand_qkv(rng, 3, 4, 2, 8, 16)
-        pos = jnp.asarray([2, 7, 15], jnp.int32)
+        q, k, v = _rand_qkv(rng, 3, 4, 2, 8, 64)
+        pos = jnp.asarray([2, 31, 63], jnp.int32)
         qk, ks = quantize_kv(k)
         qv, vs = quantize_kv(v)
         ref = decode_attention_reference(q, qk, qv, pos, ks, vs)
-        out = flash_decode_attention(q, qk, qv, pos, ks, vs, block_k=8)
+        # int8 rows tile 32 at a time: two blocks of one tile each.
+        out = flash_decode_attention(q, qk, qv, pos, ks, vs, block_k=32)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
         )

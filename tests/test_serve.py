@@ -230,13 +230,20 @@ class TestEngine:
                 spec, params, req.prompt, req.max_new_tokens
             )
 
-    def test_no_recompilation_after_warmup(self, params):
+    @pytest.mark.parametrize("committed", [False, True])
+    def test_no_recompilation_after_warmup(self, params, committed):
         """THE static-shape pin: ``warmup()`` compiles the engine's
         WHOLE bounded program set — one chunk program per bucket width
         plus the fused decode+sample program — and a varied mix
         (staggered arrivals, every prompt length, mixed sampling
-        configs, evictions, refills) grows it by NOTHING."""
+        configs, evictions, refills) grows it by NOTHING. Also under
+        COMMITTED weights, which is what a restored checkpoint hands
+        the server: jit keys on it, and an uncommitted fresh cache
+        used to make the first request recompile warmup's first
+        program."""
         clock = FakeClock()
+        if committed:
+            params = jax.device_put(params, jax.devices()[0])
         eng = ServeEngine(
             SPEC, params, slots=3, prefill_len=8,
             prefill_chunk=8, min_bucket=2, clock=clock,

@@ -11,7 +11,7 @@
 - **Cache**: round-trip through the atomic JSON file; invalidation on
   model-shape / hardware / site-version change; corrupt files read as
   empty; ``apply_tuned`` precedence explicit > cache > default.
-- **pick_block_k** (satellite): largest-divisor fallback property,
+- **pick_block** (satellite): largest tile-aligned divisor property,
   kernel-vs-reference parity on a non-divisible L, and the xprof
   ``annotate`` plumbing that surfaces the effective block in the
   compile ledger.
@@ -29,10 +29,10 @@ import numpy as np
 import pytest
 
 from ddp_tpu.models.lm import LMSpec, init_lm
+from ddp_tpu.ops.flash import pick_block
 from ddp_tpu.ops.decode import (
     decode_attention_reference,
     flash_decode_attention,
-    pick_block_k,
 )
 from ddp_tpu.serve.engine import ServeEngine, resolve_engine_knobs
 from ddp_tpu.tune import (
@@ -259,31 +259,80 @@ class TestApplyTuned:
         assert overridden == []
 
 
-# ---- pick_block_k + xprof surfacing (satellite) ---------------------
+# ---- pick_block + xprof surfacing (satellite) -----------------------
 
 
-class TestPickBlockK:
+class TestPickBlock:
+    """ops/flash.pick_block: the one block rule of the flash and
+    flash-decode kernels (tile-aligned divisor, or raise)."""
+
     def test_regression_non_divisible_requested(self):
         """The ISSUE-18 pin: L=48 with the default 32 request must land
-        on 24 (largest divisor ≤ 32), not degrade to a full-length
-        block that defeats the dead-block skip."""
-        assert pick_block_k(48, 32) == 24
+        on 24 (largest aligned divisor ≤ 32), not degrade to a
+        full-length block that defeats the dead-block skip."""
+        assert pick_block(48, 32, jnp.float32) == 24
 
     @pytest.mark.parametrize(
-        "L,req,expect",
-        [(128, 128, 128), (7, 128, 7), (97, 64, 1), (48, 16, 16)],
+        "L,req,dtype,expect",
+        [
+            (128, 128, jnp.float32, 128),
+            (7, 128, jnp.float32, 7),  # fits the request: whole lane
+            (48, 16, jnp.float32, 16),
+            (2048, 512, jnp.bfloat16, 512),
+            (2064, 128, jnp.float32, 48),  # 2064 = 16·3·43
+            (96, 64, jnp.int8, 32),  # int8 rows tile 32 at a time
+        ],
     )
-    def test_known_values(self, L, req, expect):
-        assert pick_block_k(L, req) == expect
+    def test_known_values(self, L, req, dtype, expect):
+        assert pick_block(L, req, dtype) == expect
 
-    def test_largest_divisor_property(self):
-        for L in range(1, 80):
-            for req in (1, 3, 8, 13, 32, 128):
-                got = pick_block_k(L, req)
-                assert L % got == 0 and got <= min(req, L)
-                assert not any(
-                    L % d == 0 for d in range(got + 1, min(req, L) + 1)
-                ), (L, req, got)
+    @pytest.mark.parametrize(
+        "L,req,dtype",
+        [
+            (97, 64, jnp.float32),  # prime: used to degrade to 1-wide
+            (200, 128, jnp.bfloat16),  # 8-aligned divisors only
+            (2064, 128, jnp.int8),  # the paged int8 case: 16 | L, 32 ∤ L
+        ],
+    )
+    def test_non_dividing_length_raises_with_shape(self, L, req, dtype):
+        with pytest.raises(ValueError, match=f"length-{L}"):
+            pick_block(L, req, dtype)
+
+    def test_flash_blocks_never_fall_to_whole_sequence(self):
+        """ops/flash._pick_blocks used to make the block the WHOLE
+        sequence when the request did not divide it (one [T, T] cell
+        in VMEM at long T); now it is an aligned divisor or an
+        error."""
+        from ddp_tpu.ops.flash import _pick_blocks
+
+        assert _pick_blocks(2048, 2048, 512, 512, jnp.bfloat16) == (512, 512)
+        assert _pick_blocks(1536, 1536, 1024, 1024, jnp.float32) == (768, 768)
+        with pytest.raises(ValueError, match="length-1031"):
+            _pick_blocks(1031, 1031, 512, 512, jnp.float32)
+
+    def test_aligned_divisor_property(self):
+        for dtype, align in ((jnp.float32, 8), (jnp.int8, 32)):
+            for L in range(1, 160):
+                for req in (1, 8, 13, 32, 128):
+                    try:
+                        got = pick_block(L, req, dtype)
+                    except ValueError:
+                        assert L > req and not any(
+                            L % d == 0
+                            for d in range(align, req + 1, align)
+                        ), (L, req)
+                        continue
+                    assert L % got == 0 and got <= max(req, 1), (L, req)
+                    assert got == L or got % align == 0, (L, req, got)
+
+    def test_engine_rejects_untileable_lane_at_construction(self):
+        spec = SPEC._replace(total_len=2064)
+        with pytest.raises(ValueError, match="total_len 2064"):
+            resolve_engine_knobs(
+                spec, decode_attn="flash", kv_dtype="int8"
+            )
+        knobs = resolve_engine_knobs(spec, decode_attn="flash")
+        assert knobs["decode_block_k"] == 48
 
     def test_flash_matches_reference_on_non_divisible_L(self):
         """The fallback path computes the same attention: L=48 keys,

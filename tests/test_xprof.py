@@ -102,6 +102,25 @@ def test_parse_hlo_collectives_synthetic():
     assert all(o["groups"] is None for o in got["all-reduce"]["ops"])
 
 
+def test_parse_hlo_variadic_tuple_with_index_comments():
+    """XLA (jax 0.9) prints one variadic all-reduce over the whole
+    gradient tree and annotates long tuples with ``/*index=5*/``
+    position comments — in the result shapes and the operand list.
+    The parser must count every element (it used to stop at the first
+    comment and read 7 bytes where the program moved megabytes)."""
+    hlo = (
+        "%all-reduce = (f32[32]{0}, f32[3,3,1,32]{3,2,1,0}, f32[64]{0}, "
+        "f32[3,3,32,64]{3,2,1,0}, f32[10]{0}, /*index=5*/f32[50176,10]{1,0}, "
+        "f32[]) all-reduce(%a, %b, %c, %d, %e, /*index=5*/%f, %g), "
+        "channel_id=1, replica_groups={{0,1}}, use_global_device_ids=true, "
+        "to_apply=%region_7.8\n"
+    )
+    got = parse_hlo_collectives(hlo)["all-reduce"]
+    elems = 32 + 3 * 3 * 32 + 64 + 3 * 3 * 32 * 64 + 10 + 50176 * 10 + 1
+    assert got["count"] == 1 and got["result_bytes"] == 4 * elems
+    assert got["ops"][0]["groups"] == [[0, 1]]
+
+
 _HLO_SUBGROUP_FIXTURE = """
 HloModule jit_hier
 %rs = f32[256]{0} reduce-scatter(f32[1024]{0} %g), channel_id=1, replica_groups={{0,1,2,3},{4,5,6,7}}, dimensions={0}
