@@ -259,58 +259,6 @@ def _timed_device_loop(run, state, *, repeats: int = 3):
     return loss, seconds
 
 
-def _profile_op_split(run, state) -> dict | None:
-    """One profiled dispatch → {hlo_category: fraction of device time}.
-
-    Captures a ``jax.profiler`` trace of ``run(state, 3)`` and
-    aggregates leaf HLO events on the TPU track by the category the
-    profiler assigns ('convolution fusion' = the MXU matmuls, 'data
-    formatting'/'copy-done' = layout copies, …), skipping the 'while'
-    loop container (it double-counts its body). Returns None off-TPU
-    or if anything about the trace format surprises us — the split is
-    evidence, never a reason to fail the bench.
-    """
-    import collections
-    import glob
-    import gzip
-    import tempfile
-
-    import jax
-
-    if jax.devices()[0].platform != "tpu":
-        return None  # the pid filter below only knows TPU tracks
-    try:
-        with tempfile.TemporaryDirectory() as td:
-            with jax.profiler.trace(td):
-                float(run(state, 3))
-            files = glob.glob(td + "/**/*.trace.json.gz", recursive=True)
-            if not files:
-                return None
-            tr = json.load(gzip.open(sorted(files)[-1]))
-            evs = tr["traceEvents"]
-            procs = {
-                e["pid"]: e["args"].get("name", "")
-                for e in evs
-                if e.get("ph") == "M" and e.get("name") == "process_name"
-            }
-            dev = {p for p, n in procs.items() if "TPU" in n}
-            agg = collections.Counter()
-            tot = 0.0
-            for e in evs:
-                if e.get("ph") != "X" or e.get("pid") not in dev:
-                    continue
-                cat = (e.get("args") or {}).get("hlo_category")
-                if not cat or cat == "while":
-                    continue
-                agg[cat] += e.get("dur", 0)
-                tot += e.get("dur", 0)
-            if not tot:
-                return None
-            return {k: round(v / tot, 3) for k, v in agg.most_common(6)}
-    except Exception:
-        return None
-
-
 def run_vit_bench(
     *, batch: int = 256, nsteps: int = 30, use_cls_token: bool = True
 ) -> dict:
@@ -326,8 +274,8 @@ def run_vit_bench(
     (round-3 verdict weak #5): T drops from 65 to 64 — a whole tile
     multiple — by mean-pooling instead of a cls token, attacking the
     measured ~30% of step time in 'data formatting'/'copy-done' that
-    the T=65 padding forces. Published as the ``vit_t64`` entry so the
-    two op-time splits sit side by side.
+    the T=65 padding forces. Published as the ``vit_t64`` entry beside
+    the T=65 one.
     """
     import jax
     import jax.numpy as jnp
@@ -389,23 +337,6 @@ def run_vit_bench(
     fwd = depth * (24 * T * d * d + 4 * T * T * d)
     train_flops_per_image = 3 * fwd
     mfu = _mfu(images_per_sec, train_flops_per_image, device)
-    # The MFU ceiling story (round-2 verdict weak #2), backed by a
-    # live per-category profile of this exact dispatch: ViT-Tiny's
-    # shapes are tiling-limited on the MXU — K=d=192 contractions fill
-    # 1.5 of 2 padded 128-lanes (≤75% per-matmul ceiling), T=65
-    # attention pads to 128 rows, and the [B,65,H,3,D] qkv tensors
-    # force data-formatting relayouts worth ~1/3 of device time
-    # (measured; qkv-slice layout variants and a reshape-matmul patch
-    # embed were benchmarked at parity or worse — the copies follow
-    # from the shapes, not the op choice). Dividing est. MFU by the
-    # matmul share of device time gives ~0.5 MXU-busy efficiency —
-    # in line with the LM bench at MXU-friendly shapes (d=1024).
-    split = _profile_op_split(run, (params, opt_state))
-    note = (
-        f"tiling-limited at T={T}/d=192: see op_time_split — matmuls "
-        "('convolution fusion') vs layout copies ('data formatting', "
-        "'copy-done'); est_mfu / matmul_share ≈ MXU-busy efficiency"
-    ) if split is not None else None
     return {
         "metric": "vit_tiny_bf16_train_throughput",
         "value": round(images_per_sec, 1),
@@ -420,8 +351,6 @@ def run_vit_bench(
         "estimated_mfu": round(mfu, 4) if mfu is not None else None,
         "mfu": mfu,
         "device_kind": getattr(device, "device_kind", "unknown"),
-        "op_time_split": split,
-        "profile_note": note,
     }
 
 

@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddp_tpu.data.sampler import ShardSampler
+from ddp_tpu.obs.tracer import Tracer, get_tracer
 from ddp_tpu.runtime.mesh import data_axes
 
 
@@ -73,8 +74,12 @@ class ShardedLoader:
         shuffle: bool = True,
         seed: int = 0,
         num_workers: int = 0,
+        tracer: Tracer | None = None,
     ):
         self.mesh = mesh
+        # ``data.next_batch`` spans (obs/tracer.py): the process-global
+        # tracer unless handed another.
+        self.tracer = tracer if tracer is not None else get_tracer()
         self.global_batch_size = global_batch_size
         procs = jax.process_count()
         if global_batch_size % procs:
@@ -238,9 +243,24 @@ class ShardedLoader:
                 jax.make_array_from_process_local_data(self._lbl_sharding, lbl_np),
             )
 
+        # ``data.next_batch``: the host's share of one fetch — gather
+        # the next batch's rows and dispatch its transfer. With the
+        # one-step prefetch this is what the consumer's ``next()``
+        # waits for. The fetch that finds the epoch exhausted records
+        # rows 0.
+        tracer, rows = self.tracer, self.local_batch_size
+        host = self._host_batches(epoch, skip_batches)
         pending: Batch | None = None
-        for img_np, lbl_np in self._host_batches(epoch, skip_batches):
-            nxt = put(img_np, lbl_np)  # async dispatch — overlaps prior step
+        while True:
+            with tracer.span("data.next_batch", nums=(rows,)) as span:
+                item = next(host, None)
+                if item is None:
+                    span.nums = (0,)
+                else:
+                    # async dispatch — overlaps the prior step
+                    nxt = put(*item)
+            if item is None:
+                break
             if pending is not None:
                 yield pending
             pending = nxt

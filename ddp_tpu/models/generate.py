@@ -671,6 +671,7 @@ def _full_kv(cache, layer: int):
     return kf, vf
 
 
+@jax.named_scope("cache_update")
 def _write_kv_rows(cache, layer: int, k, v, pos):
     """Write per-lane K/V rows at each lane's position, in place.
 
@@ -864,6 +865,7 @@ def sample_token(
     return lax.cond(temperature > 0.0, drawn, greedy, operand=None)
 
 
+@jax.named_scope("sampling")
 def sample_slot_tokens(
     logits: jax.Array,
     seeds: jax.Array,
@@ -927,6 +929,7 @@ def slot_decode_sample_step(
     return toks, cache, steps + 1
 
 
+@jax.named_scope("sampling")
 def sample_slot_tokens_block(
     logits: jax.Array,
     seeds: jax.Array,

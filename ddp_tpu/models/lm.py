@@ -685,10 +685,11 @@ def make_lm_train_step(
                 grad_clip_norm=zero_grad_clip_norm,
             )
         else:
-            updates, opt_state = optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
         accuracy = correct / (tokens.shape[0] * (tokens.shape[1] - 1))
         if health:
             from ddp_tpu.obs.health import health_stats

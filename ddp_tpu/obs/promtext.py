@@ -339,9 +339,25 @@ def render_serve(
         help="tokens scheduled across all requests (the aggregator's "
         "fleet tokens/s source)",
     )
+    b.add(
+        "ddp_tpu_serve_accepted_total", stats.get("accepted_total"),
+        metric_type="counter",
+        help="requests accepted into the queue",
+    )
     b.summary(
         "ddp_tpu_serve_ttft_seconds", stats.get("ttft_s"),
         help="submit to first token",
+    )
+    # What the frontend adds outside the engine's clock: ttft and
+    # queue wait start only once the server's lock is won.
+    b.summary(
+        "ddp_tpu_serve_submit_lock_wait_seconds",
+        stats.get("lock_wait_s"),
+        help="frontend call to the server's lock won, before submit",
+    )
+    b.summary(
+        "ddp_tpu_serve_result_pickup_seconds", stats.get("pickup_s"),
+        help="request finished to its answer picked up by the frontend",
     )
     b.summary(
         "ddp_tpu_serve_tpot_seconds", stats.get("tpot_s"),

@@ -32,7 +32,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Iterable, Iterator, Optional
 
-from ddp_tpu.obs.tracer import Tracer
+from ddp_tpu.obs.tracer import Tracer, get_tracer
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -125,9 +125,10 @@ class StepAttributor:
 
     ``batches`` times the gap between iterations (input wait);
     ``on_step`` times dispatch-return vs block_until_ready and reads
-    the compile-counter delta. Each attributed segment also lands in
-    ``tracer`` as a span, so the JSONL numbers and the Perfetto
-    picture come from the same measurements.
+    the compile-counter delta. This is the ``--trace_dir`` diagnosis
+    mode: it syncs on every step by design, which no span does. The
+    device wait also lands in ``tracer`` as ``step.compute``; input
+    wait and dispatch are the loader's and the trainer's own spans.
     """
 
     def __init__(
@@ -138,7 +139,7 @@ class StepAttributor:
         xprof=None,
     ):
         self.enabled = bool(enabled)
-        self.tracer = tracer if tracer is not None else Tracer()
+        self.tracer = tracer if tracer is not None else get_tracer()
         # Compile attribution (obs/xprof.py): when the hot path is
         # instrumented, a step whose compile counter moved also gets
         # the ledger events that landed during it — label, shape-diff,
@@ -204,13 +205,10 @@ class StepAttributor:
         self.epoch_totals.add(timing)
         tr = self.tracer
         if tr.enabled:
-            # Retroactive spans: begin/end stamps are already in hand.
-            tr.complete(
-                "step.input_wait",
-                self._fetch_end - timing.input_wait_s,
-                timing.input_wait_s,
-            )
-            tr.complete("step.dispatch", self._fetch_end, timing.dispatch_s)
+            # The wait for the device, a retroactive span (its stamps
+            # are in hand). Input wait and dispatch are spanned where
+            # they happen — the loader's ``data.next_batch``, the
+            # trainer's ``train.dispatch`` — and not a second time here.
             compute_args = None
             if timing.recompiles:
                 compute_args = {"recompiles": timing.recompiles}

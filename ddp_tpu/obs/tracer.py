@@ -1,32 +1,36 @@
-"""In-process span tracer → Perfetto/Chrome ``trace_event`` JSON.
+"""In-process span tracer: an always-on ring, the profiler's trace, and
+Perfetto/Chrome ``trace_event`` JSON.
 
-``jax.profiler`` answers "which kernel is slow" but costs a heavyweight
-capture and says nothing about the *host* side — input wait, scheduler
-stalls, checkpoint flushes. This tracer is the complement: always-on-
-capable host-level spans with bounded memory (a ring of the last N
-events), thread-safe begin/end, and an export any Perfetto/
-``chrome://tracing`` instance loads directly.
+``jax.profiler`` answers "which kernel is slow" but says nothing about
+the *host* side — input wait, scheduler stalls, lock waits, checkpoint
+flushes. This tracer is the complement, at two levels:
 
-Design constraints, in priority order:
+1. **The ring, always on.** ``span()`` and ``complete()`` append
+   ``(name, t0, dur, parent, nums)`` to a bounded ring (a
+   ``deque(maxlen=ring_events)``) whether or not anything was switched
+   on: two ``perf_counter`` reads, one ``jax.profiler.TraceAnnotation``
+   (which the runtime drops while no profiler session is open) and one
+   ``deque.append``. No args dict, no lock beyond the deque's own, no
+   summaries, no export, and NEVER a device sync: a host span records
+   host time. It is what a benchmark's reader takes after the program
+   is gone (``get_tracer().ring()``) and what an operator finds after
+   a stall nobody planned to trace (the ``/statusz`` tail). While a
+   profiler session is open the same spans land in its ``.xplane.pb``
+   on the clock the device events use, so an idle gap on the device
+   can be put down to the span that covers it.
+2. **``enabled``** (``--trace_dir``, ``DDP_TPU_TRACE_DIR``) adds what
+   an export needs: the args dict, the thread id, a ``StatSummary``
+   per span name (capped at ``MAX_SUMMARY_NAMES``), instants, counter
+   tracks, the per-request async spans, and the crash-safe Perfetto
+   export (temp file + ``os.replace``; the launcher path registers an
+   atexit export so a watchdog abort still leaves a trace).
 
-1. **Disabled mode is free.** ``span()`` on a disabled tracer returns
-   one cached null context manager — the SAME object every call — and
-   ``instant()`` returns immediately. No jax import, no jit, no growing
-   allocation (pinned by tests/test_obs.py).
-2. **Enabled mode is bounded.** Events live in a ``deque(maxlen=
-   ring_events)``: a week-long serving process holds at most the ring.
-   Per-span-name duration summaries (utils/metrics.StatSummary) are
-   capped at ``MAX_SUMMARY_NAMES`` distinct names so a cardinality bug
-   upstream cannot grow memory either.
-3. **Export is crash-safe.** ``export()`` writes to a temp file in the
-   target directory and ``os.replace``s it — a crash mid-export leaves
-   the previous trace intact, never a half-written JSON. The launcher
-   path (``install_from_env``) additionally registers an atexit export
-   so a watchdog abort or uncaught exception still leaves a trace.
-
-Timestamps are Unix-epoch microseconds (``perf_counter`` deltas pinned
+``t0`` is a ``time.perf_counter`` stamp; ``parent`` is the ``t0`` of the
+span that caused this one (None at the top); ``nums`` is a small tuple
+of numbers whose meaning ``SPAN_NUMS`` gives per span name. Exported
+timestamps are Unix-epoch microseconds (``perf_counter`` deltas pinned
 to ``time.time`` at construction) so per-rank traces from different
-processes merge onto one comparable timeline (scripts/trace_merge.py).
+processes merge onto one timeline (scripts/trace_merge.py).
 """
 
 from __future__ import annotations
@@ -36,6 +40,10 @@ import os
 import threading
 import time
 from typing import Any, Optional
+
+# The profiler's own annotation: written into the ``.xplane.pb`` while a
+# session is open, dropped by the runtime otherwise.
+from jax.profiler import TraceAnnotation
 
 from ddp_tpu.utils.metrics import StatSummary
 
@@ -52,47 +60,82 @@ MAX_SUMMARY_NAMES = 256
 RANK_TRACE_FILENAME = "trace_rank{rank}.trace.json"
 
 
-class _NullSpan:
-    """The disabled-mode context manager: one shared immutable object."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+# What each span's ``nums`` hold, in order. The one table the program's
+# call sites, the Perfetto export and the benchmark's readers share.
+SPAN_NUMS: dict[str, tuple[str, ...]] = {
+    # serve/engine.py — children carry the ``t0`` of their serve.step
+    "serve.step": ("tokens", "active", "queue_depth"),
+    "serve.retire": ("finished",),
+    "serve.admit": ("admitted", "chunks"),
+    "serve.prefill_chunk": ("rid", "slot", "start", "width", "final"),
+    "serve.decode": ("lanes",),
+    "serve.spec_verify": ("lanes", "drafted", "accepted"),
+    "serve.sample": ("tokens",),
+    # serve/server.py — one event per request, at hand-back
+    "server.request": ("rid", "lock_wait_s", "pickup_s", "poll_wait_s"),
+    # data/loader.py, train/trainer.py
+    "data.next_batch": ("rows",),
+    "train.dispatch": (),
+}
+# Spans in which the host WAITS for the device (the blocking token
+# fetch): host time, but not host work.
+WAIT_SPANS = frozenset({"serve.sample"})
 
 
 class _Span:
-    """A live span: records duration on ``__exit__``."""
+    """A live span: lands in the ring (and the profiler's trace) on
+    ``__exit__``. ``t0`` is valid from ``__enter__`` on and is what a
+    child passes as ``parent``; ``nums`` may be set inside the body."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "parent", "nums", "t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer, name, args, parent, nums):
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._t0 = 0.0
+        self.parent = parent
+        self.nums = nums
+        self.t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        self._ann = ann = TraceAnnotation(self.name)
+        ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer._end_span(self.name, self._t0, self.args)
+        t0 = self.t0
+        dur = time.perf_counter() - t0
+        self._ann.__exit__(None, None, None)
+        tracer = self._tracer
+        tracer._ring.append((self.name, t0, dur, self.parent, self.nums))
+        if tracer.enabled:
+            tracer._record(
+                "X", self.name, t0, dur,
+                self.args or _nums_args(self.name, self.parent, self.nums),
+            )
         return False
+
+
+def _nums_args(name: str, parent, nums: tuple) -> Optional[dict]:
+    fields = SPAN_NUMS.get(name, ())
+    args = {
+        (fields[i] if i < len(fields) else f"n{i}"): v
+        for i, v in enumerate(nums)
+    }
+    if parent is not None:
+        args["parent_t0"] = parent
+    return args or None
 
 
 class Tracer:
     """Thread-safe bounded span/instant recorder.
 
-    ``enabled=False`` (the default) makes every method a constant-cost
-    no-op. ``process_id`` becomes the Chrome ``pid`` so merged
-    multi-rank traces show one track group per rank.
+    Spans always reach the ring. ``enabled=False`` (the default) makes
+    everything else — args, summaries, instants, counters, async
+    spans — a constant-cost no-op. ``process_id`` becomes the Chrome
+    ``pid`` so merged multi-rank traces show one track group per rank.
     """
 
     def __init__(
@@ -107,6 +150,9 @@ class Tracer:
         self.enabled = bool(enabled)
         self.process_id = int(process_id)
         self.ring_events = max(1, int(ring_events))
+        # The always-on level: (name, t0, dur, parent, nums).
+        self._ring: Any = deque(maxlen=self.ring_events)
+        # The enabled level: full Perfetto records.
         self._events: Any = deque(maxlen=self.ring_events)
         self._lock = threading.Lock()
         self._summaries: dict[str, StatSummary] = {}
@@ -117,13 +163,20 @@ class Tracer:
 
     # ---- recording --------------------------------------------------
 
-    def span(self, name: str, args: Optional[dict] = None):
-        """Context manager timing one span. ``args`` (a plain dict or
-        None — not kwargs, to keep the disabled path allocation-free)
-        lands in the event's Perfetto ``args`` pane."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args)
+    def span(
+        self,
+        name: str,
+        args: Optional[dict] = None,
+        *,
+        parent: Optional[float] = None,
+        nums: tuple = (),
+    ) -> _Span:
+        """Context manager timing one span, always recorded.
+        ``parent`` is the causing span's ``t0``; ``nums`` the numbers
+        ``SPAN_NUMS`` names. An exported event's Perfetto ``args`` pane
+        shows ``args`` (a plain dict) where given, else ``nums`` under
+        their names."""
+        return _Span(self, name, args, parent, nums)
 
     def instant(self, name: str, args: Optional[dict] = None) -> None:
         """A zero-duration marker event."""
@@ -147,14 +200,21 @@ class Tracer:
         start_perf: float,
         dur_s: float,
         args: Optional[dict] = None,
+        *,
+        parent: Optional[float] = None,
+        nums: tuple = (),
     ) -> None:
         """Record a span retroactively from stamps already in hand
-        (``start_perf`` from ``time.perf_counter``) — the attribution
-        path measures first and records after, so the recording cost
-        never sits inside the measured window."""
-        if not self.enabled:
-            return
-        self._record("X", name, start_perf, max(0.0, dur_s), args)
+        (``start_perf`` from ``time.perf_counter``): measure first,
+        record after. Reaches the ring like ``span()`` but not the
+        profiler's trace, which takes no event after the fact."""
+        dur_s = max(0.0, dur_s)
+        self._ring.append((name, start_perf, dur_s, parent, nums))
+        if self.enabled:
+            self._record(
+                "X", name, start_perf, dur_s,
+                args or _nums_args(name, parent, nums),
+            )
 
     def async_complete(
         self,
@@ -193,10 +253,6 @@ class Tracer:
             return
         self._record("n", name, t_perf, 0.0, args, aid=aid, cat=cat)
 
-    def _end_span(self, name: str, t0: float, args: Optional[dict]) -> None:
-        now = time.perf_counter()
-        self._record("X", name, t0, now - t0, args)
-
     def _record(
         self, ph: str, name: str, t0: float, dur_s: float,
         args: Optional[dict],
@@ -218,11 +274,31 @@ class Tracer:
 
     # ---- export -----------------------------------------------------
 
+    def ring(self) -> list[tuple]:
+        """The always-on spans, oldest first: ``(name, t0, dur, parent,
+        nums)`` with ``t0`` on ``time.perf_counter``."""
+        while True:
+            try:
+                return list(self._ring)
+            except RuntimeError:  # appended to while copied: again
+                continue
+
     def _event_dicts(self, limit: Optional[int] = None) -> list[dict]:
-        with self._lock:
-            raw = list(self._events)
+        if self.enabled:
+            with self._lock:
+                raw = list(self._events)
+        else:
+            raw = self.ring()
         if limit is not None:
             raw = raw[-limit:]
+        if not self.enabled:
+            # Nothing was switched on: the ring is the trace. No thread
+            # ids at this level; nums ride as args under their names.
+            raw = [
+                ("X", name, t0, dur, 0, _nums_args(name, parent, nums),
+                 None, None)
+                for name, t0, dur, parent, nums in raw
+            ]
         out = []
         for ph, name, t0, dur_s, tid, args, aid, cat in raw:
             ev: dict[str, Any] = {
@@ -314,14 +390,18 @@ _GLOBAL_LOCK = threading.Lock()
 
 
 def get_tracer() -> Tracer:
-    """The process-global tracer (disabled until someone installs one)."""
+    """The process-global tracer: what ``ServeEngine``, ``LMServer``,
+    ``ShardedLoader`` and ``Trainer`` record into unless handed
+    another. Its ring is always on; ``enabled`` only once someone
+    installs an enabled one."""
     return _GLOBAL
 
 
 def install_from_env(
     process_id: int = 0, *, register_atexit: bool = True
 ) -> Tracer:
-    """Enable the global tracer iff ``DDP_TPU_TRACE_DIR`` is set.
+    """Install an enabled global tracer iff ``DDP_TPU_TRACE_DIR`` is
+    set.
 
     Called by runtime/launch.py in every spawned child so worker
     functions get per-rank trace files without new plumbing. The

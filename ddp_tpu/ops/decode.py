@@ -218,6 +218,7 @@ def flash_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((S * H_kv, G, Dh), jnp.float32),
         interpret=interpret,
+        name="flash_decode",
     )(pos_rows, *args)
     return out.reshape(S, H, Dh)
 

@@ -346,6 +346,7 @@ def _flash_forward(
             _scratch((block_q, LANES)),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     out = out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(B, H, T).transpose(0, 2, 1)  # [B, T, H]
@@ -399,6 +400,7 @@ def _flash_backward(
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
+        name="flash_dq",
     )(qt, kt, vt, gt, lse_l, dl_l)
 
     # For dK/dV the K block is the OUTER streamed dim, Q the inner.
@@ -425,6 +427,7 @@ def _flash_backward(
         ],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
         interpret=interpret,
+        name="flash_dkv",
     )(kt, vt, qt, gt, lse_l, dl_l)
 
     back = lambda x, T_: x.reshape(B, H, T_, D).transpose(0, 2, 1, 3)

@@ -160,7 +160,8 @@ def make_per_shard_step(
             n_labels = images.shape[0]
         # THE all-reduce: the entire job of DDP's C++ reducer
         # (SURVEY.md §2b N4) is this one line. pmean = psum / world.
-        grads = lax.pmean(grads, axes)
+        with jax.named_scope("grad_allreduce"):
+            grads = lax.pmean(grads, axes)
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if health_inject is not None:
             grads = inject_nan(grads, state.step, health_inject)
@@ -171,8 +172,11 @@ def make_per_shard_step(
         new_ms = jax.tree.map(
             lambda v: lax.pmean(v.astype(jnp.float32), axes), new_ms
         )
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         metrics = StepMetrics(
             loss=lax.pmean(loss, axes),
             accuracy=lax.psum(correct, axes) / (n_labels * world),

@@ -76,7 +76,7 @@ from ddp_tpu.models.generate import slot_decode_step as _decode_step
 from ddp_tpu.models.generate import slot_verify_step as _verify_step
 from ddp_tpu.models.lm import LMSpec
 from ddp_tpu.ops.decode import DEFAULT_BLOCK_K
-from ddp_tpu.obs.tracer import Tracer
+from ddp_tpu.obs.tracer import Tracer, get_tracer
 from ddp_tpu.serve.pages import PrefixCache, page_demand
 from ddp_tpu.serve.scheduler import (
     Admission,
@@ -552,20 +552,22 @@ class ServeEngine:
         self.step_token_budget = knobs["step_token_budget"]
         self.clock = clock
         self.metrics = metrics or MetricsWriter(None)
-        # Span tracing (ddp_tpu.obs): chunk/decode device work plus the
-        # sampled-token retirement land on the host timeline; disabled
-        # by default and pinned free when off. When ENABLED, dispatches
-        # block until ready so spans cover device compute — measuring
-        # mode trades the pipeline overlap for span fidelity.
-        self.tracer = tracer or Tracer()
+        # Span tracing (ddp_tpu.obs): the step, its retire and admit
+        # parts, each chunk and decode DISPATCH and the blocking token
+        # fetch land in the tracer's always-on ring (and, while a
+        # profiler session is open, in its trace). Host spans record
+        # host time and never sync the device: device time is read
+        # from the profiler's trace by program name. The process-
+        # global tracer unless the caller hands one in, so a reader
+        # can reach the spans after the engine is gone.
+        self.tracer = tracer if tracer is not None else get_tracer()
         # Runtime sanitizer (--sanitize, runtime/sanitize.py): the
         # transfer guard arms around the steady-state DECODE dispatch
         # in step(), proving it does zero implicit host transfer. The
         # engine's two deliberate transfers — the chunk-argument
         # upload and the one-step-behind [S] int32 fetch — execute
         # OUTSIDE the guarded region (no allow() windows here, unlike
-        # the trainer). Disabled = a free nullcontext, like the
-        # tracer.
+        # the trainer). Disabled = a free nullcontext.
         from ddp_tpu.runtime.sanitize import Sanitizer
 
         self._sanitizer = Sanitizer(sanitize)
@@ -692,6 +694,16 @@ class ServeEngine:
         # Monotone token counter (the aggregator's tokens/s source —
         # per-request rate summaries are not additive across a fleet).
         self.tokens_emitted_total = 0
+        # Monotone count of requests accepted into the queue: what a
+        # load that hands requests over in order waits on.
+        self.accepted_total = 0
+        # What the frontend adds to a request's latency outside the
+        # engine's own clock (serve/server.py feeds both under its
+        # lock): the wait for that lock before ``submit``, and finish
+        # to the answer being picked up. ``ttft_s`` and ``queue_s``
+        # start only once the lock is won.
+        self.lock_wait = StatSummary()
+        self.pickup = StatSummary()
         # Recent retirement clock times (bounded): the queue-drain-rate
         # window behind ``queue_drain_eta_s`` — what a backpressure
         # 429's Retry-After is derived from, so a rejected client (or
@@ -713,14 +725,20 @@ class ServeEngine:
         # past 2·len(buckets) + 1 after warmup(). Fresh lambdas (not
         # bare function objects): jit tracing caches are shared per
         # function object, and the static-shape pin must be
-        # per-engine.
-        def _chunk_fn(lane_attend, chunk_spec):
+        # per-engine. Each gets a name of its own, which is how the
+        # profiler's trace tells the programs apart
+        # (``jit_serve_decode``, ``jit_serve_prefill_first``, ...).
+        def _named(name, fn):
+            fn.__name__ = fn.__qualname__ = name
+            return fn
+
+        def _chunk_fn(name, lane_attend, chunk_spec):
             return jax.jit(
-                lambda p, c, t, se, sp, tm, tp, s, ch, st, ln, fi, sd,
-                rtm, rtp: _prefill_chunk(
+                _named(name, lambda p, c, t, se, sp, tm, tp, s, ch, st,
+                       ln, fi, sd, rtm, rtp: _prefill_chunk(
                     chunk_spec, p, c, t, se, sp, tm, tp, s, ch, st, ln,
                     fi, sd, rtm, rtp, lane_attend=lane_attend,
-                ),
+                )),
                 donate_argnums=(1,),
             )
 
@@ -737,17 +755,20 @@ class ServeEngine:
         self._xprof = xprof if xprof is not None else Xprof(enabled=False)
         self._hbm = DeviceMemorySampler(enabled=self._xprof.enabled)
         self._chunk_first = self._xprof.instrument(
-            _chunk_fn(False, spec), "serve.prefill_first"
+            _chunk_fn("serve_prefill_first", False, spec),
+            "serve.prefill_first"
         )
         self._chunk_cont = self._xprof.instrument(
-            _chunk_fn(True, spec), "serve.prefill_chunk"
+            _chunk_fn("serve_prefill_chunk", True, spec),
+            "serve.prefill_chunk"
         )
         impl = self.decode_attn
         self._decode = self._xprof.instrument(
             jax.jit(
-                lambda p, c, t, sd, st, tm, tp: _decode_sample(
+                _named("serve_decode", lambda p, c, t, sd, st, tm, tp:
+                       _decode_sample(
                     spec, p, c, t, sd, st, tm, tp, attn_impl=impl
-                ),
+                )),
                 donate_argnums=(1,),
             ),
             # The label names the program actually built: recompile
@@ -794,20 +815,26 @@ class ServeEngine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), c
 
             self._draft_chunk_first = self._xprof.instrument(
-                _chunk_fn(False, dspec), "serve.draft_prefill_first"
+                _chunk_fn("serve_draft_prefill_first", False, dspec),
+                "serve.draft_prefill_first"
             )
             self._draft_chunk_cont = self._xprof.instrument(
-                _chunk_fn(True, dspec), "serve.draft_prefill_chunk"
+                _chunk_fn("serve_draft_prefill_chunk", True, dspec),
+                "serve.draft_prefill_chunk"
             )
             self._draft_decode = self._xprof.instrument(
-                jax.jit(_draft_propose, donate_argnums=(1,)),
+                jax.jit(
+                    _named("serve_draft_decode", _draft_propose),
+                    donate_argnums=(1,),
+                ),
                 "serve.draft_decode",
             )
             self._verify = self._xprof.instrument(
                 jax.jit(
-                    lambda p, c, t, dr, sd, st, tm, tp: _verify_step(
+                    _named("serve_spec_verify", lambda p, c, t, dr, sd,
+                           st, tm, tp: _verify_step(
                         spec, p, c, t, dr, sd, st, tm, tp
-                    ),
+                    )),
                     donate_argnums=(1,),
                 ),
                 "serve.spec_verify",
@@ -880,6 +907,7 @@ class ServeEngine:
                 queue_depth=self.scheduler.depth,
             )
             return adm
+        self.accepted_total += 1
         if hops:
             self._request_hops[adm.request.rid] = dict(hops)
         if self._reqtrace is not None:
@@ -1307,7 +1335,10 @@ class ServeEngine:
             "steps": self._steps,
             "completed": len(self._completed),
             "tokens_total": self.tokens_emitted_total,
+            "accepted_total": self.accepted_total,
             "ttft_s": self.ttft.snapshot(),
+            "lock_wait_s": self.lock_wait.snapshot(ndigits=6),
+            "pickup_s": self.pickup.snapshot(ndigits=6),
             "tpot_s": self.tpot.snapshot(ndigits=6),
             "queue_s": self.queue_wait.snapshot(ndigits=6),
             "decode_tokens_per_s": self.decode_rate.snapshot(),
@@ -1452,61 +1483,106 @@ class ServeEngine:
         retire the PREVIOUS step's [S] int32 token vector — the only
         steady-state device→host transfer — while the device computes
         what was just dispatched.
+
+        Spans (obs/tracer.py, always on): ``serve.step`` over all of
+        it, and as its children ``serve.retire`` (1-2), ``serve.admit``
+        (3 and the chunk plan), one ``serve.prefill_chunk`` per chunk
+        and ``serve.decode`` (the host's dispatch only) and
+        ``serve.sample`` (6, the wait for the device). None of them
+        syncs: tracing leaves the one-step-behind overlap as it is.
         """
+        with self.tracer.span("serve.step") as span:
+            produced = self._step(span.t0)
+            span.nums = (produced, self.active, self.scheduler.depth)
+        return produced
+
+    def _step(self, parent: float) -> int:
+        tracer = self.tracer
         now = self.clock()
         t_step = time.perf_counter()
-        traced = self.tracer.enabled
         evictions = 0
-        for slot in self._slots:
-            req = slot.request
-            if req is None:
-                continue
-            if slot.emitted >= req.max_new_tokens:
-                self._drain()  # the completion needs its token values
-                self._finish(slot, COMPLETE)
-            elif req.expired(now):
-                self._drain()
-                self._finish(slot, TIMEOUT_EVICTED)
+        with tracer.span("serve.retire", parent=parent) as span:
+            finished = 0
+            for slot in self._slots:
+                req = slot.request
+                if req is None:
+                    continue
+                if slot.emitted >= req.max_new_tokens:
+                    # the completion needs its token values
+                    self._drain(parent=span.t0)
+                    self._finish(slot, COMPLETE)
+                    finished += 1
+                elif req.expired(now):
+                    self._drain(parent=span.t0)
+                    self._finish(slot, TIMEOUT_EVICTED)
+                    evictions += 1
+            for req in self.scheduler.evict_expired():
+                now2 = self.clock()
+                c = Completion(
+                    rid=req.rid, status=TIMEOUT_QUEUE, prompt=req.prompt,
+                    tokens=[], ttft=None, decode_seconds=0.0,
+                    submitted=req.submitted, finished=now2,
+                )
+                self._completed[req.rid] = c
+                self._retire_trace(c)
+                self._record_request(c)
                 evictions += 1
-        for req in self.scheduler.evict_expired():
-            now2 = self.clock()
-            c = Completion(
-                rid=req.rid, status=TIMEOUT_QUEUE, prompt=req.prompt,
-                tokens=[], ttft=None, decode_seconds=0.0,
-                submitted=req.submitted, finished=now2,
-            )
-            self._completed[req.rid] = c
-            self._retire_trace(c)
-            self._record_request(c)
-            evictions += 1
+            span.nums = (finished + evictions,)
 
-        for slot in self._slots:
-            # Admission pause (hot-swap barrier): running lanes keep
-            # decoding above; queue heads stay queued until the swap
-            # commits or rolls back.
-            if self._admission_paused:
-                break
-            if not slot.free or self.scheduler.depth == 0:
-                continue
-            req = self.scheduler.next_request()
-            if req is None:
-                break
-            if self._admit_to_slot(slot, req) == "starved":
-                # Page-starved bind: _admit_to_slot put the FIFO head
-                # back at the queue front — stop admitting (later
-                # requests must not overtake it) and retry after
-                # retirements free pages.
-                break
+        with tracer.span("serve.admit", parent=parent) as span:
+            admitted = 0
+            for slot in self._slots:
+                # Admission pause (hot-swap barrier): running lanes
+                # keep decoding above; queue heads stay queued until
+                # the swap commits or rolls back.
+                if self._admission_paused:
+                    break
+                if not slot.free or self.scheduler.depth == 0:
+                    continue
+                req = self.scheduler.next_request()
+                if req is None:
+                    break
+                outcome = self._admit_to_slot(slot, req)
+                if outcome == "starved":
+                    # Page-starved bind: _admit_to_slot put the FIFO
+                    # head back at the queue front — stop admitting
+                    # (later requests must not overtake it) and retry
+                    # after retirements free pages.
+                    break
+                admitted += outcome == "bound"
 
-        # Paged mode: page-table mutations (binds above, retires at
-        # the top of this step) upload ONCE here, before any dispatch
-        # — one [S, lane_pages] int32 host→device copy per mutating
-        # step, nothing on the steady-state path.
-        if self.paged and self._table_dirty:
-            self._cache = self._cache._replace(
-                table=self._put(self._table_np)
+            # Paged mode: page-table mutations (binds above, retires
+            # at the top of this step) upload ONCE here, before any
+            # dispatch — one [S, lane_pages] int32 host→device copy
+            # per mutating step, nothing on the steady-state path.
+            if self.paged and self._table_dirty:
+                self._cache = self._cache._replace(
+                    table=self._put(self._table_np)
+                )
+                self._table_dirty = False
+
+            prefilling = [
+                (i, s.prefill_pos, len(s.request.prompt) - s.prefill_pos)
+                for i, s in enumerate(self._slots)
+                if s.prefilling
+            ]
+            # plan_chunks' FIFO contract is ADMISSION order, not
+            # slot-index order: under a tight budget the head gets
+            # full width and followers shrink/defer, so a newer
+            # request refilled into a lower-index lane must not starve
+            # an older one's prefill.
+            prefilling.sort(key=lambda t: self._slots[t[0]].request.rid)
+            decode_lanes = [
+                i for i, s in enumerate(self._slots) if s.decoding
+            ]
+            # Budget accounting: a decoding lane costs
+            # tokens_per_decode budget tokens this step — 1 on the
+            # plain path, γ under speculation (the verify program runs
+            # γ positions per lane).
+            plan = self.scheduler.plan_chunks(
+                prefilling, len(decode_lanes) * self._tokens_per_decode
             )
-            self._table_dirty = False
+            span.nums = (admitted, len(plan))
 
         # Everything below is device dispatch + the one-step-lagged
         # retirement; anything fetched in (6) was dispatched LAST step
@@ -1518,85 +1594,63 @@ class ServeEngine:
         w0 = self.clock()
         t_dispatch = time.perf_counter()
         device_work = False
-
-        prefilling = [
-            (i, s.prefill_pos, len(s.request.prompt) - s.prefill_pos)
-            for i, s in enumerate(self._slots)
-            if s.prefilling
-        ]
-        # plan_chunks' FIFO contract is ADMISSION order, not slot-index
-        # order: under a tight budget the head gets full width and
-        # followers shrink/defer, so a newer request refilled into a
-        # lower-index lane must not starve an older one's prefill.
-        prefilling.sort(key=lambda t: self._slots[t[0]].request.rid)
-        decode_lanes = [i for i, s in enumerate(self._slots) if s.decoding]
         chunk_tokens = 0
-        # Budget accounting: a decoding lane costs tokens_per_decode
-        # budget tokens this step — 1 on the plain path, γ under
-        # speculation (the verify program runs γ positions per lane).
-        for i, width in self.scheduler.plan_chunks(
-            prefilling, len(decode_lanes) * self._tokens_per_decode
-        ):
+        for i, width in plan:
             slot = self._slots[i]
             req = slot.request
             start = slot.prefill_pos
             live = min(width, len(req.prompt) - start)
             final = start + live == len(req.prompt)
-            buf = np.zeros((width,), np.int32)
-            buf[:live] = req.prompt[start : start + live]
-            # First chunk: self-contained causal attention (the chunk
-            # IS its own causal prefix at start == 0) — short prompts
-            # never pay a total_len-wide lane read. Continuations
-            # attend the full lane under the banded q_offset mask.
-            fn = self._chunk_first if start == 0 else self._chunk_cont
-            t0 = time.perf_counter()
-            slot_i, tok_buf = jnp.int32(i), jnp.asarray(buf)
-            start_t, live_t = jnp.int32(start), jnp.int32(live)
-            final_t = jnp.asarray(final)
-            (self._cache, self._toks, self._seeds, self._sample_steps,
-             self._temps, self._top_ps, first) = fn(
-                self.params, self._cache, self._toks, self._seeds,
-                self._sample_steps, self._temps, self._top_ps,
-                slot_i, tok_buf, start_t, live_t, final_t,
-                # Exact int32 seed (admission range-checks it): any
-                # masking here would break token-identity with
-                # generate(seed=...) for negative seeds.
-                jnp.int32(req.seed),
-                jnp.float32(req.temperature), jnp.float32(req.top_p),
-            )
-            if self.spec_tokens:
-                # The draft cache tracks the same token history: the
-                # same chunk ingests into its lane (never final — the
-                # request's first token is the TARGET's draw; the
-                # draft's dummy sampling state is never read).
-                dfn = (
-                    self._draft_chunk_first
-                    if start == 0
-                    else self._draft_chunk_cont
-                )
-                (self._draft_cache, self._d_toks, self._d_seeds,
-                 self._d_steps, self._d_temps, self._d_top_ps, _) = dfn(
-                    self.draft_params, self._draft_cache, self._d_toks,
-                    self._d_seeds, self._d_steps, self._d_temps,
-                    self._d_top_ps,
-                    slot_i, tok_buf, start_t, live_t, self._keep_pos,
+            with tracer.span(
+                "serve.prefill_chunk", parent=parent,
+                nums=(req.rid, i, start, width, int(final)),
+            ) as span:
+                buf = np.zeros((width,), np.int32)
+                buf[:live] = req.prompt[start : start + live]
+                # First chunk: self-contained causal attention (the chunk
+                # IS its own causal prefix at start == 0) — short prompts
+                # never pay a total_len-wide lane read. Continuations
+                # attend the full lane under the banded q_offset mask.
+                fn = self._chunk_first if start == 0 else self._chunk_cont
+                slot_i, tok_buf = jnp.int32(i), jnp.asarray(buf)
+                start_t, live_t = jnp.int32(start), jnp.int32(live)
+                final_t = jnp.asarray(final)
+                (self._cache, self._toks, self._seeds, self._sample_steps,
+                 self._temps, self._top_ps, first) = fn(
+                    self.params, self._cache, self._toks, self._seeds,
+                    self._sample_steps, self._temps, self._top_ps,
+                    slot_i, tok_buf, start_t, live_t, final_t,
+                    # Exact int32 seed (admission range-checks it): any
+                    # masking here would break token-identity with
+                    # generate(seed=...) for negative seeds.
                     jnp.int32(req.seed),
-                    jnp.float32(req.temperature),
-                    jnp.float32(req.top_p),
+                    jnp.float32(req.temperature), jnp.float32(req.top_p),
                 )
+                if self.spec_tokens:
+                    # The draft cache tracks the same token history: the
+                    # same chunk ingests into its lane (never final — the
+                    # request's first token is the TARGET's draw; the
+                    # draft's dummy sampling state is never read).
+                    dfn = (
+                        self._draft_chunk_first
+                        if start == 0
+                        else self._draft_chunk_cont
+                    )
+                    (self._draft_cache, self._d_toks, self._d_seeds,
+                     self._d_steps, self._d_temps, self._d_top_ps, _) = dfn(
+                        self.draft_params, self._draft_cache, self._d_toks,
+                        self._d_seeds, self._d_steps, self._d_temps,
+                        self._d_top_ps,
+                        slot_i, tok_buf, start_t, live_t, self._keep_pos,
+                        jnp.int32(req.seed),
+                        jnp.float32(req.temperature),
+                        jnp.float32(req.top_p),
+                    )
+            t0 = span.t0
+            chunk_dur = time.perf_counter() - t0
             device_work = True
             slot.prefill_pos = start + live
             chunk_tokens += live
-            if traced:
-                jax.block_until_ready(self._toks)
-            chunk_dur = time.perf_counter() - t0
-            self.tracer.complete(
-                "serve.prefill_chunk", t0, chunk_dur,
-                {"rid": req.rid, "slot": i, "start": start,
-                 "width": width, "final": final}
-                if traced
-                else None,
-            )
             if self._reqtrace is not None:
                 tr = self._reqtrace.get(req.rid)
                 if tr is not None:
@@ -1621,28 +1675,24 @@ class ServeEngine:
         # next step) would compute a full [S, total_len] decode and
         # throw the entire output away.
         if emit_lanes and self.spec_tokens:
-            produced += self._spec_round(emit_lanes, traced)
+            produced += self._spec_round(emit_lanes, parent)
             device_work = True
         elif emit_lanes:
-            t0 = time.perf_counter()
             # --sanitize: every steady-state decode input is already
             # device-resident, so the guard proves this dispatch does
             # ZERO implicit host transfer — the PR-3 invariant,
             # enforced instead of assumed. (Chunk dispatch above
             # legitimately uploads prompt content; the retire below
             # legitimately fetches [S] int32 — both deliberate.)
-            with self._sanitizer.guard():
+            with tracer.span(
+                "serve.decode", parent=parent, nums=(len(decode_lanes),),
+            ) as span, self._sanitizer.guard():
                 self._toks, self._cache, self._sample_steps = self._decode(
                     self.params, self._cache, self._toks, self._seeds,
                     self._sample_steps, self._temps, self._top_ps,
                 )
+            t0 = span.t0
             device_work = True
-            if traced:
-                jax.block_until_ready(self._toks)
-            self.tracer.complete(
-                "serve.decode", t0, time.perf_counter() - t0,
-                {"lanes": len(decode_lanes)} if traced else None,
-            )
             for i in emit_lanes:
                 self._slots[i].emitted += 1
                 if self._reqtrace is not None:
@@ -1657,7 +1707,7 @@ class ServeEngine:
 
         dispatch_s = time.perf_counter() - t_dispatch
         t_retire = time.perf_counter()
-        drained = self._drain(prev_pending)
+        drained = self._drain(prev_pending, parent=parent)
         retire_s = time.perf_counter() - t_retire
         if device_work or drained:
             self._productive_s += self.clock() - w0
@@ -1726,7 +1776,7 @@ class ServeEngine:
 
     # ---- internals --------------------------------------------------
 
-    def _spec_round(self, emit_lanes: list[int], traced: bool) -> int:
+    def _spec_round(self, emit_lanes: list[int], parent: float) -> int:
         """One speculative round: γ draft proposals + one batched
         verify → tokens emitted (1..γ per lane).
 
@@ -1741,66 +1791,62 @@ class ServeEngine:
         match counts, still never logits): spec mode trades the
         one-step retirement lag for up to γ tokens per big-model
         step. Runs fully under the --sanitize transfer guard up to
-        that deliberate fetch.
+        that deliberate fetch. ``serve.spec_verify`` spans the round,
+        that fetch included (its wait is the round's own).
         """
         gamma = self.spec_tokens
         # First-token scalars from THIS step's final chunks must land
         # before the verify tokens (slot.tokens is in stream order).
-        self._drain()
-        t0 = time.perf_counter()
-        with self._sanitizer.guard():
-            t = self._toks
-            pos0 = self._cache.pos
-            drafts = []
-            for j in range(gamma):
-                t, self._draft_cache = self._draft_decode(
-                    self.draft_params, self._draft_cache, t, pos0,
-                    self._sync_pos if j == 0 else self._keep_pos,
-                )
-                drafts.append(t)
-            (self._toks, self._cache, self._sample_steps, target,
-             matched) = self._verify(
-                self.params, self._cache, self._toks,
-                jnp.stack(drafts, axis=1),
-                self._seeds, self._sample_steps, self._temps,
-                self._top_ps,
-            )
-        t_np = np.asarray(target)  # [S, γ] int32
-        m_np = np.asarray(matched)  # [S] int32
-        round_dur = time.perf_counter() - t0
-        produced = 0
-        drafted = accepted = 0
-        for i in emit_lanes:
-            slot = self._slots[i]
-            m = int(m_np[i])
-            n = min(
-                m + 1, gamma,
-                slot.request.max_new_tokens - slot.emitted,
-            )
-            slot.tokens.extend(int(x) for x in t_np[i, :n])
-            slot.emitted += n
-            produced += n
-            drafted += gamma
-            accepted += m
-            slot.spec_drafted += gamma
-            slot.spec_accepted += m
-            if self._reqtrace is not None:
-                tr = self._reqtrace.get(slot.request.rid)
-                if tr is not None:
-                    tr.spec_round(
-                        t0, round_dur, drafted=gamma, accepted=m,
-                        emitted=n,
+        self._drain(parent=parent)
+        with self.tracer.span("serve.spec_verify", parent=parent) as span:
+            t0 = span.t0
+            with self._sanitizer.guard():
+                t = self._toks
+                pos0 = self._cache.pos
+                drafts = []
+                for j in range(gamma):
+                    t, self._draft_cache = self._draft_decode(
+                        self.draft_params, self._draft_cache, t, pos0,
+                        self._sync_pos if j == 0 else self._keep_pos,
                     )
-        self.spec_drafted_total += drafted
-        self.spec_accepted_total += accepted
-        self._step_spec = (drafted, accepted)
-        self.tracer.complete(
-            "serve.spec_verify", t0, round_dur,
-            {"lanes": len(emit_lanes), "drafted": drafted,
-             "accepted": accepted}
-            if traced
-            else None,
-        )
+                    drafts.append(t)
+                (self._toks, self._cache, self._sample_steps, target,
+                 matched) = self._verify(
+                    self.params, self._cache, self._toks,
+                    jnp.stack(drafts, axis=1),
+                    self._seeds, self._sample_steps, self._temps,
+                    self._top_ps,
+                )
+            t_np = np.asarray(target)  # [S, γ] int32
+            m_np = np.asarray(matched)  # [S] int32
+            round_dur = time.perf_counter() - t0
+            produced = 0
+            drafted = accepted = 0
+            for i in emit_lanes:
+                slot = self._slots[i]
+                m = int(m_np[i])
+                n = min(
+                    m + 1, gamma,
+                    slot.request.max_new_tokens - slot.emitted,
+                )
+                slot.tokens.extend(int(x) for x in t_np[i, :n])
+                slot.emitted += n
+                produced += n
+                drafted += gamma
+                accepted += m
+                slot.spec_drafted += gamma
+                slot.spec_accepted += m
+                if self._reqtrace is not None:
+                    tr = self._reqtrace.get(slot.request.rid)
+                    if tr is not None:
+                        tr.spec_round(
+                            t0, round_dur, drafted=gamma, accepted=m,
+                            emitted=n,
+                        )
+            self.spec_drafted_total += drafted
+            self.spec_accepted_total += accepted
+            self._step_spec = (drafted, accepted)
+            span.nums = (len(emit_lanes), drafted, accepted)
         return produced
 
     def _admit_to_slot(self, slot: _Slot, req: Request) -> str:
@@ -1895,7 +1941,10 @@ class ServeEngine:
         # upload here.
         return "bound"
 
-    def _drain(self, items: Optional[list] = None) -> int:
+    def _drain(
+        self, items: Optional[list] = None, *,
+        parent: Optional[float] = None,
+    ) -> int:
         """Fetch dispatched-but-unread token values → tokens appended.
 
         The steady-state host sync: each item is either the previous
@@ -1903,35 +1952,33 @@ class ServeEngine:
         scalar — never logits. Called with the previous step's items
         after this step's dispatches (the fetch overlaps the device
         computing the new work), and with everything outstanding when
-        a retirement needs its values now.
+        a retirement needs its values now. The one place the engine
+        waits for the device: ``serve.sample`` is a WAIT span
+        (obs/tracer.WAIT_SPANS), host time that is not host work.
         """
         if items is None:
             items = self._pending
             self._pending = []
         if not items:
             return 0
-        traced = self.tracer.enabled
-        t0 = time.perf_counter()
-        appended = 0
-        for kind, arr, meta in items:
-            vals = np.asarray(arr)
-            if kind == "first":
-                slot = self._slots[meta]
-                slot.tokens.append(int(vals))
-                appended += 1
-                slot.first_token_at = self.clock()
-                if slot.request is not None:
-                    self.ttft.add(
-                        slot.first_token_at - slot.request.submitted
-                    )
-            else:
-                for i in meta:
-                    self._slots[i].tokens.append(int(vals[i]))
+        with self.tracer.span("serve.sample", parent=parent) as span:
+            appended = 0
+            for kind, arr, meta in items:
+                vals = np.asarray(arr)
+                if kind == "first":
+                    slot = self._slots[meta]
+                    slot.tokens.append(int(vals))
                     appended += 1
-        self.tracer.complete(
-            "serve.sample", t0, time.perf_counter() - t0,
-            {"tokens": appended} if traced else None,
-        )
+                    slot.first_token_at = self.clock()
+                    if slot.request is not None:
+                        self.ttft.add(
+                            slot.first_token_at - slot.request.submitted
+                        )
+                else:
+                    for i in meta:
+                        self._slots[i].tokens.append(int(vals[i]))
+                        appended += 1
+            span.nums = (appended,)
         return appended
 
     def _finish(self, slot: _Slot, status: str) -> None:
@@ -2037,10 +2084,9 @@ class ServeEngine:
     def emit_request_spans(self) -> int:
         """Retroactively emit retired request traces into the span
         tracer (→ count). The bench path: its timed window runs with
-        the tracer's measuring mode off (span fidelity would destroy
-        the dispatch/retire overlap being measured) and exports the
-        request spans afterwards — stamps were recorded live, so the
-        exported timeline is the measured one."""
+        the tracer's ``enabled`` level off and exports the request
+        spans afterwards — stamps were recorded live, so the exported
+        timeline is the measured one."""
         if self._reqtrace is None:
             return 0
         return self._reqtrace.emit_all(self.tracer)

@@ -1,9 +1,12 @@
 """Stdlib-HTTP frontend for the serve engine.
 
 One background thread drives ``ServeEngine.step()`` whenever work is
-pending; HTTP handler threads only touch the engine under the same
+pending; HTTP handler threads only change the engine under the same
 lock (the engine is deliberately single-threaded — slots and cache are
-one device program's state). No web framework: ``http.server`` is in
+one device program's state). ``/stats`` and ``/metricsz`` only READ
+plain host-side counters and summaries and do so without that lock:
+the loop holds it for every whole step, and a scrape must not wait
+behind the decode loop. No web framework: ``http.server`` is in
 every container this repo targets, and the API is three routes:
 
   POST /generate   {"prompt_tokens": [...], "max_new_tokens": N,
@@ -102,6 +105,10 @@ class LMServer:
         # every surface byte-identical to the pre-disagg server.
         self.role = role
         self.engine = engine
+        # The engine's tracer (the process-global one unless the engine
+        # was handed another): ``server.request`` lands beside the
+        # engine's own spans.
+        self.tracer = engine.tracer
         # Multi-model serving (lifecycle tentpole): extra NAMED
         # engines, each with its own scheduler/slots/pages — per-model
         # accounting by construction. ``model=`` in a /generate body
@@ -271,7 +278,14 @@ class LMServer:
                 "error": "draining",
                 "retry_after_s": self.drain_retry_after,
             }
+        # The engine loop holds the lock for every whole step and takes
+        # it again at once, so this wait can be long, and it is in none
+        # of the engine's own numbers (``ttft_s`` and ``queue_s`` start
+        # at ``submit``). Each request sums its own waits and reports
+        # them once, at hand-back, as ``server.request``.
+        t_call = time.perf_counter()
         with self._lock:
+            lock_wait_s = time.perf_counter() - t_call
             adm = engine.submit(
                 prompt,
                 max_new,
@@ -307,10 +321,25 @@ class LMServer:
                 }
             return 400, {"error": adm.reason}
         rid = adm.request.rid
+        poll_wait_s = 0.0
         while True:
+            t_poll = time.perf_counter()
             with self._lock:
+                poll_wait_s += time.perf_counter() - t_poll
                 done = engine.pop_result(rid)
+                if done is not None:
+                    # Finish (the engine's clock) to the answer in this
+                    # thread's hand. Fed under the lock: StatSummary is
+                    # not thread-safe.
+                    pickup_s = max(0.0, engine.clock() - done.finished)
+                    engine.lock_wait.add(lock_wait_s)
+                    engine.pickup.add(pickup_s)
             if done is not None:
+                self.tracer.complete(
+                    "server.request", t_call,
+                    time.perf_counter() - t_call,
+                    nums=(rid, lock_wait_s, pickup_s, poll_wait_s),
+                )
                 break
             if self._engine_error is not None:
                 return 500, {"error": f"engine failed: {self._engine_error}"}
@@ -584,19 +613,17 @@ class LMServer:
                     ),
                 }
         if route == "/stats":
-            with self._lock:
-                return self.engine.stats(include_ledger=True)
+            return self._stats_unlocked(include_ledger=True)
         if route == "/metricsz":
-            # Prometheus text, not JSON: rendered under the engine
-            # lock from the same stats() snapshot /stats serves.
+            # Prometheus text, not JSON, from the same stats()
+            # snapshot /stats serves.
             from ddp_tpu.obs.promtext import render_serve
 
-            with self._lock:
-                return render_serve(
-                    self.engine.stats(),
-                    up=self._engine_error is None,
-                    draining=self.draining,
-                )
+            return render_serve(
+                self._stats_unlocked(),
+                up=self._engine_error is None,
+                draining=self.draining,
+            )
         if route == "/statusz":
             # Live observability snapshot (ddp_tpu.obs): operational
             # stats + goodput (inside engine.stats()) plus the tail of
@@ -625,9 +652,25 @@ class LMServer:
                         if self.models
                         else {}
                     ),
-                    "trace": self.engine.tracer.snapshot(limit=512),
+                    "trace": self.tracer.snapshot(limit=512),
                 }
         return None
+
+    def _stats_unlocked(self, **kw) -> dict:
+        """``engine.stats()`` WITHOUT the lock the engine loop holds
+        for every whole step (a locked read has waited 13 s on the
+        chip). Reads only: counters, gauges and copies of bounded
+        summaries, each a single atomic read under the interpreter
+        lock, so a snapshot may straddle a step (one field a step
+        newer than another) but never blocks or corrupts. A container
+        that changes size mid-copy raises RuntimeError: read again."""
+        for _ in range(8):
+            try:
+                return self.engine.stats(**kw)
+            except RuntimeError:
+                continue
+        with self._lock:
+            return self.engine.stats(**kw)
 
     # ---- disaggregated serving: the /pages transfer plane (PR 16) ---
 

@@ -52,12 +52,13 @@ def device_put_replicated(array, mesh: Mesh, tracer=None):
 
     ``tracer`` (ddp_tpu.obs) spans the staging: for large datasets
     this host→HBM copy is the fast path's one up-front cost, and it
-    belongs on the same timeline as the epochs it amortizes into.
+    belongs on the same timeline as the epochs it amortizes into. The
+    span is the host's share (the enqueue): tracing never syncs.
     """
-    from ddp_tpu.obs.tracer import Tracer
+    from ddp_tpu.obs.tracer import get_tracer
 
     rep = NamedSharding(mesh, P())
-    with (tracer or Tracer()).span(
+    with (tracer or get_tracer()).span(
         "fast.stage_dataset", {"bytes": int(array.nbytes)}
     ):
         if jax.process_count() == 1:
@@ -68,10 +69,6 @@ def device_put_replicated(array, mesh: Mesh, tracer=None):
             staged = jax.make_array_from_process_local_data(
                 rep, np.asarray(array)
             )
-        if tracer is not None and tracer.enabled:
-            # Only when measuring: the span must cover the copy, not
-            # just its enqueue. Untraced staging stays async.
-            jax.block_until_ready(staged)
         return staged
 
 

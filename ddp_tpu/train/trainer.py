@@ -44,7 +44,7 @@ from ddp_tpu.obs.health import (
 from ddp_tpu.obs.recorder import FlightRecorder, snapshot_env
 from ddp_tpu.obs.sentry import AnomalySentry, SentryConfig
 from ddp_tpu.obs.steptime import StepAttributor, dispatch_compute_split
-from ddp_tpu.obs.tracer import Tracer
+from ddp_tpu.obs.tracer import Tracer, get_tracer
 from ddp_tpu.obs.xprof import DeviceMemorySampler, Xprof
 from ddp_tpu.parallel.ddp import (
     create_train_state,
@@ -142,14 +142,21 @@ class Trainer:
             emulate_devices=config.emulate_devices,
         )
         setup_logging(self.ctx.process_id)
-        # Observability (ddp_tpu.obs), constructed first so dataset
-        # staging and step-builder work below can be spanned: tracer +
-        # per-step attribution, both gated on --trace_dir (disabled
-        # mode is pinned free by tests/test_obs.py).
-        self.tracer = Tracer(
-            enabled=bool(config.trace_dir),
-            ring_events=config.trace_ring_events,
-            process_id=self.ctx.process_id,
+        # Observability (ddp_tpu.obs), set up first so dataset staging
+        # and step-builder work below can be spanned. The tracer is the
+        # process-global one, whose ring is always on (the loader's
+        # ``data.next_batch`` and the step's ``train.dispatch`` land
+        # there in every run); --trace_dir swaps in an ENABLED one of
+        # this trainer's own (args, summaries, the Perfetto export)
+        # and gates the per-step attribution.
+        self.tracer = (
+            Tracer(
+                enabled=True,
+                ring_events=config.trace_ring_events,
+                process_id=self.ctx.process_id,
+            )
+            if config.trace_dir
+            else get_tracer()
         )
         # Compiled-program introspection (--xprof, obs/xprof.py): the
         # hot-path jit programs are instrumented below (per family, at
@@ -849,6 +856,7 @@ class Trainer:
             num_workers=0
             if (config.fast_epoch or self.seq_mode or self.pipe_lm_mode)
             else config.num_workers,
+            tracer=self.tracer,
         )
 
         compute_dtype = jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
@@ -1341,6 +1349,16 @@ class Trainer:
                 self.eval_step = self._xprof.instrument(
                     self.eval_step, "eval_step"
                 )
+        # ``train.dispatch``: the host's share of one step — the call
+        # of the jitted step, whatever family built it. Wrapped once,
+        # here, where ``train_step`` is final; never a sync.
+        step, tracer = self.train_step, self.tracer
+
+        def dispatch(*args, **kwargs):
+            with tracer.span("train.dispatch"):
+                return step(*args, **kwargs)
+
+        self.train_step = dispatch
         self.fast_runner = None
         if config.fast_epoch:
             if not (self.lm_mode or self.pipe_mode) and (

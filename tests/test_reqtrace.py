@@ -323,17 +323,22 @@ class TestDisabledPin:
 
 
 class TestTransferInvariant:
+    @pytest.mark.parametrize("enabled", [True, False])
     def test_token_identity_and_spy_with_tracing_enabled(
-        self, params, monkeypatch
+        self, params, monkeypatch, enabled
     ):
         """The ISSUE-11 re-pin: with request tracing AND the span
         tracer AND --sanitize all on, the engine still produces
         token-identical output to generate() and the steady-state
         fetches stay ()/[S] int32 — request events are stamped only
-        at existing host-touch points."""
+        at existing host-touch points. And the ISSUE-24 one: tracing
+        never synchronises. Whether the tracer is ``enabled`` or only
+        its always-on ring runs, a steady-state step calls
+        ``block_until_ready`` zero times (the old "measuring mode"
+        called it after every dispatch)."""
         import ddp_tpu.serve.engine as engine_mod
 
-        tracer = Tracer(enabled=True)
+        tracer = Tracer(enabled=enabled)
         eng = ServeEngine(
             SPEC, params, slots=2, prefill_len=8, tracer=tracer,
             reqtrace=True, trace_seed=7, sanitize=True,
@@ -356,10 +361,17 @@ class TestTransferInvariant:
             def __getattr__(self, name):
                 return getattr(real_np, name)
 
+        syncs = []
         monkeypatch.setattr(engine_mod, "np", _NpSpy())
+        monkeypatch.setattr(
+            jax, "block_until_ready", lambda x: syncs.append(x) or x
+        )
         for _ in range(4):
             eng.step()
         monkeypatch.undo()
+        assert not syncs, f"tracing added {len(syncs)} device syncs"
+        steps = [e for e in tracer.ring() if e[0] == "serve.step"]
+        assert len(steps) == 3 + 4  # spanned, enabled or not
         assert fetched and all(
             shape == () or shape == (eng.num_slots,) for shape in fetched
         ), f"tracing-enabled steady state fetched: {fetched}"
