@@ -653,21 +653,15 @@ def _full_kv(cache, layer: int):
     The verify step's key/value source (decode steps go through
     ops/decode instead, where the flash path avoids materializing
     this)."""
-    kf, vf = cache.k[layer], cache.v[layer]
+    views = [cache.k[layer], cache.v[layer], *_scales(cache, layer)]
     if isinstance(cache, PagedSlotCache):
-        kf = gather_paged_kv(kf, cache.table)
-        vf = gather_paged_kv(vf, cache.table)
-        if cache.quantized():
-            kf = dequantize_kv(
-                kf, gather_paged_kv(cache.k_scale[layer], cache.table)
-            )
-            vf = dequantize_kv(
-                vf, gather_paged_kv(cache.v_scale[layer], cache.table)
-            )
-        return kf, vf
+        views = [
+            x if x is None else gather_paged_kv(x, cache.table)
+            for x in views
+        ]
+    kf, vf, ksc, vsc = views
     if cache.quantized():
-        kf = dequantize_kv(kf, cache.k_scale[layer])
-        vf = dequantize_kv(vf, cache.v_scale[layer])
+        kf, vf = dequantize_kv(kf, ksc), dequantize_kv(vf, vsc)
     return kf, vf
 
 
@@ -679,7 +673,12 @@ def _write_kv_rows(cache, layer: int, k, v, pos):
     ``pos[s]..pos[s]+T-1``. On a quantized cache the rows quantize on
     write (ops/decode.quantize_kv — int8 rows + per-head scales), so
     the cache never holds full-precision lines. Returns the updated
-    cache. The vmapped ``dynamic_update_slice`` clamps per lane, so
+    cache. One scatter of the S·T rows straight into the (donated)
+    ``[depth, S, L, H_kv, Dh]`` buffer — the step moves the new rows
+    and nothing that grows with the cache; a layer is never sliced
+    out and written back. The write start clamps per lane to
+    ``[0, L - T]`` (``dynamic_update_slice``'s rule, kept): an idle
+    lane parked at ``pos == total_len`` lands on the last line, and
     callers must pre-clamp ``pos`` when T > 1 (a clamp-shift would
     silently move the write over live lines). Paged caches take the
     scatter-through-the-table twin instead (same rows, same
@@ -687,34 +686,37 @@ def _write_kv_rows(cache, layer: int, k, v, pos):
     """
     if isinstance(cache, PagedSlotCache):
         return _paged_write_rows(cache, layer, k, v, pos)
-    write = jax.vmap(
-        lambda lane, row, p: lax.dynamic_update_slice(
-            lane, row, (p, 0, 0)
+    S, T = k.shape[:2]
+    lanes = jnp.arange(S, dtype=jnp.int32)[:, None]
+    posns = (
+        jnp.clip(pos, 0, cache.k.shape[2] - T)[:, None]
+        + jnp.arange(T, dtype=jnp.int32)[None, :]
+    )  # [S, T]: row-major over (lane, position), so sorted and unique
+
+    def write(buf, rows):
+        return buf.at[layer, lanes, posns].set(
+            rows.astype(buf.dtype),
+            indices_are_sorted=True, unique_indices=True,
         )
-    )  # ([S, L, H_kv, Dh], [S, T, H_kv, Dh], [S]) → written lanes
-    ck, cv, ksc, vsc = cache.k, cache.v, cache.k_scale, cache.v_scale
+
+    ksc, vsc = cache.k_scale, cache.v_scale
     if cache.quantized():
-        write_sc = jax.vmap(
-            lambda lane, row, p: lax.dynamic_update_slice(
-                lane, row, (p, 0)
-            )
-        )  # ([S, L, H_kv], [S, T, H_kv], [S])
-        qk, k_s = quantize_kv(k)
-        qv, v_s = quantize_kv(v)
-        ck = ck.at[layer].set(write(ck[layer], qk, pos))
-        cv = cv.at[layer].set(write(cv[layer], qv, pos))
-        ksc = ksc.at[layer].set(write_sc(ksc[layer], k_s, pos))
-        vsc = vsc.at[layer].set(write_sc(vsc[layer], v_s, pos))
-    else:
-        ck = ck.at[layer].set(write(ck[layer], k.astype(ck.dtype), pos))
-        cv = cv.at[layer].set(write(cv[layer], v.astype(cv.dtype), pos))
-    return cache._replace(k=ck, v=cv, k_scale=ksc, v_scale=vsc)
+        k, k_s = quantize_kv(k)
+        v, v_s = quantize_kv(v)
+        ksc, vsc = write(ksc, k_s), write(vsc, v_s)
+    return cache._replace(
+        k=write(cache.k, k), v=write(cache.v, v), k_scale=ksc, v_scale=vsc
+    )
 
 
-def _lane_scales(cache: SlotCache, layer: int):
-    if cache.quantized():
-        return cache.k_scale[layer], cache.v_scale[layer]
-    return None, None
+def _scales(cache, layer: int | None = None):
+    """The int8 scale planes (one layer's when ``layer`` is given);
+    ``(None, None)`` on a float cache."""
+    if not cache.quantized():
+        return None, None
+    if layer is None:
+        return cache.k_scale, cache.v_scale
+    return cache.k_scale[layer], cache.v_scale[layer]
 
 
 def slot_decode_step(
@@ -730,15 +732,20 @@ def slot_decode_step(
     ``tokens``: [S] int32, slot s's token written at ``cache.pos[s]``.
     Numerics per lane are identical to ``decode_step`` (same einsums,
     same mask rule ``key_pos <= pos``) — only the position bookkeeping
-    is vectorized: the K/V write is a vmapped ``dynamic_update_slice``
-    over the slot dim (a scatter of S rows, not a full-cache rewrite),
-    the position embedding a per-slot gather. Idle slots are decoded
-    too (the batch shape never changes); their outputs are garbage the
+    is vectorized: the K/V write is one scatter of S rows into the
+    donated cache (``_write_kv_rows``), the position embedding a
+    per-slot gather. Per layer the step moves S new rows and, on the
+    flash path, each lane's live K/V blocks once, read by the kernel
+    in the stored layout — no slice, transpose or write-back of a
+    layer's lanes (pinned by tests/test_flash_decode.py). Idle slots
+    are decoded too (the batch shape never changes); their outputs are
+    garbage the
     engine ignores, but never NaN — position 0 is always live, so the
     softmax normalizes over at least one (zero) logit. ``pos`` is
     clamped at ``total_len`` so an idle slot can sit in the batch
     indefinitely without indexing past the cache (writes at the clamp
-    land on the last line, which a refill overwrites).
+    land on the last line, which a refill overwrites); once there it
+    attends key 0 alone, so a parked lane reads one block a layer.
 
     ``attn_impl`` (Python-static — the engine compiles its choice in)
     picks the banded single-query attention: ``reference`` is the
@@ -759,11 +766,18 @@ def slot_decode_step(
     x = x + pe[jnp.minimum(pos, spec.total_len - 1)][:, None, :].astype(
         x.dtype
     )
+    # A lane at the position ceiling has no reader: a live lane's last
+    # decode writes line total_len - 2 at the latest (admission keeps
+    # prompt + max_new_tokens inside the lane), so only an idle lane
+    # that has drifted there (it keeps decoding, and its position keeps
+    # rising, clamped) sits at total_len. It attends key 0 alone — on
+    # the flash path an idle lane then costs one block a layer instead
+    # of its whole lane, every step, for as long as it idles.
+    attend = jnp.where(pos >= spec.total_len, 0, pos)
     for i in range(spec.depth):
         p = params[f"block{i + 1}"]
         q, k, v = _block_qkv(p, x, H, Dh, H_kv)
         cache = _write_kv_rows(cache, i, k, v, pos)
-        ksc, vsc = _lane_scales(cache, i)
         if isinstance(cache, PagedSlotCache):
             # Same banded math over the table's gathered view
             # (ops/decode.paged_decode_attention) — scratch/stale
@@ -771,13 +785,16 @@ def slot_decode_step(
             # step is token-identical to the fixed-lane one (pinned
             # by tests/test_paged.py).
             attn = paged_decode_attention(
-                q[:, 0], cache.k[i], cache.v[i], cache.table, pos,
-                ksc, vsc, impl=attn_impl,
+                q[:, 0], cache.k[i], cache.v[i], cache.table, attend,
+                *_scales(cache, i), impl=attn_impl,
             )  # [S, H, Dh] fp32
         else:
+            # The whole stored cache and a layer index: the flash
+            # kernel's index maps pick the layer's live blocks; only
+            # the reference path slices ``cache.k[i]``.
             attn = decode_attention(
-                q[:, 0], cache.k[i], cache.v[i], pos, ksc, vsc,
-                impl=attn_impl,
+                q[:, 0], cache.k, cache.v, attend, *_scales(cache),
+                impl=attn_impl, layer=i,
             )  # [S, H, Dh] fp32
         attn = attn.reshape(S, 1, spec.d_model).astype(x.dtype)
         x = _block_finish(spec, p, x, attn)
@@ -1025,10 +1042,10 @@ def slot_verify_step(
     Output equivalence to the non-speculative stream is exact for
     greedy AND seeded sampling (tests/test_spec_decode.py).
 
-    The write start is pre-clamped at ``total_len - K`` (the vmapped
-    ``dynamic_update_slice`` would clamp-shift over live lines
-    otherwise): the engine reserves K-1 positions at admission so a
-    LIVE lane never triggers the clamp — it only guards idle lanes
+    The write start is pre-clamped at ``total_len - K`` (the row write
+    clamps its start to keep K rows in the lane, and would shift the
+    write over live lines otherwise): the engine reserves K-1
+    positions at admission so a LIVE lane never triggers the clamp — it only guards idle lanes
     parked at the position ceiling.
     """
     embed = params["embed"]

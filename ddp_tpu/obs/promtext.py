@@ -344,6 +344,21 @@ def render_serve(
         metric_type="counter",
         help="requests accepted into the queue",
     )
+    b.add(
+        "ddp_tpu_serve_kv_rows_attended_total",
+        stats.get("kv_rows_attended_total"),
+        metric_type="counter",
+        help="cache rows the decoding lanes attended (pos + 1 a lane "
+        "a decode step)",
+    )
+    b.add(
+        "ddp_tpu_serve_kv_rows_lane_total",
+        stats.get("kv_rows_lane_total"),
+        metric_type="counter",
+        help="cache rows the lanes hold (slots x total_len a decode "
+        "step): attended over this is the share of lane bytes the "
+        "banded read fetches",
+    )
     b.summary(
         "ddp_tpu_serve_ttft_seconds", stats.get("ttft_s"),
         help="submit to first token",

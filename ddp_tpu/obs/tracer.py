@@ -68,7 +68,7 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "serve.retire": ("finished",),
     "serve.admit": ("admitted", "chunks"),
     "serve.prefill_chunk": ("rid", "slot", "start", "width", "final"),
-    "serve.decode": ("lanes",),
+    "serve.decode": ("lanes", "rows_attended"),
     "serve.spec_verify": ("lanes", "drafted", "accepted"),
     "serve.sample": ("tokens",),
     # serve/server.py — one event per request, at hand-back

@@ -13,21 +13,27 @@ sibling:
   out so the kernel has a bit-identical baseline to pin against and
   non-TPU platforms keep the PR-3 numerics unchanged.
 - :func:`flash_decode_attention` — a Pallas TPU kernel on a
-  ``(S·H_kv, L/block_k)`` grid: each grid row owns one (slot, kv-head)
-  pair's G grouped queries, KV blocks stream through VMEM under the
-  online-softmax recurrence (fp32 scratch persisting across the
-  innermost grid dim, flushed on its last iteration — the
-  ``ops/flash.py`` scheme), and the **banded read honors per-slot
-  positions**: key columns past ``pos[s]`` are masked, and whole
-  blocks that start past ``pos[s]`` are ``pl.when``-skipped, so a
-  young lane in a long cache pays O(pos) compute, not O(total_len).
-  No [T, S]-style score tensor ever exists; per-step HBM traffic is
-  the K/V lanes once.
+  ``(S, L/block_k)`` grid that reads the cache IN ITS STORED LAYOUT
+  ``[depth, S, L, H_kv, Dh]``: the index maps pick the layer and the
+  lane, a grid step streams ``block_k`` rows of all kv heads of one
+  lane (``[block_k, H_kv, Dh]``, 1 MB of K and of V at block_k 128 x
+  16 heads x 128 fp32) through VMEM under the online-softmax
+  recurrence (fp32 scratch persisting across the innermost grid dim,
+  flushed on its last iteration — the ``ops/flash.py`` scheme). No
+  slice, transpose or copy of a layer's lanes surrounds the call. The
+  **banded read honors per-slot positions**: key rows past ``pos[s]``
+  are masked; blocks that start past ``pos[s]`` are neither fetched
+  (:func:`live_block` clamps the block index with the scalar-prefetched
+  position, and Pallas issues no DMA for a repeated index) nor
+  computed (``pl.when``), so a young lane in a long cache pays O(pos)
+  HBM traffic and compute, not O(total_len). No [T, S]-style score
+  tensor ever exists; per-step HBM traffic of the attention is each
+  lane's live K/V blocks, once.
 - **int8 KV dequantize-in-kernel**: when the cache stores int8 K/V
   with per-(position, head) scales (:func:`quantize_kv`), both paths
   dequantize at the compute site — the kernel widens int8 blocks in
-  VMEM, so HBM reads stay half-width (the whole point of quantizing:
-  decode is cache-bandwidth bound).
+  VMEM, so HBM reads stay quarter-width (the whole point of
+  quantizing: decode is cache-bandwidth bound).
 - :func:`shard_decode_attention` — mesh composition: the compiled
   Mosaic call has no partitioning rule (same wall as
   ``ops/attention.gspmd_flash_attention``), so TP serving routes the
@@ -66,6 +72,12 @@ LANES = 128
 
 # KV rows streamed per grid step unless the caller asks otherwise.
 DEFAULT_BLOCK_K = 128
+
+# A grid step holds ``block_k`` rows of ALL kv heads: K and V blocks,
+# double-buffered, beside the block's fp32 temporaries. 2 MiB of fp32
+# rows a block (256 rows of 16 heads x 128) is what a v5e's 16 MiB of
+# scoped VMEM compiles; 512 such rows do not.
+_MAX_BLOCK_BYTES = 2 << 20
 
 # int8 quantization range: symmetric, NaN-free at zero rows (the amax
 # floor below keeps the scale strictly positive).
@@ -144,6 +156,33 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None):
 # ---- the Pallas kernel ----------------------------------------------
 
 
+def decode_block(
+    L: int, H_kv: int, Dh: int, dtype, block_k: int = DEFAULT_BLOCK_K
+) -> int:
+    """K/V rows one grid step of the kernel streams from a length-``L``
+    lane: ``ops.flash.pick_block`` of ``block_k``, capped so that a
+    block of all kv heads fits VMEM (``_MAX_BLOCK_BYTES``) — never one
+    full-length block for a long lane (that would defeat the banded
+    read). Raises, with the shape named, when ``L`` has no tile-aligned
+    divisor; the engine asks at construction."""
+    cap = max(32, _MAX_BLOCK_BYTES // (H_kv * Dh * 4))
+    return pick_block(L, min(block_k, cap), dtype)
+
+
+def live_block(j, pos, block_k: int):
+    """The K/V block grid step ``j`` of a lane at ``pos`` is given.
+
+    Blocks up to ``pos // block_k`` hold attendable keys; every later
+    step repeats that last live index. Pallas fetches a block only
+    when its index differs from the previous grid step's, so a dead
+    step costs no DMA (and ``pl.when`` skips its compute): the lane
+    read is O(pos). A plain function of its arguments — the index
+    maps below call it on the scalar-prefetched position, the tests on
+    integers.
+    """
+    return jnp.minimum(j, pos // block_k)
+
+
 def flash_decode_attention(
     q,
     k,
@@ -152,105 +191,99 @@ def flash_decode_attention(
     k_scale=None,
     v_scale=None,
     *,
+    layer: int = 0,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
 ):
     """Pallas flash-decode → [S, H, Dh] fp32 (the reference's contract).
 
-    Same signature/semantics as :func:`decode_attention_reference`;
+    ``k``/``v`` are the STORED cache, ``[depth, S, L, H_kv, Dh]``
+    (scales ``[depth, S, L, H_kv]``), and ``layer`` (Python-static)
+    the layer to attend: the index maps pick the layer, so nothing is
+    sliced, transposed or copied on the way in — the only cache bytes
+    a call moves are the live blocks of that layer, once. One layer's
+    ``[S, L, H_kv, Dh]`` lanes (:func:`decode_attention_reference`'s
+    operand, the paged gather) are a depth-1 stored cache.
+
+    The grid is ``(S, L/block_k)``; a grid step streams ``block_k``
+    rows of ALL kv heads of one lane, ``[block_k, H_kv, Dh]``, in the
+    stored layout. What runs on a block adapts to the operands: with
+    G = H/H_kv = 1 and float rows the scores are a broadcast multiply
+    and a lane reduce over the whole block (full fp32 on the VPU, no
+    relayout; a one-row MXU dot per head would waste the array);
+    grouped queries and int8 rows take one ``[G, Dh] x [Dh, block_k]``
+    dot per kv head on that head's strided rows, int8 widened (and
+    scaled) at the compute site.
+
     ``interpret=None`` auto-detects (compiled Mosaic on TPU, the
     interpreter elsewhere so one engine config runs anywhere). The
-    effective KV block is ``ops.flash.pick_block(L, block_k, k.dtype)``
-    — tile-aligned, never one full-length block for a long lane (that
-    would defeat the ``pl.when`` dead-block skip that makes young
-    lanes O(pos)); a lane length with no such block raises.
+    effective block is :func:`decode_block`'s.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if k.ndim == 4:
+        k, v = k[None], v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     S, H, Dh = q.shape
-    L, H_kv = k.shape[1], k.shape[2]
+    L, H_kv = k.shape[2], k.shape[3]
     G = H // H_kv
-    block_k = pick_block(L, block_k, k.dtype)
+    block_k = decode_block(L, H_kv, Dh, k.dtype, block_k)
     quantized = k.dtype == jnp.int8
-    # One grid row per (slot, kv-head): q regrouped kv-head-major
-    # (exactly the engine's qg = q.reshape(S, H_kv, G, Dh) grouping),
-    # K/V lanes transposed so each row streams [L, Dh] blocks.
-    qt = q.reshape(S * H_kv, G, Dh)
-    kt = k.transpose(0, 2, 1, 3).reshape(S * H_kv, L, Dh)
-    vt = v.transpose(0, 2, 1, 3).reshape(S * H_kv, L, Dh)
-    # Per-row lane position: a scalar the kernel branches on, so it
-    # rides scalar prefetch into SMEM (Mosaic reads no scalars out of
-    # VMEM blocks).
-    pos_rows = jnp.repeat(pos.astype(jnp.int32), H_kv)  # [S·H_kv]
+    all_heads = G == 1 and not quantized
     vmem = {"memory_space": pltpu.VMEM}
-    qmap = lambda b, j, pos_ref: (b, 0, 0)
-    kmap = lambda b, j, pos_ref: (b, j, 0)
-    in_specs = [
-        pl.BlockSpec((1, G, Dh), qmap, **vmem),
-        pl.BlockSpec((1, block_k, Dh), kmap, **vmem),
-        pl.BlockSpec((1, block_k, Dh), kmap, **vmem),
-    ]
-    args = [qt, kt, vt]
+
+    # The lane position is a scalar the index maps and the kernel
+    # branch on, so it rides scalar prefetch into SMEM.
+    def kvmap(s, j, pos_ref):
+        return (layer, s, live_block(j, pos_ref[s], block_k), 0, 0)
+
+    def scmap(s, j, pos_ref):
+        return kvmap(s, j, pos_ref)[:-1]
+
+    # q and the output travel kv-head-major (the engine's qg =
+    # q.reshape(S, H_kv, G, Dh) grouping; G = 1 needs no group dim).
+    qshape = (H, Dh) if all_heads else (H_kv, G, Dh)
+    qspec = pl.BlockSpec(
+        (None, *qshape), lambda s, j, pos_ref: (s,) + (0,) * len(qshape),
+        **vmem,
+    )
+    kvspec = pl.BlockSpec((None, None, block_k, H_kv, Dh), kvmap, **vmem)
+    in_specs = [qspec, kvspec, kvspec]
+    args = [q.reshape(S, *qshape), k, v]
     if quantized:
-        ksc = k_scale.transpose(0, 2, 1).reshape(S * H_kv, L, 1)
-        vsc = v_scale.transpose(0, 2, 1).reshape(S * H_kv, L, 1)
-        in_specs += [
-            pl.BlockSpec((1, block_k, 1), kmap, **vmem),
-            pl.BlockSpec((1, block_k, 1), kmap, **vmem),
-        ]
-        args += [ksc.astype(jnp.float32), vsc.astype(jnp.float32)]
+        scspec = pl.BlockSpec((None, None, block_k, H_kv), scmap, **vmem)
+        in_specs += [scspec, scspec]
+        args += [k_scale, v_scale]
 
     out = pl.pallas_call(
         functools.partial(
-            _quantized_kernel if quantized else _plain_kernel,
+            _all_heads_kernel if all_heads else _per_head_kernel,
             scale=Dh**-0.5, block_k=block_k,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(S * H_kv, L // block_k),
+            grid=(S, L // block_k),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, G, Dh), qmap, **vmem),
+            out_specs=qspec,
             scratch_shapes=[
-                pltpu.VMEM((G, Dh), jnp.float32),
-                pltpu.VMEM((G, LANES), jnp.float32),
-                pltpu.VMEM((G, LANES), jnp.float32),
+                pltpu.VMEM(qshape, jnp.float32),
+                pltpu.VMEM((*qshape[:-1], LANES), jnp.float32),
+                pltpu.VMEM((*qshape[:-1], LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S * H_kv, G, Dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, *qshape), jnp.float32),
         interpret=interpret,
         name="flash_decode",
-    )(pos_rows, *args)
+    )(pos.astype(jnp.int32), *args)
     return out.reshape(S, H, Dh)
 
 
-def _plain_kernel(
-    pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale, block_k,
-):
-    _decode_body(
-        pos_ref, q_ref, k_ref, v_ref, None, None, o_ref,
-        acc_ref, m_ref, l_ref, scale=scale, block_k=block_k,
-    )
-
-
-def _quantized_kernel(
-    pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
-    acc_ref, m_ref, l_ref, *, scale, block_k,
-):
-    _decode_body(
-        pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
-        acc_ref, m_ref, l_ref, scale=scale, block_k=block_k,
-    )
-
-
-def _decode_body(
-    pos_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, o_ref,
-    acc_ref, m_ref, l_ref, *, scale, block_k,
-):
-    """Shared online-softmax body; ``pos_ref`` is the scalar-prefetched
-    [S·H_kv] position vector (SMEM)."""
+def _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body):
+    """The grid-step skeleton both kernels share: init the fp32
+    scratch on a lane's first step, run ``body(j, pos)`` on live
+    blocks only, flush ``acc / l`` on the last step."""
     j = pl.program_id(1)
-    n_kb = pl.num_programs(1)
     pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
@@ -260,44 +293,88 @@ def _decode_body(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     # Banded read: a block whose first key is past the lane position
-    # is dead in full — skip its MXU work entirely (block 0 is always
-    # live since pos >= 0, so the denominator can never be empty).
+    # is dead in full (and was not fetched: ``live_block``). Block 0
+    # is always live since pos >= 0, and a live block's first key is
+    # attendable, so the running max is finite from the first step on.
     @pl.when(j * block_k <= pos)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale  # [G, Dh]
-        kb = k_ref[0].astype(jnp.float32)  # [block_k, Dh]
-        vb = v_ref[0].astype(jnp.float32)
-        if ksc_ref is not None:
-            # int8 rows widen at the compute site: HBM traffic for
-            # the lane read stays half-width.
-            kb = kb * ksc_ref[0][:, :1]
-            vb = vb * vsc_ref[0][:, :1]
-        s = lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, block_k]
-        cols = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= pos, s, -jnp.inf)
+        body(j, pos)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _flush():
+        o_ref[...] = (
+            acc_ref[...] / l_ref[...][..., :1]
+        ).astype(o_ref.dtype)
+
+
+def _all_heads_kernel(
+    pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, scale, block_k,
+):
+    """G = 1, float rows: every head of the block at once, heads on
+    sublanes and Dh on lanes as stored; scores stay ``[block_k, H, 1]``
+    columns, so softmax statistics reduce over the leading dim."""
+
+    def body(j, pos):
+        q = q_ref[...].astype(jnp.float32) * scale  # [H, Dh]
+        kb = k_ref[...].astype(jnp.float32)  # [block_k, H, Dh]
+        s = jnp.sum(q[None] * kb, axis=-1, keepdims=True)
+        rows = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        live = rows <= pos
+        s = jnp.where(live, s, -jnp.inf)
+        # Rows past ``pos`` are unwritten (or stale): their V must not
+        # reach the sum even under a zero weight (0 · NaN).
+        vb = jnp.where(live, v_ref[...].astype(jnp.float32), 0.0)
         m = m_ref[...][:, :1]
-        l = l_ref[...][:, :1]
-        new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        shift = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-        p = jnp.exp(s - shift)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - shift), 0.0)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        new_m = jnp.maximum(m, s.max(axis=0))  # [H, 1]
+        p = jnp.exp(s - new_m[None])
+        corr = jnp.exp(m - new_m)
+        acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * vb, axis=0)
+        l_new = l_ref[...][:, :1] * corr + p.sum(axis=0)
         m_ref[...] = jnp.broadcast_to(new_m, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == n_kb - 1)
-    def _flush():
-        l = l_ref[...][:, :1]
-        o_ref[0] = (
-            acc_ref[...] / jnp.maximum(l, 1e-30)
-        ).astype(o_ref.dtype)
+    _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
+
+
+def _per_head_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k):
+    """Grouped queries and/or int8 rows: per kv head, that head's
+    ``[block_k, Dh]`` rows (a strided read of the stored block) against
+    its G queries on the MXU. ``rest`` is ``[ksc_ref, vsc_ref,] o_ref,
+    acc_ref, m_ref, l_ref`` — scale planes only on an int8 cache."""
+    *scales, o_ref, acc_ref, m_ref, l_ref = rest
+
+    def body(j, pos):
+        for h in range(k_ref.shape[1]):
+            kb = k_ref[:, h, :].astype(jnp.float32)  # [block_k, Dh]
+            vb = v_ref[:, h, :].astype(jnp.float32)
+            if scales:
+                # int8 rows widen at the compute site: HBM traffic for
+                # the lane read stays quarter-width.
+                kb = kb * scales[0][:, h : h + 1]
+                vb = vb * scales[1][:, h : h + 1]
+            q = q_ref[h].astype(jnp.float32) * scale  # [G, Dh]
+            s = lax.dot_general(
+                q, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [G, block_k]
+            cols = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols <= pos, s, -jnp.inf)
+            rows = j * block_k + lax.broadcasted_iota(jnp.int32, vb.shape, 0)
+            vb = jnp.where(rows <= pos, vb, 0.0)  # see _all_heads_kernel
+            m = m_ref[h][:, :1]
+            new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - new_m)
+            corr = jnp.exp(m - new_m)
+            acc_ref[h] = acc_ref[h] * corr + lax.dot_general(
+                p, vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            l_new = l_ref[h][:, :1] * corr + p.sum(axis=-1, keepdims=True)
+            m_ref[h] = jnp.broadcast_to(new_m, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
 
 
 # ---- paged KV: gather lane views through int32 page tables ----------
@@ -317,15 +394,15 @@ def _decode_body(
 # pattern).
 #
 # Honest cost note: the gather MATERIALIZES the per-lane views before
-# the kernel runs, so on this path the kernel's dead-block skip saves
-# compute only — the gather already paid O(total_len) HBM traffic per
-# layer per step, the bandwidth the fixed-lane banded read avoids.
-# The O(pos) paged hot path needs IN-KERNEL table indexing (a
-# scalar-prefetch BlockSpec index_map resolving page ids per grid
-# row, the vLLM/TPU paged-attention shape) — the wired on-chip
-# follow-up; until then an on-chip capture of paged+flash measures
-# gather + kernel, and bench.py's serve_decode paged_kv sub-record
-# should be read accordingly.
+# the kernel runs, so on this path the gather pays O(total_len) HBM
+# traffic per layer per step (a read of the mapped pages and a write of
+# the views); the kernel then reads the views' live blocks only, as a
+# depth-1 stored cache. The O(pos) paged hot path needs IN-KERNEL table
+# indexing (a scalar-prefetch index_map resolving page ids per grid
+# step, the vLLM/TPU paged-attention shape) — the on-chip follow-up;
+# until then an on-chip capture of paged+flash measures gather +
+# kernel, and bench.py's serve_decode paged_kv sub-record should be
+# read accordingly.
 
 
 def gather_paged_kv(pages: jax.Array, table: jax.Array) -> jax.Array:
@@ -350,10 +427,9 @@ def paged_decode_attention(
     fixed-lane call over the table's gathered view — positions past
     ``pos[s]`` (including every scratch-page line) are masked, so a
     stale or zero table entry above the live region can never leak
-    into the softmax. The flash kernel's dead-block skip is
-    compute-side only here — see the module's cost note: the gather
-    materializes the full lane views first; in-kernel table indexing
-    is the on-chip follow-up.
+    into the softmax. The gather materializes the full lane views
+    first (the module's cost note); the flash kernel then takes them
+    as a depth-1 stored cache and reads their live blocks.
     """
     k = gather_paged_kv(k_pages, table)
     v = gather_paged_kv(v_pages, table)
@@ -370,29 +446,39 @@ def paged_decode_attention(
 
 def decode_attention(
     q, k, v, pos, k_scale=None, v_scale=None, *,
-    impl: str = "reference", block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool | None = None,
+    impl: str = "reference", layer: int = 0,
+    block_k: int = DEFAULT_BLOCK_K, interpret: bool | None = None,
 ):
     """The engine-facing entry: ``impl`` picks the path at trace time.
 
-    ``reference`` — the jnp einsum math (bit-identical to the PR-3
-    engine on fp32 caches); ``flash`` — the Pallas kernel (compiled
-    Mosaic on TPU, interpreter elsewhere); ``auto`` — flash on TPU,
-    reference everywhere else (the serving default: off-TPU nothing
-    beats XLA's fused einsums, and the PR-3 numerics stay untouched).
+    ``k``/``v`` (and int8 scales) are either one layer's lanes
+    ``[S, L, H_kv, Dh]`` or the stored cache ``[depth, S, L, H_kv,
+    Dh]`` with ``layer`` naming the layer to attend — what the decode
+    step passes, so the flash path never slices a layer out.
+
+    ``reference`` — the jnp einsum math over ``k[layer]``
+    (bit-identical to the PR-3 engine on fp32 caches); ``flash`` — the
+    Pallas kernel (compiled Mosaic on TPU, interpreter elsewhere);
+    ``auto`` — flash on TPU, reference everywhere else (the serving
+    default: off-TPU nothing beats XLA's fused einsums, and the PR-3
+    numerics stay untouched).
     """
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "flash":
         return flash_decode_attention(
             q, k, v, pos, k_scale, v_scale,
-            block_k=block_k, interpret=interpret,
+            layer=layer, block_k=block_k, interpret=interpret,
         )
     if impl != "reference":
         raise ValueError(
             f"unknown decode attention impl {impl!r}: expected "
             "'auto', 'flash' or 'reference'"
         )
+    if k.ndim == 5:
+        k, v = k[layer], v[layer]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
     return decode_attention_reference(q, k, v, pos, k_scale, v_scale)
 
 

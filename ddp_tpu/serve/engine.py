@@ -75,7 +75,7 @@ from ddp_tpu.models.generate import (
 from ddp_tpu.models.generate import slot_decode_step as _decode_step
 from ddp_tpu.models.generate import slot_verify_step as _verify_step
 from ddp_tpu.models.lm import LMSpec
-from ddp_tpu.ops.decode import DEFAULT_BLOCK_K
+from ddp_tpu.ops.decode import DEFAULT_BLOCK_K, decode_block
 from ddp_tpu.obs.tracer import Tracer, get_tracer
 from ddp_tpu.serve.pages import PrefixCache, page_demand
 from ddp_tpu.serve.scheduler import (
@@ -271,15 +271,17 @@ def resolve_engine_knobs(
     decode_kernel = "xla"
     decode_block_k = None
     if decode_attn == "flash":
-        from ddp_tpu.ops.flash import pallas_kernel_mode, pick_block
+        from ddp_tpu.ops.flash import pallas_kernel_mode
 
         decode_kernel = pallas_kernel_mode()
         # The kernel's effective KV block, resolved here so a lane
         # length with no tile-aligned block fails at construction with
-        # the shape named (ops/flash.pick_block), not inside Mosaic.
+        # the shape named (ops/decode.decode_block), not inside Mosaic.
         try:
-            decode_block_k = pick_block(
-                spec.total_len, DEFAULT_BLOCK_K,
+            decode_block_k = decode_block(
+                spec.total_len,
+                spec.num_kv_heads or spec.num_heads,
+                spec.d_model // spec.num_heads,
                 jnp.int8 if kv_dtype == "int8" else jnp.float32,
             )
         except ValueError as e:
@@ -694,6 +696,13 @@ class ServeEngine:
         # Monotone token counter (the aggregator's tokens/s source —
         # per-request rate summaries are not additive across a fleet).
         self.tokens_emitted_total = 0
+        # What the banded lane read is for, per plain decode step: the
+        # cache rows the decoding lanes attend (each lane's pos + 1)
+        # against the rows the lanes hold (slots x total_len). Their
+        # quotient is the share of lane bytes a step has to fetch —
+        # host arithmetic on positions the engine tracks anyway.
+        self.kv_rows_attended_total = 0
+        self.kv_rows_lane_total = 0
         # Monotone count of requests accepted into the queue: what a
         # load that hands requests over in order waits on.
         self.accepted_total = 0
@@ -777,8 +786,8 @@ class ServeEngine:
             "serve.flash_decode" if impl == "flash" else "serve.decode",
         )
         if impl == "flash":
-            # The kernel snaps block_k to the largest tile-aligned
-            # divisor of the lane length (ops/flash.pick_block) — a
+            # The kernel snaps block_k to a tile-aligned divisor of the
+            # lane length that fits VMEM (ops/decode.decode_block) — a
             # host-side decision XLA introspection can't see. Ledger
             # it so the tuner and humans read the EFFECTIVE block, not
             # the requested default.
@@ -1336,6 +1345,8 @@ class ServeEngine:
             "completed": len(self._completed),
             "tokens_total": self.tokens_emitted_total,
             "accepted_total": self.accepted_total,
+            "kv_rows_attended_total": self.kv_rows_attended_total,
+            "kv_rows_lane_total": self.kv_rows_lane_total,
             "ttft_s": self.ttft.snapshot(),
             "lock_wait_s": self.lock_wait.snapshot(ndigits=6),
             "pickup_s": self.pickup.snapshot(ndigits=6),
@@ -1684,8 +1695,17 @@ class ServeEngine:
             # enforced instead of assumed. (Chunk dispatch above
             # legitimately uploads prompt content; the retire below
             # legitimately fetches [S] int32 — both deliberate.)
+            # A decoding lane writes its last token at pos = prompt +
+            # emitted - 1 and attends the pos + 1 rows up to it.
+            rows = sum(
+                len(self._slots[i].request.prompt) + self._slots[i].emitted
+                for i in decode_lanes
+            )
+            self.kv_rows_attended_total += rows
+            self.kv_rows_lane_total += self.num_slots * self.spec.total_len
             with tracer.span(
-                "serve.decode", parent=parent, nums=(len(decode_lanes),),
+                "serve.decode", parent=parent,
+                nums=(len(decode_lanes), rows),
             ) as span, self._sanitizer.guard():
                 self._toks, self._cache, self._sample_steps = self._decode(
                     self.params, self._cache, self._toks, self._seeds,

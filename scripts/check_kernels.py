@@ -99,10 +99,13 @@ def check_flash(B: int, T: int, H: int, D: int, block: int) -> dict:
 
 def check_decode(
     S: int, H: int, H_kv: int, Dh: int, L: int,
-    *, int8: bool = False, page_size: int = 0,
+    *, int8: bool = False, page_size: int = 0, depth: int = 0,
 ) -> dict:
     """Flash-decode (fixed-lane, or paged through a shuffled page
-    table) against ``decode_attention_reference`` on the same cache."""
+    table) against ``decode_attention_reference`` on the same cache.
+    ``depth`` > 0 hands the kernel what the serve engine's decode step
+    does: the stored ``[depth, S, L, H_kv, Dh]`` cache and a layer
+    index (the last layer; the others hold other numbers)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -129,7 +132,19 @@ def check_decode(
     if int8:
         k, ks = quantize_kv(k)
         v, vs = quantize_kv(v)
-    if page_size:
+    if depth:
+        layer = depth - 1
+
+        def stored(x):
+            return jnp.stack([x[::-1]] * layer + [x])
+
+        cache = [stored(x) if x is not None else None for x in (k, v, ks, vs)]
+        out = jax.jit(
+            lambda q, k, v, ks, vs: decode_attention(
+                q, k, v, pos, ks, vs, impl="flash", layer=layer
+            )
+        )(q, *cache)
+    elif page_size:
         # Scatter every lane's pages over a shuffled pool (page 0 is
         # the engine's scratch page and stays unmapped).
         n = L // page_size
@@ -181,6 +196,10 @@ def cases(tiny: bool, every: bool):
         dec = dict(S=8, H=8, H_kv=8, Dh=128, L=2048)
     yield "flash_fwd_bwd_bf16_causal", lambda: check_flash(**flash)
     yield "decode_fp32_g1", lambda: check_decode(**dec)
+    # The path a serving cell runs: the stored cache of the benchmark's
+    # model (16 heads of 128, 8 lanes of 2048), a layer other than 0.
+    stored = dict(dec, depth=2) if tiny else dict(dec, H=16, H_kv=16, depth=3)
+    yield "decode_fp32_g1_stored", lambda: check_decode(**stored)
     if every:
         gqa = dict(dec, H_kv=dec["H"] // 4)
         yield "decode_fp32_g4", lambda: check_decode(**gqa)
@@ -189,6 +208,9 @@ def cases(tiny: bool, every: bool):
         )
         yield "decode_int8_g1", lambda: check_decode(**dec, int8=True)
         yield "decode_int8_g4", lambda: check_decode(**gqa, int8=True)
+        yield "decode_int8_g4_stored", lambda: check_decode(
+            **gqa, int8=True, depth=2
+        )
         yield "decode_paged16_fp32", lambda: check_decode(**dec, page_size=16)
         yield "decode_paged16_int8_g4", lambda: check_decode(
             **gqa, int8=True, page_size=16

@@ -28,13 +28,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ddp_tpu.models.generate import generate, init_slot_cache
+from ddp_tpu.models.generate import (
+    generate,
+    init_slot_cache,
+    slot_decode_step,
+)
 from ddp_tpu.models.lm import LMSpec, init_lm
 from ddp_tpu.ops.decode import (
     decode_attention,
     decode_attention_reference,
     dequantize_kv,
     flash_decode_attention,
+    live_block,
     quantize_kv,
     shard_decode_attention,
 )
@@ -64,16 +69,19 @@ def _rand_qkv(rng, S, H, H_kv, Dh, L):
     return q, k, v
 
 
+# The kernel grid (S, H, H_kv, Dh, L, block_k). An int8 cache tiles 32
+# rows at a time: its tests run lanes and blocks four times as long
+# (same block counts).
+_GRID = [
+    (3, 4, 4, 8, 16, 8),    # MHA (the all-heads form), two key blocks
+    (2, 8, 2, 16, 32, 8),   # GQA group 4 (the per-kv-head form)
+    (4, 4, 2, 8, 24, 16),   # 16 does not divide 24 → three blocks of 8
+    (1, 2, 1, 4, 8, 128),   # block_k > L → clamped to L
+]
+
+
 class TestKernel:
-    @pytest.mark.parametrize(
-        "S,H,H_kv,Dh,L,block_k",
-        [
-            (3, 4, 4, 8, 16, 8),    # MHA, two key blocks
-            (2, 8, 2, 16, 32, 8),   # GQA group 4, four blocks
-            (4, 4, 2, 8, 24, 16),   # 16 does not divide 24 → three blocks of 8
-            (1, 2, 1, 4, 8, 128),   # block_k > L → clamped to L
-        ],
-    )
+    @pytest.mark.parametrize("S,H,H_kv,Dh,L,block_k", _GRID)
     def test_matches_reference(self, S, H, H_kv, Dh, L, block_k):
         """The kernel's online-softmax over banded blocks computes the
         reference einsum math (1-ulp-class reassociation only), for
@@ -141,6 +149,182 @@ class TestKernel:
         assert jnp.array_equal(auto, ref)
         with pytest.raises(ValueError, match="impl"):
             decode_attention(q, k, v, pos, impl="dense")
+
+
+def _stored(rng, S, H, H_kv, Dh, L, kv_dtype, depth=3):
+    """A random [depth, S, L, H_kv, Dh] cache (every layer different)
+    → (q, k, v, k_scale, v_scale); scales None on a float cache."""
+    q = jnp.asarray(rng.normal(size=(S, H, Dh)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(depth, S, L, H_kv, Dh)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(depth, S, L, H_kv, Dh)), jnp.float32)
+    if kv_dtype == "int8":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        return q, k, v, ks, vs
+    return q, k, v, None, None
+
+
+def _edge_pos(rng, S, L):
+    return jnp.asarray(
+        rng.integers(0, L, size=(S,)), jnp.int32
+    ).at[0].set(0).at[-1].set(L - 1)
+
+
+class TestStoredLayout:
+    """The kernel reads the cache as the engine stores it — [depth, S,
+    L, H_kv, Dh], the layer picked by the index map — and fetches no
+    block past a lane's position."""
+
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+    @pytest.mark.parametrize("layer", [1, 2])
+    @pytest.mark.parametrize("S,H,H_kv,Dh,L,block_k", _GRID)
+    def test_layer_of_stored_cache_matches_reference(
+        self, S, H, H_kv, Dh, L, block_k, layer, kv_dtype
+    ):
+        if kv_dtype == "int8":
+            L, block_k = 4 * L, 4 * block_k
+        rng = np.random.default_rng(S * 100 + L + layer)
+        q, k, v, ks, vs = _stored(rng, S, H, H_kv, Dh, L, kv_dtype)
+        pos = _edge_pos(rng, S, L)
+        sc = (None, None) if ks is None else (ks[layer], vs[layer])
+        ref = decode_attention_reference(q, k[layer], v[layer], pos, *sc)
+        out = flash_decode_attention(
+            q, k, v, pos, ks, vs, layer=layer, block_k=block_k
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
+        )
+        # the engine-facing entry takes the same operands on both paths
+        for impl in ("flash", "reference"):
+            got = decode_attention(
+                q, k, v, pos, ks, vs, impl=impl, layer=layer,
+                block_k=block_k,
+            )
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(ref), atol=1e-5, rtol=1e-5
+            )
+
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+    @pytest.mark.parametrize(
+        "H,H_kv", [(4, 4), (8, 2)], ids=["all_heads", "per_kv_head"]
+    )
+    def test_rows_past_pos_may_hold_nan(self, H, H_kv, kv_dtype):
+        """Every cache row past pos[s] poisoned with NaN (on an int8
+        cache: its scale): the output is finite and equals the
+        reference's on the clean cache — a dead row reaches neither
+        the softmax nor the weighted sum (0 · NaN)."""
+        S, Dh, L, block_k, layer = 3, 8, 128, 32, 1
+        rng = np.random.default_rng(5)
+        q, k, v, ks, vs = _stored(rng, S, H, H_kv, Dh, L, kv_dtype)
+        pos = jnp.asarray([0, 37, L - 2], jnp.int32)
+        sc = (None, None) if ks is None else (ks[layer], vs[layer])
+        ref = decode_attention_reference(q, k[layer], v[layer], pos, *sc)
+        dead = jnp.arange(L)[None, None, :, None] > pos[None, :, None, None]
+        if kv_dtype == "int8":
+            ks, vs = (jnp.where(dead, jnp.nan, x) for x in (ks, vs))
+        else:
+            k, v = (jnp.where(dead[..., None], jnp.nan, x) for x in (k, v))
+        out = flash_decode_attention(
+            q, k, v, pos, ks, vs, layer=layer, block_k=block_k
+        )
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
+        )
+
+    @pytest.mark.parametrize("block_k", [8, 32, 128])
+    def test_index_map_never_names_a_dead_block(self, block_k):
+        """``live_block`` (what the K/V index maps return for the block
+        dim): grid step j reads block j while it is live, and repeats
+        the last live block after — a repeated index is no DMA."""
+        L = 4 * block_k
+        for pos in (0, 1, block_k - 1, block_k, 2 * block_k + 3, L - 1, L):
+            last = min(pos // block_k, L // block_k - 1)
+            got = [int(live_block(j, pos, block_k)) for j in range(L // block_k)]
+            assert got == [min(j, last) for j in range(L // block_k)]
+            assert max(got) * block_k <= pos
+
+    def test_lane_parked_at_the_ceiling_attends_key_zero(self, params):
+        """An idle lane that has drifted to ``pos == total_len`` has no
+        reader; the decode step has it attend key 0 alone (one block a
+        layer, not its whole lane). Rows 1.. of the parked lane hold
+        NaN here: nothing of them reaches its logits, the live lane's
+        logits are the reference path's, and the parked lane's write
+        still lands on the last line."""
+        L = SPEC.total_len
+        rng = np.random.default_rng(9)
+        cache = init_slot_cache(SPEC, 2)
+        k = jnp.asarray(rng.normal(size=cache.k.shape), jnp.float32)
+        v = jnp.asarray(rng.normal(size=cache.v.shape), jnp.float32)
+        cache = cache._replace(k=k, v=v, pos=jnp.asarray([5, L], jnp.int32))
+        toks = jnp.asarray([3, 4], jnp.int32)
+        ref_logits, _ = slot_decode_step(SPEC, params, cache, toks)
+        parked_rows = (jnp.arange(L) > 0)[None, None, :, None, None] & (
+            jnp.arange(2) == 1
+        )[None, :, None, None, None]
+        poisoned = cache._replace(
+            k=jnp.where(parked_rows, jnp.nan, k),
+            v=jnp.where(parked_rows, jnp.nan, v),
+        )
+        logits, new = slot_decode_step(
+            SPEC, params, poisoned, toks, attn_impl="flash"
+        )
+        assert np.isfinite(np.asarray(logits)).all()
+        np.testing.assert_allclose(
+            np.asarray(logits), np.asarray(ref_logits), atol=1e-4, rtol=1e-4
+        )
+        assert np.isfinite(np.asarray(new.k[:, 1, L - 1])).all()
+        assert np.isnan(np.asarray(new.k[:, 1, 1 : L - 1])).all()
+        assert new.pos.tolist() == [6, L]
+
+    @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.int8])
+    def test_decode_step_moves_no_layer_of_lanes(self, kv_dtype):
+        """Structural pin on the flash decode step: outside the kernel
+        no operation produces a layer's worth of lane elements
+        (S·L·H_kv·Dh) — no slice of ``cache.k[i]``, no transpose for
+        the kernel, no write-back of an updated layer — and every
+        cache write carries rows, not lanes. Only the cache itself
+        (the in-place scatter's result) is that large."""
+        S = 5  # a layer of lanes (5120) is larger than any weight
+        spec = SPEC
+        cache = init_slot_cache(spec, S, dtype=kv_dtype)
+        layer_elems = int(np.prod(cache.k.shape[1:]))
+        params = init_lm(spec, seed=0)
+        assert layer_elems > max(
+            x.size for x in jax.tree.leaves(params)
+        )
+        jaxpr = jax.make_jaxpr(
+            lambda c, t: slot_decode_step(
+                spec, params, c, t, attn_impl="flash"
+            )
+        )(cache, jnp.zeros((S,), jnp.int32))
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                name = eqn.primitive.name
+                yield eqn
+                if name == "pallas_call":
+                    continue  # the kernel's own blocks
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from walk(sub)
+
+        kernels = writes = 0
+        for eqn in walk(jaxpr.jaxpr):
+            name = eqn.primitive.name
+            kernels += name == "pallas_call"
+            if name in ("scatter", "dynamic_update_slice"):
+                update = eqn.invars[2 if name == "scatter" else 1].aval
+                if eqn.invars[0].aval.shape == cache.k.shape:
+                    writes += 1
+                    assert update.size == S * cache.k.shape[3] * cache.k.shape[4]
+            for out in eqn.outvars:
+                if out.aval.size >= layer_elems:
+                    assert out.aval.shape == cache.k.shape, (
+                        f"{name} makes {out.aval.shape}: a layer of "
+                        "lanes moved outside the kernel"
+                    )
+                    assert name in ("scatter", "pjit", "jit"), name
+        assert kernels == spec.depth
+        assert writes == 2 * spec.depth  # K and V rows, once a layer
 
 
 class TestInt8KV:

@@ -555,6 +555,41 @@ def test_engine_and_server_record_into_the_global_ring():
     assert "ddp_tpu_serve_result_pickup_seconds_count 3" in text
 
 
+def test_kv_rows_counters_against_a_hand_count():
+    """``kv_rows_attended_total`` / ``kv_rows_lane_total``: three
+    requests on a two-lane engine (one has to wait for a lane). A
+    request with prompt P and N tokens takes N - 1 decode steps, its
+    first token coming from prefill, and attends P + e rows in the
+    step that emits token e + 1 — whatever the schedule. Each
+    ``serve.decode`` span carries its step's share as ``nums[1]``."""
+    from ddp_tpu.obs.promtext import render_serve, validate_promtext
+    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+
+    assert SPAN_NUMS["serve.decode"] == ("lanes", "rows_attended")
+    before = get_tracer().ring()
+    eng = _tiny_engine()
+    for prompt, n in (([1, 2, 3], 4), ([4, 5], 3), ([6, 7, 8, 9], 5)):
+        eng.submit(prompt, n)
+    eng.run()
+    # P=3,N=4: 4+5+6 = 15; P=2,N=3: 3+4 = 7; P=4,N=5: 5+6+7+8 = 26
+    assert eng.kv_rows_attended_total == 15 + 7 + 26
+    decodes = [e for e in _since(before) if e[0] == "serve.decode"]
+    assert sum(e[4][1] for e in decodes) == 48
+    # at most two lanes decode in a step, each at most total_len deep
+    assert all(1 <= e[4][0] <= 2 and e[4][1] <= 2 * 32 for e in decodes)
+    # the lanes hold slots x total_len rows, decoded or idle
+    assert eng.kv_rows_lane_total == len(decodes) * 2 * 32
+    stats = eng.stats()
+    assert stats["kv_rows_attended_total"] == 48
+    assert stats["kv_rows_lane_total"] == eng.kv_rows_lane_total
+    text = render_serve(stats)
+    validate_promtext(text)
+    assert "ddp_tpu_serve_kv_rows_attended_total 48" in text
+    assert (
+        f"ddp_tpu_serve_kv_rows_lane_total {eng.kv_rows_lane_total}" in text
+    )
+
+
 def test_stats_and_metricsz_do_not_take_the_engine_lock():
     """/stats and /metricsz are reads of plain host-side state: they
     answer while another thread holds the lock the engine loop holds

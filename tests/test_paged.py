@@ -172,6 +172,61 @@ class TestTokenIdentity:
         assert run("flash") == run("reference")
         assert run("reference") == _reference(SPEC, params, prompt, 6)
 
+    @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+    def test_gathered_view_is_a_depth_one_stored_cache(self, kv_dtype):
+        """One kernel path: ``paged_decode_attention`` hands its
+        gathered lane views to the stored-layout kernel as a depth-1
+        cache, so paged flash equals fixed-lane flash over the same
+        logical lanes — with the scratch page and every row past
+        ``pos`` poisoned, which the banded read must never see."""
+        from ddp_tpu.ops.decode import (
+            decode_attention_reference,
+            flash_decode_attention,
+            paged_decode_attention,
+            quantize_kv,
+        )
+
+        S, H, H_kv, Dh, L, ps = 3, 4, 2, 8, 64, 16
+        rng = np.random.default_rng(12)
+        q = jnp.asarray(rng.normal(size=(S, H, Dh)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(S, L, H_kv, Dh)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(S, L, H_kv, Dh)), jnp.float32)
+        pos = jnp.asarray([0, 17, L - 1], jnp.int32)
+        ks = vs = None
+        if kv_dtype == "int8":
+            (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        ref = decode_attention_reference(q, k, v, pos, ks, vs)
+        fixed = flash_decode_attention(q, k, v, pos, ks, vs, block_k=32)
+        n = L // ps
+        table = 1 + rng.permutation(S * n).reshape(S, n).astype(np.int32)
+        # pages wholly past a lane's position map to the scratch page,
+        # as an engine's unallocated tail does
+        table = np.where(
+            np.arange(n)[None, :] * ps > np.asarray(pos)[:, None], 0, table
+        )
+
+        def pool(x, poison):
+            pages = x.reshape(S * n, ps, *x.shape[2:])
+            out = jnp.full((S * n + 1, *pages.shape[1:]), poison, x.dtype)
+            live = table.reshape(-1) > 0
+            return out.at[table.reshape(-1)[live]].set(pages[live])
+
+        nan = jnp.nan
+        pools = (
+            (pool(k, 77), pool(v, 77), pool(ks, nan), pool(vs, nan))
+            if kv_dtype == "int8"
+            else (pool(k, nan), pool(v, nan), None, None)
+        )
+        paged = paged_decode_attention(
+            q, pools[0], pools[1], jnp.asarray(table), pos, *pools[2:],
+            impl="flash",
+        )
+        assert np.isfinite(np.asarray(paged)).all()
+        for want in (fixed, ref):
+            np.testing.assert_allclose(
+                np.asarray(paged), np.asarray(want), atol=1e-5, rtol=1e-5
+            )
+
     def test_speculative_paged_identity(self, params):
         """Spec decoding over a paged target cache (fixed-lane draft):
         greedy AND seeded streams identical to generate(), and a

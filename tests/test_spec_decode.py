@@ -384,3 +384,92 @@ class TestSpecEngine:
                 SPEC, params, prefill_len=8, spec_tokens=24,
                 draft_spec=DRAFT, draft_params=draft_params,
             )
+
+
+def _write_kv_rows_through_layer(cache, layer, k, v, pos):
+    """How ``_write_kv_rows`` wrote a fixed-lane cache before PR 25:
+    slice the layer out, update it lane by lane (the vmapped
+    ``dynamic_update_slice``, which clamps the start), write the whole
+    layer back. Kept here as the rule the in-place scatter must equal
+    bit for bit."""
+    from jax import lax
+
+    from ddp_tpu.ops.decode import quantize_kv
+
+    def write(lanes, rows):
+        start = (0,) * (lanes.ndim - 2)
+        return jax.vmap(
+            lambda lane, row, p: lax.dynamic_update_slice(
+                lane, row, (p, *start)
+            )
+        )(lanes, rows.astype(lanes.dtype), pos)
+
+    def put(buf, rows):
+        return buf.at[layer].set(write(buf[layer], rows))
+
+    ksc, vsc = cache.k_scale, cache.v_scale
+    if cache.quantized():
+        (k, k_s), (v, v_s) = quantize_kv(k), quantize_kv(v)
+        ksc, vsc = put(ksc, k_s), put(vsc, v_s)
+    return cache._replace(
+        k=put(cache.k, k), v=put(cache.v, v), k_scale=ksc, v_scale=vsc
+    )
+
+
+class TestRowWrite:
+    """``_write_kv_rows`` on a fixed-lane cache: S·T rows scattered
+    into the stored buffers, equal to the old slice-update-write-back
+    in every bit of every buffer."""
+
+    @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.int8])
+    @pytest.mark.parametrize("T", [1, 3], ids=["decode", "verify"])
+    def test_equals_update_through_the_layer(self, T, kv_dtype):
+        from ddp_tpu.models.generate import _write_kv_rows
+
+        S, layer = 4, 1
+        L = SPEC.total_len
+        rng = np.random.default_rng(T)
+        cache = init_slot_cache(SPEC, S, dtype=kv_dtype)
+        # every row of every buffer distinct and non-zero, so a write
+        # that strays (or a row left unwritten) shows
+        fill = lambda x: jnp.asarray(
+            rng.integers(1, 100, size=x.shape), x.dtype
+        )
+        cache = cache._replace(
+            k=fill(cache.k), v=fill(cache.v),
+            **(
+                dict(k_scale=fill(cache.k_scale), v_scale=fill(cache.v_scale))
+                if cache.quantized() else {}
+            ),
+        )
+        shape = (S, T, *cache.k.shape[3:])
+        k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        # lane 0 at the start, a live lane, one on the last line, and
+        # an idle lane parked at pos == total_len
+        pos = jnp.asarray([0, 11, L - 1, L], jnp.int32)
+        if T > 1:
+            # the verify step's contract: the caller pre-clamps
+            pos = jnp.minimum(pos, L - T)
+        # both jitted, as the engine runs them (XLA rewrites the
+        # quantizer's division; eager and jitted scales differ by an ulp)
+        new, old = (
+            jax.jit(fn, static_argnums=1)(cache, layer, k, v, pos)
+            for fn in (_write_kv_rows, _write_kv_rows_through_layer)
+        )
+        for name, a, b in zip(new._fields, new, old):
+            if name in ("k_scale", "v_scale") and not cache.quantized():
+                continue
+            assert jnp.array_equal(a, b), name
+        # the rows landed where the contract says, nowhere else
+        start = np.clip(np.asarray(pos), 0, L - T)
+        untouched = np.ones(cache.k.shape[:3], bool)
+        for s in range(S):
+            untouched[layer, s, start[s] : start[s] + T] = False
+        assert start[-1] == L - T  # the parked lane: the last line(s)
+        np.testing.assert_array_equal(
+            np.asarray(new.k)[untouched], np.asarray(cache.k)[untouched]
+        )
+        assert not np.array_equal(
+            np.asarray(new.k)[~untouched], np.asarray(cache.k)[~untouched]
+        )
