@@ -1,184 +1,73 @@
 """``BENCHMARK.json`` against the contract's limits on names, units and
-shape, and against the files it names."""
-
-import json
-import os
-import re
+shape, against the files it names, and against what it had when each
+rule was written. The rules are functions of a root
+(``bench_contract.py``): here they run on the checkout, and
+``test_bench_extend.py`` runs the same ones on a copy that has been
+added to."""
 
 import pytest
 
-from benchmarks.harness import manifest as mf
-
-ROOT = mf.ROOT
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+import bench_contract as bc
 
 
-@pytest.fixture(scope="module")
-def m():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+@pytest.mark.parametrize("check", bc.CHECKS, ids=bc.check_id)
+def test_the_checkout_keeps_the_contract(check):
+    check(bc.mf.ROOT)
 
 
-def _metrics(m):
-    return m["end_to_end"] + m["per_layer"]
+# ---- what may be in ``reduced``: counts, never widths ---------------------
+
+MAY_BE_REDUCED = {
+    "layers": ["n_layer", "num_hidden_layers", "num_layers",
+               "mtp_num_hidden_layers", "num_nextn_predict_layers",
+               "num_dense_layers"],
+    "experts": ["num_experts", "num_local_experts", "n_routed_experts",
+                "moe_num_experts", "zero_expert_num"],
+    "heads": ["n_head", "num_attention_heads", "num_key_value_heads",
+              "mamba_n_heads", "mamba_num_heads", "index_n_heads",
+              "swa_num_key_value_heads", "linear_num_value_heads"],
+    "vocabulary": ["vocab_size", "unpadded_vocab_size"],
+}
+NEVER_REDUCED = {
+    "width": [
+        "hidden_size", "n_embd", "n_inner", "d_model", "intermediate_size",
+        "moe_intermediate_size", "shared_intermediate_size",
+        "ffn_hidden_size", "expert_ffn_hidden_size", "head_dim",
+        "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+        "kv_lora_rank", "q_lora_rank", "sliding_window", "mamba_d_state",
+        "mamba_d_head", "mamba_d_conv", "mamba_expand", "ssm_state_size",
+        "mlp_expansion_factor", "num_experts_per_tok",
+        "num_experts_per_token", "moe_topk", "moe_top_k", "topk_group",
+        "n_group", "num_expert_group", "index_topk",
+    ],
+    # not placed: a context length, a shared expert (every chip computes
+    # it), a period, a count under a name the rule does not read
+    None: [
+        "n_positions", "max_position_embeddings", "n_shared_experts",
+        "num_shared_experts", "global_attn_every_n_layers",
+        "attn_layer_period", "first_k_dense_replace", "rope_theta",
+        "rms_norm_eps", "transformer_num_blocks", "num_mtp_modules",
+    ],
+}
 
 
-def test_top_level_keys_and_size(m):
-    assert set(m) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
+@pytest.mark.parametrize("key,meaning", [
+    (k, m) for m, keys in MAY_BE_REDUCED.items() for k in keys])
+def test_a_count_may_be_reduced(key, meaning):
+    assert bc.key_meaning(key) == meaning
+    assert bc.may_be_reduced(key)
 
 
-def test_run_seconds_fits_a_full_check_of_24_cells(m):
-    runs = 2 + 14 * 24
-    total = runs * (m["run_seconds"] + 60) + 24 * 2 * 90 + 1200
-    assert total <= 43200
+@pytest.mark.parametrize("key,meaning", [
+    (k, m) for m, keys in NEVER_REDUCED.items() for k in keys])
+def test_a_width_or_an_unplaced_key_is_never_reduced(key, meaning):
+    assert bc.key_meaning(key) == meaning
+    assert not bc.may_be_reduced(key)
 
 
-def test_command_and_paths(m):
-    assert 1 <= len(m["paths"]) <= 16
-    for p in m["paths"]:
-        assert PATH.match(p) and not p.startswith("/") and ".." not in p
-        assert os.path.isdir(os.path.join(ROOT, p))
-    assert len(m["command"]) <= 32
-    script = m["command"][1]
-    assert any(script.startswith(p + "/") for p in m["paths"])
-    assert os.path.isfile(os.path.join(ROOT, script))
-
-
-def test_names_are_unique_and_well_formed(m):
-    for section in ("configs", "workloads"):
-        names = [e["name"] for e in m[section]]
-        assert len(set(names)) == len(names)
-        assert all(NAME.match(n) for n in names), names
-    names = [e["name"] for e in _metrics(m)]
-    assert len(set(names)) == len(names)
-    assert all(NAME.match(n) for n in names), names
-    for w in m["workloads"]:
-        assert NAME.match(w["traffic"]) and NAME.match(w["config"])
-
-
-def test_units_sources_and_directions(m):
-    for e in _metrics(m):
-        assert UNIT.match(e["unit"]), e
-        assert e["better"] in ("lower", "higher")
-        assert e["source"] in SOURCES
-    for e in m["end_to_end"]:
-        assert e["source"] in ("host_clock", "device_trace")
-        assert 0 < e["bound"] <= 0.1
-        assert set(e) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-    assert any(e["name"] == "setup_s" and "workloads" not in e
-               for e in m["end_to_end"])
-
-
-def test_entries_have_just_the_keys_shown(m):
-    for c in m["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert len(c["reduced"]) <= 16
-    for w in m["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4)
-    for e in m["per_layer"]:
-        assert set(e) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert "bound" not in e
-
-
-def test_one_line_texts(m):
-    texts = [w["why"] for w in m["workloads"]]
-    texts += [c["why"] for c in m["configs"]]
-    texts += [c["source"] for c in m["configs"]]
-    texts += [e["layer"] for e in m["per_layer"]] + m["command"]
-    for t in texts:
-        assert 1 <= len(t) <= 200 and "\n" not in t and "\t" not in t, t
-
-
-def test_four_chip_cells_within_the_quarter(m):
-    four = sum(w["chips"] == 4 for w in m["workloads"])
-    assert four <= max(1, len(m["workloads"]) // 4)
-
-
-def test_configs_files_and_reduced(m):
-    files = [c["file"] for c in m["configs"]]
-    assert len(set(files)) == len(files)
-    used = {w["config"] for w in m["workloads"]}
-    for c in m["configs"]:
-        assert c["name"] in used
-        assert any(c["file"].startswith(p + "/") for p in m["paths"])
-        cfg = mf.load_json(os.path.join(ROOT, c["file"]))
-        assert cfg["source"] == c["source"]
-        assert cfg["reduced"] == c["reduced"]
-        for key in c["reduced"]:
-            assert NAME.match(key)
-            assert not re.search(r"(_dim|_rank|embd|inner|hidden|head)", key)
-        assert cfg["kind"] in ("train", "serve")
-
-
-def test_published_widths_are_never_cut(m):
-    for c in m["configs"]:
-        cfg = mf.load_json(os.path.join(ROOT, c["file"]))
-        assert (cfg["n_embd"], cfg["n_head"], cfg["n_inner"],
-                cfg["vocab_size"], cfg["n_positions"]) == (
-            2048, 16, 8192, 50257, 2048)
-        assert cfg["n_layer"] == 24 or "n_layer" in c["reduced"]
-
-
-def test_every_cell_resolves_and_reports_enough(m):
-    e2e = {e["name"] for e in m["end_to_end"]}
-    for w in m["workloads"]:
-        cell = mf.load_cell(w["name"])
-        assert os.path.isfile(os.path.join(
-            cell.bench_dir, "drivers", cell.config["kind"] + ".py"))
-        assert os.path.isfile(os.path.join(
-            cell.bench_dir, "generators",
-            cell.traffic["generator"] + ".py"))
-        names = [x["name"] for x in cell.end_to_end()]
-        assert "setup_s" in names and len(names) >= 2
-        assert len(cell.per_layer()) >= 1
-        for x in cell.per_layer():
-            assert x["moves"] in names  # the cell reports what it moves
-    for e in m["per_layer"]:
-        assert e["moves"] in e2e
-
-
-def test_each_per_layer_metric_is_a_reader_of_its_own(m):
-    d = os.path.join(ROOT, "benchmarks", "layer_metrics")
-    for e in m["per_layer"]:
-        mod = mf.load_module(os.path.join(d, e["name"] + ".py"),
-                             "t_" + e["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE) == (
-            e["name"], e["unit"], e["layer"], e["moves"], e["source"])
-        assert callable(mod.read)
-
-
-def test_roofline_and_mfu_shares_are_percentages(m):
-    for e in _metrics(m):
-        if e["name"].endswith("_roofline_pct") or "mfu" in e["name"]:
-            assert e["unit"] == "%"
-
-
-def test_harness_holds_no_cell_configuration_or_metric_name(m):
-    names = {e["name"] for s in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for e in m[s]}
-    names -= {"setup_s"}
-    for rel in ("run.py", "sweep.py", "harness/manifest.py",
-                "harness/window.py", "harness/trace.py",
-                "harness/result.py", "harness/device.py"):
-        text = open(os.path.join(ROOT, "benchmarks", rel)).read()
-        held = [n for n in names if n in text]
-        assert not held, (rel, held)
-
-
-def test_files_under_paths_are_named_from_allowed_characters(m):
-    for p in m["paths"]:
-        for d, _, fs in os.walk(os.path.join(ROOT, p)):
-            if "__pycache__" in d:
-                continue
-            for f in fs:
-                rel = os.path.relpath(os.path.join(d, f), ROOT)
-                assert PATH.match(rel), rel
+def test_floors_of_a_reduced_count():
+    assert bc.floor_of("num_hidden_layers", 48) == 4
+    assert bc.floor_of("num_dense_layers", 3) == 1  # a leading group
+    assert bc.floor_of("n_routed_experts", 128) == 8
+    assert bc.floor_of("num_key_value_heads", 4) == 1
+    assert bc.floor_of("vocab_size", 151936) == 18992
