@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ddp_tpu.models.lm import LMSpec
+from ddp_tpu.models.lm import LMSpec, head_dim_of
 from ddp_tpu.ops.attention import best_attention, dot_product_attention
 from ddp_tpu.ops.decode import (
     decode_attention,
@@ -59,8 +59,8 @@ def _kv_heads(spec: LMSpec) -> int:
 
 
 def init_cache(spec: LMSpec, batch: int, dtype=jnp.float32) -> DecodeCache:
-    head_dim = spec.d_model // spec.num_heads
-    shape = (spec.depth, batch, spec.total_len, _kv_heads(spec), head_dim)
+    shape = (spec.depth, batch, spec.total_len, _kv_heads(spec),
+             head_dim_of(spec))
     return DecodeCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(shape, dtype),
@@ -494,8 +494,8 @@ def init_slot_cache(
     plus per-(position, head) fp32 scales — cache HBM per slot drops
     to ~(1 + 4/Dh)/8 of the fp32 layout, the ``slots``-per-chip
     capacity win `bench.py serve_decode` measures."""
-    head_dim = spec.d_model // spec.num_heads
-    shape = (spec.depth, slots, spec.total_len, _kv_heads(spec), head_dim)
+    shape = (spec.depth, slots, spec.total_len, _kv_heads(spec),
+             head_dim_of(spec))
     # Two DISTINCT buffers: the cache is donated through every engine
     # program, and aliased leaves ((x,) * 2) make XLA reject the
     # donation ("same buffer twice").
@@ -580,8 +580,8 @@ def init_paged_slot_cache(
             f"page_size {page_size} must divide total_len "
             f"{spec.total_len}"
         )
-    head_dim = spec.d_model // spec.num_heads
-    shape = (spec.depth, num_pages, page_size, _kv_heads(spec), head_dim)
+    shape = (spec.depth, num_pages, page_size, _kv_heads(spec),
+             head_dim_of(spec))
     scales = (
         (jnp.zeros(shape[:-1], jnp.float32),
          jnp.zeros(shape[:-1], jnp.float32))

@@ -152,6 +152,32 @@ class LMSpec(NamedTuple):
     # decode HBM reads shrink by num_heads/num_kv_heads.
     num_kv_heads: int = 0
     mlp_ratio: int = 4
+    # The block's architecture. ``gpt2`` (learned positions, LayerNorm,
+    # GELU, tied head) is everything above; ``qwen3_moe`` is
+    # models/sdar.py's block (rotary positions, RMSNorm, per-head q/k
+    # norm, every MLP ``num_experts`` routed SwiGLU experts of width
+    # ``moe_intermediate``, untied head, no biases, no position table:
+    # ``total_len`` is then the cache's length alone). Serving only.
+    block: str = "gpt2"
+    head_dim: int = 0  # 0 -> d_model // num_heads
+    moe_intermediate: int = 0
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    # How the model generates. 0: one token a step, left to right.
+    # B > 0: a block of B positions at a time by masked diffusion
+    # (models/sdar.py): ``denoise_steps`` steps a block, masked
+    # positions fed ``mask_token_id``, unmasked by ``unmask``
+    # (low_confidence_static | low_confidence_dynamic, the latter
+    # taking every position above ``unmask_threshold``).
+    block_length: int = 0
+    denoise_steps: int = 0
+    mask_token_id: int = -1
+    unmask: str = "low_confidence_static"
+    unmask_threshold: float = 0.9
+
+
+def head_dim_of(spec: LMSpec) -> int:
+    return spec.head_dim or spec.d_model // spec.num_heads
 
 
 def derive_lm_spec(params: Any, *, num_heads: int, **overrides) -> LMSpec:
@@ -167,6 +193,10 @@ def derive_lm_spec(params: Any, *, num_heads: int, **overrides) -> LMSpec:
     Raises ValueError when the tree is not a causal-LM tree or the
     head count does not explain the shapes.
     """
+    if "embed_tokens" in params:
+        from ddp_tpu.models.sdar import derive_spec
+
+        return derive_spec(params, num_heads=num_heads, **overrides)
     try:
         vocab_size, d_model = (int(s) for s in params["embed"].shape)
         total_len = int(params["pos_embed"].shape[1])

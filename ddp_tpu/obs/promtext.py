@@ -359,6 +359,32 @@ def render_serve(
         "step): attended over this is the share of lane bytes the "
         "banded read fetches",
     )
+    # Block diffusion (models/sdar.py): present only on an engine whose
+    # model generates by blocks.
+    bd = stats.get("block_diffusion") or {}
+    for key, help_ in (
+        ("block_forwards_total", "lane-forwards of generating lanes"),
+        ("blocks_committed_total", "blocks whose K/V rows were committed"),
+        ("tokens_committed_total",
+         "tokens committed, never more than a request asked for"),
+        ("positions_unmasked_total", "block positions unmasked"),
+    ):
+        if key in bd:
+            b.add(f"ddp_tpu_serve_{key}", bd[key],
+                  metric_type="counter", help=help_)
+    for key, help_ in (
+        ("moe_tokens_routed_total",
+         "rows the expert layers were handed (tokens x top_k x layers)"),
+        ("moe_experts_hit_total", "experts that held a row, over layer calls"),
+        ("moe_layer_calls_total", "expert-layer calls"),
+    ):
+        if key in bd:
+            b.add(f"ddp_tpu_serve_{key}", bd[key],
+                  metric_type="counter", help=help_)
+    if "moe_expert_load_max" in bd:
+        b.add("ddp_tpu_serve_moe_expert_load_max", bd["moe_expert_load_max"],
+              help="the fullest expert's rows in the last step, mean "
+              "over layers")
     b.summary(
         "ddp_tpu_serve_ttft_seconds", stats.get("ttft_s"),
         help="submit to first token",

@@ -70,6 +70,9 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "serve.prefill_chunk": ("rid", "slot", "start", "width", "final"),
     "serve.decode": ("lanes", "rows_attended"),
     "serve.spec_verify": ("lanes", "drafted", "accepted"),
+    # the dispatch of one forward over every lane's block; unmasked and
+    # committed are the last FETCHED report's (a step or two behind)
+    "serve.block_step": ("lanes", "unmasked", "committed"),
     "serve.sample": ("tokens",),
     # serve/server.py — one event per request, at hand-back
     "server.request": ("rid", "lock_wait_s", "pickup_s", "poll_wait_s"),

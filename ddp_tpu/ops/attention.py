@@ -123,7 +123,8 @@ def gspmd_flash_attention(mesh, *, causal: bool = False, block_q: int = 512,
     return fn
 
 
-def dot_product_attention(q, k, v, *, causal: bool = False, q_offset=None):
+def dot_product_attention(q, k, v, *, causal: bool = False, q_offset=None,
+                          block: int = 1):
     """Plain softmax attention, fp32 accumulation.
 
     [B, T, H, D] in/out. Softmax runs in fp32 regardless of input dtype
@@ -141,6 +142,11 @@ def dot_product_attention(q, k, v, *, causal: bool = False, q_offset=None):
     ``q_offset`` inside an S = total_len key lane, so the banded mask
     depends on a runtime value while the compiled shape stays fixed
     (one program per chunk width, any chunk position).
+
+    ``block`` > 1 makes the causal mask BLOCK-causal: key j is visible
+    to the query at absolute position i iff ``j // block <= i // block``
+    — bidirectional inside a block, causal between blocks (the
+    block-diffusion prefill, models/sdar.py). 1 is the plain triangle.
     """
     dtype = q.dtype
     scale = q.shape[-1] ** -0.5
@@ -148,9 +154,10 @@ def dot_product_attention(q, k, v, *, causal: bool = False, q_offset=None):
     if causal:
         T, S = logits.shape[-2:]
         offset = (S - T) if q_offset is None else q_offset
-        mask = (
-            jnp.arange(T)[:, None] + offset >= jnp.arange(S)[None, :]
-        )
+        rows = jnp.arange(T)[:, None] + offset
+        if block > 1:  # a query sees its whole block
+            rows = rows // block * block + (block - 1)
+        mask = rows >= jnp.arange(S)[None, :]
         logits = jnp.where(mask, logits, MASK_VALUE)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhts,bshd->bthd", weights.astype(dtype), v)

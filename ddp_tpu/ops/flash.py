@@ -76,12 +76,26 @@ def _row_stat(ref):
     return ref[0][:, :1]
 
 
-def _causal_mask(s, q_start, k_start, block_q, block_k, S_total, T_total):
+def _last_key(row, causal):
+    """The last key the query at (end-anchored) position ``row`` sees.
+    ``causal`` is True / 1 for the plain triangle (the row itself), or
+    an int B > 1 for the BLOCK-causal mask: the end of the row's block
+    of B positions, so key j is visible iff ``j // B <= row // B`` —
+    bidirectional inside a block, causal between blocks. A plain
+    function of its arguments: the kernels call it on iotas and on
+    block corners, the tests on integers."""
+    b = int(causal)
+    return row if b <= 1 else row // b * b + (b - 1)
+
+
+def _causal_mask(s, q_start, k_start, block_q, block_k, S_total, T_total,
+                 causal=True):
     """End-anchored causal mask: query t sees keys up to t + S − T
-    (the dense reference's tril(k=S−T); KV-cache convention for T≠S)."""
-    rows = q_start + (S_total - T_total) + lax.broadcasted_iota(
+    (the dense reference's tril(k=S−T); KV-cache convention for T≠S),
+    or to the end of its block under a block-causal ``causal``."""
+    rows = _last_key(q_start + (S_total - T_total) + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
-    )
+    ), causal)
     cols = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     return jnp.where(rows >= cols, s, -jnp.inf)
 
@@ -103,7 +117,9 @@ def _fwd_kernel(
 
     if causal:
         # Fully-masked (strictly future) block: skip all compute.
-        live = q_start + block_q - 1 + (S_total - T_total) >= j * block_k
+        live = _last_key(
+            q_start + block_q - 1 + (S_total - T_total), causal
+        ) >= j * block_k
     else:
         live = True
 
@@ -118,7 +134,8 @@ def _fwd_kernel(
         )  # [block_q, block_k]
         if causal:
             s = _causal_mask(
-                s, q_start, j * block_k, block_q, block_k, S_total, T_total
+                s, q_start, j * block_k, block_q, block_k, S_total, T_total,
+                causal,
             )
         m = m_ref[...][:, :1]
         l = l_ref[...][:, :1]
@@ -168,7 +185,9 @@ def _dq_kernel(
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     if causal:
-        live = q_start + block_q - 1 + (S_total - T_total) >= j * block_k
+        live = _last_key(
+            q_start + block_q - 1 + (S_total - T_total), causal
+        ) >= j * block_k
     else:
         live = True
 
@@ -189,7 +208,8 @@ def _dq_kernel(
         )
         if causal:
             s = _causal_mask(
-                s, q_start, j * block_k, block_q, block_k, S_total, T_total
+                s, q_start, j * block_k, block_q, block_k, S_total, T_total,
+                causal,
             )
         p = jnp.exp(s - lse)  # masked: exp(-inf) = 0
         dp = lax.dot_general(
@@ -225,7 +245,9 @@ def _dkv_kernel(
     if causal:
         # Last query row of this Q block must see the first key of
         # this K block: (i+1)·bq − 1 + S − T >= k_start.
-        live = (i + 1) * block_q - 1 + (S_total - T_total) >= k_start
+        live = _last_key(
+            (i + 1) * block_q - 1 + (S_total - T_total), causal
+        ) >= k_start
     else:
         live = True
 
@@ -246,7 +268,8 @@ def _dkv_kernel(
         )  # [block_q, block_k]
         if causal:
             s = _causal_mask(
-                s, i * block_q, k_start, block_q, block_k, S_total, T_total
+                s, i * block_q, k_start, block_q, block_k, S_total, T_total,
+                causal,
             )
         p = jnp.exp(s - lse)
         dv_acc[...] = dv_acc[...] + lax.dot_general(
@@ -447,8 +470,8 @@ def _reference(q, k, v, causal: bool):
     )
     if causal:
         T, S = logits.shape[-2:]
-        mask = jnp.tril(jnp.ones((T, S), bool), k=S - T)
-        logits = jnp.where(mask, logits, -jnp.inf)
+        rows = _last_key(jnp.arange(T)[:, None] + (S - T), causal)
+        logits = jnp.where(rows >= jnp.arange(S)[None, :], logits, -jnp.inf)
     weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhts,bshd->bthd", weights, v.astype(jnp.float32))
     return out.astype(dtype)
@@ -468,7 +491,10 @@ def flash_attention(
 
     ``interpret=True`` for CPU (tests); on TPU the kernels compile via
     Mosaic. Use keyword-style through ``make_flash_attention`` for the
-    model-facing ``(q, k, v) -> out`` contract.
+    model-facing ``(q, k, v) -> out`` contract. ``causal`` may be an
+    int B > 1: the block-causal mask of :func:`_last_key` (a block-
+    diffusion prefill; B divides the chunk, so whole blocks stay
+    inside a query tile).
     """
     out, _ = _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,

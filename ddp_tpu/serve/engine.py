@@ -74,7 +74,8 @@ from ddp_tpu.models.generate import (
 )
 from ddp_tpu.models.generate import slot_decode_step as _decode_step
 from ddp_tpu.models.generate import slot_verify_step as _verify_step
-from ddp_tpu.models.lm import LMSpec
+from ddp_tpu.models import sdar as _sdar
+from ddp_tpu.models.lm import LMSpec, head_dim_of
 from ddp_tpu.ops.decode import DEFAULT_BLOCK_K, decode_block
 from ddp_tpu.obs.tracer import Tracer, get_tracer
 from ddp_tpu.serve.pages import PrefixCache, page_demand
@@ -125,17 +126,25 @@ class Completion:
     # cached prefix pages — zero prefill compute paid for them. None
     # on fixed-lane engines; 0 = paged but missed.
     prefix_hit_tokens: Optional[int] = None
+    # Tokens that arrived AT ``ttft``: 1 on the one-token path; under
+    # block diffusion the first committed block's share of the answer.
+    first_tokens: int = 1
+    # Block diffusion, on request (``record_blocks``): every forward
+    # the lane ran for this request, ``(pos, tokens [B], mask [B])`` as
+    # the forward saw them — what the benchmark hands its reference.
+    block_inputs: Optional[list] = None
 
     @property
     def decode_tokens_per_s(self) -> float:
-        n = len(self.tokens) - 1  # tokens after the prefill token
+        n = len(self.tokens) - self.first_tokens  # tokens after the first
         return n / self.decode_seconds if self.decode_seconds > 0 else 0.0
 
     @property
     def tpot_s(self) -> Optional[float]:
         """Time per output token (decode only) — the per-request SLI
-        behind the tpot_p50 objective; None before a second token."""
-        n = len(self.tokens) - 1
+        behind the tpot_p50 objective; None before a second token.
+        Committed tokens, never forward positions."""
+        n = len(self.tokens) - self.first_tokens
         if n <= 0 or self.decode_seconds <= 0:
             return None
         return self.decode_seconds / n
@@ -168,24 +177,40 @@ class _Slot:
     # came from cached prefix pages.
     pages: list[int] = field(default_factory=list)
     matched_tokens: int = 0
+    # Block diffusion only. ``prefill_target``: the prompt tokens the
+    # chunks ingest (its whole blocks; the rest opens the first
+    # generated block), None on the one-token path. ``installed``: the
+    # final chunk has put the lane's first block on the device.
+    # ``lead``: prompt tokens at the head of the block in hand.
+    # ``block_pos``: that block's first position. ``first_tokens``:
+    # tokens the first commit brought. ``block_log``: the recorded
+    # forwards, when the request asked for them.
+    prefill_target: Optional[int] = None
+    installed: bool = True
+    lead: int = 0
+    block_pos: int = 0
+    first_tokens: int = 1
+    block_log: Optional[list] = None
 
     @property
     def free(self) -> bool:
         return self.request is None
 
     @property
+    def prefill_goal(self) -> int:
+        if self.prefill_target is not None:
+            return self.prefill_target
+        return len(self.request.prompt)
+
+    @property
     def prefilling(self) -> bool:
-        return (
-            self.request is not None
-            and self.prefill_pos < len(self.request.prompt)
+        return self.request is not None and (
+            self.prefill_pos < self.prefill_goal or not self.installed
         )
 
     @property
     def decoding(self) -> bool:
-        return (
-            self.request is not None
-            and self.prefill_pos >= len(self.request.prompt)
-        )
+        return self.request is not None and not self.prefilling
 
 
 def drain_eta_s(
@@ -281,7 +306,7 @@ def resolve_engine_knobs(
             decode_block_k = decode_block(
                 spec.total_len,
                 spec.num_kv_heads or spec.num_heads,
-                spec.d_model // spec.num_heads,
+                head_dim_of(spec),
                 jnp.int8 if kv_dtype == "int8" else jnp.float32,
             )
         except ValueError as e:
@@ -357,13 +382,27 @@ def resolve_engine_knobs(
                 f"{spec.total_len}"
             )
     spec_tokens = int(spec_tokens)
-    # Admission context ceiling: the verify round's K-1 reserve
-    # comes off the budget check, never the cache geometry.
-    ctx_len = spec.total_len - max(0, spec_tokens - 1)
+    # Block diffusion (models/sdar.py): the model's spec says so, no
+    # flag does. A lane then holds a block of ``block_len`` positions
+    # and a step is one forward over it.
+    block_len = int(spec.block_length)
+    if block_len or spec.block != "gpt2":
+        _sdar.validate(spec)
+        if paged or kv_dtype != "fp32" or spec_tokens:
+            raise ValueError(
+                "a model that generates by blocks serves from fixed "
+                "fp32 lanes without speculation: page_size, "
+                f"kv_dtype={kv_dtype!r} and spec_tokens do not apply"
+            )
+    # Admission context ceiling: the verify round's K-1 reserve (the
+    # block step's B-1: a last block may overhang what the request
+    # asked for) comes off the budget check, never the cache geometry.
+    ctx_len = spec.total_len - max(0, spec_tokens - 1, block_len - 1)
     # Decode-path tokens dispatched per running lane per step: 1
     # plain, K under speculation (the verify round processes K
-    # positions per lane — plan_chunks accounts them all).
-    tokens_per_decode = max(1, spec_tokens)
+    # positions per lane — plan_chunks accounts them all), B in a
+    # block.
+    tokens_per_decode = max(1, spec_tokens, block_len)
     chunk = next_pow2(
         prefill_chunk
         if prefill_chunk
@@ -382,6 +421,11 @@ def resolve_engine_knobs(
         next_pow2(min_bucket) if min_bucket else min(8, chunk),
         prev_pow2(spec.total_len - prefill_len + 1),
     )
+    if min_bucket < block_len:
+        raise ValueError(
+            f"min_bucket {min_bucket} is narrower than the model's "
+            f"block of {block_len}: chunks hold whole blocks"
+        )
     step_token_budget = (
         step_token_budget
         if step_token_budget
@@ -409,6 +453,7 @@ def resolve_engine_knobs(
         "lane_pages": lane_pages,
         "kv_pages": resolved_kv_pages,
         "spec_tokens": spec_tokens,
+        "block_len": block_len,
         "ctx_len": ctx_len,
         "tokens_per_decode": tokens_per_decode,
         "chunk": chunk,
@@ -427,9 +472,19 @@ class ServeEngine:
     ``min_bucket`` floors the power-of-two bucket of the final partial
     chunk; ``step_token_budget`` bounds chunk-plus-decode tokens
     dispatched per step (default chunk + slots — one full-width chunk
-    can ride along with a full decode batch). ``clock`` is injectable
-    for deterministic tests; MetricsWriter ``metrics`` may be shared
-    with a trainer's stream or omitted.
+    can ride along with a full decode batch). ``admit_every`` N > 0
+    spaces admissions: while any lane runs, at most one request is
+    bound to a lane every N engine steps (0, the default: every free
+    lane is refilled at once). Requests of one length that are admitted
+    together finish together and are refilled together, for ever: the
+    engine then alternates between steps full of prefill chunks and
+    steps of decode alone, and answers come ``slots`` at a time. With
+    N a little under (steps a request takes) / ``slots`` the lanes
+    spread over a request's length while the engine fills; from then
+    on each lane frees N or more steps after the last and is refilled
+    at once, so the spacing costs nothing. A larger N starves lanes.
+    ``clock`` is injectable for deterministic tests; MetricsWriter
+    ``metrics`` may be shared with a trainer's stream or omitted.
 
     ``page_size`` > 0 (power of two dividing ``spec.total_len``)
     switches the KV cache to the PAGED layout (PR 12): K/V live in a
@@ -458,6 +513,7 @@ class ServeEngine:
         prefill_chunk: Optional[int] = None,
         min_bucket: Optional[int] = None,
         step_token_budget: Optional[int] = None,
+        admit_every: int = 0,
         max_queue: int = 64,
         metrics: Optional[MetricsWriter] = None,
         tracer: Optional[Tracer] = None,
@@ -520,6 +576,10 @@ class ServeEngine:
         # may overshoot its context by up to K-2 positions — reserved
         # rather than clamp-shifted over live lines).
         self.spec_tokens = knobs["spec_tokens"]
+        # Block diffusion (models/sdar.py), read from the model's spec:
+        # > 0, a decoding lane holds a block of this many positions and
+        # a step runs one forward over it (``_block_round``).
+        self.block_len = knobs["block_len"]
         # The engine drives ONE device; it has no mesh (ROADMAP C9).
         # Weights and every piece of engine state are COMMITTED to it
         # up front: a restored checkpoint's arrays are committed, jit
@@ -552,6 +612,12 @@ class ServeEngine:
         self._ctx_len = ctx_len
         self._tokens_per_decode = tokens_per_decode
         self.step_token_budget = knobs["step_token_budget"]
+        if admit_every < 0:
+            raise ValueError(
+                f"admit_every must be >= 0, got {admit_every}"
+            )
+        self.admit_every = int(admit_every)
+        self._last_admit_step: Optional[int] = None
         self.clock = clock
         self.metrics = metrics or MetricsWriter(None)
         # Span tracing (ddp_tpu.obs): the step, its retire and admit
@@ -848,6 +914,57 @@ class ServeEngine:
                 ),
                 "serve.spec_verify",
             )
+        if self.block_len:
+            # A lane "in a block": the block's tokens and mask (and the
+            # sampling state) live on the device with the lane
+            # (sdar.BlockLanes, donated through both programs like the
+            # cache). The same two chunk programs per bucket, under the
+            # block-causal mask and owing no token, and ONE block-step
+            # program in the decode program's place: forward, choice,
+            # unmasking and, for lanes whose block is clean, the
+            # commit, for all lanes in one call.
+            self._lanes = self._put(_sdar.init_block_lanes(spec, slots))
+
+            def _block_chunk_fn(name, lane_attend):
+                return jax.jit(
+                    _named(name, lambda p, c, ln, *a: _sdar.prefill_chunk(
+                        spec, p, c, ln, *a, lane_attend=lane_attend,
+                    )),
+                    donate_argnums=(1, 2),
+                )
+
+            self._chunk_first = self._xprof.instrument(
+                _block_chunk_fn("serve_prefill_first", False),
+                "serve.prefill_first",
+            )
+            self._chunk_cont = self._xprof.instrument(
+                _block_chunk_fn("serve_prefill_chunk", True),
+                "serve.prefill_chunk",
+            )
+            self._decode = self._xprof.instrument(
+                jax.jit(
+                    _named("serve_block_step", lambda p, c, ln:
+                           _sdar.block_step(spec, p, c, ln, attn_impl=impl)),
+                    donate_argnums=(1, 2),
+                ),
+                "serve.block_step",
+            )
+        # Block-diffusion tallies, from what the device reports one
+        # step behind: lane-forwards of generating lanes, blocks and
+        # tokens COMMITTED (never forward positions, never more than a
+        # request asked for); and the expert layer's routing counts
+        # (rows routed, the fullest expert's rows summed over layers
+        # and steps beside the last step's fullest, experts hit).
+        self.block_forwards_total = 0
+        self.blocks_committed_total = 0
+        self.tokens_committed_total = 0
+        self.positions_unmasked_total = 0
+        self.moe_tokens_routed_total = 0
+        self.moe_expert_load_max_sum = 0
+        self.moe_expert_load_max = 0
+        self.moe_experts_hit_total = 0
+        self.moe_layer_calls_total = 0
+        self._last_round = (0, 0)  # (unmasked, committed) last fetched
         # Engine-lifetime speculative tallies (the /stats + bench
         # acceptance-rate source); zero-cost when speculation is off.
         self.spec_drafted_total = 0
@@ -873,8 +990,12 @@ class ServeEngine:
         trace: Optional[str] = None,
         hops: Optional[dict] = None,
         model: Optional[str] = None,
+        record_blocks: bool = False,
     ) -> Admission:
         """Admission-checked enqueue; rejections carry a reason.
+
+        ``record_blocks`` (a model that generates by blocks only):
+        keep every forward's block inputs for the completion.
 
         ``trace`` is an inbound fleet trace-context line (the router's
         ``00-<trace>-<span>-<parent>``): a VALID one is adopted — the
@@ -905,6 +1026,7 @@ class ServeEngine:
             timeout=timeout,
             trace_id=adopted[0] if adopted else None,
             model=model,
+            record_blocks=bool(record_blocks) and bool(self.block_len),
         )
         if not adm.accepted:
             self.reject_counts[adm.reason] = (
@@ -963,7 +1085,9 @@ class ServeEngine:
         counts = {
             "prefill_first": self._chunk_first._cache_size(),
             "prefill_chunk": self._chunk_cont._cache_size(),
-            "decode": self._decode._cache_size(),
+            # the block step stands in the decode program's place
+            "block_step" if self.block_len else "decode":
+                self._decode._cache_size(),
         }
         if self.spec_tokens:
             counts.update(
@@ -999,6 +1123,21 @@ class ServeEngine:
         if self.active:
             raise RuntimeError("warmup() requires an idle engine")
         zero = jnp.int32(0)
+        if self.block_len:
+            tail = jnp.zeros((self.block_len,), jnp.int32)
+            for fn in (self._chunk_first, self._chunk_cont):
+                for w in self.buckets:
+                    self._cache, self._lanes, _ = fn(
+                        self.params, self._cache, self._lanes, zero,
+                        jnp.zeros((w,), jnp.int32), zero, jnp.int32(w),
+                        jnp.asarray(False), tail, zero, zero, zero,
+                        jnp.float32(0.0), jnp.float32(1.0),
+                    )
+            self._cache, self._lanes, report, _ = self._decode(
+                self.params, self._cache, self._lanes
+            )
+            jax.block_until_ready(report)
+            return self.compile_counts()
         for fn in (self._chunk_first, self._chunk_cont):
             for w in self.buckets:
                 (self._cache, self._toks, self._seeds,
@@ -1292,6 +1431,26 @@ class ServeEngine:
             "tokens": len(pids) * self.page_size,
         }
 
+    def block_stats(self) -> dict:
+        """The block-diffusion counters (``/stats``'s
+        ``block_diffusion``, ``/metricsz``'s ``ddp_tpu_serve_block_*``
+        and ``ddp_tpu_serve_moe_*``); plain host ints, readable
+        without the server's lock."""
+        return {
+            "block_length": self.block_len,
+            "denoise_steps": self.spec.denoise_steps,
+            "unmask": self.spec.unmask,
+            "block_forwards_total": self.block_forwards_total,
+            "blocks_committed_total": self.blocks_committed_total,
+            "tokens_committed_total": self.tokens_committed_total,
+            "positions_unmasked_total": self.positions_unmasked_total,
+            "moe_tokens_routed_total": self.moe_tokens_routed_total,
+            "moe_expert_load_max": self.moe_expert_load_max,
+            "moe_expert_load_max_sum": self.moe_expert_load_max_sum,
+            "moe_experts_hit_total": self.moe_experts_hit_total,
+            "moe_layer_calls_total": self.moe_layer_calls_total,
+        }
+
     def spec_acceptance_rate(self) -> Optional[float]:
         """Lifetime draft-acceptance fraction, None before any verify
         round (or when speculation is off)."""
@@ -1370,6 +1529,12 @@ class ServeEngine:
                 if include_states
                 else {}
             ),
+            # Block diffusion: absent on a one-token model, whose
+            # stats stay byte-identical.
+            **(
+                {"block_diffusion": self.block_stats()}
+                if self.block_len else {}
+            ),
             # Paged KV + prefix index (PR 12): absent on fixed-lane
             # engines, so the default /metricsz exposition stays
             # byte-identical to the pre-paging engine's.
@@ -1434,6 +1599,7 @@ class ServeEngine:
                 "min_bucket": self.min_bucket,
                 "buckets": list(self.buckets),
                 "step_token_budget": self.step_token_budget,
+                "admit_every": self.admit_every,
             },
             "decode_path": {
                 "attn_impl": self.decode_attn,
@@ -1519,8 +1685,11 @@ class ServeEngine:
                 if req is None:
                     continue
                 if slot.emitted >= req.max_new_tokens:
-                    # the completion needs its token values
-                    self._drain(parent=span.t0)
+                    # the completion needs its token values (in a
+                    # block they are in hand: commits are counted as
+                    # they are fetched, so nothing waits)
+                    if not self.block_len:
+                        self._drain(parent=span.t0)
                     self._finish(slot, COMPLETE)
                     finished += 1
                 elif req.expired(now):
@@ -1550,6 +1719,16 @@ class ServeEngine:
                     break
                 if not slot.free or self.scheduler.depth == 0:
                     continue
+                # Spaced admission (``admit_every``): an idle engine
+                # takes its first request at once.
+                if (
+                    self.admit_every
+                    and self._last_admit_step is not None
+                    and self._steps - self._last_admit_step
+                    < self.admit_every
+                    and self.active > 0
+                ):
+                    break
                 req = self.scheduler.next_request()
                 if req is None:
                     break
@@ -1560,7 +1739,9 @@ class ServeEngine:
                     # (later requests must not overtake it) and retry
                     # after retirements free pages.
                     break
-                admitted += outcome == "bound"
+                if outcome == "bound":
+                    admitted += 1
+                    self._last_admit_step = self._steps
 
             # Paged mode: page-table mutations (binds above, retires
             # at the top of this step) upload ONCE here, before any
@@ -1573,7 +1754,7 @@ class ServeEngine:
                 self._table_dirty = False
 
             prefilling = [
-                (i, s.prefill_pos, len(s.request.prompt) - s.prefill_pos)
+                (i, s.prefill_pos, s.prefill_goal - s.prefill_pos)
                 for i, s in enumerate(self._slots)
                 if s.prefilling
             ]
@@ -1602,6 +1783,7 @@ class ServeEngine:
         self._pending = []
         self._step_spec = (0, 0)  # (drafted, accepted) this step
         produced = 0
+        tokens_before = self.tokens_emitted_total
         w0 = self.clock()
         t_dispatch = time.perf_counter()
         device_work = False
@@ -1610,8 +1792,8 @@ class ServeEngine:
             slot = self._slots[i]
             req = slot.request
             start = slot.prefill_pos
-            live = min(width, len(req.prompt) - start)
-            final = start + live == len(req.prompt)
+            live = min(width, slot.prefill_goal - start)
+            final = start + live == slot.prefill_goal
             with tracer.span(
                 "serve.prefill_chunk", parent=parent,
                 nums=(req.rid, i, start, width, int(final)),
@@ -1626,17 +1808,38 @@ class ServeEngine:
                 slot_i, tok_buf = jnp.int32(i), jnp.asarray(buf)
                 start_t, live_t = jnp.int32(start), jnp.int32(live)
                 final_t = jnp.asarray(final)
-                (self._cache, self._toks, self._seeds, self._sample_steps,
-                 self._temps, self._top_ps, first) = fn(
-                    self.params, self._cache, self._toks, self._seeds,
-                    self._sample_steps, self._temps, self._top_ps,
-                    slot_i, tok_buf, start_t, live_t, final_t,
-                    # Exact int32 seed (admission range-checks it): any
-                    # masking here would break token-identity with
-                    # generate(seed=...) for negative seeds.
+                # Exact int32 seed (admission range-checks it): any
+                # masking here would break token-identity with
+                # generate(seed=...) for negative seeds.
+                sampling = (
                     jnp.int32(req.seed),
                     jnp.float32(req.temperature), jnp.float32(req.top_p),
                 )
+                if self.block_len:
+                    # The final chunk installs the lane's first block:
+                    # the prompt's last len % B tokens open it, and
+                    # what the request is owed rides along. It owes no
+                    # token; its routing counts are fetched a step
+                    # behind like everything else.
+                    tail = np.zeros((self.block_len,), np.int32)
+                    tail[: slot.lead] = req.prompt[slot.prefill_goal :]
+                    self._cache, self._lanes, moe = fn(
+                        self.params, self._cache, self._lanes,
+                        slot_i, tok_buf, start_t, live_t, final_t,
+                        jnp.asarray(tail), jnp.int32(slot.lead),
+                        jnp.int32(req.max_new_tokens), *sampling,
+                    )
+                    self._pending.append(("moe", moe, None))
+                    first = None
+                else:
+                    (self._cache, self._toks, self._seeds,
+                     self._sample_steps, self._temps, self._top_ps,
+                     first) = fn(
+                        self.params, self._cache, self._toks, self._seeds,
+                        self._sample_steps, self._temps, self._top_ps,
+                        slot_i, tok_buf, start_t, live_t, final_t,
+                        *sampling,
+                    )
                 if self.spec_tokens:
                     # The draft cache tracks the same token history: the
                     # same chunk ingests into its lane (never final — the
@@ -1669,7 +1872,10 @@ class ServeEngine:
                         t0, chunk_dur, start=start, bucket=width,
                         tokens=live, final=final,
                     )
-            if final:
+            if final and self.block_len:
+                slot.installed = True
+                decode_lanes.append(i)
+            elif final:
                 slot.emitted = 1
                 produced += 1
                 self._pending.append(("first", first, i))
@@ -1685,7 +1891,10 @@ class ServeEngine:
         # every decoding lane already filled its budget (all retiring
         # next step) would compute a full [S, total_len] decode and
         # throw the entire output away.
-        if emit_lanes and self.spec_tokens:
+        if emit_lanes and self.block_len:
+            self._block_round(emit_lanes, parent)
+            device_work = True
+        elif emit_lanes and self.spec_tokens:
             produced += self._spec_round(emit_lanes, parent)
             device_work = True
         elif emit_lanes:
@@ -1733,7 +1942,12 @@ class ServeEngine:
             self._productive_s += self.clock() - w0
 
         self._steps += 1
-        self.tokens_emitted_total += produced
+        if self.block_len:
+            # Committed tokens were counted as they were fetched
+            # (``_drain``): a forward yields none of its own.
+            produced = self.tokens_emitted_total - tokens_before
+        else:
+            self.tokens_emitted_total += produced
         self.step_latency.add(time.perf_counter() - t_step)
         # Speculative rounds report their per-step acceptance in the
         # serve_step stream (the ISSUE-10 contract); non-speculative
@@ -1795,6 +2009,73 @@ class ServeEngine:
         )
 
     # ---- internals --------------------------------------------------
+
+    def _block_round(self, lanes: list[int], parent: float) -> None:
+        """One forward over every lane's block (``sdar.block_step``),
+        dispatched and left: what it did — which lanes were
+        generating, which committed their block and with which tokens,
+        how many positions were unmasked — comes back in ONE
+        ``[S, 2B + 3]`` int32 report, fetched a step behind like the
+        decode step's token vector, so the steps of a block cost no
+        host round trip. ``lanes`` are the lanes the host holds to be
+        in a block; each rides along with its request's id, because by
+        the time the report is read the lane may have been retired and
+        bound again. Counts (forwards, commits, tokens) are taken from
+        the report: the device stops a lane the moment its request is
+        paid off, a step before the host can know.
+        """
+        unmasked, committed = self._last_round
+        with self.tracer.span(
+            "serve.block_step", parent=parent,
+            nums=(len(lanes), unmasked, committed),
+        ), self._sanitizer.guard():
+            self._cache, self._lanes, report, moe = self._decode(
+                self.params, self._cache, self._lanes
+            )
+        self._pending.append((
+            "block", (report, moe),
+            [(i, self._slots[i].request.rid) for i in lanes],
+        ))
+
+    def _read_block_report(self, report, lanes) -> int:
+        """Book one fetched block-step report -> tokens committed."""
+        B = self.block_len
+        appended = unmasked = committed = 0
+        for i, rid in lanes:
+            slot = self._slots[i]
+            req = slot.request
+            row = report[i]
+            # after the block's tokens and mask: sdar.REPORT_EXTRA
+            was_active, did_commit, n_unmasked = row[2 * B : 2 * B + 3]
+            if req is None or req.rid != rid or not was_active:
+                continue
+            self.block_forwards_total += 1
+            unmasked += int(n_unmasked)
+            if slot.block_log is not None:
+                slot.block_log.append((
+                    slot.block_pos, row[:B].tolist(),
+                    row[B : 2 * B].tolist(),
+                ))
+            if not did_commit:
+                continue
+            committed += 1
+            new = row[slot.lead : B].tolist()
+            new = new[: req.max_new_tokens - slot.emitted]
+            slot.tokens.extend(new)
+            slot.emitted += len(new)
+            appended += len(new)
+            slot.lead = 0
+            slot.block_pos += B
+            if slot.first_token_at is None:
+                slot.first_token_at = self.clock()
+                slot.first_tokens = len(new)
+                self.ttft.add(slot.first_token_at - req.submitted)
+        self.blocks_committed_total += committed
+        self.positions_unmasked_total += unmasked
+        self.tokens_committed_total += appended
+        self.tokens_emitted_total += appended
+        self._last_round = (unmasked, committed)
+        return appended
 
     def _spec_round(self, emit_lanes: list[int], parent: float) -> int:
         """One speculative round: γ draft proposals + one batched
@@ -1929,6 +2210,15 @@ class ServeEngine:
         slot.spec_accepted = 0
         slot.pages = pids
         slot.matched_tokens = matched
+        if self.block_len:
+            # The prompt's whole blocks are prefilled; its last
+            # len % B tokens open the first generated block.
+            B = self.block_len
+            slot.prefill_target = len(req.prompt) // B * B
+            slot.installed = False
+            slot.lead = len(req.prompt) - slot.prefill_target
+            slot.block_pos = slot.prefill_target
+            slot.block_log = [] if req.record_blocks else None
         if self.paged:
             self._table_np[slot.index] = 0
             self._table_np[slot.index, : len(pids)] = pids
@@ -1984,6 +2274,22 @@ class ServeEngine:
         with self.tracer.span("serve.sample", parent=parent) as span:
             appended = 0
             for kind, arr, meta in items:
+                if kind in ("block", "moe"):
+                    # The expert layer's routing counts of the call
+                    # (rows, fullest expert summed over layers,
+                    # experts hit), and a block step's report.
+                    moe = arr[1] if kind == "block" else arr
+                    rows, load, hit = (int(x) for x in np.asarray(moe))
+                    self.moe_tokens_routed_total += rows
+                    self.moe_expert_load_max_sum += load
+                    self.moe_experts_hit_total += hit
+                    self.moe_layer_calls_total += self.spec.depth
+                    if kind == "block":
+                        self.moe_expert_load_max = load // self.spec.depth
+                        appended += self._read_block_report(
+                            np.asarray(arr[0]), meta
+                        )
+                    continue
                 vals = np.asarray(arr)
                 if kind == "first":
                     slot = self._slots[meta]
@@ -2027,10 +2333,12 @@ class ServeEngine:
             prefix_hit_tokens=(
                 slot.matched_tokens if self.paged else None
             ),
+            first_tokens=slot.first_tokens,
+            block_inputs=slot.block_log,
         )
         self._completed[req.rid] = c
         self._retire_times.append(now)
-        if len(c.tokens) > 1:
+        if len(c.tokens) > c.first_tokens:
             self.decode_rate.add(c.decode_tokens_per_s)
         if c.tpot_s is not None:
             self.tpot.add(c.tpot_s)
@@ -2061,6 +2369,11 @@ class ServeEngine:
         slot.spec_accepted = 0
         slot.pages = []
         slot.matched_tokens = 0
+        slot.prefill_target = None
+        slot.installed = True
+        slot.lead = slot.block_pos = 0
+        slot.first_tokens = 1
+        slot.block_log = None
 
     def _retire_trace(self, c: Completion) -> None:
         """Close the request's trace (if tracing) and hang the digest

@@ -114,6 +114,9 @@ class Request:
     # routes on it, and per-model engines each run their own scheduler
     # so slot/page accounting stays per-model by construction.
     model: Optional[str] = None
+    # Block diffusion only: keep every forward's block inputs for the
+    # completion (what the benchmark's reference is handed).
+    record_blocks: bool = False
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now >= self.deadline
@@ -168,6 +171,7 @@ class Scheduler:
         timeout: Optional[float] = None,
         trace_id: Optional[int] = None,
         model: Optional[str] = None,
+        record_blocks: bool = False,
     ) -> Admission:
         """Validate + enqueue → Admission (never raises on bad input).
 
@@ -228,6 +232,7 @@ class Scheduler:
                 else derive_trace_id(self.trace_seed, rid)
             ),
             model=model,
+            record_blocks=record_blocks,
         )
         self._queue.append(req)
         return Admission(True, request=req)

@@ -296,6 +296,7 @@ class LMServer:
                 trace=trace,
                 hops=hops,
                 model=model,
+                record_blocks=bool(body.get("record_blocks", False)),
             )
         if not adm.accepted:
             # Only queue_full is transient (retry-after-backoff
@@ -362,6 +363,13 @@ class LMServer:
             **(
                 {"prefix_hit_tokens": done.prefix_hit_tokens}
                 if done.prefix_hit_tokens is not None
+                else {}
+            ),
+            # A model that generates by blocks, asked with
+            # ``record_blocks``: every forward's (pos, tokens, mask).
+            **(
+                {"block_inputs": done.block_inputs}
+                if done.block_inputs is not None
                 else {}
             ),
             # Which model version served this request (absent on

@@ -4,7 +4,8 @@ with its reference.
 
     python scripts/check_kernels.py           # the shapes chip_smoke.py trains and serves
     python scripts/check_kernels.py --all     # + the variants the server exposes:
-                                              #   GQA, int8 KV, paged lanes
+                                              #   GQA, int8 KV, paged lanes, and a
+                                              #   block-diffusion MoE model's kernels
     python scripts/check_kernels.py --tiny    # small shapes (CPU rehearsal: the
                                               #   Pallas interpreter, not Mosaic)
 
@@ -39,6 +40,11 @@ TOL = {
     "flash": 2e-2,
     "flash_grad": 6e-2,
     "decode": 3e-2,
+    # The grouped expert matmuls take bfloat16 operands (the stored
+    # weights; the rows and the SiLU product rounded to match): the
+    # error is relative to the output's largest magnitude, a few times
+    # bfloat16's 2^-8.
+    "moe_rel": 2e-2,
 }
 
 
@@ -50,9 +56,11 @@ def _max_err(a, b) -> float:
     )
 
 
-def check_flash(B: int, T: int, H: int, D: int, block: int) -> dict:
+def check_flash(B: int, T: int, H: int, D: int, block: int,
+                causal=True) -> dict:
     """Flash forward AND backward (the trainer's causal bf16 call)
-    against dense attention."""
+    against dense attention. ``causal`` an int > 1: the block-causal
+    mask of a block-diffusion prefill."""
     import jax
     import jax.numpy as jnp
 
@@ -68,11 +76,13 @@ def check_flash(B: int, T: int, H: int, D: int, block: int) -> dict:
     w = jax.random.normal(kw, shape, jnp.float32)  # the cotangent
 
     def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, True, block, block, interpret)
+        out = flash_attention(q, k, v, causal, block, block, interpret)
         return (out.astype(jnp.float32) * w).sum(), out
 
+    block_causal = int(causal)  # 1: the plain triangle
+
     def dense_loss(q, k, v):
-        out = dot_product_attention(q, k, v, causal=True)
+        out = dot_product_attention(q, k, v, causal=True, block=block_causal)
         return (out * w).sum(), out
 
     (_, out), grads = jax.jit(
@@ -186,6 +196,39 @@ def check_decode(
     }
 
 
+def check_moe(N: int, d: int, f: int, E: int, top_k: int) -> dict:
+    """The sort-by-expert grouped matmuls (ops/moe.py: gate and up,
+    then down, no drops) against every expert run on every token in
+    fp32, on bfloat16 weights as a served model stores them."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.moe import moe_layer, moe_reference
+
+    ks = jax.random.split(jax.random.key(3), 5)
+    x = jax.random.normal(ks[0], (N, d), jnp.float32)
+    logits = jax.random.normal(ks[1], (N, E), jnp.float32)
+    wg, wu, wd = (
+        (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(jnp.bfloat16)
+        for k, shape in zip(ks[2:], ((E, d, f), (E, d, f), (E, f, d)))
+    )
+    out, stats = jax.jit(
+        lambda *a: moe_layer(*a, top_k=top_k, impl="pallas")
+    )(x, logits, wg, wu, wd)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(
+            lambda *a: moe_reference(*a, top_k=top_k)
+        )(x, logits, wg, wu, wd)
+    err = _max_err(out, ref)
+    rel = err / float(jnp.abs(ref).max())
+    return {
+        "max_abs_err": err, "rel_err": rel, "tol": TOL["moe_rel"],
+        "rows_routed": int(stats[0]), "fullest_expert": int(stats[1]),
+        "experts_hit": int(stats[2]),
+        "ok": bool(jnp.isfinite(out).all()) and rel <= TOL["moe_rel"],
+    }
+
+
 def cases(tiny: bool, every: bool):
     if tiny:
         flash = dict(B=1, T=128, H=2, D=128, block=64)
@@ -215,6 +258,20 @@ def cases(tiny: bool, every: bool):
         yield "decode_paged16_int8_g4", lambda: check_decode(
             **gqa, int8=True, page_size=16
         )
+        # A block-diffusion model (models/sdar.py): the block-causal
+        # prefill mask; a block's 4 queries folded into the decode
+        # kernel's group dimension (8 query heads a kv head become 32
+        # rows) on the stored cache; the routed experts at the
+        # benchmark's widths (128 lanes' positions, top-8 of 128).
+        yield "flash_fwd_bwd_bf16_block_causal4", lambda: check_flash(
+            **flash, causal=4
+        )
+        fold = (dict(S=2, H=32, H_kv=1, Dh=128, L=256, depth=2) if tiny
+                else dict(S=32, H=128, H_kv=4, Dh=128, L=512, depth=3))
+        yield "decode_fp32_g32_block_folded", lambda: check_decode(**fold)
+        moe = (dict(N=16, d=128, f=128, E=8, top_k=2) if tiny
+               else dict(N=128, d=2048, f=768, E=128, top_k=8))
+        yield "moe_grouped_bf16", lambda: check_moe(**moe)
 
 
 def main() -> int:

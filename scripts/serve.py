@@ -66,6 +66,14 @@ def main() -> None:
         "per engine step (default prefill_chunk + slots)",
     )
     p.add_argument(
+        "--admit_every", type=int, default=0,
+        help="while any lane runs, bind at most one queued request "
+        "to a lane every N engine steps (0: refill every free lane at "
+        "once). Spreads lanes that would otherwise stay in step for "
+        "ever under requests of one length; set a little under "
+        "(steps a request takes) / slots",
+    )
+    p.add_argument(
         "--no_warmup", action="store_true",
         help="skip eager compilation of the engine program set "
         "(first requests then pay the XLA compiles)",
@@ -452,6 +460,7 @@ def main() -> None:
         prefill_chunk=args.prefill_chunk,
         min_bucket=args.min_bucket,
         step_token_budget=args.step_token_budget,
+        admit_every=args.admit_every,
         max_queue=args.max_queue,
         metrics=metrics,
         tracer=tracer,
@@ -509,6 +518,7 @@ def main() -> None:
             mspec,
             mparams,
             slots=args.slots,
+            admit_every=args.admit_every,
             max_queue=args.max_queue,
             metrics=metrics,
             kv_dtype=args.kv_dtype,
