@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddp_tpu.models.vit import EncoderBlock
 from ddp_tpu.ops.attention import best_attention
-from ddp_tpu.parallel.ddp import StepMetrics
+from ddp_tpu.parallel.ddp import StepMetrics, jit_train_step
 from ddp_tpu.parallel.ring import sequence_sharded_attention
 
 
@@ -740,4 +740,4 @@ def make_lm_train_step(
 
     if not jit:
         return step
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return jit_train_step(step, mesh, donate=donate, zero_layout=zero_layout)

@@ -79,6 +79,14 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # data/loader.py, train/trainer.py
     "data.next_batch": ("rows",),
     "train.dispatch": (),
+    # parallel/ddp.py — one per compile of the train step (lower +
+    # compile seconds): the gradient all-reduces of the compiled step,
+    # how many are asynchronous start/done pairs, how many start before
+    # the last backward kernel, how many have backward-pass compute
+    # between start and done, and the bytes of the first two
+    "train.compile": ("reduces", "asynchronous", "start_in_backward",
+                      "under_backward", "reduce_bytes",
+                      "asynchronous_bytes"),
 }
 # Spans in which the host WAITS for the device (the blocking token
 # fetch): host time, but not host work.

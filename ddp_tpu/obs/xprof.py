@@ -113,6 +113,15 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return n * _HLO_DTYPE_BYTES.get(dtype, 4)
 
 
+def _shapes_bytes(shapes: str, *, arrays_only: bool = False) -> int:
+    """Bytes of every shape in an HLO result (one shape or a tuple);
+    ``arrays_only`` leaves the scalars out."""
+    return sum(
+        _shape_bytes(dt, dims) for dt, dims in _SHAPE_RE.findall(shapes)
+        if dims or not arrays_only
+    )
+
+
 def _parse_replica_groups(line: str) -> Optional[list[list[int]]]:
     """The collective's replica groups from its HLO line, or None when
     the op carries none (= one group of the whole world)."""
@@ -186,9 +195,7 @@ def parse_hlo_collectives(hlo_text: str) -> dict[str, dict]:
                 groups = _parse_replica_groups(line)
         else:
             groups = _parse_replica_groups(line)
-        total = sum(
-            _shape_bytes(dt, dims) for dt, dims in _SHAPE_RE.findall(shapes)
-        )
+        total = _shapes_bytes(shapes)
         ent = out.setdefault(
             op, {"count": 0, "result_bytes": 0, "ops": []}
         )
@@ -196,6 +203,221 @@ def parse_hlo_collectives(hlo_text: str) -> dict[str, dict]:
         ent["result_bytes"] += total
         ent["ops"].append({"result_bytes": total, "groups": groups})
     return out
+
+
+# ---- where the all-reduces stand in the scheduled program -------------
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*")
+_CALLS_RE = re.compile(r"calls=%([\w.\-]+)")
+_COMPUTE_OPCODES = frozenset({"fusion", "custom-call", "convolution", "dot"})
+# A fused computation that holds a piece of an asynchronous collective
+# on a TPU: the start and done fusions wrap these custom calls; with
+# several steps the fusions between them are ``async_collective_fusion``
+# computations that carry compute and a step of the collective.
+_ASYNC_START, _ASYNC_DONE = "AsyncCollectiveStart", "AsyncCollectiveDone"
+
+
+def _closing_paren(s: str, start: int) -> int:
+    """Index of the parenthesis that closes the one opened just before
+    ``s[start]`` (``len(s)`` if none does)."""
+    depth = 1
+    for i in range(start, len(s)):
+        depth += (s[i] == "(") - (s[i] == ")")
+        if depth == 0:
+            return i
+    return len(s)
+
+
+def _split_instruction(line: str):
+    """One line of HLO text -> (name, shapes, opcode, rest after the
+    opcode's opening parenthesis), or None for anything else."""
+    m = _INSTR_RE.match(line)
+    if not m:
+        return None
+    rest = line[m.end():]
+    if rest.startswith("("):  # a tuple shape: to its closing paren
+        i = _closing_paren(rest, 1)
+        shapes, rest = rest[: i + 1], rest[i + 1:].lstrip()
+    else:
+        shapes, _, rest = rest.partition(" ")
+    opcode, _, args = rest.partition("(")
+    return m.group(1), shapes, opcode.strip(), args
+
+
+def collective_schedule(hlo_text: str) -> dict:
+    """The all-reduces of a compiled program, by where its SCHEDULED
+    entry computation puts them.
+
+    ``compiled.as_text()`` of an executable is scheduled: the order of
+    the entry computation's instructions is the order the core issues
+    them in. A plain ``all-reduce`` there is synchronous — the core
+    waits for it wherever it stands. An asynchronous one is a pair:
+    ``all-reduce-start`` / ``-done``, or on a TPU a ``fusion`` wrapping
+    the custom calls ``AsyncCollectiveStart`` / ``AsyncCollectiveDone``
+    (named ``async-collective-start`` / ``-done``), with the compute
+    scheduled between the two running under it.
+
+    Returns positions (indices into the entry computation), not times:
+
+    - ``reduces``: one dict per all-reduce that carries an array (a
+      gradient reduce; a scalar ``psum`` of the loss is listed under
+      ``other``): ``name``, ``kind`` (``all-reduce`` |
+      ``all-reduce-start`` | ``async-collective-start``), ``start``,
+      ``done`` (= ``start`` when synchronous), ``bytes`` (the reduced
+      arrays), ``compute_between`` (fusions, kernels and matmuls
+      between start and done) and ``backward_between`` (those of them
+      that belong to the backward pass: ``transpose(`` in their
+      ``op_name``; the TPU compiler defers weight-gradient matmuls
+      past the last attention kernel to pair them with the reduces);
+    - ``first_backward`` / ``last_backward``: positions of the first
+      and last Pallas kernel of the backward pass (a ``tpu_custom_call``
+      whose ``op_name`` holds ``transpose(``), None where there is none
+      (a CPU compile interprets the kernels);
+    - ``summary``: ``reduces``, ``bytes``, ``asynchronous`` with
+      ``asynchronous_bytes``, ``start_in_backward`` (start before the
+      last backward kernel), ``under_backward`` (asynchronous with
+      backward-pass compute between start and done) with
+      ``under_backward_bytes``.
+    """
+    text = _HLO_COMMENT_RE.sub("", hlo_text)
+    # computations that hold a piece of an asynchronous collective
+    comp_kind: dict[str, str] = {}
+    comp_reduce_bytes: dict[str, int] = {}
+    entry: list[str] = []
+    cur, in_entry = None, False
+    for line in text.split("\n"):
+        if line.startswith("ENTRY "):
+            in_entry, cur = True, None
+            continue
+        if line.startswith("}"):
+            in_entry, cur = False, None
+            continue
+        if in_entry:
+            entry.append(line)
+            continue
+        if line.startswith("%") and line.rstrip().endswith("{"):
+            cur = line[1:].split(" ", 1)[0]
+            continue
+        if cur is None:
+            continue
+        if _ASYNC_START in line:
+            comp_kind[cur] = "start"
+        elif _ASYNC_DONE in line:
+            comp_kind[cur] = "done"
+        parts = _split_instruction(line)
+        if parts and parts[2] == "all-reduce":
+            comp_reduce_bytes[cur] = comp_reduce_bytes.get(cur, 0) + (
+                _shapes_bytes(parts[1], arrays_only=True)
+            )
+            comp_kind.setdefault(cur, "step")
+
+    instrs = []  # (name, shapes, opcode, operands, line)
+    index: dict[str, int] = {}
+    for line in entry:
+        parts = _split_instruction(line)
+        if parts is None:
+            continue
+        name, shapes, opcode, args = parts
+        operands = _OPERAND_NAME_RE.findall(args[: _closing_paren(args, 0)])
+        index[name] = len(instrs)
+        instrs.append((name, shapes, opcode, operands, line))
+
+    def async_piece(i: int) -> Optional[str]:
+        name, _, opcode, _, line = instrs[i]
+        if opcode == "all-reduce-start":
+            return "start"
+        if opcode == "all-reduce-done":
+            return "done"
+        if opcode != "fusion":
+            return None
+        m = _CALLS_RE.search(line)
+        return comp_kind.get(m.group(1)) if m else None
+
+    def start_of(i: int, seen: set) -> Optional[int]:
+        """The start an asynchronous done (or step) hangs from."""
+        for op in instrs[i][3]:
+            j = index.get(op)
+            while j is not None and instrs[j][2] in (
+                "get-tuple-element", "bitcast"
+            ):
+                j = index.get(instrs[j][3][0]) if instrs[j][3] else None
+            if j is None or j in seen:
+                continue
+            seen.add(j)
+            piece = async_piece(j)
+            if piece == "start":
+                return j
+            if piece in ("step", "done"):
+                k = start_of(j, seen)
+                if k is not None:
+                    return k
+        return None
+
+    backward = [
+        i for i, (_, _, opcode, _, line) in enumerate(instrs)
+        if opcode == "custom-call" and "tpu_custom_call" in line
+        and "transpose(" in line
+    ]
+    last_bwd = backward[-1] if backward else None
+
+    reduces, other, starts = [], [], {}
+    for i, (name, shapes, opcode, operands, line) in enumerate(instrs):
+        piece = async_piece(i)
+        if opcode == "all-reduce":
+            groups = _parse_replica_groups(line)
+            if groups is not None and max(map(len, groups)) <= 1:
+                continue  # over one device (XLA:CPU keeps these): a copy
+            nbytes = _shapes_bytes(shapes, arrays_only=True)
+            rec = {"name": name, "kind": "all-reduce", "start": i,
+                   "done": i, "bytes": nbytes, "compute_between": 0}
+            (reduces if nbytes else other).append(rec)
+        elif piece == "start":
+            if opcode == "fusion":
+                comp = _CALLS_RE.search(line).group(1)
+                nbytes = comp_reduce_bytes.get(comp, 0)
+                kind = "async-collective-start"
+            else:
+                # the start's tuple aliases its operand beside the result
+                nbytes = _shapes_bytes(shapes, arrays_only=True) // 2
+                kind = "all-reduce-start"
+            rec = {"name": name, "kind": kind, "start": i, "done": i,
+                   "bytes": nbytes, "compute_between": 0}
+            starts[i] = rec
+            (reduces if nbytes else other).append(rec)
+        elif piece == "done":
+            j = start_of(i, set())
+            if j in starts:
+                starts[j]["done"] = i
+    for rec in reduces:
+        between = [
+            instrs[k][4] for k in range(rec["start"] + 1, rec["done"])
+            if instrs[k][2] in _COMPUTE_OPCODES
+            and async_piece(k) not in ("start", "done")
+        ]
+        rec["compute_between"] = len(between)
+        rec["backward_between"] = sum("transpose(" in l for l in between)
+    over = [r for r in reduces if r["backward_between"]]
+    return {
+        "instructions": len(instrs),
+        "first_backward": backward[0] if backward else None,
+        "last_backward": last_bwd,
+        "reduces": reduces,
+        "other": other,
+        "summary": {
+            "reduces": len(reduces),
+            "bytes": sum(r["bytes"] for r in reduces),
+            "asynchronous": sum(r["done"] > r["start"] for r in reduces),
+            "asynchronous_bytes": sum(
+                r["bytes"] for r in reduces if r["done"] > r["start"]
+            ),
+            "start_in_backward": sum(
+                last_bwd is not None and r["start"] < last_bwd
+                for r in reduces
+            ),
+            "under_backward": len(over),
+            "under_backward_bytes": sum(r["bytes"] for r in over),
+        },
+    }
 
 
 def _op_ring_bytes(op: str, result_bytes: int, group: int) -> int:
@@ -419,6 +641,30 @@ def _introspect(compiled) -> tuple[Optional[float], Optional[float], dict]:
     )
 
 
+def call_signature(args: tuple):
+    """What ``jit`` keys its cache on, for a wrapper that owns the
+    signature -> executable cache: the FLATTENED avals (shape, dtype,
+    weak type, sharding) under the arguments' tree. ``_Instrumented``
+    pays it on every call (0.2 ms for a train state of 300 leaves), so
+    the dtype goes in as it is: its ``str`` alone cost four times the
+    rest."""
+    import jax
+
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    return (
+        treedef,
+        tuple(
+            (
+                getattr(l, "shape", None),
+                getattr(l, "dtype", type(l)),
+                bool(getattr(l, "weak_type", False)),
+                getattr(l, "sharding", None),
+            )
+            for l in leaves
+        ),
+    )
+
+
 class _Instrumented:
     """The enabled-mode wrapper: owns the signature→executable cache.
 
@@ -443,21 +689,7 @@ class _Instrumented:
         self._aot = hasattr(fn, "lower")
 
     def _key(self, args: tuple):
-        import jax
-
-        leaves, treedef = jax.tree_util.tree_flatten(args)
-        return (
-            treedef,
-            tuple(
-                (
-                    getattr(l, "shape", None),
-                    str(getattr(l, "dtype", type(l).__name__)),
-                    bool(getattr(l, "weak_type", False)),
-                    getattr(l, "sharding", None),
-                )
-                for l in leaves
-            ),
-        )
+        return call_signature(args)
 
     def _cache_size(self) -> int:
         return len(self._compiled)

@@ -113,3 +113,85 @@ def test_remat_variant_runs(devices):
     toks = jnp.asarray(synthetic_tokens(4, total_len=64, vocab_size=32))
     state, m = step(state, toks)
     assert np.isfinite(float(m.loss))
+
+
+# ---- the compile options of the data-parallel step (parallel/ddp.py) ----
+
+
+@pytest.mark.parametrize(
+    "backend, axes, zero, chosen",
+    [
+        ("cpu", dict(data=4), False, False),  # XLA:CPU refuses xla_tpu_*
+        ("tpu", dict(data=4), False, True),  # the one case: pure DDP
+        ("tpu", dict(data=8), False, True),
+        ("tpu", dict(data=1), False, False),  # one chip: no collective
+        ("tpu", dict(data=2, model=2), False, False),
+        ("tpu", dict(data=2, seq=2), False, False),
+        ("tpu", dict(data=2, fsdp=2), False, False),
+        ("tpu", dict(data=2, expert=2), False, False),
+        ("tpu", dict(data=4), True, False),  # ZeRO: other collectives
+    ],
+)
+def test_overlap_options_follow_backend_and_mesh(
+        devices, monkeypatch, backend, axes, zero, chosen):
+    """The choice is made from what the code sees — the backend, the
+    mesh, a ZeRO layout — and is ``{}`` everywhere but on a pure
+    data-parallel TPU mesh, so every other program keeps its compile."""
+    from ddp_tpu.parallel import ddp
+
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(MeshSpec(**axes), devices=devices[:n])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    opts = ddp.overlap_compile_options(
+        mesh, zero_layout=object() if zero else None
+    )
+    if chosen:
+        assert opts == ddp._OVERLAP_OPTIONS and opts is not ddp._OVERLAP_OPTIONS
+        assert opts["xla_enable_async_all_reduce"] == "true"
+    else:
+        assert opts == {}
+
+
+@pytest.mark.parametrize("data", [1, 4])
+def test_train_compile_record_once_per_compile(devices, data):
+    """Each compile of the train step leaves ONE ``train.compile``
+    span in the tracer's ring — lower + compile seconds, the gradient
+    reduces of the compiled step, how many are asynchronous, how many
+    start before the last backward kernel — and a call that hits the
+    compiled step leaves none."""
+    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+
+    def records(since):
+        return [e for e in get_tracer().ring()[len(since):]
+                if e[0] == "train.compile"]
+
+    mesh = make_mesh(MeshSpec(data=data), devices=devices[:data])
+    tx = optax.adam(1e-3)
+    step = make_lm_train_step(SPEC, tx, mesh, donate=False)
+    state = create_lm_train_state(SPEC, tx, mesh, seed=0)
+    toks = jnp.asarray(synthetic_tokens(4, total_len=64, vocab_size=32))
+    before = get_tracer().ring()
+    state1, m1 = step(state, toks)
+    assert len(records(before)) == 1
+    state2, _ = step(state1, toks)  # same signature: no compile
+    assert len(records(before)) == 1 and step._cache_size() == 1
+    step(state2, jnp.concatenate([toks, toks]))  # new shape: a compile
+    step(state2, toks)  # the first shape again: its executable is kept
+    recs = records(before)
+    assert len(recs) == 2 and step._cache_size() == 2
+    name, t0, dur, parent, nums = recs[0]
+    assert dur > 0 and parent is None
+    assert len(nums) == len(SPAN_NUMS["train.compile"])
+    reduces, asynchronous, in_backward, under, nbytes, async_bytes = nums
+    # XLA:CPU compiles no asynchronous all-reduce and no Pallas kernel
+    assert (asynchronous, in_backward, under, async_bytes) == (0, 0, 0, 0)
+    if data == 1:
+        assert reduces == 0 and nbytes == 0
+    else:
+        n_param_bytes = 4 * sum(
+            x.size for x in jax.tree.leaves(state.params))
+        assert reduces >= 1 and nbytes >= n_param_bytes
+    # the step the wrapper runs is the step jit would have run
+    ref = jax.jit(make_lm_train_step(SPEC, tx, mesh, jit=False))
+    _, m_ref = ref(state, toks)
+    assert float(m1.loss) == float(m_ref.loss)

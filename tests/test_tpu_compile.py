@@ -1,9 +1,15 @@
-"""The block-diffusion model's kernels compiled for a TPU v5e that is
-described, not attached (the TPU's compiler is installed here): the
-grouped expert matmuls and the decode kernel with a block's queries
-folded in, at the published widths of SDAR-30B-A3B. What the Pallas
-interpreter cannot show — a slice off the tiling, more fast memory than
-a kernel may use — fails here, at no chip time. Nothing runs.
+"""Programs compiled for a TPU v5e that is described, not attached (the
+TPU's compiler is installed here). Nothing runs.
+
+- The block-diffusion model's kernels — the grouped expert matmuls and
+  the decode kernel with a block's queries folded in — at the published
+  widths of SDAR-30B-A3B. What the Pallas interpreter cannot show — a
+  slice off the tiling, more fast memory than a kernel may use — fails
+  here, at no chip time.
+- The LM train step on mesh ``data=4`` (PR 29): the gradient all-reduces
+  of the compiled, scheduled program are asynchronous and stand where
+  compute runs under them; on one chip the step compiles to the program
+  it always was (``scripts/show_collectives.py`` is the reader).
 
 The topology is described inside a fixture, in this file only: one
 process at a time holds the TPU's library."""
@@ -11,23 +17,29 @@ process at a time holds the TPU's library."""
 from __future__ import annotations
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+sys.path.insert(
+    0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "scripts"))
+
 
 @pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
+def topo():
+    from show_collectives import describe_topology
 
     try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
+        return describe_topology("v5e:2x2")
     except Exception as e:  # noqa: BLE001 — whatever keeps it from here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -77,3 +89,77 @@ def test_decode_kernel_compiles_with_a_blocks_queries_folded(
         _shape((S,), jnp.int32, one_chip),
     ).compile()
     assert "flash_decode" in compiled.as_text()
+
+
+# ---- the LM train step's gradient all-reduces (parallel/ddp.py) ---------
+
+
+@pytest.fixture(scope="module")
+def ddp4_schedule(topo):
+    """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
+    way the program compiles it on a TPU: ~20 s."""
+    from show_collectives import compile_lm_step, schedule
+
+    return schedule(compile_lm_step(
+        topo.devices[:4], mesh_axes={"data": 4}, d_model=1024, depth=4,
+    ))
+
+
+@pytest.mark.parametrize("what", [
+    "every_leaf_reduced_in_fp32",
+    "asynchronous",
+    "under_backward_compute",
+    "compute_between_every_pair",
+    "the_tied_embedding_runs_under_the_update",
+])
+def test_ddp4_step_reduces_gradients_under_compute(ddp4_schedule, what):
+    s, reduces = ddp4_schedule["summary"], ddp4_schedule["reduces"]
+    pairs = [r for r in reduces if r["done"] > r["start"]]
+    if what == "every_leaf_reduced_in_fp32":
+        # same bytes on the wire as the plain compile: every parameter,
+        # four bytes each (the tuple with the loss's scalars adds none)
+        n_params = (4 * (4 * 1024 * 1024 + 8 * 1024 * 1024 + 13 * 1024)
+                    + 50257 * 1024 + 2048 * 1024 + 2 * 1024)
+        assert s["bytes"] == 4 * n_params
+    elif what == "asynchronous":
+        assert s["asynchronous"] == len(pairs) >= 1
+        assert s["asynchronous_bytes"] >= 0.9 * s["bytes"]
+    elif what == "under_backward_compute":
+        # What the compiler does with the options: it defers the
+        # weight-gradient matmuls and fuses each with a reduce, so the
+        # reduce of one block runs while another's backward computes.
+        assert ddp4_schedule["last_backward"] is not None
+        assert s["under_backward"] >= 4
+        assert s["under_backward_bytes"] >= 0.25 * s["bytes"]
+    elif what == "compute_between_every_pair":
+        assert all(r["compute_between"] >= 1 for r in pairs)
+    else:
+        embed = max(reduces, key=lambda r: r["bytes"])
+        assert embed["bytes"] == 4 * 50257 * 1024
+        assert embed["done"] > embed["start"]
+        assert embed["compute_between"] >= 1
+
+
+def test_one_chip_step_compiles_to_the_same_program(topo):
+    """``overlap_compile_options`` is ``{}`` on a one-chip mesh, so the
+    program's own jit and a bare ``jax.jit`` give the same module."""
+    from show_collectives import compile_lm_step
+
+    kw = dict(mesh_axes={"data": 1}, d_model=512, depth=1, num_heads=4,
+              vocab_size=1024, seq_len=1024, rows_per_chip=2)
+    ours = compile_lm_step(topo.devices[:1], overlap=True, **kw).as_text()
+    bare = compile_lm_step(topo.devices[:1], overlap=False, **kw).as_text()
+    assert "flash_dkv" in ours and "all-reduce" not in ours
+    assert _program(ours) == _program(bare)
+
+
+def _program(text: str) -> str:
+    """A module's computations without what names the Python call stack
+    they were traced under (the tables at its top, each ``metadata``)."""
+    import re
+
+    lines = text.split("\n")
+    first = next(i for i, l in enumerate(lines)
+                 if l.startswith(("%", "ENTRY")))
+    return re.sub(r", metadata=\{[^{}]*\}", "",
+                  "\n".join(lines[:1] + lines[first:]))
