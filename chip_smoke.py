@@ -367,8 +367,6 @@ def phase_serve(ch: Children, size: dict, platform: str) -> dict:
             "scripts/serve.py", "--checkpoint_dir", os.path.join(WORK, "ck"),
             "--port", str(port), "--slots", str(size["slots"]),
             "--metrics_file", os.path.join(OUT, "serve.jsonl"),
-            # The smoke must not read a tuning cache an earlier run left.
-            "--tuned", "off",
         ],
         log,
     )

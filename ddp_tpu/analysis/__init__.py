@@ -46,9 +46,8 @@ CHECKS = {
 }
 
 # What `--self` lints: the package plus every entry point. One list,
-# shared by the CLI, the smoke-tier CI gate, and bench.py's
-# `lint_clean` field — they must not drift.
-SELF_LINT_TARGETS = ("ddp_tpu", "scripts", "train.py", "bench.py")
+# shared by the CLI and the smoke-tier CI gate — they must not drift.
+SELF_LINT_TARGETS = ("ddp_tpu", "scripts", "train.py")
 
 
 def lint_paths(
@@ -90,13 +89,3 @@ def self_lint(*, select: set[str] | None = None) -> LintResult:
         if os.path.exists(os.path.join(root, t))
     ]
     return lint_paths(targets, select=select)
-
-
-def self_lint_clean() -> bool:
-    """True when the tree self-lints with zero unsuppressed findings
-    (bench.py stamps this on headline records so a lint regression is
-    visible in the perf-trajectory sidecars)."""
-    try:
-        return not self_lint().unsuppressed
-    except Exception:  # never let the linter break a bench record
-        return False

@@ -44,8 +44,7 @@ class ShardedLoader:
 
     # Below this many bytes per local batch the native worker pool is
     # auto-disabled — the handoff overhead exceeds the gather it
-    # offloads (bench.py loader micro-bench: MNIST-sized rows lose,
-    # ImageNet-sized rows win).
+    # offloads (MNIST-sized rows lose, ImageNet-sized rows win).
     POOL_MIN_BATCH_BYTES = 1 << 20
 
     @classmethod
@@ -53,9 +52,7 @@ class ShardedLoader:
         """The native-pool gate: big-enough batches AND a spare core.
 
         Single source of the policy — the loader consults it at
-        construction and bench.py reports it alongside the loader
-        micro-bench so the recorded context cannot drift from the
-        code.
+        construction.
         """
         import os
 
@@ -159,8 +156,7 @@ class ShardedLoader:
                 # A worker pool is overhead, not help, when one batch
                 # gathers in microseconds (MNIST-sized rows) or when
                 # there is no spare core to run it on — the ticket/
-                # slot handoff costs more than the memcpy it offloads
-                # (both regimes measured: bench.py loader micro-bench).
+                # slot handoff costs more than the memcpy it offloads.
                 # Auto-disable instead of making the reference's
                 # num_workers=2 default a pessimization.
                 import logging

@@ -118,7 +118,7 @@ def _moe_mlp(p, x, *, top_k: int = 2, normalize_gates: bool = True):
     E-way compute is the right serving shape here: decode batches are
     small and the capacity/dispatch einsums exist for training-scale
     token counts. ``top_k``/``normalize_gates`` come from the LMSpec
-    (round-5 ADVICE fix: decode no longer hardcodes the MoEMLP
+    (round-5 fix: decode no longer hardcodes the MoEMLP
     defaults — a checkpoint trained at top_k=1 or with raw gates now
     serves with its own routing)."""
     B, T, d = x.shape
@@ -492,8 +492,8 @@ def init_slot_cache(
 ) -> SlotCache:
     """``dtype=jnp.int8`` allocates the quantized variant: int8 K/V
     plus per-(position, head) fp32 scales — cache HBM per slot drops
-    to ~(1 + 4/Dh)/8 of the fp32 layout, the ``slots``-per-chip
-    capacity win `bench.py serve_decode` measures."""
+    to ~(1 + 4/Dh)/8 of the fp32 layout, so a chip holds more
+    ``slots``."""
     shape = (spec.depth, slots, spec.total_len, _kv_heads(spec),
              head_dim_of(spec))
     # Two DISTINCT buffers: the cache is donated through every engine

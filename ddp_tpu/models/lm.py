@@ -142,7 +142,7 @@ class LMSpec(NamedTuple):
     num_experts: int = 0  # >0: MoE MLPs every moe_every-th block
     moe_every: int = 2
     aux_loss_weight: float = 0.01  # GShard load-balance loss weight
-    # MoE routing config (round-5 ADVICE: decode hardcoded top_k=2 and
+    # MoE routing config (round 5: decode hardcoded top_k=2 and
     # always-normalized gates — now derived from the spec, and recorded
     # in the lm_spec.json checkpoint sidecar so serving recovers it).
     moe_top_k: int = 2

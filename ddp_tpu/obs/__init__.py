@@ -45,7 +45,7 @@ jit compilation and no growing per-step allocations):
 Wiring: ``--trace_dir`` / ``--health`` / ``--metrics_port`` on
 train.py (train/trainer.py), the serve engine/server (spans +
 ``/statusz`` + ``/metricsz``), runtime/launch.py (per-rank trace
-files, merged by scripts/trace_merge.py), bench.py, and
+files, merged by scripts/trace_merge.py), and
 scripts/health_report.py (JSONL → triage report).
 docs/OBSERVABILITY.md has the full story.
 """

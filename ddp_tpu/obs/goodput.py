@@ -30,8 +30,8 @@ from typing import Callable, Optional
 
 # ---- per-chip peak ---------------------------------------------------
 
-# bf16 peak FLOP/s per chip by device kind (public spec sheets) —
-# shared with bench.py's MFU estimates.
+# bf16 peak FLOP/s per chip by device kind (public spec sheets): the
+# denominator of the trainer's MFU field.
 TPU_BF16_PEAK = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,

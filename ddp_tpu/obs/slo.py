@@ -22,10 +22,9 @@ the metrics stream and the PR-4 flight recorder).
 
 Surfaced as: ``/statusz`` state (``stats.slo``), linted
 ``ddp_tpu_slo_{target,current,burn_rate,breached}`` gauges on
-``/metricsz`` (obs/promtext.py), an ``slo`` sub-record in
-``bench.py serve_decode``, and the aggregator's worst-endpoint view
-(obs/aggregate.py). Pure host-side Python, clock-injectable; memory is
-bounded by a ring of observations.
+``/metricsz`` (obs/promtext.py), and the aggregator's worst-endpoint
+view (obs/aggregate.py). Pure host-side Python, clock-injectable;
+memory is bounded by a ring of observations.
 """
 
 from __future__ import annotations

@@ -30,9 +30,9 @@ it:
 - cross-checks — :func:`ring_collective_traffic` converts HLO payload
   shapes into the same ring model ``zero_comm_bytes`` prices, so the
   hand ledger is validated against the compiled program
-  (:meth:`Xprof.comm_check`); ``Xprof.measured_flops`` validates the
-  analytic MFU estimators (tests/test_xprof.py pins per-family
-  tolerance bands).
+  (:meth:`Xprof.comm_check`); the ledger's XLA-counted ``flops``
+  validate the analytic MFU estimators (tests/test_xprof.py pins
+  per-family tolerance bands).
 
 Disabled mode is FREE, the tracer's discipline: ``instrument`` returns
 the caller's function object unchanged (not a wrapper), the sampler
@@ -784,7 +784,7 @@ class Xprof:
         host-resolved bucket width, a dtype chosen by a knob. Callers
         record them here; the fields ride every subsequent (and, for
         robustness, every already-ledgered) compile record under a
-        ``notes`` key, so the tuner and humans read one surface. No-op
+        ``notes`` key, so the ledger's reader sees one surface. No-op
         when disabled — the free-when-disabled contract holds.
         """
         if not self.enabled:
@@ -865,15 +865,6 @@ class Xprof:
         the remainder is XLA's compile (or its persistent-cache load)."""
         with self._lock:
             return self._total_lower_s
-
-    def measured_flops(self, label: str) -> Optional[float]:
-        """XLA-counted FLOPs of the label's most recent compile (the
-        analytic-estimator cross-check input)."""
-        with self._lock:
-            for p in reversed(self._ledger):
-                if p.label == label and p.flops is not None:
-                    return p.flops
-        return None
 
     def label_collectives(self, label: str) -> Optional[dict]:
         """Raw parsed collectives of the label's most recent AOT

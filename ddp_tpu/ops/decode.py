@@ -401,8 +401,7 @@ def _per_head_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k):
 # indexing (a scalar-prefetch index_map resolving page ids per grid
 # step, the vLLM/TPU paged-attention shape) — the on-chip follow-up;
 # until then an on-chip capture of paged+flash measures gather +
-# kernel, and bench.py's serve_decode paged_kv sub-record should be
-# read accordingly.
+# kernel.
 
 
 def gather_paged_kv(pages: jax.Array, table: jax.Array) -> jax.Array:

@@ -1526,7 +1526,7 @@ def run_spmd_control(cfg: MPMDConfig) -> dict:
 
 # --------------------------------------------------------------------
 # CLI: supervisor mode by default; --control runs the SPMD baseline
-# in-process (bench.py drives both as subprocesses).
+# in-process.
 # --------------------------------------------------------------------
 
 

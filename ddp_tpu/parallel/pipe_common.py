@@ -46,9 +46,9 @@ def split_microbatch_stream(x, num_microbatches: int, num_stages: int):
     replicated stream dim onto ``pipe``. A contiguous split would
     demand a dim0-batch → (None, pipe, batch) resharding that XLA's
     SPMD partitioner can only express by involuntary full
-    rematerialization (the %reshape warning in MULTICHIP_r04; fixed
-    round 5). One definition for both pipe families so the stream,
-    label, and output orderings cannot drift."""
+    rematerialization (XLA warned of it on the %reshape until round
+    5 interleaved the split). One definition for both pipe families
+    so the stream, label, and output orderings cannot drift."""
     import jax.numpy as jnp
 
     B, M, S = x.shape[0], num_microbatches, num_stages

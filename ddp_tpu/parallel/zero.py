@@ -29,8 +29,8 @@ scheduler may dispatch bucket k's scatter while backward compute for
 bucket k+1's layers is still in flight — and the all-gathers pipeline
 against the sharded updates the same way. ``overlap=False`` builds the
 control: an ``optimization_barrier`` fence after the full backward
-plus a serial chain through the collectives, which is what bench.py
-measures the overlapped step against (prove it, don't assume it).
+plus a serial chain through the collectives, the step the overlapped
+one is to be measured against (not measured on a chip).
 
 Two expressions of the same decomposition, per the paper's framing:
 
@@ -369,7 +369,7 @@ def _replica_geometry(
     two-level step (scatter within a slice over ``data``, shard
     exchange across slices). ``hier=False`` on a dcn mesh is the flat
     control — ONE scatter spanning ``('dcn', 'data')``, every byte
-    riding the slow fabric (what bench.py measures hier against).
+    riding the slow fabric.
     """
     dcn = int(mesh.shape.get("dcn", 1))
     hier = (dcn > 1) if hier is None else (bool(hier) and dcn > 1)

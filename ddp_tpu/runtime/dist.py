@@ -79,7 +79,7 @@ def enable_compile_cache() -> str | None:
 
     THE one place the cache directory is decided, called by every
     entry point that compiles for the chip (``setup`` — so the trainer
-    and every launcher worker — ``scripts/serve.py``, ``bench.py``,
+    and every launcher worker — ``scripts/serve.py``,
     ``scripts/check_kernels.py``):
 
     - ``JAX_COMPILATION_CACHE_DIR`` set → nothing is set in code; JAX

@@ -256,11 +256,7 @@ def resolve_engine_knobs(
     """Validate + resolve the engine's knob surface — the SINGLE rule
     set for what configurations are constructible.
 
-    ``ServeEngine.__init__`` consumes this verbatim, and the autotuner
-    (``ddp_tpu.tune``) uses it as the validity predicate for proposed
-    configs: a candidate is proposable iff this returns — so the tuner
-    can never propose a config the CLI would reject, by construction
-    rather than by a parallel re-implementation of the rules. Raises
+    ``ServeEngine.__init__`` consumes this verbatim. Raises
     the same ``ValueError`` messages the engine always raised; returns
     the resolved values (defaults filled, pow2 snapping and caps
     applied) the engine assigns.
@@ -535,9 +531,7 @@ class ServeEngine:
         model_version: Optional[str] = None,
     ):
         # The whole knob surface validates + resolves through the
-        # module-level resolver — the same rule set the autotuner's
-        # validity predicates call, so tuner and CLI can never
-        # disagree about what constructs.
+        # module-level resolver.
         knobs = resolve_engine_knobs(
             spec,
             slots=slots,
@@ -855,8 +849,8 @@ class ServeEngine:
             # The kernel snaps block_k to a tile-aligned divisor of the
             # lane length that fits VMEM (ops/decode.decode_block) — a
             # host-side decision XLA introspection can't see. Ledger
-            # it so the tuner and humans read the EFFECTIVE block, not
-            # the requested default.
+            # it so the reader sees the EFFECTIVE block, not the
+            # requested default.
             self._xprof.annotate(
                 "serve.flash_decode",
                 block_k_requested=DEFAULT_BLOCK_K,
@@ -1101,8 +1095,8 @@ class ServeEngine:
     def compile_budget(self) -> int:
         """The engine's whole-program-set ceiling: 2 chunk programs
         per bucket + 1 decode, doubled-chunks + draft-decode + verify
-        when speculating — asserted by ``bench.py serve_decode`` and
-        the static-shape tests."""
+        when speculating — asserted by the static-shape tests
+        (tests/test_serve.py, tests/test_flash_decode.py)."""
         base = 2 * len(self.buckets) + 1
         if self.spec_tokens:
             base += 2 * len(self.buckets) + 2
@@ -1258,8 +1252,8 @@ class ServeEngine:
     def cache_bytes_per_slot(self) -> int:
         """KV-cache HBM per decode lane, scales included — the number
         int8 quantization halves (better: int8 rows + one fp32 scale
-        per head per position vs fp32 rows), and the per-chip ``slots``
-        capacity story in ``bench.py serve_decode``."""
+        per head per position vs fp32 rows), and with it how many
+        ``slots`` a chip holds."""
         leaves = [self._cache.k, self._cache.v]
         if self._cache.quantized():
             leaves += [self._cache.k_scale, self._cache.v_scale]

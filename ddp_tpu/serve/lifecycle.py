@@ -26,7 +26,7 @@ compiles the program set over same-shaped init params. Dispatch
 correctness still requires the full tree (the forward pass reads
 every layer), so first dispatch waits for full residency; the win is
 wall-clock overlap (restore I/O behind XLA compiles, admission open
-early), measured by ``bench.py serve_reload``.
+early); drilled by tests/test_lifecycle.py, not measured on a chip.
 """
 
 from __future__ import annotations

@@ -229,7 +229,7 @@ def verify_manifest(root: str, epoch: int) -> list[str] | None:
 # Inference tooling (scripts/predict.py, scripts/serve.py) merges it
 # over the shape-derived spec (models/lm.py derive_lm_spec), so a
 # checkpoint trained at --moe_top_k 1 serves with top-1 routing
-# instead of silently assuming the top-2 default (round-5 ADVICE).
+# instead of silently assuming the top-2 default.
 
 LM_SPEC_FILENAME = "lm_spec.json"
 

@@ -40,7 +40,7 @@ class TrainConfig:
     model_dim: int | None = None
     # Attention heads for the spec-driven families (seq + pipe);
     # registry models fix theirs. head_dim = model_dim / num_heads —
-    # 128-wide heads measurably fill the MXU better (bench.py).
+    # 128-wide heads match the MXU's width.
     num_heads: int = 4
     # Grouped-query attention for the causal LM: kv heads < num_heads
     # shrink the generation KV cache (and its decode bandwidth) by
@@ -111,7 +111,7 @@ class TrainConfig:
     # Routing config for those MoE blocks: experts per token, and
     # whether the surviving top-k gates renormalize to sum to 1.
     # Recorded in the lm_spec.json checkpoint sidecar so the decode /
-    # serving path reproduces the training routing (round-5 ADVICE).
+    # serving path reproduces the training routing.
     moe_top_k: int = 2
     moe_normalize_gates: bool = True
     # Real LM data: a file read as raw bytes (--dataset text),
@@ -139,11 +139,6 @@ class TrainConfig:
     # the moments) stay full precision — only the forward sees
     # bf16-rounded params, so rounding never compounds across steps.
     zero_gather_dtype: str = "fp32"  # fp32 | bf16
-    # Tuning cache (ddp_tpu.tune): auto = load tuning_cache.json
-    # beside checkpoint_dir and fill zero knobs left at defaults from
-    # the cached winner (explicit flags always win); off = never
-    # touch it; a path = that cache file.
-    tuned: str = "auto"
     # Rematerialize block activations in the backward (jax.checkpoint):
     # HBM for FLOPs. Supported by the block-structured families
     # (resnet*, vit*, vit_moe*); simple_cnn has no block stack to remat.
@@ -377,14 +372,6 @@ class TrainConfig:
             "update exact (fp32 = bit-identical default)",
         )
         p.add_argument(
-            "--tuned", default=cls.tuned, metavar="auto|off|PATH",
-            help="tuning cache (ddp_tpu.tune, scripts/autotune.py): "
-            "'auto' loads tuning_cache.json beside --checkpoint_dir "
-            "and fills zero knobs left at their defaults from the "
-            "cached winner for this model shape — explicit flags "
-            "always win; 'off' disables; a path loads that file",
-        )
-        p.add_argument(
             "--mesh_dcn", type=int, default=cls.mesh_dcn,
             help="pod slices on the outermost dcn axis: the zero step "
             "goes hierarchical (reduce-scatter within a slice over "
@@ -518,26 +505,6 @@ class TrainConfig:
         kwargs.pop("list_datasets", None)
         return cls(**kwargs)
 
-    @staticmethod
-    def scan_explicit_flags(argv=None) -> frozenset:
-        """Which flags the user ACTUALLY typed (vs defaulted): the
-        tuning cache's precedence rule is explicit-flag-beats-cache,
-        and argparse alone can't distinguish ``--zero_bucket_mb 4``
-        from the 4.0 default. Callers attach the result as a plain
-        attribute — not a field — so ``dataclasses.asdict()``
-        (flight-recorder context, restart argv round-trips) is
-        unchanged."""
-        import sys
-
-        raw = list(sys.argv[1:]) if argv is None else list(argv)
-        explicit = set()
-        for tok in raw:
-            if tok.startswith("--"):
-                explicit.add(tok[2:].split("=", 1)[0].replace("-", "_"))
-        return frozenset(explicit)
-
     @classmethod
     def from_args(cls, argv=None) -> "TrainConfig":
-        cfg = cls.from_namespace(cls.parser().parse_args(argv))
-        cfg.explicit_flags = cls.scan_explicit_flags(argv)
-        return cfg
+        return cls.from_namespace(cls.parser().parse_args(argv))

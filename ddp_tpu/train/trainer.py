@@ -416,56 +416,6 @@ class Trainer:
                 "or the GSPMD/pipe/seq/fast_epoch flags"
             )
         self.zero_mode = config.parallel == "zero"
-        # Tuning cache (ddp_tpu.tune): fill zero knobs the command
-        # line left at defaults from the cached winner for this model
-        # shape. Applied BEFORE the validation below so a cached value
-        # passes the same checks a flag would; explicit flags always
-        # win (config.explicit_flags, set by TrainConfig.from_args —
-        # directly-constructed configs fall back to comparing against
-        # the dataclass defaults). --tuned off, or no cache file:
-        # nothing here runs and every record stays byte-identical.
-        self._tuning: dict | None = None
-        if self.zero_mode and getattr(config, "tuned", "off") != "off":
-            from ddp_tpu.tune import (
-                apply_tuned,
-                cache_key,
-                resolve_cache,
-                train_signature,
-            )
-
-            _tcache = resolve_cache(config.tuned, config.checkpoint_dir)
-            _tent = (
-                _tcache.lookup(cache_key("zero", train_signature(config)))
-                if _tcache is not None
-                else None
-            )
-            if _tent is not None:
-                explicit = getattr(config, "explicit_flags", None)
-                if explicit is None:
-                    defaults = {
-                        f.name: f.default
-                        for f in dataclasses.fields(type(config))
-                    }
-                    explicit = {
-                        k
-                        for k in ("zero_bucket_mb", "zero_gather_dtype")
-                        if getattr(config, k) != defaults.get(k)
-                    }
-                current = {
-                    "zero_bucket_mb": config.zero_bucket_mb,
-                    "zero_gather_dtype": config.zero_gather_dtype,
-                }
-                merged, applied, overridden = apply_tuned(
-                    current, _tent["config"], explicit=set(explicit)
-                )
-                config.zero_bucket_mb = merged["zero_bucket_mb"]
-                config.zero_gather_dtype = merged["zero_gather_dtype"]
-                self._tuning = {
-                    "site": "zero",
-                    "cache": _tcache.path,
-                    "applied": applied,
-                    "overridden": overridden,
-                }
         # Global-norm clipping under zero is applied IN-STEP from the
         # scattered shards (psum of per-shard squared sums); the
         # optimizer is then built without the chained optax clip.
@@ -2295,13 +2245,6 @@ class Trainer:
         from ddp_tpu.obs.recorder import build_info
 
         self._build_info = build_info()
-        # Tuning provenance rides run_start (and its own `tuning`
-        # record, the health_report triage input) ONLY when the cache
-        # was actually consulted and hit — default runs keep today's
-        # record schema byte for byte.
-        tuning_fields = (
-            {"tuning": self._tuning} if self._tuning else {}
-        )
         # What was built from what the platform offers — the attention
         # implementation and where compiled programs persist — is
         # chosen from observation, so the generation anchor says it.
@@ -2315,7 +2258,6 @@ class Trainer:
             "run_start", start_epoch=start_epoch,
             restarts=self._goodput.restarts,
             build_info=self._build_info, **world_fields,
-            **tuning_fields,
         )
         self.metrics_writer.write(
             "run_start",
@@ -2324,18 +2266,8 @@ class Trainer:
             global_batch_size=self.global_batch_size,
             build_info=self._build_info,
             **world_fields,
-            **tuning_fields,
             **built_fields,
         )
-        if self._tuning:
-            self.metrics_writer.write(
-                "tuning",
-                cache_hit=True,
-                site=self._tuning["site"],
-                cache=self._tuning["cache"],
-                applied=self._tuning["applied"],
-                overridden=self._tuning["overridden"],
-            )
         # Mid-epoch preemption saves are tagged with their (incomplete)
         # epoch and record how many batches ran as an explicit
         # mid_batch marker; resume re-enters that epoch at that batch.

@@ -25,7 +25,7 @@ class StatSummary:
 
     The serving engine (ddp_tpu.serve) feeds per-request latencies
     (TTFT, decode tokens/s) through these; ``snapshot()`` is what the
-    server's /stats endpoint and bench.py's serve record publish.
+    server's /stats endpoint publishes.
     Memory is bounded — a long-lived server must not grow a float per
     request forever: count/mean/min/max are exact running values, and
     percentiles come from a fixed-size uniform reservoir

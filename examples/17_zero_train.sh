@@ -43,9 +43,3 @@ python train.py --epochs 1 --batch_size 8 \
     --parallel zero \
     --checkpoint_dir "$WORK/ck_lm" --data_root "$WORK/data" \
     --synthetic_size 128 --log_interval 4 --eval_every 0
-
-# 4. The measured claims — step-time p50 vs the ddp baseline,
-#    optimizer-memory high-water (live-buffer accounting, ratio 1/N),
-#    comm_bytes breakdown, and the MEASURED overlap fraction of the
-#    bucketed collectives vs the serialized control:
-python bench.py --zero-worker

@@ -43,9 +43,3 @@ python scripts/health_report.py "$WORK/metrics.jsonl"
 #    summarizes each series' max so "how high did memory get" is
 #    greppable without opening Perfetto.
 python scripts/trace_merge.py "$WORK/traces" -o "$WORK/merged.trace.json"
-
-# 5. The zero strategy's measured record: per-variant compile
-#    seconds, the HBM high-water of the measured loops, and the
-#    hlo_comm_check — the hand-priced comm_bytes vs what the compiled
-#    programs actually do (ratio 1.0 at world 2).
-python bench.py --zero-worker

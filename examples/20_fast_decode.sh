@@ -1,7 +1,6 @@
 #!/usr/bin/env bash
 # The decode-speed stack (docs/SERVING.md "Raw decode speed"):
-# flash-decode kernel, speculative decoding, int8 KV cache — ROADMAP
-# item 2, gated by bench.py serve_decode's per-variant sub-records.
+# flash-decode kernel, speculative decoding, int8 KV cache.
 # Runs green end to end on a CPU dev box: the kernel pins run through
 # the Pallas interpreter, flash `auto` honestly resolves to the
 # bit-identical jnp reference off-TPU, and the speculative/int8
@@ -58,36 +57,5 @@ wait $SERVER 2>/dev/null || true
 # 3. The serve_step records carry the per-step drafted/accepted
 #    counts (None-safe: prefill-only steps report 0 drafted).
 grep -m 3 '"spec_drafted"' "$WORK/serve.jsonl"
-
-# 4. The gate: bench.py serve_decode's per-variant sub-records —
-#    baseline vs flash_decode vs spec (+ the acceptance-1.0
-#    self-draft ceiling) vs int8_kv, each with step-latency p50/p99,
-#    acceptance, cache bytes/slot, and the platform/backend/
-#    cpu_fallback provenance fields (this CPU run says so honestly).
-python - <<'EOF'
-import json
-
-import bench
-
-rec = bench.run_serve_bench()
-keep = {
-    k: rec[k]
-    for k in (
-        "metric", "value", "platform", "cpu_fallback",
-        "flash_p50_vs_baseline", "int8_cache_bytes_ratio",
-        "int8_slots_capacity_gain",
-    )
-}
-keep["variants"] = {
-    name: {
-        "p50": v["step_latency_s"]["p50"],
-        "tokens_per_s": v["tokens_per_s"],
-        "acceptance": v["acceptance_rate"],
-        "cache_bytes_per_slot": v["cache_bytes_per_slot"],
-    }
-    for name, v in rec["variants"].items()
-}
-print(json.dumps(keep, indent=1))
-EOF
 
 echo "example 20 OK"

@@ -4,9 +4,8 @@
 # prompt — the second request's prefix pages come from the radix
 # index (zero prefill compute for the matched tokens), the hit rate
 # and page gauges climb on /statusz + /metricsz, token identity
-# against a fixed-lane control, the health_report page triage line,
-# and the serve_prefix bench (hit rate + effective-slots multiplier
-# vs the lane-copies baseline). Green on CPU.
+# against a fixed-lane control, and the health_report page triage
+# line. Green on CPU.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -85,30 +84,5 @@ EOF
 kill $(jobs -p) 2>/dev/null || true; wait 2>/dev/null || true
 echo "--- health_report (pages line)"
 python scripts/health_report.py "$WORK/serve.jsonl" | grep -E "serve|pages"
-
-# 6. The measurement: bench.py serve_prefix — shared-prefix open-loop
-#    traffic, prefix-hit rate (>= 0.5 asserted), effective-slots
-#    multiplier (> 1.5 asserted: pages the lane-copies baseline would
-#    need over unique resident pages), TTFT p50/p99 hit vs miss, and
-#    throughput against a fixed-lane control. CPU wall-clock numbers
-#    are honest nulls (provenance fields say so).
-python - <<'EOF'
-import json
-
-import bench
-
-rec = bench.run_serve_prefix_bench()
-print(json.dumps({
-    "hit_rate": rec["value"],
-    "effective_slots_multiplier_peak":
-        rec["effective_slots_multiplier_peak"],
-    "ttft_hit_p50": rec["paged_kv"]["ttft_hit_s"]["p50"],
-    "ttft_miss_p50": rec["paged_kv"]["ttft_miss_s"]["p50"],
-    "paged_vs_baseline_tokens_per_s":
-        rec["paged_vs_baseline_tokens_per_s"],
-    "platform": rec["platform"],
-    "cpu_fallback": rec["cpu_fallback"],
-}, indent=1))
-EOF
 
 echo "example 22 OK"

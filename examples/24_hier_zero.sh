@@ -66,12 +66,3 @@ print("comm_bytes_ici  :", step["comm_bytes_ici"])
 print("comm_bytes_dcn  :", step["comm_bytes_dcn"])
 assert step["comm_bytes_dcn"] < step["comm_bytes_ici"]
 PY
-
-# 5. The measured claims, asserted not narrated (bench.py `zero` at
-#    world 4 = 2 emulated slices × 2 in-process): per-variant
-#    sub-records for gather_bf16 (HLO all-gather ratio vs fp32 = 0.5,
-#    asserted) and hier (per-axis comm_bytes + per-fabric
-#    hlo_comm_check at ratio 1.0, cross-slice ≤ 1/N of flat,
-#    asserted), each with gather_dtype + mesh-axis provenance.
-XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=4" \
-    python bench.py --zero-worker

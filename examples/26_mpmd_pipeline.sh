@@ -5,8 +5,7 @@
 # ACTV wire, 1F1B over processes. A clean 2-stage causal-LM run, then
 # the same run with stage 1 SIGKILLed mid-training — exactly one
 # classified restart, survivors roll back without recompiling, final
-# metrics identical — triaged by health_report and measured by
-# bench.py's mpmd entry. Green on CPU.
+# metrics identical — triaged by health_report. Green on CPU.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -66,30 +65,5 @@ EOF
 #    restarts) appears only on streams carrying stage-tagged records.
 echo "--- health_report (mpmd triage)"
 python scripts/health_report.py "$WORK/drill.jsonl" | grep -E "mpmd"
-
-# 4. The measurement: bench.py mpmd — step-time p50/p99, bubble
-#    fraction, per-stage compile seconds (sum < the SPMD
-#    single-program compile, asserted inside), loss parity vs the
-#    in-graph 1F1B control, and the kill-drill recovery time. CPU
-#    wall-clock numbers are honest nulls (provenance fields say so).
-python - <<'EOF'
-import json
-
-import bench
-
-rec = bench.run_mpmd_bench()
-print(json.dumps({
-    "step_time_p50_s": rec["step_time_p50_s"],
-    "measured_bubble_fraction": rec["measured_bubble_fraction"],
-    "p2p_wait_fraction": rec["p2p_wait_fraction"],
-    "compile_s_sum": rec["compile_s_sum"],
-    "control_compile_s": rec["control_compile_s"],
-    "loss_parity": rec["loss_parity"],
-    "kill_drill_restarts": rec["kill_drill_restarts"],
-    "kill_drill_recovery_s": rec["kill_drill_recovery_s"],
-    "platform": rec["platform"],
-    "cpu_fallback": rec["cpu_fallback"],
-}, indent=1))
-EOF
 
 echo "example 26 OK"

@@ -152,15 +152,6 @@ def main() -> None:
         "replicas this long to finish lanes before the kill",
     )
     p.add_argument(
-        "--tuned", default="auto", metavar="auto|off|PATH",
-        help="tuning cache (ddp_tpu.tune): 'auto' loads "
-        "tuning_cache.json beside the forwarded --checkpoint_dir "
-        "and fills router knobs (--hedge_after) left at defaults "
-        "from the cached winner — an explicit flag always wins; "
-        "'off' disables. Replicas resolve their own --tuned from "
-        "the forwarded serve args",
-    )
-    p.add_argument(
         "serve_args", nargs=argparse.REMAINDER,
         help="everything after -- goes verbatim to every replica's "
         "scripts/serve.py",
@@ -174,39 +165,6 @@ def main() -> None:
             "replica --port/--host are manager-assigned; drop them "
             "from the forwarded serve args"
         )
-
-    # Tuning cache: the router's own knob surface (hedge_after today).
-    # The fleet's key is model-shape-agnostic — hedging tracks traffic
-    # and hardware, not parameter dims.
-    tuning = None
-    if args.tuned != "off":
-        from ddp_tpu.tune import apply_tuned, cache_key, resolve_cache
-
-        ckpt = "./checkpoints"
-        if "--checkpoint_dir" in serve_args:
-            i = serve_args.index("--checkpoint_dir")
-            if i + 1 < len(serve_args):
-                ckpt = serve_args[i + 1]
-        _cache = resolve_cache(args.tuned, ckpt)
-        _ent = (
-            _cache.lookup(cache_key("fleet", "any"))
-            if _cache is not None
-            else None
-        )
-        if _ent is not None:
-            current = {"hedge_after": args.hedge_after}
-            explicit = {
-                k for k, v in current.items() if v is not None
-            }
-            merged, applied, overridden = apply_tuned(
-                current, _ent["config"], explicit=explicit
-            )
-            args.hedge_after = merged["hedge_after"]
-            tuning = {
-                "cache": _cache.path,
-                "applied": applied,
-                "overridden": overridden,
-            }
 
     from ddp_tpu.serve.fleet import (
         ROLE_HYBRID,
@@ -233,15 +191,6 @@ def main() -> None:
                 f"--replicas is {args.replicas}"
             )
     metrics = MetricsWriter(args.metrics_file)
-    if tuning:
-        metrics.write(
-            "tuning",
-            site="fleet",
-            cache_hit=True,
-            cache=tuning["cache"],
-            applied=tuning["applied"],
-            overridden=tuning["overridden"],
-        )
     manager = ReplicaManager(
         args.replicas,
         serve_args,
@@ -312,7 +261,6 @@ def main() -> None:
                             {"trace_dir": args.trace_dir}
                             if args.trace_dir else {}
                         ),
-                        **({"tuning": tuning} if tuning else {}),
                     }
                 ),
                 flush=True,

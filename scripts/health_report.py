@@ -492,35 +492,6 @@ def build_report(records: list[dict]) -> str:
             f"{bub}, {n_restarts} restart(s)"
         )
 
-    # Autotuner triage (ddp_tpu.tune): surfaces whether this run's
-    # knobs came from a tuning-cache hit, what was applied and what an
-    # explicit flag overrode. Gated on the record — untuned streams
-    # (and every existing golden) carry no "tuning" records and stay
-    # byte-identical.
-    tunes = [r for r in records if r.get("kind") == "tuning"]
-    if tunes:
-        parts = []
-        for t in tunes:
-            applied = t.get("applied") or {}
-            overridden = t.get("overridden") or []
-            knobs = ", ".join(
-                f"{k}={v}" for k, v in sorted(applied.items())
-            )
-            part = (
-                f"{t.get('site', '?')} "
-                f"{'hit' if t.get('cache_hit') else 'miss'}"
-                f" ({len(applied)} applied"
-                + (f": {knobs}" if knobs else "")
-                + (
-                    f"; {len(overridden)} overridden by flags"
-                    if overridden
-                    else ""
-                )
-                + ")"
-            )
-            parts.append(part)
-        lines.append(f"tuning        : {'; '.join(parts)}")
-
     sentry = [h for h in health if h.get("detector") != "nonfinite"]
     if sentry:
         by_det: dict[str, int] = {}

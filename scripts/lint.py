@@ -44,8 +44,8 @@ def main(argv=None) -> int:
     p.add_argument("paths", nargs="*", help="files or directories to lint")
     p.add_argument(
         "--self", action="store_true", dest="self_mode",
-        help="lint the repo's own tree (ddp_tpu/, scripts/, train.py, "
-        "bench.py) — the CI smoke-tier gate",
+        help="lint the repo's own tree (ddp_tpu/, scripts/, train.py) "
+        "— the CI smoke-tier gate",
     )
     p.add_argument(
         "--json", default=None, metavar="PATH",

@@ -196,16 +196,6 @@ def main() -> None:
         "ROUTER enforces who gets which traffic",
     )
     p.add_argument(
-        "--tuned", default="auto", metavar="auto|off|PATH",
-        help="tuning cache (ddp_tpu.tune, scripts/autotune.py): "
-        "'auto' loads tuning_cache.json beside --checkpoint_dir and "
-        "fills every scheduler knob the command line left at its "
-        "default from the cached winner for this (model shape, "
-        "hardware) pair — explicit flags always win; 'off' disables; "
-        "a path loads that cache file. A hit costs zero search and "
-        "is stamped on the startup JSON",
-    )
-    p.add_argument(
         "--model", action="append", default=None, metavar="NAME=DIR",
         help="register an EXTRA named model from its own checkpoint "
         "dir (repeatable): requests carrying model=NAME route to its "
@@ -310,61 +300,6 @@ def main() -> None:
             )
         model_version = model_version_token(args.checkpoint_dir, epoch)
 
-    # Tuning cache (ddp_tpu.tune): fill knobs the command line left
-    # at defaults from the cached winner for this (model shape,
-    # hardware) pair. Explicit flags always win; --tuned off (or no
-    # cache file) leaves every code path byte-identical to today.
-    # Resolved BEFORE the draft block so a cached γ can still
-    # synthesize its --init_demo draft.
-    tuning = None
-    if args.tuned != "off":
-        from ddp_tpu.tune import (
-            apply_tuned,
-            cache_key,
-            model_signature,
-            resolve_cache,
-        )
-
-        _cache = resolve_cache(args.tuned, args.checkpoint_dir)
-        _ent = (
-            _cache.lookup(cache_key("serve", model_signature(spec)))
-            if _cache is not None
-            else None
-        )
-        if _ent is not None:
-            current = {
-                "prefill_chunk": args.prefill_chunk,
-                "min_bucket": args.min_bucket,
-                "step_token_budget": args.step_token_budget,
-                "page_size": args.page_size,
-                "kv_pages": args.kv_pages,
-                "spec_tokens": args.spec_tokens,
-            }
-            explicit = {
-                k for k, v in current.items()
-                if (v is not None and k in (
-                    "prefill_chunk", "min_bucket",
-                    "step_token_budget", "kv_pages",
-                )) or (v and k in ("page_size", "spec_tokens"))
-            }
-            merged, applied, overridden = apply_tuned(
-                current, _ent["config"], explicit=explicit
-            )
-            if merged.get("spec_tokens") and not (
-                args.draft_checkpoint_dir or args.init_demo
-            ):
-                # A cached γ is unusable without a draft source —
-                # drop it rather than failing startup.
-                merged["spec_tokens"] = args.spec_tokens
-                applied.pop("spec_tokens", None)
-            for k, v in merged.items():
-                setattr(args, k, v)
-            tuning = {
-                "cache": _cache.path,
-                "applied": applied,
-                "overridden": overridden,
-            }
-
     # Speculative decoding's draft model: a real (smaller) checkpoint
     # with its own lm_spec.json, or — under --init_demo — a freshly
     # initialized half-width sibling so the demo/CI path exercises
@@ -403,18 +338,6 @@ def main() -> None:
             )
 
     metrics = MetricsWriter(args.metrics_file)
-    if tuning:
-        # Provenance record: a tuned run is distinguishable from a
-        # default run in every triage surface (health_report prints
-        # the one-line `tuning` summary off this).
-        metrics.write(
-            "tuning",
-            site="serve",
-            cache_hit=True,
-            cache=tuning["cache"],
-            applied=tuning["applied"],
-            overridden=tuning["overridden"],
-        )
     tracer = Tracer(
         enabled=bool(args.trace_dir),
         ring_events=args.trace_ring_events,
@@ -450,7 +373,6 @@ def main() -> None:
     recorder.set_context(
         build_info=build_info(), env=snapshot_env(),
         slo=args.slo, role="serve",
-        **({"tuning": tuning} if tuning else {}),
     )
     engine = ServeEngine(
         spec,
@@ -592,7 +514,6 @@ def main() -> None:
                         **({"role": args.role} if args.role else {}),
                         "reqtrace": bool(args.reqtrace),
                         **({"slo": args.slo} if args.slo else {}),
-                        **({"tuning": tuning} if tuning else {}),
                         **(
                             {"model_version": model_version}
                             if model_version

@@ -69,7 +69,7 @@ def test_one_place_decides_the_cache_directory():
     hits = []
     roots = [os.path.join(REPO, d) for d in ("ddp_tpu", "scripts")]
     files = [
-        os.path.join(REPO, f) for f in ("train.py", "bench.py", "chip_smoke.py")
+        os.path.join(REPO, f) for f in ("train.py", "chip_smoke.py")
     ]
     for root in roots:
         for dirpath, _, names in os.walk(root):

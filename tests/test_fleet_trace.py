@@ -20,8 +20,7 @@ hops — the cross-PROCESS half of the ISSUE-11 request tracer:
    stay byte-identical.
 5. **Fleet reconstruction** — router + replica events merge into one
    causally-validated timeline per trace id (in-process smoke here;
-   the real 3-process disagg drill is the slow tier below, and
-   ``bench.py serve_fleet`` phase 7 repeats it with migration).
+   the real 3-process disagg drill is the slow tier below).
 6. **Zero added syncs** — the ISSUE-3 transfer spy re-runs green
    with a fleet-ADOPTED trace context and router hops attached.
 """

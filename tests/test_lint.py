@@ -216,35 +216,8 @@ def test_self_lint_clean():
     proc = _run_cli("--self")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert proc.stdout.strip().endswith("file(s)")
-    # and the in-process API agrees (bench.py's lint_clean path)
+    # and the in-process API agrees
     assert self_lint().unsuppressed == []
-
-
-def test_bench_key_reuse_fixed():
-    """Regression pin for the PR-6 self-lint catch: bench.py's ViT
-    side-bench drew labels with the SAME key as the images (DDP005 —
-    labels correlated with pixels), fixed with a split. The rule must
-    keep passing on bench.py so the bug cannot return."""
-    result = lint_paths(
-        [os.path.join(REPO, "bench.py")], select={"DDP005"}
-    )
-    assert result.unsuppressed == []
-    # and the fix is the split-per-consumer idiom, not a suppression
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    assert "k_img, k_lbl = jax.random.split(key)" in src
-
-
-def test_bench_headline_lint_clean_field():
-    """bench.py stamps the self-lint verdict on headline records so a
-    lint regression is visible in the perf-trajectory sidecars; on
-    this tree it must be True (and never raise)."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    assert bench._lint_clean() is True
 
 
 def test_health_seg_constant_fixed():

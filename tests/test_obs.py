@@ -235,7 +235,7 @@ def test_flops_goldens():
         (32, 32, 3), 100, patch_size=4, embed_dim=192, depth=12,
         num_heads=3,
     ) == 2_190_804_480.0
-    # bench.py's LM config; GQA shrinks it, MoE top-2 grows it
+    # the d1024x8 LM; GQA shrinks it, MoE top-2 grows it
     mha = lm_train_flops_per_token(
         vocab_size=8192, total_len=2048, d_model=1024, depth=8,
         num_heads=8,

@@ -212,7 +212,7 @@ class TestEngine:
 
     def test_moe_routing_config_threaded(self):
         """MoE-LM serves through the engine with its OWN routing
-        config (top_k=1: the round-5 ADVICE hardcode would compute
+        config (top_k=1: the round-5 hardcode would compute
         top-2 here and diverge from the training forward)."""
         spec = SPEC._replace(
             num_experts=4, moe_every=2, moe_top_k=1,

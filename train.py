@@ -73,9 +73,6 @@ def main(argv=None) -> int:
         print("\n".join(sorted(rows)))
         return 0
     config = TrainConfig.from_namespace(ns)
-    # The typed-flag set rides along for the tuning cache's
-    # explicit-beats-cache precedence (from_namespace can't see argv).
-    config.explicit_flags = TrainConfig.scan_explicit_flags(args)
     if config.max_restarts and config.spawn <= 1:
         raise ValueError(
             "--max_restarts is the --spawn launcher's restart loop "
