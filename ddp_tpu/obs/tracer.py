@@ -87,6 +87,13 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "train.compile": ("reduces", "asynchronous", "start_in_backward",
                       "under_backward", "reduce_bytes",
                       "asynchronous_bytes"),
+    # ops/flash.py — one per traced ``pallas_call`` of a flash training
+    # kernel (trace time, zero duration; a compiled step leaves none):
+    # the grid steps a (batch·head) visits, how many of them run the
+    # masked program, how many are dead (visited, nothing computed).
+    # ``kernel`` and ``operand_dtype`` are names, not numbers
+    "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
+                   "dead", "operand_dtype"),
 }
 # Spans in which the host WAITS for the device (the blocking token
 # fetch): host time, but not host work.

@@ -2,9 +2,9 @@
 
 The hot op of the attention path, written for the hardware
 (/opt/skills/guides/pallas_guide.md): Q and K/V blocks stream through
-VMEM on a (batch·head, q-block, kv-block) grid, the online-softmax
-recurrence lives in fp32 VMEM scratch that persists across the
-innermost grid dimension, every matmul hits the MXU with
+VMEM on a (batch·head, live block pair) grid, the online-softmax
+recurrence lives in fp32 VMEM scratch that persists across the pairs
+of an output block, every matmul hits the MXU with
 ``preferred_element_type=jnp.float32``, and HBM traffic is O(T·D) —
 the [T, S] score matrix never exists. This is the TPU-native answer to
 the fused ATen attention kernels the reference inherits invisibly from
@@ -20,9 +20,30 @@ saved residuals. Peak memory of the whole VJP is O(T·D); the round-1
 version recomputed backward through a dense O(T²) reference
 (VERDICT.md "What's missing" #1).
 
-Causal masking skips FLOPs: strictly-future (q-block, kv-block) cells
-are ``pl.when``-gated off in all three kernels, so ~half the MXU work
-disappears at large T.
+Causal masking skips arithmetic, grid steps and fetches (PR 31). Every
+(q block, k block) pair is classified from the static shapes
+(``_classify``: *interior*, *diagonal* or *dead*, by the block corners
+under the same ``_last_key`` the mask applies). The grid's second
+dimension runs over the LIVE pairs only, through scalar-prefetched
+(outer, inner, flags) tables (``_live_pairs``): a strictly-future pair
+is no grid step, so its blocks are never fetched — 10 steps of 16 at
+2048 x 2048 in blocks of 512, in all three kernels. Of the live pairs
+only the diagonal ones run the program with the mask (iota, compare,
+select); interior pairs run the plain one, and the guards against a
+row with no visible key are built only where such a row can exist
+(``T > S``). Each traced ``pallas_call`` leaves a ``flash.plan`` record
+in the tracer's ring (``obs/tracer.py``): blocks, steps visited, of
+which masked, of which dead.
+
+What a step does NOT pay for, found on the chip (PERF.md section 6, PR
+31): a cross-lane sum (the forward's ``l`` travels as lane-partial
+sums, reduced once a q block), lane broadcasts of [block, 1] columns
+(the forward's statistics stay [block_q, LANES]), and transposes of
+the [block_q, block_k] tiles on their way to the MXU (the dK/dV kernel
+builds its tile keys first). MXU operands are float32 copies of the
+blocks: at the default precision the MXU takes them in one bfloat16
+pass, bit for bit what explicit bfloat16 operands give, and the
+explicit casts measured no faster.
 
 ``flash_attention_with_lse`` additionally returns the LSE rows, which
 makes the kernel composable as the per-hop block primitive of ring
@@ -35,25 +56,32 @@ Layout notes (Mosaic constraints): per-row statistics (LSE, delta)
 travel as [B·H, T, LANES] fp32 broadcast across a 128-lane minor
 dimension — a [.., T, 1] layout would be lane-padded to 128 in VMEM
 anyway, and 2-D [B·H, T] blocks of one row are not tileable. Scratch
-accumulators persist across the innermost grid dimension and flush on
-its last iteration (``pl.when``), the same scheme as
-jax.experimental.pallas.ops.tpu.flash_attention.
+accumulators persist across the steps of an output block and flush on
+its last one (``pl.when`` on the step's flags), the scheme of
+jax.experimental.pallas.ops.tpu.flash_attention. The live-pair tables
+sit in scalar memory, 12 bytes a pair: T/block_q x S/block_k entries at
+most (4,096 at 32k x 32k in blocks of 512).
 
 ``interpret=True`` runs the kernels on CPU for tests — the same
 program the TPU compiles, minus Mosaic. On-chip agreement with the
 dense reference is checked by ``scripts/check_kernels.py`` (run by
-``chip_smoke.py``); the tolerances it found are in CHANGES.md.
+``chip_smoke.py``; ``--time`` prints the three kernels' device ms a
+call at the train cells' shape); the tolerances it found are in
+CHANGES.md.
 """
 
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ddp_tpu.obs.tracer import get_tracer
 
 # Minor-most lanes of a TPU vector register; per-row stats are carried
 # broadcast across this many lanes (see module docstring).
@@ -84,211 +112,281 @@ def _last_key(row, causal):
     bidirectional inside a block, causal between blocks. A plain
     function of its arguments: the kernels call it on iotas and on
     block corners, the tests on integers."""
-    b = int(causal)
-    return row if b <= 1 else row // b * b + (b - 1)
+    if causal <= 1:  # True is the plain triangle too
+        return row
+    return row // causal * causal + (causal - 1)
 
 
 def _causal_mask(s, q_start, k_start, block_q, block_k, S_total, T_total,
-                 causal=True):
+                 causal=True, keys_first=False):
     """End-anchored causal mask: query t sees keys up to t + S − T
     (the dense reference's tril(k=S−T); KV-cache convention for T≠S),
-    or to the end of its block under a block-causal ``causal``."""
+    or to the end of its block under a block-causal ``causal``. ``s``
+    is [block_q, block_k], or its transpose with ``keys_first``."""
+    shape = (block_k, block_q) if keys_first else (block_q, block_k)
     rows = _last_key(q_start + (S_total - T_total) + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
+        jnp.int32, shape, int(keys_first)
     ), causal)
-    cols = k_start + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    cols = k_start + lax.broadcasted_iota(jnp.int32, shape, 1 - keys_first)
     return jnp.where(rows >= cols, s, -jnp.inf)
 
 
+# What a grid step's entry in the scalar-prefetched ``flags`` table
+# says: the step is the first / the last of its output block (zero the
+# accumulators / write the block out), and its block pair's class.
+_FIRST, _LAST, _DIAGONAL, _DEAD = 1, 2, 4, 8
+
+
+def _classify(T, S, block_q, block_k, causal):
+    """Class of every (q block, k block) pair, ``T/bq`` rows of ``S/bk``:
+    0 (*interior*: the tile's first query row already sees its last
+    key, so no element is masked), ``_DIAGONAL`` (only part of the tile
+    is visible) or ``_DEAD`` (no query row sees the tile's first key).
+    Decided on the block corners by the ``_last_key`` the mask itself
+    applies, so the end-anchored ``T != S`` and the block-causal cases
+    are classified as they are masked; without ``causal`` every pair
+    is interior. Python ints from static shapes: trace time only."""
+    n_q, n_k = T // block_q, S // block_k
+    classes = [[0] * n_k for _ in range(n_q)]
+    if not causal:
+        return classes
+    for i, row in enumerate(classes):
+        first_row = i * block_q + (S - T)
+        sees_first = _last_key(first_row, causal)
+        sees_last = _last_key(first_row + block_q - 1, causal)
+        for j in range(n_k):
+            if sees_last < j * block_k:
+                row[j] = _DEAD
+            elif sees_first < (j + 1) * block_k - 1:
+                row[j] = _DIAGONAL
+    return classes
+
+
+def _live_pairs(classes, *, by_key: bool = False):
+    """The grid's second dimension: ``(outer, inner, flags)`` lists
+    with one entry per LIVE block pair of ``classes``, grouped by
+    output block (the q block; the k block with ``by_key``, for dK/dV)
+    in ascending order. A dead pair is no grid step, so it is neither
+    visited nor fetched. An output block with no live pair at all
+    (query rows before the first key, ``T > S``) keeps one entry
+    flagged ``_DEAD``: its step only zeroes and writes the block."""
+    if by_key:
+        classes = list(zip(*classes))
+    outer, inner, flags = [], [], []
+    for o, row in enumerate(classes):
+        live = [n for n, c in enumerate(row) if c != _DEAD] or [0]
+        outer += [o] * len(live)
+        inner += live
+        flags += [row[n] for n in live]
+        flags[-len(live)] |= _FIRST
+        flags[-1] |= _LAST
+    return outer, inner, flags
+
+
+def _by_class(flags, causal, body):
+    """Run ``body(masked)`` once for a live step: the masked program on
+    a diagonal pair, the plain one (no iota, compare or select) on an
+    interior pair, nothing on a dead one. Without ``causal`` there is
+    no diagonal pair and the masked program is not even built."""
+    if causal:
+        pl.when(flags & _DIAGONAL != 0)(functools.partial(body, True))
+    pl.when(flags & (_DIAGONAL | _DEAD) == 0)(functools.partial(body, False))
+
+
+def _dot(a, b, contract):
+    """``a`` · ``b`` over the dimension pair ``contract``, accumulated
+    in float32. The operands are float32 too; at the default precision
+    the MXU takes them in ONE bfloat16 pass (bit for bit what explicit
+    bfloat16 operands give on a TPU v5e: PERF.md section 6, PR 31)."""
+    return lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32),
+        ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _over_lanes(stat, n):
+    """A [rows, LANES] lane-broadcast statistic as it meets a [rows, n]
+    tile: whole lane groups repeated where n allows, else a column."""
+    if n % LANES:
+        return stat[:, :1]
+    return stat if n == LANES else jnp.concatenate(
+        [stat] * (n // LANES), axis=1)
+
+
+def _lane_partial_sum(p):
+    """[rows, n] → [rows, LANES] whose lanes sum to each row's sum: the
+    cross-lane reduction is left to whoever reads the total."""
+    rows, n = p.shape
+    if n % LANES:
+        return jnp.broadcast_to(
+            p.sum(axis=-1, keepdims=True) * (1.0 / LANES), (rows, LANES))
+    return functools.reduce(
+        jnp.add, (p[:, c:c + LANES] for c in range(0, n, LANES)))
+
+
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+    acc_ref, m_ref, l_ref,
     *, scale, causal, block_q, block_k, T_total, S_total,
 ):
-    """Grid (B·H, T/bq, S/bk): online softmax over streamed KV blocks."""
-    j = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-    q_start = pl.program_id(1) * block_q
+    """Grid (B·H, live pairs by q block): online softmax over the
+    streamed KV blocks of each q block.
 
-    @pl.when(j == 0)
+    The running statistics stay [block_q, LANES] from step to step:
+    ``m_ref`` the row maximum on every lane, ``l_ref`` PARTIAL row sums
+    (its lanes add up to the row's), so that a step pays neither a
+    cross-lane sum nor a lane broadcast of a [block_q, 1] column; the
+    flush reduces ``l`` once a q block."""
+    step = pl.program_id(1)
+    flags = flags_ref[step]
+    q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
+    # Only with more queries than keys does a row see no key at all.
+    empty_rows = bool(causal) and T_total > S_total
+
+    @pl.when(flags & _FIRST != 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal:
-        # Fully-masked (strictly future) block: skip all compute.
-        live = _last_key(
-            q_start + block_q - 1 + (S_total - T_total), causal
-        ) >= j * block_k
-    else:
-        live = True
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale  # [block_q, D]
-        kb = k_ref[0].astype(jnp.float32)  # [block_k, D]
-        vb = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if causal:
+        s = _dot(q, k_ref[0], (1, 1))  # [block_q, block_k]
+        if masked:
             s = _causal_mask(
-                s, q_start, j * block_k, block_q, block_k, S_total, T_total,
+                s, q_start, k_start, block_q, block_k, S_total, T_total,
                 causal,
             )
-        m = m_ref[...][:, :1]
-        l = l_ref[...][:, :1]
-        new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        # A fully-masked ROW has new_m = -inf; exp(-inf − -inf) would
-        # be NaN. Guard the shift.
-        shift = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-        p = jnp.exp(s - shift)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - shift), 0.0)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(new_m, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.broadcast_to(
+            s.max(axis=-1, keepdims=True), m.shape))
+        shift, corr = new_m, jnp.exp(m - new_m)
+        if empty_rows:
+            # A fully-masked ROW has new_m = -inf; exp(-inf − -inf)
+            # would be NaN. Guard the shift.
+            shift = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
+            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - shift), 0.0)
+        p = jnp.exp(s - _over_lanes(shift, block_k))
+        acc_ref[...] = acc_ref[...] * _over_lanes(
+            corr, acc_ref.shape[1]) + _dot(p, v_ref[0], (1, 0))
+        l_ref[...] = l_ref[...] * corr + _lane_partial_sum(p)
+        m_ref[...] = new_m
 
-    @pl.when(j == n_kb - 1)
+    _by_class(flags, causal, _compute)
+
+    @pl.when(flags & _LAST != 0)
     def _flush():
         m = m_ref[...][:, :1]
-        l = l_ref[...][:, :1]
+        l = l_ref[...].sum(axis=-1, keepdims=True)
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse = jnp.where(
-            l > 0.0,
-            jnp.where(jnp.isfinite(m), m, 0.0)
-            + jnp.log(jnp.maximum(l, 1e-30)),
-            -jnp.inf,
-        )
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))
+        if empty_rows:
+            lse = jnp.where(l > 0.0, lse, -jnp.inf)
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
 
 
+def _saved_lse(lse, empty_rows):
+    """The forward's LSE as the backward subtracts it: a row that saw
+    no key saved -inf, and must give P = exp(S − LSE) = 0, not NaN."""
+    if not empty_rows:
+        return lse
+    return jnp.where(jnp.isfinite(lse), lse, 0.5 * jnp.finfo(jnp.float32).max)
+
+
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_acc,
+    qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+    dq_ref, dq_acc,
     *, scale, causal, block_q, block_k, T_total, S_total,
 ):
-    """Grid (B·H, T/bq, S/bk): dQ accumulates over streamed KV blocks.
+    """Grid (B·H, live pairs by q block): dQ accumulates over the
+    streamed KV blocks of each q block.
 
     ``dl_ref`` holds delta' = rowsum(dO ∘ O) − dLSE; with P recomputed
     as exp(S − LSE), dS = P ∘ (dO·Vᵀ − delta') and dQ = scale · dS·K.
     """
-    j = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-    q_start = pl.program_id(1) * block_q
+    step = pl.program_id(1)
+    flags = flags_ref[step]
+    q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
+    empty_rows = bool(causal) and T_total > S_total
 
-    @pl.when(j == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    if causal:
-        live = _last_key(
-            q_start + block_q - 1 + (S_total - T_total), causal
-        ) >= j * block_k
-    else:
-        live = True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = _row_stat(lse_ref)
-        lse = jnp.where(
-            jnp.isfinite(lse), lse, 0.5 * jnp.finfo(jnp.float32).max
-        )
-        dl = _row_stat(dl_ref)
-        s = scale * lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if causal:
+    def _compute(masked):
+        q = q_ref[0].astype(jnp.float32) * scale
+        s = _dot(q, k_ref[0], (1, 1))  # [block_q, block_k]
+        if masked:
             s = _causal_mask(
-                s, q_start, j * block_k, block_q, block_k, S_total, T_total,
+                s, q_start, k_start, block_q, block_k, S_total, T_total,
                 causal,
             )
-        p = jnp.exp(s - lse)  # masked: exp(-inf) = 0
-        dp = lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dl)
-        dq_acc[...] = dq_acc[...] + lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        # masked: exp(-inf) = 0
+        p = jnp.exp(s - _saved_lse(_row_stat(lse_ref), empty_rows))
+        dp = _dot(do_ref[0], v_ref[0], (1, 1))
+        ds = p * (dp - _row_stat(dl_ref))
+        dq_acc[...] = dq_acc[...] + _dot(ds, k_ref[0], (1, 0))
 
-    @pl.when(j == n_kb - 1)
+    _by_class(flags, causal, _compute)
+
+    @pl.when(flags & _LAST != 0)
     def _flush():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc,
+    kj_ref, qi_ref, flags_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref,
+    dk_ref, dv_ref, dk_acc, dv_acc,
     *, scale, causal, block_q, block_k, T_total, S_total,
 ):
-    """Grid (B·H, S/bk, T/bq): dK/dV accumulate over streamed Q blocks."""
-    i = pl.program_id(2)
-    n_qb = pl.num_programs(2)
-    k_start = pl.program_id(1) * block_k
+    """Grid (B·H, live pairs by k block): dK/dV accumulate over the
+    streamed Q blocks of each k block.
 
-    @pl.when(i == 0)
+    Where the q block fills whole lane groups the tile is built KEYS
+    FIRST, Sᵀ = K·Qᵀ [block_k, block_q]: then Pᵀ and dSᵀ enter the two
+    accumulating matmuls as they stand (queries first, each would be
+    transposed on its way to the MXU) and the row statistics are
+    [1, block_q] rows that meet the tile along the sublanes."""
+    step = pl.program_id(1)
+    flags = flags_ref[step]
+    q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
+    empty_rows = bool(causal) and T_total > S_total
+    keys_first = block_q % LANES == 0
+
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        # Last query row of this Q block must see the first key of
-        # this K block: (i+1)·bq − 1 + S − T >= k_start.
-        live = _last_key(
-            (i + 1) * block_q - 1 + (S_total - T_total), causal
-        ) >= k_start
-    else:
-        live = True
-
-    @pl.when(live)
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        qb = q_ref[0].astype(jnp.float32)
-        dob = do_ref[0].astype(jnp.float32)
-        lse = _row_stat(lse_ref)
-        lse = jnp.where(
-            jnp.isfinite(lse), lse, 0.5 * jnp.finfo(jnp.float32).max
-        )
-        dl = _row_stat(dl_ref)
-        s = scale * lax.dot_general(
-            qb, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if causal:
+    def _compute(masked):
+        # q carries the scale, and so does the dK it accumulates into
+        q = q_ref[0].astype(jnp.float32) * scale
+        if keys_first:
+            stat = lambda ref: ref[0].T[:1]  # [1, block_q]
+            swap = lambda a, b: (b, a)
+            over_queries = 1
+        else:
+            stat = _row_stat  # [block_q, 1]
+            swap = lambda a, b: (a, b)
+            over_queries = 0
+        s = _dot(*swap(q, k_ref[0]), (1, 1))
+        if masked:
             s = _causal_mask(
-                s, i * block_q, k_start, block_q, block_k, S_total, T_total,
-                causal,
+                s, q_start, k_start, block_q, block_k, S_total, T_total,
+                causal, keys_first,
             )
-        p = jnp.exp(s - lse)
-        dv_acc[...] = dv_acc[...] + lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            dob, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dl)
-        dk_acc[...] = dk_acc[...] + lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        p = jnp.exp(s - _saved_lse(stat(lse_ref), empty_rows))
+        dv_acc[...] = dv_acc[...] + _dot(p, do_ref[0], (over_queries, 0))
+        dp = _dot(*swap(do_ref[0], v_ref[0]), (1, 1))
+        ds = p * (dp - stat(dl_ref))
+        dk_acc[...] = dk_acc[...] + _dot(ds, q, (over_queries, 0))
 
-    @pl.when(i == n_qb - 1)
+    _by_class(flags, causal, _compute)
+
+    @pl.when(flags & _LAST != 0)
     def _flush():
-        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
@@ -331,6 +429,35 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
+def _outer_block(b, n, outer, inner, flags):
+    """Index map of an operand blocked like the OUTPUT (the q block in
+    forward and dQ, the k block in dK/dV): the step's table entry."""
+    return (b, outer[n], 0)
+
+
+def _inner_block(b, n, outer, inner, flags):
+    """Index map of an operand streamed under an output block."""
+    return (b, inner[n], 0)
+
+
+def _plan(kernel, T, S, block_q, block_k, causal, *, by_key=False):
+    """The live-pair tables of one ``pallas_call``, and its
+    ``flash.plan`` record in the tracer's ring (trace time: a compiled
+    step leaves none): the grid steps a (batch·head) visits, how many
+    of them run the masked program, how many are dead, and the dtype
+    the MXU's operands are handed over in."""
+    tables = _live_pairs(
+        _classify(T, S, block_q, block_k, causal), by_key=by_key)
+    flags = tables[2]
+    get_tracer().complete(
+        "flash.plan", time.perf_counter(), 0.0,
+        nums=(kernel, block_q, block_k, len(flags),
+              sum(1 for f in flags if f & _DIAGONAL),
+              sum(1 for f in flags if f & _DEAD), "float32"),
+    )
+    return tuple(jnp.asarray(t, jnp.int32) for t in tables)
+
+
 def _flash_forward(
     q, k, v, *, causal: bool, block_q: int, block_k: int, interpret: bool
 ):
@@ -340,37 +467,40 @@ def _flash_forward(
     block_q, block_k = _pick_blocks(T, S, block_q, block_k, q.dtype)
     scale = D**-0.5
     qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
+    by_q = _plan("flash_fwd", T, S, block_q, block_k, causal)
 
     kw = {"memory_space": pltpu.VMEM}
-    qmap = lambda b, i, j: (b, i, 0)
-    kmap = lambda b, i, j: (b, j, 0)
+    qmap, kmap = _outer_block, _inner_block
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
             block_k=block_k, T_total=T, S_total=S,
         ),
-        grid=(B * H, T // block_q, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qmap, **kw),
-            pl.BlockSpec((1, block_k, D), kmap, **kw),
-            pl.BlockSpec((1, block_k, D), kmap, **kw),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), qmap, **kw),
-            pl.BlockSpec((1, block_q, LANES), qmap, **kw),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B * H, len(by_q[0])),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), qmap, **kw),
+                pl.BlockSpec((1, block_k, D), kmap, **kw),
+                pl.BlockSpec((1, block_k, D), kmap, **kw),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), qmap, **kw),
+                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
+            ],
+            scratch_shapes=[
+                _scratch((block_q, D)),
+                _scratch((block_q, LANES)),
+                _scratch((block_q, LANES)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, T, LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            _scratch((block_q, D)),
-            _scratch((block_q, LANES)),
-            _scratch((block_q, LANES)),
-        ],
         interpret=interpret,
         name="flash_fwd",
-    )(qt, kt, vt)
+    )(*by_q, qt, kt, vt)
     out = out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(B, H, T).transpose(0, 2, 1)  # [B, T, H]
     return out, lse
@@ -400,58 +530,64 @@ def _flash_backward(
     dl_l = _to_lanes(delta - dlse.astype(jnp.float32))
     lse_l = _to_lanes(lse)
     qt, kt, vt, gt = _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g)
+    by_q = _plan("flash_dq", T, S, block_q, block_k, causal)
+    by_k = _plan("flash_dkv", T, S, block_q, block_k, causal, by_key=True)
 
     kw = {"memory_space": pltpu.VMEM}
     common = dict(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         T_total=T, S_total=S,
     )
-    qmap = lambda b, i, j: (b, i, 0)
-    kmap = lambda b, i, j: (b, j, 0)
+    qmap, kmap = _outer_block, _inner_block
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
-        grid=(B * H, T // block_q, S // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qmap, **kw),
-            pl.BlockSpec((1, block_k, D), kmap, **kw),
-            pl.BlockSpec((1, block_k, D), kmap, **kw),
-            pl.BlockSpec((1, block_q, D), qmap, **kw),
-            pl.BlockSpec((1, block_q, LANES), qmap, **kw),
-            pl.BlockSpec((1, block_q, LANES), qmap, **kw),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), qmap, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B * H, len(by_q[0])),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), qmap, **kw),
+                pl.BlockSpec((1, block_k, D), kmap, **kw),
+                pl.BlockSpec((1, block_k, D), kmap, **kw),
+                pl.BlockSpec((1, block_q, D), qmap, **kw),
+                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
+                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, D), qmap, **kw),
+            scratch_shapes=[_scratch((block_q, D))],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
         name="flash_dq",
-    )(qt, kt, vt, gt, lse_l, dl_l)
+    )(*by_q, qt, kt, vt, gt, lse_l, dl_l)
 
     # For dK/dV the K block is the OUTER streamed dim, Q the inner.
-    kvmap = lambda b, jk, i: (b, jk, 0)
-    qmap2 = lambda b, jk, i: (b, i, 0)
+    kvmap, qmap2 = _outer_block, _inner_block
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
-        grid=(B * H, S // block_k, T // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_k, D), kvmap, **kw),
-            pl.BlockSpec((1, block_k, D), kvmap, **kw),
-            pl.BlockSpec((1, block_q, D), qmap2, **kw),
-            pl.BlockSpec((1, block_q, D), qmap2, **kw),
-            pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
-            pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), kvmap, **kw),
-            pl.BlockSpec((1, block_k, D), kvmap, **kw),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B * H, len(by_k[0])),
+            in_specs=[
+                pl.BlockSpec((1, block_k, D), kvmap, **kw),
+                pl.BlockSpec((1, block_k, D), kvmap, **kw),
+                pl.BlockSpec((1, block_q, D), qmap2, **kw),
+                pl.BlockSpec((1, block_q, D), qmap2, **kw),
+                pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
+                pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), kvmap, **kw),
+                pl.BlockSpec((1, block_k, D), kvmap, **kw),
+            ],
+            scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
         ],
-        scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
         interpret=interpret,
         name="flash_dkv",
-    )(kt, vt, qt, gt, lse_l, dl_l)
+    )(*by_k, kt, vt, qt, gt, lse_l, dl_l)
 
     back = lambda x, T_: x.reshape(B, H, T_, D).transpose(0, 2, 1, 3)
     return back(dq, T), back(dk, S), back(dv, S)

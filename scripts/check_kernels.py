@@ -8,6 +8,9 @@ with its reference.
                                               #   block-diffusion MoE model's kernels
     python scripts/check_kernels.py --tiny    # small shapes (CPU rehearsal: the
                                               #   Pallas interpreter, not Mosaic)
+    python scripts/check_kernels.py --time    # device ms a call of flash_fwd,
+                                              #   flash_dq, flash_dkv at the train
+                                              #   cells' shape (needs the chip)
 
 On a TPU the kernels compile under Mosaic; elsewhere they run in the
 Pallas interpreter, which proves the program and nothing about the
@@ -15,7 +18,9 @@ chip. References run under ``jax.default_matmul_precision("highest")``
 on fp32 copies of the inputs. One JSON line per case —
 ``{"case", "kernel", "max_abs_err", "tol", "ok"}`` — then one summary
 line; exit status 1 when any case is outside its tolerance or failed
-to build. Nothing here is timed.
+to build. Only ``--time`` times anything: the three flash training
+kernels, by their names in a profiler trace of a few calls (the loop a
+change to ``ops/flash.py`` iterates in; no benchmark cell runs this).
 """
 
 from __future__ import annotations
@@ -56,11 +61,27 @@ def _max_err(a, b) -> float:
     )
 
 
+# The flash call of both train cells (benchmarks/configs/
+# cerebras-gpt-1.3b-train.json): 4 rows of 2048 tokens a chip, 16 heads
+# of 128, ``best_attention``'s default blocks.
+CELL_FLASH = dict(B=4, T=2048, H=16, D=128, block=512)
+
+
+def _flash_inputs(B, T, H, D, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.key(0), 4)
+    q, k, v = (jax.random.normal(key, (B, T, H, D), dtype) for key in keys[:3])
+    return q, k, v, jax.random.normal(keys[3], (B, T, H, D), jnp.float32)
+
+
 def check_flash(B: int, T: int, H: int, D: int, block: int,
-                causal=True) -> dict:
+                causal=True, dtype="bfloat16", backward=True) -> dict:
     """Flash forward AND backward (the trainer's causal bf16 call)
     against dense attention. ``causal`` an int > 1: the block-causal
-    mask of a block-diffusion prefill."""
+    mask of a block-diffusion prefill. ``backward=False``: the forward
+    alone (the whole-prompt prefill's float32 call)."""
     import jax
     import jax.numpy as jnp
 
@@ -68,12 +89,7 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
     from ddp_tpu.ops.flash import flash_attention
 
     interpret = jax.default_backend() != "tpu"
-    kq, kk, kv, kw = jax.random.split(jax.random.key(0), 4)
-    shape = (B, T, H, D)
-    q, k, v = (
-        jax.random.normal(key, shape, jnp.bfloat16) for key in (kq, kk, kv)
-    )
-    w = jax.random.normal(kw, shape, jnp.float32)  # the cotangent
+    q, k, v, w = _flash_inputs(B, T, H, D, dtype)  # w: the cotangent
 
     def flash_loss(q, k, v):
         out = flash_attention(q, k, v, causal, block, block, interpret)
@@ -85,6 +101,15 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
         out = dot_product_attention(q, k, v, causal=True, block=block_causal)
         return (out * w).sum(), out
 
+    if not backward:
+        out = jax.jit(lambda *a: flash_loss(*a)[1])(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a: dense_loss(*a)[1])(q, k, v)
+        err = _max_err(out, ref)
+        return {
+            "max_abs_err": err, "tol": TOL["flash"],
+            "ok": bool(jnp.isfinite(out).all()) and err <= TOL["flash"],
+        }
     (_, out), grads = jax.jit(
         jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True)
     )(q, k, v)
@@ -104,6 +129,83 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
         "grad_max_abs_err": grad_err,
         "grad_tol": TOL["flash_grad"],
         "ok": finite and err <= TOL["flash"] and grad_err <= TOL["flash_grad"],
+    }
+
+
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def kernel_ms_per_call(log_dir: str, calls: int) -> dict:
+    """Device ms a call of each flash kernel in the profiler trace
+    under ``log_dir``: the ``XLA Ops`` events of the first TPU whose
+    instruction carries the kernel's name (``pallas_call(name=)``),
+    summed, over ``calls``. Empty where the trace holds no such event
+    (nothing ran on a chip)."""
+    import glob
+    import re
+
+    import jax
+
+    path = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    planes = sorted(
+        (p for p in jax.profiler.ProfileData.from_file(path).planes
+         if p.name.startswith("/device:TPU:")), key=lambda p: p.name)
+    total = dict.fromkeys(FLASH_KERNELS, 0)
+    for line in planes[0].lines if planes else ():
+        if line.name != "XLA Ops":
+            continue
+        for ev in line.events:
+            # "%flash_fwd.3 = (bf16[...]) custom-call(...)"; under a
+            # vjp the instruction is "%transpose_jvp_flash_dq__.1"
+            m = re.match(
+                r"%?[\w\-]*?(flash_(?:fwd|dq|dkv))(?![a-z])[\w\-.]* = ",
+                ev.name)
+            if m:
+                total[m.group(1)] += ev.duration_ns
+    if not any(total.values()):
+        return {}
+    return {k: ns / 1e6 / calls for k, ns in total.items()}
+
+
+def time_flash(B: int, T: int, H: int, D: int, block: int,
+               calls: int = 8) -> dict:
+    """Device ms a call of ``flash_fwd``, ``flash_dq`` and ``flash_dkv``
+    (causal, bf16): ``calls`` forward-and-backward calls inside a
+    profiler session, after one that compiles."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.flash import flash_attention
+
+    interpret = jax.default_backend() != "tpu"
+    q, k, v, w = _flash_inputs(B, T, H, D, jnp.bfloat16)
+
+    @jax.jit
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda *a: flash_attention(*a, True, block, block, interpret),
+            q, k, v,
+        )
+        return out, vjp(g)
+
+    g = w.astype(jnp.bfloat16)
+    jax.block_until_ready(fwd_bwd(q, k, v, g))
+    with tempfile.TemporaryDirectory() as log_dir:
+        jax.profiler.start_trace(log_dir)
+        try:
+            for _ in range(calls):
+                res = fwd_bwd(q, k, v, g)
+            jax.block_until_ready(res)
+        finally:
+            jax.profiler.stop_trace()
+        ms = kernel_ms_per_call(log_dir, calls)
+    return {
+        "shape": dict(B=B, T=T, H=H, D=D, block=block), "calls": calls,
+        "ms_per_call": ms, "ok": bool(ms),
+        **({} if ms else {"error": "no TPU in the trace: nothing timed"}),
     }
 
 
@@ -266,6 +368,13 @@ def cases(tiny: bool, every: bool):
         yield "flash_fwd_bwd_bf16_block_causal4", lambda: check_flash(
             **flash, causal=4
         )
+        # The train cells' own call, and its forward on float32 inputs
+        # (the whole-prompt prefill of a model that states fp32).
+        cell = flash if tiny else CELL_FLASH
+        yield "flash_fwd_bwd_bf16_causal_cell", lambda: check_flash(**cell)
+        yield "flash_fwd_fp32_causal_cell", lambda: check_flash(
+            **cell, dtype="float32", backward=False
+        )
         fold = (dict(S=2, H=32, H_kv=1, Dh=128, L=256, depth=2) if tiny
                 else dict(S=32, H=128, H_kv=4, Dh=128, L=512, depth=3))
         yield "decode_fp32_g32_block_folded", lambda: check_decode(**fold)
@@ -278,6 +387,7 @@ def main() -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--all", action="store_true")
     p.add_argument("--tiny", action="store_true")
+    p.add_argument("--time", action="store_true")
     args = p.parse_args()
 
     from ddp_tpu.obs.recorder import build_info
@@ -287,6 +397,13 @@ def main() -> int:
     enable_compile_cache()
     kernel = pallas_kernel_mode()
     print(json.dumps({"build_info": build_info(), "kernel": kernel}), flush=True)
+    if args.time:
+        rec = time_flash(**(
+            dict(B=1, T=128, H=2, D=128, block=64) if args.tiny else CELL_FLASH
+        ))
+        print(json.dumps({"case": "flash_time", "kernel": kernel, **rec}),
+              flush=True)
+        return 0 if rec["ok"] else 1
     failed = []
     for name, run in cases(args.tiny, args.all):
         try:

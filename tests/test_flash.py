@@ -8,6 +8,7 @@ under causal masking and through the custom-VJP backward.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ddp_tpu.ops.attention import dot_product_attention
 from ddp_tpu.ops.flash import flash_attention, make_flash_attention
@@ -224,3 +225,184 @@ def test_flash_bf16_finite():
     for g in grads:
         assert g.dtype == jnp.bfloat16
         assert np.isfinite(np.asarray(g, dtype=np.float32)).all()
+
+
+# ---- every block class, both operand dtypes (PR 31) ------------------
+#
+# The kernels visit only LIVE (q block, k block) pairs and run the
+# masked program only on DIAGONAL ones. Each shape below is chosen for
+# the classes it holds; T, S, block_q, block_k, causal.
+SHAPES = {
+    "causal_1_block": (32, 32, 32, 32, True),  # one diagonal pair
+    "causal_2_blocks": (32, 32, 16, 16, True),  # 1 interior, 2 diag, 1 dead
+    "causal_4_blocks": (64, 64, 16, 16, True),  # 6 / 4 / 6: the cell's grid
+    "causal_T_lt_S": (32, 64, 16, 16, True),  # end-anchored: 4 / 2 / 2
+    "causal_T_gt_S": (64, 32, 16, 16, True),  # rows that see no key
+    "causal_uneven_blocks": (64, 64, 32, 16, True),  # two diagonal a row
+    "block_causal_4": (32, 32, 16, 16, 4),
+    "block_causal_4_T_lt_S": (32, 96, 16, 32, 4),
+    "non_causal": (32, 48, 16, 16, False),  # interior throughout
+    # blocks that fill whole 128-lane groups, heads of 128: the paths
+    # the chip's shapes take (lane-dense statistics, dK/dV keys first)
+    "causal_lanes": (256, 256, 128, 128, True),
+    "causal_T_gt_S_lanes": (384, 256, 128, 128, True),
+    "block_causal_4_lanes": (256, 384, 128, 128, 4),
+    "non_causal_lanes": (128, 512, 128, 256, False),
+}
+
+
+def _dense_safe(q, k, v, causal):
+    """``_reference`` in float32 with rows that see no key giving 0
+    (and a gradient of 0) where a softmax over nothing gives NaN."""
+    from ddp_tpu.ops.flash import _last_key
+
+    T, S = q.shape[1], k.shape[1]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    logits = jnp.einsum("bthd,bshd->bhts", q, k) * q.shape[-1] ** -0.5
+    seen = jnp.ones((T, S), bool)
+    if causal:
+        rows = _last_key(jnp.arange(T)[:, None] + (S - T), causal)
+        seen = rows >= jnp.arange(S)[None, :]
+    w = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1)
+    w = w * seen.any(-1, keepdims=True)
+    return jnp.einsum("bhts,bshd->bthd", w, v)
+
+
+# max |kernel − dense| accepted: float32 as tightly as the tests above;
+# bfloat16 by its 2^-8 rounding of operands, probabilities and outputs
+# (scripts/check_kernels.py holds the chip to 2e-2 / 6e-2 at T 2048).
+ATOL = {"float32": (2e-5, 2e-5), "bfloat16": (2e-2, 6e-2)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_flash_every_block_class(shape, dtype):
+    """Outputs and all three gradients against the dense reference."""
+    from ddp_tpu.ops.flash import _reference
+
+    T, S, bq, bk, causal = SHAPES[shape]
+    B, H, D = (1, 1, 128) if shape.endswith("_lanes") else (2, 2, 16)
+    q, _, _ = _qkv(B, T, H, D, seed=20)
+    _, k, v = _qkv(B, S, H, D, seed=21)
+    w = _qkv(B, T, H, D, seed=22)[0]  # the cotangent
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+
+    def loss(attn):
+        def f(q, k, v):
+            out = attn(q, k, v)
+            return (out.astype(jnp.float32) * w).sum(), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, out), grads = loss(
+        lambda q, k, v: flash_attention(q, k, v, causal, bq, bk, True)
+    )(q, k, v)
+    (_, ref), ref_grads = loss(
+        lambda q, k, v: _dense_safe(q, k, v, causal)
+    )(*(x.astype(jnp.float32) for x in (q, k, v)))
+    assert out.dtype == q.dtype and all(g.dtype == q.dtype for g in grads)
+    if S >= T:  # every row sees a key: the module's own reference holds
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(_reference(
+                *(x.astype(jnp.float32) for x in (q, k, v)), causal)),
+            atol=2e-6,
+        )
+    else:  # the rows before the first key attend to nothing
+        assert not np.asarray(out, np.float32)[:, : T - S].any()
+    out_tol, grad_tol = ATOL[dtype]
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=out_tol)
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(r), atol=grad_tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_with_lse_gradients_under_a_nonzero_dlse(causal, dtype):
+    """``flash_attention_with_lse`` differentiated in BOTH outputs (ring
+    attention's hop): the lse cotangent reaches dq and dk."""
+    from ddp_tpu.ops.flash import flash_attention_with_lse
+
+    q, k, v = (x.astype(dtype) for x in _qkv(1, 32, 2, 16, seed=23))
+    w = _qkv(1, 32, 2, 16, seed=24)[0]
+    u = jnp.asarray(np.random.default_rng(25).normal(size=(1, 32, 2)),
+                    jnp.float32)
+
+    def dense(q, k, v):
+        logits = jnp.einsum("bthd,bshd->bhts", q, k) * q.shape[-1] ** -0.5
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), logits,
+                               -jnp.inf)
+        lse = jax.nn.logsumexp(logits, axis=-1).transpose(0, 2, 1)
+        out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(logits, -1), v)
+        return out, lse
+
+    def loss(attn):
+        def f(q, k, v):
+            out, lse = attn(q, k, v)
+            return (out.astype(jnp.float32) * w).sum() + (lse * u).sum()
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    grads = loss(
+        lambda q, k, v: flash_attention_with_lse(q, k, v, causal, 16, 16, True)
+    )(q, k, v)
+    ref_grads = loss(dense)(*(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, r in zip(grads, ref_grads):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(r), atol=ATOL[dtype][1])
+
+
+def _brute_classes(T, S, bq, bk, causal):
+    """Class of each block pair from the mask itself, element by element."""
+    from ddp_tpu.ops.flash import _DEAD, _DIAGONAL, _last_key
+
+    seen = np.ones((T, S), bool)
+    if causal:
+        rows = np.array([_last_key(t + S - T, causal) for t in range(T)])
+        seen = rows[:, None] >= np.arange(S)[None, :]
+    tiles = seen.reshape(T // bq, bq, S // bk, bk).transpose(0, 2, 1, 3)
+    return np.where(tiles.all((2, 3)), 0,
+                    np.where(tiles.any((2, 3)), _DIAGONAL, _DEAD))
+
+
+@pytest.mark.parametrize(
+    "shape", [*SHAPES, "the_train_cells", "prefill_T_gt_S_block_causal"])
+def test_block_classifier_and_live_pair_tables(shape):
+    """Interior / diagonal / dead from the block corners equals the
+    class the mask's own elements give; the grid visits every live
+    pair exactly once, in the order of its output block, and nothing
+    else but one zeroing step for an output block without a live pair."""
+    from ddp_tpu.ops.flash import (
+        _DEAD, _DIAGONAL, _FIRST, _LAST, _classify, _live_pairs,
+    )
+
+    T, S, bq, bk, causal = {
+        **SHAPES, "the_train_cells": (2048, 2048, 512, 512, True),
+        "prefill_T_gt_S_block_causal": (96, 32, 16, 8, 4),
+    }[shape]
+    classes = np.array(_classify(T, S, bq, bk, causal))
+    np.testing.assert_array_equal(classes, _brute_classes(T, S, bq, bk, causal))
+    if shape in ("the_train_cells", "causal_4_blocks"):
+        assert [int((classes == c).sum()) for c in (0, _DIAGONAL, _DEAD)] == [
+            6, 4, 6]
+    for by_key in (False, True):
+        outer, inner, flags = (
+            np.array(t) for t in _live_pairs(classes.tolist(), by_key=by_key))
+        grid = classes.T if by_key else classes
+        live = {(o, n) for o, n in zip(*np.nonzero(grid != _DEAD))}
+        visited = [(o, n) for o, n, f in zip(outer, inner, flags)
+                   if not f & _DEAD]
+        assert len(visited) == len(set(visited)) and set(visited) == live
+        # a step's class is its pair's; a dead step stands only for an
+        # output block that has no live pair
+        for o, n, f in zip(outer, inner, flags):
+            assert f & (_DIAGONAL | _DEAD) == grid[o, n]
+            if f & _DEAD:
+                assert (grid[o] == _DEAD).all() and f & _FIRST and f & _LAST
+        # output blocks in order, each opened and closed exactly once
+        assert list(np.unique(outer)) == list(range(grid.shape[0]))
+        assert (np.diff(outer) >= 0).all()
+        opens = np.r_[True, np.diff(outer) > 0]
+        closes = np.r_[np.diff(outer) > 0, True]
+        np.testing.assert_array_equal(flags & _FIRST != 0, opens)
+        np.testing.assert_array_equal(flags & _LAST != 0, closes)

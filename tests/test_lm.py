@@ -195,3 +195,48 @@ def test_train_compile_record_once_per_compile(devices, data):
     ref = jax.jit(make_lm_train_step(SPEC, tx, mesh, jit=False))
     _, m_ref = ref(state, toks)
     assert float(m1.loss) == float(m_ref.loss)
+
+
+def test_flash_plan_record_once_per_traced_call():
+    """Tracing a flash training kernel leaves ONE ``flash.plan`` record
+    in the tracer's ring — blocks, grid steps visited a (batch·head),
+    of which masked, of which dead, operand dtype — and a call of the
+    compiled program leaves none. At the train cells' shape every
+    kernel visits the 10 live pairs of its 4 x 4 grid, 4 of them
+    diagonal, none dead."""
+    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+    from ddp_tpu.ops.flash import flash_attention
+
+    def records(since):
+        return [e for e in get_tracer().ring()[len(since):]
+                if e[0] == "flash.plan"]
+
+    def grad(block):
+        return jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, True, block, block, True
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ))
+
+    kernels = ["flash_dkv", "flash_dq", "flash_fwd"]
+    q = jnp.ones((1, 64, 2, 16), jnp.float32)
+    step = grad(16)
+    before = get_tracer().ring()
+    step(q, q, q)
+    recs = records(before)
+    assert sorted(r[4][0] for r in recs) == kernels
+    for name, t0, dur, parent, nums in recs:
+        assert dur == 0.0 and parent is None
+        assert dict(zip(SPAN_NUMS["flash.plan"], nums)) == {
+            "kernel": nums[0], "block_q": 16, "block_k": 16, "visited": 10,
+            "diagonal": 4, "dead": 0, "operand_dtype": "float32"}
+    step(q, q, q)  # compiled: nothing is traced, nothing recorded
+    assert len(records(before)) == 3
+    # the cells' own call (4 x 2048 tokens, 16 heads of 128, bf16, blocks
+    # of 512), traced and not run
+    cell = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16)
+    before = get_tracer().ring()
+    jax.eval_shape(grad(512), cell, cell, cell)
+    assert sorted(r[4] for r in records(before)) == [
+        (kernel, 512, 512, 10, 4, 0, "float32") for kernel in kernels]

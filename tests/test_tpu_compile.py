@@ -94,6 +94,34 @@ def test_decode_kernel_compiles_with_a_blocks_queries_folded(
 # ---- the LM train step's gradient all-reduces (parallel/ddp.py) ---------
 
 
+@pytest.mark.parametrize("case", [
+    "train_cells_bf16", "prefill_fp32", "more_queries_than_keys",
+    "block_causal_4"])
+def test_flash_training_kernels_compile_at_the_cells_shape(one_chip, case):
+    """The flash trio under Mosaic with its live-pair tables in scalar
+    memory: the train cells' call (4 x 2048 tokens, 16 heads of 128,
+    bf16, blocks of 512), float32 inputs (float32 MXU operands), rows
+    that see no key, and the block-causal mask."""
+    from ddp_tpu.ops.flash import flash_attention
+
+    dtype, T, S, causal = {
+        "train_cells_bf16": (jnp.bfloat16, 2048, 2048, True),
+        "prefill_fp32": (jnp.float32, 2048, 2048, True),
+        "more_queries_than_keys": (jnp.bfloat16, 2048, 1024, True),
+        "block_causal_4": (jnp.bfloat16, 1024, 2048, 4),
+    }[case]
+    q = _shape((4, T, 16, 128), dtype, one_chip)
+    kv = _shape((4, S, 16, 128), dtype, one_chip)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal, 512, 512, False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert kernel in text
+
+
 @pytest.fixture(scope="module")
 def ddp4_schedule(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
