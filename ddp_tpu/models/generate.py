@@ -475,6 +475,22 @@ class SlotCache(NamedTuple):
     tuples there, so the plain cache's pytree (and every donation
     path over it) is unchanged. ``quantized()`` is a trace-time
     dispatch: dtype is static under jit.
+
+    A model with recurrent layers (``spec.layer_types``,
+    models/granite_hybrid.py) keeps TWO kinds of state in a lane:
+    ``k``/``v`` hold rows for its attention layers only
+    (``[n_attention, S, L, H_kv * Dh]``: a position's kv heads side by
+    side on lanes, ops/decode.packed_decode_attention), and its
+    Mamba-2 layers hold ``ssm`` ``[n_mamba, S, N, H*P]`` float32 (the
+    state, laid out as ops/ssm.py says) and ``conv``
+    ``[n_mamba, S, K-1, C]`` float32 (the convolution's last
+    inputs). ``live`` ``[S]`` bool names the lanes
+    that decode: a recurrence cannot absorb a step it was not owed (a
+    K/V row written past ``pos`` is overwritten later; a state is not),
+    so a step leaves every other lane's state, tail and ``pos`` as they
+    are. ``granite_hybrid.layer_rows`` maps a layer's number to its row
+    in either. Models without such layers carry empty tuples, as for
+    the scales.
     """
 
     k: jax.Array
@@ -482,6 +498,9 @@ class SlotCache(NamedTuple):
     pos: jax.Array
     k_scale: Any = ()
     v_scale: Any = ()
+    ssm: Any = ()
+    conv: Any = ()
+    live: Any = ()
 
     def quantized(self) -> bool:
         return self.k.dtype == jnp.int8
@@ -494,8 +513,25 @@ def init_slot_cache(
     plus per-(position, head) fp32 scales — cache HBM per slot drops
     to ~(1 + 4/Dh)/8 of the fp32 layout, so a chip holds more
     ``slots``."""
-    shape = (spec.depth, slots, spec.total_len, _kv_heads(spec),
+    kinds = spec.layer_types
+    n_ssm = sum(1 for t in kinds if t == "mamba")
+    shape = (spec.depth - n_ssm, slots, spec.total_len, _kv_heads(spec),
              head_dim_of(spec))
+    recurrent = {}
+    if kinds:
+        # kv heads side by side on lanes (ops/decode.py, "heads packed
+        # on lanes"): a minor dimension of Dh 64 has no TPU layout that
+        # neither pads nor is relayouted around the kernel
+        shape = (*shape[:3], shape[3] * shape[4])
+        inner = spec.mamba_n_heads * spec.mamba_d_head
+        conv_dim = inner + 2 * spec.mamba_n_groups * spec.mamba_d_state
+        recurrent = dict(
+            ssm=jnp.zeros((n_ssm, slots, spec.mamba_d_state, inner),
+                          jnp.float32),
+            conv=jnp.zeros((n_ssm, slots, spec.mamba_d_conv - 1, conv_dim),
+                           jnp.float32),
+            live=jnp.zeros((slots,), bool),
+        )
     # Two DISTINCT buffers: the cache is donated through every engine
     # program, and aliased leaves ((x,) * 2) make XLA reject the
     # donation ("same buffer twice").
@@ -511,6 +547,7 @@ def init_slot_cache(
         pos=jnp.zeros((slots,), jnp.int32),
         k_scale=scales[0],
         v_scale=scales[1],
+        **recurrent,
     )
 
 
@@ -1117,6 +1154,42 @@ def slot_verify_step(
     )
 
 
+def install_lane_sampling(
+    toks, seeds, steps, temps, top_ps, slot, final, seed, temperature,
+    top_p, last_logits,
+):
+    """The tail every one-token chunk program shares: when ``final``,
+    sample the request's FIRST token from ``last_logits()`` (``[V]``
+    float32 at the prompt's last position) and splice it into ``toks``
+    at ``slot``; install the lane's sampling state. Returns ``(toks,
+    seeds, steps, temps, top_ps, first_token)``."""
+
+    def _sample_first(_):
+        # Only the FINAL chunk owes a token: the last-position layer
+        # norm, the [d]×[vocab] logits projection and the sampling
+        # draw sit behind a real branch (scalar cond) so every
+        # non-final chunk of a long prompt skips them entirely.
+        tok = sample_token(
+            last_logits(), seed, jnp.int32(0), temperature, top_p
+        )
+        return lax.dynamic_update_slice(toks, tok[None], (slot,)), tok
+
+    new_toks, first = lax.cond(
+        final, _sample_first, lambda _: (toks, jnp.int32(0)),
+        operand=None,
+    )
+    put = lax.dynamic_update_slice
+    seeds = put(seeds, seed[None].astype(seeds.dtype), (slot,))
+    steps = put(
+        steps,
+        jnp.where(final, jnp.int32(1), jnp.int32(0))[None],
+        (slot,),
+    )
+    temps = put(temps, temperature[None].astype(temps.dtype), (slot,))
+    top_ps = put(top_ps, top_p[None].astype(top_ps.dtype), (slot,))
+    return new_toks, seeds, steps, temps, top_ps, first
+
+
 def prefill_chunk(
     spec: LMSpec,
     params: Any,
@@ -1302,37 +1375,20 @@ def prefill_chunk(
             )
         attn = attn.reshape(1, C, spec.d_model).astype(x.dtype)
         x = _block_finish(spec, p, x, attn)
-    def _sample_first(_):
-        # Only the FINAL chunk owes a token: the last-position layer
-        # norm, the [d]×[vocab] logits projection and the sampling
-        # draw sit behind a real branch (scalar cond) so every
-        # non-final chunk of a long prompt skips them entirely.
+    def last_logits():
         xt = lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
         xt = _layer_norm(xt, params["ln_final"])
-        logits = (
+        return (
             xt[0, 0] @ embed.T.astype(jnp.float32)
         ).astype(jnp.float32)
-        tok = sample_token(
-            logits, seed, jnp.int32(0), temperature, top_p
-        )
-        return lax.dynamic_update_slice(toks, tok[None], (slot,)), tok
 
-    new_toks, first = lax.cond(
-        final, _sample_first, lambda _: (toks, jnp.int32(0)),
-        operand=None,
+    new_toks, seeds, steps, temps, top_ps, first = install_lane_sampling(
+        toks, seeds, steps, temps, top_ps, slot, final, seed,
+        temperature, top_p, last_logits,
     )
     new_pos = lax.dynamic_update_slice(
         cache.pos, (start + length)[None].astype(jnp.int32), (slot,)
     )
-    put = lax.dynamic_update_slice
-    seeds = put(seeds, seed[None].astype(seeds.dtype), (slot,))
-    steps = put(
-        steps,
-        jnp.where(final, jnp.int32(1), jnp.int32(0))[None],
-        (slot,),
-    )
-    temps = put(temps, temperature[None].astype(temps.dtype), (slot,))
-    top_ps = put(top_ps, top_p[None].astype(top_ps.dtype), (slot,))
     return (
         # _replace keeps the cache KIND: the paged pytree carries its
         # table through untouched (tables only change at the engine's

@@ -157,7 +157,11 @@ class LMSpec(NamedTuple):
     # models/sdar.py's block (rotary positions, RMSNorm, per-head q/k
     # norm, every MLP ``num_experts`` routed SwiGLU experts of width
     # ``moe_intermediate``, untied head, no biases, no position table:
-    # ``total_len`` is then the cache's length alone). Serving only.
+    # ``total_len`` is then the cache's length alone);
+    # ``granite_hybrid`` is models/granite_hybrid.py's (HF
+    # ``granitemoehybrid``: Mamba-2 and position-free GQA layers by
+    # ``layer_types``, a dense gated MLP, four scalar multipliers, tied
+    # head, one token a step). Both serving only.
     block: str = "gpt2"
     head_dim: int = 0  # 0 -> d_model // num_heads
     moe_intermediate: int = 0
@@ -174,6 +178,32 @@ class LMSpec(NamedTuple):
     mask_token_id: int = -1
     unmask: str = "low_confidence_static"
     unmask_threshold: float = 0.9
+    # The ``granite_hybrid`` block. ``layer_types``: one of "mamba" |
+    # "attention" a layer (a tuple; the sidecar's list is converted).
+    # A Mamba-2 layer has ``mamba_n_heads`` heads of ``mamba_d_head``
+    # channels (their product is the inner width), a state of
+    # ``mamba_d_state`` a channel, B and C shared by the heads of one
+    # of ``mamba_n_groups`` groups, a depthwise convolution of
+    # ``mamba_d_conv`` taps in front, and prefills in chunks of
+    # ``mamba_chunk_size``. ``mlp_intermediate``: the gated MLP's width.
+    # The multipliers scale the embedding, the attention logits (0:
+    # ``head_dim ** -0.5``), each residual branch, and DIVIDE the
+    # logits. ``tie_embeddings``: the head is the embedding.
+    # ``position_embedding`` "nope": no table, no rotary.
+    layer_types: tuple = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mlp_intermediate: int = 0
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False
+    position_embedding: str = ""
 
 
 def head_dim_of(spec: LMSpec) -> int:
@@ -194,7 +224,14 @@ def derive_lm_spec(params: Any, *, num_heads: int, **overrides) -> LMSpec:
     head count does not explain the shapes.
     """
     if "embed_tokens" in params:
-        from ddp_tpu.models.sdar import derive_spec
+        # the HF-named trees: the sidecar says which block (a tree with
+        # Mamba layers says so itself)
+        if overrides.get("block") == "granite_hybrid" or any(
+            "mamba" in layer for layer in params.get("layers", {}).values()
+        ):
+            from ddp_tpu.models.granite_hybrid import derive_spec
+        else:
+            from ddp_tpu.models.sdar import derive_spec
 
         return derive_spec(params, num_heads=num_heads, **overrides)
     try:

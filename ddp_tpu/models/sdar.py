@@ -217,17 +217,10 @@ def save_checkpoint(directory: str, spec: LMSpec, params, *,
     ``lm_spec.json`` sidecar that carries what the shapes cannot: the
     cache's length, the routing and the generation's settings. How a
     converted HF checkpoint (the table above) gets on disk."""
-    from ddp_tpu.parallel.ddp import TrainState
-    from ddp_tpu.train.checkpoint import CheckpointManager, save_lm_spec
+    from ddp_tpu.train.checkpoint import save_params_with_spec
 
     validate(spec)
-    mgr = CheckpointManager(directory, async_save=False)
-    mgr.save(epoch, TrainState(
-        step=jnp.zeros((), jnp.int32), params=params, opt_state={},
-        model_state={},
-    ))
-    mgr.close()
-    save_lm_spec(directory, spec)
+    save_params_with_spec(directory, spec, params, epoch=epoch)
 
 
 # ---- the layer --------------------------------------------------------

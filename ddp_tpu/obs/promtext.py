@@ -385,6 +385,25 @@ def render_serve(
         b.add("ddp_tpu_serve_moe_expert_load_max", bd["moe_expert_load_max"],
               help="the fullest expert's rows in the last step, mean "
               "over layers")
+    # Recurrent lanes (models/granite_hybrid.py): present only on an
+    # engine whose lanes hold state beside K/V rows.
+    rs = stats.get("recurrent_state") or {}
+    for key, kind, help_ in (
+        ("ssm_lane_updates_total", "counter",
+         "live lanes summed over decode steps: each is every recurrent "
+         "layer's state read and written once"),
+        ("ssm_prefill_tokens_total", "counter",
+         "real (not padded) prompt positions through the chunked scan"),
+        ("ssm_state_resets_total", "counter",
+         "lanes whose state was reset at admission (first chunks)"),
+        ("ssm_state_bytes_per_slot", "gauge",
+         "recurrent state and convolution tail one lane holds"),
+        ("kv_bytes_per_slot", "gauge",
+         "K/V rows one lane holds, attention layers only"),
+    ):
+        if key in rs:
+            b.add(f"ddp_tpu_serve_{key}", rs[key], metric_type=kind,
+                  help=help_)
     b.summary(
         "ddp_tpu_serve_ttft_seconds", stats.get("ttft_s"),
         help="submit to first token",

@@ -94,6 +94,11 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # ``kernel`` and ``operand_dtype`` are names, not numbers
     "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
                    "dead", "operand_dtype"),
+    # ops/ssm.py — one per traced ``pallas_call`` of the state update
+    # (trace time, zero duration): lanes and heads of a lane a grid step
+    # holds. ``kernel`` and ``state_dtype`` are names
+    "ssm.plan": ("kernel", "lanes_per_tile", "heads_per_tile",
+                 "state_dtype"),
 }
 # Spans in which the host WAITS for the device (the blocking token
 # fetch): host time, but not host work.

@@ -121,7 +121,8 @@ def _maybe_dequant(x, scale):
 # ---- jnp reference (the PR-3 decode math, verbatim) ------------------
 
 
-def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None):
+def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None,
+                               *, scale: float | None = None):
     """Single-query banded attention → [S, H, Dh] fp32.
 
     ``q``: [S, H, Dh] (one query per lane); ``k``/``v``: [S, L, H_kv,
@@ -131,6 +132,8 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None):
     casts and the ``-inf`` mask are EXACTLY ``slot_decode_step``'s
     original inline math, so the fp32 path is bit-identical to the
     PR-3 engine (the token-identity baseline the kernel pins against).
+    ``scale`` multiplies the logits; None is ``Dh**-0.5`` (a model
+    whose softmax scale is its own passes it, both paths alike).
     """
     S, H, Dh = q.shape
     L, H_kv = k.shape[1], k.shape[2]
@@ -144,7 +147,7 @@ def decode_attention_reference(q, k, v, pos, k_scale=None, v_scale=None):
             qg.astype(jnp.float32),
             kf.astype(jnp.float32),
         )
-        * Dh**-0.5
+        * (Dh**-0.5 if scale is None else scale)
     )  # [S, H_kv, G, L]
     live = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, None, :]
     logits = jnp.where(live, logits, -jnp.inf)
@@ -194,6 +197,7 @@ def flash_decode_attention(
     layer: int = 0,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
+    scale: float | None = None,
 ):
     """Pallas flash-decode → [S, H, Dh] fp32 (the reference's contract).
 
@@ -259,7 +263,7 @@ def flash_decode_attention(
     out = pl.pallas_call(
         functools.partial(
             _all_heads_kernel if all_heads else _per_head_kernel,
-            scale=Dh**-0.5, block_k=block_k,
+            scale=Dh**-0.5 if scale is None else scale, block_k=block_k,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -337,6 +341,32 @@ def _all_heads_kernel(
     _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
 
 
+def _absorb_block(i, q, kb, vb, j, pos, block_k, acc_ref, m_ref, l_ref):
+    """One online-softmax step of entry ``i`` of the scratch: queries
+    ``q`` ``[rows, D]`` (scaled) against block ``j``'s keys ``kb`` and
+    values ``vb`` ``[block_k, D]`` on the MXU, keys past ``pos``
+    masked."""
+    s = lax.dot_general(
+        q, kb, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [rows, block_k]
+    cols = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(cols <= pos, s, -jnp.inf)
+    rows = j * block_k + lax.broadcasted_iota(jnp.int32, vb.shape, 0)
+    vb = jnp.where(rows <= pos, vb, 0.0)  # see _all_heads_kernel
+    m = m_ref[i][:, :1]
+    new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - new_m)
+    corr = jnp.exp(m - new_m)
+    acc_ref[i] = acc_ref[i] * corr + lax.dot_general(
+        p, vb, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    l_new = l_ref[i][:, :1] * corr + p.sum(axis=-1, keepdims=True)
+    m_ref[i] = jnp.broadcast_to(new_m, m_ref.shape[1:])
+    l_ref[i] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
 def _per_head_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k):
     """Grouped queries and/or int8 rows: per kv head, that head's
     ``[block_k, Dh]`` rows (a strided read of the stored block) against
@@ -354,27 +384,179 @@ def _per_head_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k):
                 kb = kb * scales[0][:, h : h + 1]
                 vb = vb * scales[1][:, h : h + 1]
             q = q_ref[h].astype(jnp.float32) * scale  # [G, Dh]
-            s = lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [G, block_k]
-            cols = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= pos, s, -jnp.inf)
-            rows = j * block_k + lax.broadcasted_iota(jnp.int32, vb.shape, 0)
-            vb = jnp.where(rows <= pos, vb, 0.0)  # see _all_heads_kernel
-            m = m_ref[h][:, :1]
-            new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - new_m)
-            corr = jnp.exp(m - new_m)
-            acc_ref[h] = acc_ref[h] * corr + lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            l_new = l_ref[h][:, :1] * corr + p.sum(axis=-1, keepdims=True)
-            m_ref[h] = jnp.broadcast_to(new_m, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            _absorb_block(h, q, kb, vb, j, pos, block_k,
+                          acc_ref, m_ref, l_ref)
 
     _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
+
+
+# ---- heads packed on lanes: head sizes under 128 -----------------------
+#
+# A stored ``[.., H_kv, Dh]`` cache with ``Dh`` < 128 has no good TPU
+# layout: the tiled layout pads its minor dimension to 128 lanes (twice
+# the bytes at Dh 64), and XLA avoids that by storing the buffer
+# transposed, then relayouts the WHOLE cache into and out of the
+# kernel's row-major operand every step. So a model with such heads
+# (models/granite_hybrid.py: 8 kv heads of 64) stores its rows
+# ``[depth, S, L, H_kv * Dh]``, all kv heads of a position side by side
+# on lanes: no padding, row-major as stored. A 128-lane group then
+# holds ``P = 128 // Dh`` kv heads, and the kernel never slices inside
+# it: the P heads' queries arrive block-diagonal, ``[P * G, 128]`` with
+# head p's G queries in lanes ``[p Dh, (p + 1) Dh)`` and zeros
+# elsewhere, so ONE ``[P G, 128] x [128, block_k]`` dot gives every
+# head's scores against its own keys (the zeros cancel the neighbour's),
+# and ``p @ V`` gives ``[P G, 128]`` whose diagonal blocks are the
+# heads' outputs. The MXU multiplies P times the zeros; at a few rows a
+# dot that is nothing beside the block's DMA.
+
+
+def _pack_queries(q, H_kv: int):
+    """``[S, H, Dh]`` -> block-diagonal ``[S, C, P * G, 128]``."""
+    S, H, Dh = q.shape
+    P = LANES // Dh
+    eye = jnp.eye(P, dtype=q.dtype)
+    qg = q.reshape(S, H_kv // P, P, H // H_kv, Dh)
+    return jnp.einsum("scpgd,pq->scpgqd", qg, eye).reshape(
+        S, H_kv // P, P * (H // H_kv), LANES)
+
+
+def _unpack_outputs(o, H: int, Dh: int):
+    """The diagonal blocks of ``[S, C, P * G, 128]`` -> ``[S, H, Dh]``."""
+    S, C, R, _ = o.shape
+    P = LANES // Dh
+    eye = jnp.eye(P, dtype=o.dtype)
+    return jnp.einsum(
+        "scpgqd,pq->scpgd", o.reshape(S, C, P, R // P, P, Dh), eye
+    ).reshape(S, H, Dh)
+
+
+def _packed_heads_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                         m_ref, l_ref, *, scale, block_k):
+    """Per 128-lane group of the stored rows: its P kv heads' queries
+    (block-diagonal) against the group's ``[block_k, 128]`` keys."""
+
+    def body(j, pos):
+        for c in range(q_ref.shape[0]):
+            lanes = slice(c * LANES, (c + 1) * LANES)
+            kb = k_ref[:, lanes].astype(jnp.float32)  # [block_k, 128]
+            vb = v_ref[:, lanes].astype(jnp.float32)
+            q = q_ref[c].astype(jnp.float32) * scale  # [P G, 128]
+            _absorb_block(c, q, kb, vb, j, pos, block_k,
+                          acc_ref, m_ref, l_ref)
+
+    _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
+
+
+def packed_decode_attention(
+    q, k, v, pos, *, layer: int = 0, impl: str = "reference",
+    block_k: int = DEFAULT_BLOCK_K, interpret: bool | None = None,
+    scale: float | None = None,
+):
+    """Single-query banded attention over a cache stored with its kv
+    heads packed on lanes -> ``[S, H, Dh]`` fp32.
+
+    ``q`` ``[S, H, Dh]``; ``k``/``v`` the STORED cache ``[depth, S, L,
+    H_kv * Dh]`` float (``H_kv`` is what the width holds of ``Dh``);
+    ``layer`` Python-static; ``pos``, ``scale`` and ``impl`` as
+    :func:`decode_attention`'s. ``"reference"`` is
+    :func:`decode_attention_reference` on the layer's rows viewed
+    ``[S, L, H_kv, Dh]``; ``"flash"`` the same ``flash_decode`` grid,
+    banded read and online softmax over ``[block_k, H_kv * Dh]`` blocks
+    (the note above)."""
+    S, H, Dh = q.shape
+    L, W = k.shape[2], k.shape[3]
+    H_kv = W // Dh
+    if impl == "reference":
+        rows = lambda c: c[layer].reshape(S, L, H_kv, Dh)
+        return decode_attention_reference(q, rows(k), rows(v), pos,
+                                          scale=scale)
+    if impl != "flash":
+        raise ValueError(
+            f"unknown decode attention impl {impl!r}: expected "
+            "'reference' or 'flash'"
+        )
+    if LANES % Dh or W % LANES or H % H_kv:
+        raise ValueError(
+            f"packed flash_decode needs a head size that divides {LANES} "
+            f"and rows that are whole {LANES}-lane groups, got Dh {Dh}, "
+            f"row width {W}, {H} query heads"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    block_k = decode_block(L, 1, W, k.dtype, block_k)
+    qp = _pack_queries(q, H_kv)
+    C, R = qp.shape[1], qp.shape[2]
+    vmem = {"memory_space": pltpu.VMEM}
+
+    def kvmap(s, j, pos_ref):
+        return (layer, s, live_block(j, pos_ref[s], block_k), 0)
+
+    qspec = pl.BlockSpec(
+        (None, C, R, LANES), lambda s, j, pos_ref: (s, 0, 0, 0), **vmem)
+    kvspec = pl.BlockSpec((None, None, block_k, W), kvmap, **vmem)
+    out = pl.pallas_call(
+        functools.partial(
+            _packed_heads_kernel,
+            scale=Dh**-0.5 if scale is None else scale, block_k=block_k,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, L // block_k),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=qspec,
+            scratch_shapes=[pltpu.VMEM((C, R, LANES), jnp.float32)] * 3,
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, C, R, LANES), jnp.float32),
+        interpret=interpret,
+        name="flash_decode",
+    )(pos.astype(jnp.int32), qp, k, v)
+    return _unpack_outputs(out, H, Dh)
+
+
+def _copy_kernel(slot_ref, src_ref, dst_ref):
+    del slot_ref
+    dst_ref[...] = src_ref[...]
+
+
+def read_lane(buf, layer: int, slot, *, impl: str = "auto",
+              interpret: bool | None = None):
+    """Lane ``slot`` (traced) of ``layer`` (static) of a stored
+    ``[depth, S, L, W]`` buffer -> ``[L, W]``.
+
+    On the chip a ``pallas_call`` named ``read_lane`` whose index map
+    picks the lane: the buffer is then an operand with a fixed
+    row-major layout. Cut out with ``lax.dynamic_slice`` and reshaped
+    to heads, XLA's layout assignment makes that reshape free by
+    re-laying the WHOLE donated buffer out around it (a 1 GB copy in
+    and out of a prefill chunk at the published size). ``impl``:
+    ``"auto"`` | ``"pallas"`` | ``"jnp"`` (the dynamic slice, off the
+    chip)."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    L, W = buf.shape[2], buf.shape[3]
+    if impl == "jnp":
+        return lax.dynamic_slice(buf, (layer, slot, 0, 0), (1, 1, L, W))[0, 0]
+    if impl != "pallas":
+        raise ValueError(f"unknown read_lane impl {impl!r}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows = decode_block(L, 1, W, buf.dtype, 512)
+    vmem = {"memory_space": pltpu.VMEM}
+    return pl.pallas_call(
+        _copy_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(L // rows,),
+            in_specs=[pl.BlockSpec(
+                (None, None, rows, W),
+                lambda j, slot_ref: (layer, slot_ref[0], j, 0), **vmem)],
+            out_specs=pl.BlockSpec(
+                (rows, W), lambda j, slot_ref: (j, 0), **vmem),
+        ),
+        out_shape=jax.ShapeDtypeStruct((L, W), buf.dtype),
+        interpret=interpret,
+        name="read_lane",
+    )(jnp.asarray(slot, jnp.int32).reshape(1), buf)
 
 
 # ---- paged KV: gather lane views through int32 page tables ----------
@@ -447,6 +629,7 @@ def decode_attention(
     q, k, v, pos, k_scale=None, v_scale=None, *,
     impl: str = "reference", layer: int = 0,
     block_k: int = DEFAULT_BLOCK_K, interpret: bool | None = None,
+    scale: float | None = None,
 ):
     """The engine-facing entry: ``impl`` picks the path at trace time.
 
@@ -468,6 +651,7 @@ def decode_attention(
         return flash_decode_attention(
             q, k, v, pos, k_scale, v_scale,
             layer=layer, block_k=block_k, interpret=interpret,
+            scale=scale,
         )
     if impl != "reference":
         raise ValueError(
@@ -478,7 +662,8 @@ def decode_attention(
         k, v = k[layer], v[layer]
         if k_scale is not None:
             k_scale, v_scale = k_scale[layer], v_scale[layer]
-    return decode_attention_reference(q, k, v, pos, k_scale, v_scale)
+    return decode_attention_reference(q, k, v, pos, k_scale, v_scale,
+                                      scale=scale)
 
 
 def shard_decode_attention(

@@ -68,9 +68,9 @@ from ddp_tpu.models.generate import (
     init_paged_slot_cache,
     init_slot_cache,
 )
-from ddp_tpu.models.generate import prefill_chunk as _prefill_chunk
+from ddp_tpu.models.generate import prefill_chunk as _gpt2_chunk
 from ddp_tpu.models.generate import (
-    slot_decode_sample_step as _decode_sample,
+    slot_decode_sample_step as _gpt2_decode,
 )
 from ddp_tpu.models.generate import slot_decode_step as _decode_step
 from ddp_tpu.models.generate import slot_verify_step as _verify_step
@@ -87,6 +87,26 @@ from ddp_tpu.serve.scheduler import (
     prev_pow2,
 )
 from ddp_tpu.utils.metrics import MetricsWriter, StatSummary
+
+# The block whose lanes hold recurrent state beside K/V rows
+# (models/granite_hybrid.py, imported where a spec asks for it), and
+# why each knob below has no meaning yet for such a lane: its history
+# is a state, not rows that can be paged, shared, rolled back or
+# re-quantized.
+_HYBRID = "granite_hybrid"
+RECURRENT_REFUSALS = {
+    "page_size": "pages and the radix prefix cache share K/V rows "
+    "between lanes; a recurrent state at a page boundary would have to "
+    "be snapshotted to be shared, and is not",
+    "kv_dtype": "int8 rows are quantized once on write; a state is "
+    "rewritten every step and stays float32",
+    "spec_tokens": "a rejected draft rolls the cache back by moving "
+    "pos; a state that has absorbed the drafts cannot be rolled back",
+    "export_prefix": "a prefix's K/V pages can be shipped, the "
+    "recurrent state that goes with them is not kept per page",
+    "install_prefix": "a frame of K/V pages carries no recurrent "
+    "state to resume from",
+}
 
 # Completion statuses.
 COMPLETE = "complete"
@@ -378,11 +398,28 @@ def resolve_engine_knobs(
                 f"{spec.total_len}"
             )
     spec_tokens = int(spec_tokens)
-    # Block diffusion (models/sdar.py): the model's spec says so, no
-    # flag does. A lane then holds a block of ``block_len`` positions
-    # and a step is one forward over it.
+    # How the model generates is its spec's to say, no flag's. Block
+    # diffusion (models/sdar.py): a lane holds a block of ``block_len``
+    # positions and a step is one forward over it; a block that
+    # generates by blocks needs a block length. The hybrid block
+    # (models/granite_hybrid.py) needs none: it decodes one token a
+    # step like ``gpt2``, with recurrent state beside its K/V rows.
     block_len = int(spec.block_length)
-    if block_len or spec.block != "gpt2":
+    recurrent = spec.block == _HYBRID
+    if recurrent:
+        from ddp_tpu.models import granite_hybrid
+
+        granite_hybrid.validate(spec)
+        for knob, given in (
+            ("page_size", paged), ("kv_dtype", kv_dtype != "fp32"),
+            ("spec_tokens", spec_tokens),
+        ):
+            if given:
+                raise ValueError(
+                    f"{knob} does not apply to the {_HYBRID} block: "
+                    f"{RECURRENT_REFUSALS[knob]}"
+                )
+    elif block_len or spec.block != "gpt2":
         _sdar.validate(spec)
         if paged or kv_dtype != "fp32" or spec_tokens:
             raise ValueError(
@@ -450,6 +487,7 @@ def resolve_engine_knobs(
         "kv_pages": resolved_kv_pages,
         "spec_tokens": spec_tokens,
         "block_len": block_len,
+        "recurrent": recurrent,
         "ctx_len": ctx_len,
         "tokens_per_decode": tokens_per_decode,
         "chunk": chunk,
@@ -574,6 +612,13 @@ class ServeEngine:
         # > 0, a decoding lane holds a block of this many positions and
         # a step runs one forward over it (``_block_round``).
         self.block_len = knobs["block_len"]
+        # A lane holds recurrent state beside its K/V rows
+        # (models/granite_hybrid.py). A decode step then advances only
+        # the lanes ``cache.live`` names; the engine owns that mask and
+        # uploads it when the decoding set changes (as it does a paged
+        # table), never on a steady step.
+        self.recurrent = knobs["recurrent"]
+        self._live_lanes: tuple = ()
         # The engine drives ONE device; it has no mesh (ROADMAP C9).
         # Weights and every piece of engine state are COMMITTED to it
         # up front: a restored checkpoint's arrays are committed, jit
@@ -797,6 +842,18 @@ class ServeEngine:
         # per-engine. Each gets a name of its own, which is how the
         # profiler's trace tells the programs apart
         # (``jit_serve_decode``, ``jit_serve_prefill_first``, ...).
+        # The one-token programs are the spec's block's: the hybrid
+        # block brings its own chunk and decode functions under the
+        # GPT-2 path's signatures, so everything below, and the step
+        # loop, holds three jitted callables and asks no more.
+        if self.recurrent:
+            from ddp_tpu.models import granite_hybrid
+
+            _prefill_chunk = granite_hybrid.prefill_chunk
+            _decode_sample = granite_hybrid.slot_decode_sample_step
+        else:
+            _prefill_chunk, _decode_sample = _gpt2_chunk, _gpt2_decode
+
         def _named(name, fn):
             fn.__name__ = fn.__qualname__ = name
             return fn
@@ -959,6 +1016,13 @@ class ServeEngine:
         self.moe_experts_hit_total = 0
         self.moe_layer_calls_total = 0
         self._last_round = (0, 0)  # (unmasked, committed) last fetched
+        # Recurrent-lane tallies, host arithmetic like the rows above:
+        # live lanes summed over decode steps (each is every recurrent
+        # layer's state read and written once), real prompt positions
+        # through the chunked scan, and lanes reset at admission.
+        self.ssm_lane_updates_total = 0
+        self.ssm_prefill_tokens_total = 0
+        self.ssm_state_resets_total = 0
         # Engine-lifetime speculative tallies (the /stats + bench
         # acceptance-rate source); zero-cost when speculation is off.
         self.spec_drafted_total = 0
@@ -1116,16 +1180,20 @@ class ServeEngine:
         """
         if self.active:
             raise RuntimeError("warmup() requires an idle engine")
+        # The chunk programs are warmed with what a step hands them
+        # (numpy scalars, uploaded with the call): an argument of
+        # another kind is another entry in the jit cache.
+        czero, off, cold, whole = (
+            np.int32(0), np.bool_(False), np.float32(0.0), np.float32(1.0))
         zero = jnp.int32(0)
         if self.block_len:
-            tail = jnp.zeros((self.block_len,), jnp.int32)
+            tail = np.zeros((self.block_len,), np.int32)
             for fn in (self._chunk_first, self._chunk_cont):
                 for w in self.buckets:
                     self._cache, self._lanes, _ = fn(
-                        self.params, self._cache, self._lanes, zero,
-                        jnp.zeros((w,), jnp.int32), zero, jnp.int32(w),
-                        jnp.asarray(False), tail, zero, zero, zero,
-                        jnp.float32(0.0), jnp.float32(1.0),
+                        self.params, self._cache, self._lanes, czero,
+                        np.zeros((w,), np.int32), czero, np.int32(w),
+                        off, tail, czero, czero, czero, cold, whole,
                     )
             self._cache, self._lanes, report, _ = self._decode(
                 self.params, self._cache, self._lanes
@@ -1139,9 +1207,8 @@ class ServeEngine:
                  _) = fn(
                     self.params, self._cache, self._toks, self._seeds,
                     self._sample_steps, self._temps, self._top_ps,
-                    zero, jnp.zeros((w,), jnp.int32), zero,
-                    jnp.int32(w), jnp.asarray(False), zero,
-                    jnp.float32(0.0), jnp.float32(1.0),
+                    czero, np.zeros((w,), np.int32), czero,
+                    np.int32(w), off, czero, cold, whole,
                 )
         self._toks, self._cache, self._sample_steps = self._decode(
             self.params, self._cache, self._toks, self._seeds,
@@ -1249,15 +1316,39 @@ class ServeEngine:
             self._table_np[:] = 0
             self._table_dirty = True
 
-    def cache_bytes_per_slot(self) -> int:
+    def kv_bytes_per_slot(self) -> int:
         """KV-cache HBM per decode lane, scales included — the number
         int8 quantization halves (better: int8 rows + one fp32 scale
-        per head per position vs fp32 rows), and with it how many
-        ``slots`` a chip holds."""
+        per head per position vs fp32 rows)."""
         leaves = [self._cache.k, self._cache.v]
         if self._cache.quantized():
             leaves += [self._cache.k_scale, self._cache.v_scale]
         return sum(int(x.nbytes) for x in leaves) // self.num_slots
+
+    def state_bytes_per_slot(self) -> int:
+        """Recurrent state per lane (every Mamba layer's state and
+        convolution tail); 0 for a model without such layers."""
+        if not self.recurrent:
+            return 0
+        leaves = (self._cache.ssm, self._cache.conv)
+        return sum(int(x.nbytes) for x in leaves) // self.num_slots
+
+    def cache_bytes_per_slot(self) -> int:
+        """HBM one decode lane holds, K/V and recurrent state both —
+        and with it how many ``slots`` a chip holds."""
+        return self.kv_bytes_per_slot() + self.state_bytes_per_slot()
+
+    def recurrent_stats(self) -> dict:
+        """The recurrent lanes' counters and gauges (``/stats``'s
+        ``recurrent_state``, ``/metricsz``'s ``ddp_tpu_serve_ssm_*``);
+        plain host ints, readable without the server's lock."""
+        return {
+            "ssm_lane_updates_total": self.ssm_lane_updates_total,
+            "ssm_prefill_tokens_total": self.ssm_prefill_tokens_total,
+            "ssm_state_resets_total": self.ssm_state_resets_total,
+            "ssm_state_bytes_per_slot": self.state_bytes_per_slot(),
+            "kv_bytes_per_slot": self.kv_bytes_per_slot(),
+        }
 
     def page_stats(self) -> Optional[dict]:
         """Paged-mode pool/index snapshot (None on fixed-lane engines
@@ -1293,6 +1384,11 @@ class ServeEngine:
         is a control-plane event, like the bind-time table upload) —
         the steady-state transfer invariant is untouched.
         """
+        if self.recurrent:
+            raise ValueError(
+                f"export_prefix does not apply to the {_HYBRID} block: "
+                f"{RECURRENT_REFUSALS['export_prefix']}"
+            )
         if not self.paged:
             return None
         from ddp_tpu.serve.disagg import encode_pages
@@ -1359,6 +1455,12 @@ class ServeEngine:
             PageWireError,
         )
 
+        if self.recurrent:
+            raise PageWireError(
+                SHAPE_MISMATCH,
+                f"install_prefix does not apply to the {_HYBRID} block: "
+                f"{RECURRENT_REFUSALS['install_prefix']}",
+            )
         if not self.paged:
             raise PageWireError(
                 SHAPE_MISMATCH, "this engine is not paged (--page_size)"
@@ -1529,6 +1631,11 @@ class ServeEngine:
                 {"block_diffusion": self.block_stats()}
                 if self.block_len else {}
             ),
+            # Recurrent lanes: absent where no lane holds such state.
+            **(
+                {"recurrent_state": self.recurrent_stats()}
+                if self.recurrent else {}
+            ),
             # Paged KV + prefix index (PR 12): absent on fixed-lane
             # engines, so the default /metricsz exposition stays
             # byte-identical to the pre-paging engine's.
@@ -1644,8 +1751,10 @@ class ServeEngine:
     def step(self) -> int:
         """One engine iteration → number of tokens scheduled.
 
-        Order: (1) retire finished / evict expired requests (draining
-        any in-flight token values they are owed), (2) evict expired
+        Order: (1) retire finished / evict expired requests (a
+        finished lane whose last values are still in flight waits one
+        step for them while other lanes decode; otherwise the values
+        it is owed are drained here), (2) evict expired
         queued requests, (3) admit queue heads into free slots, (4)
         dispatch prefill chunks within the step token budget — a slot
         whose FINAL chunk lands this step joins the decode batch
@@ -1674,6 +1783,18 @@ class ServeEngine:
         evictions = 0
         with tracer.span("serve.retire", parent=parent) as span:
             finished = 0
+            # Lanes that still owe tokens: while there are any, a lane
+            # whose LAST values are in flight (dispatched by the step
+            # before this one) is retired a step later, when this
+            # step's own lagged fetch has brought them. Fetching them
+            # here would wait for the device with nothing queued behind
+            # it, and the device would then idle through this step's
+            # retire, admit and dispatch.
+            owing = sum(
+                1 for s in self._slots
+                if s.request is not None
+                and s.emitted < s.request.max_new_tokens
+            )
             for slot in self._slots:
                 req = slot.request
                 if req is None:
@@ -1683,6 +1804,8 @@ class ServeEngine:
                     # block they are in hand: commits are counted as
                     # they are fetched, so nothing waits)
                     if not self.block_len:
+                        if owing and len(slot.tokens) < slot.emitted:
+                            continue
                         self._drain(parent=span.t0)
                     self._finish(slot, COMPLETE)
                     finished += 1
@@ -1799,15 +1922,20 @@ class ServeEngine:
                 # never pay a total_len-wide lane read. Continuations
                 # attend the full lane under the banded q_offset mask.
                 fn = self._chunk_first if start == 0 else self._chunk_cont
-                slot_i, tok_buf = jnp.int32(i), jnp.asarray(buf)
-                start_t, live_t = jnp.int32(start), jnp.int32(live)
-                final_t = jnp.asarray(final)
+                # The chunk's scalars go in as NUMPY values, uploaded
+                # with the call: ``jnp.int32(x)`` is a device program of
+                # its own (``jit_convert_element_type``), a host dispatch
+                # each, and a chunk that follows a retirement's full
+                # drain is dispatched with the device idle behind it.
+                slot_i, tok_buf = np.int32(i), buf
+                start_t, live_t = np.int32(start), np.int32(live)
+                final_t = np.bool_(final)
                 # Exact int32 seed (admission range-checks it): any
                 # masking here would break token-identity with
                 # generate(seed=...) for negative seeds.
                 sampling = (
-                    jnp.int32(req.seed),
-                    jnp.float32(req.temperature), jnp.float32(req.top_p),
+                    np.int32(req.seed),
+                    np.float32(req.temperature), np.float32(req.top_p),
                 )
                 if self.block_len:
                     # The final chunk installs the lane's first block:
@@ -1820,8 +1948,8 @@ class ServeEngine:
                     self._cache, self._lanes, moe = fn(
                         self.params, self._cache, self._lanes,
                         slot_i, tok_buf, start_t, live_t, final_t,
-                        jnp.asarray(tail), jnp.int32(slot.lead),
-                        jnp.int32(req.max_new_tokens), *sampling,
+                        tail, np.int32(slot.lead),
+                        np.int32(req.max_new_tokens), *sampling,
                     )
                     self._pending.append(("moe", moe, None))
                     first = None
@@ -1859,6 +1987,10 @@ class ServeEngine:
             device_work = True
             slot.prefill_pos = start + live
             chunk_tokens += live
+            if self.recurrent:
+                # a first chunk starts the lane's state from zero
+                self.ssm_state_resets_total += start == 0
+                self.ssm_prefill_tokens_total += live
             if self._reqtrace is not None:
                 tr = self._reqtrace.get(req.rid)
                 if tr is not None:
@@ -1906,6 +2038,8 @@ class ServeEngine:
             )
             self.kv_rows_attended_total += rows
             self.kv_rows_lane_total += self.num_slots * self.spec.total_len
+            if self.recurrent:
+                self._name_live_lanes(emit_lanes)
             with tracer.span(
                 "serve.decode", parent=parent,
                 nums=(len(decode_lanes), rows),
@@ -2003,6 +2137,22 @@ class ServeEngine:
         )
 
     # ---- internals --------------------------------------------------
+
+    def _name_live_lanes(self, lanes: list[int]) -> None:
+        """Tell the device which lanes the coming decode step advances
+        (``cache.live``): one ``[S]`` bool upload when the set differs
+        from the last step's — a lane joined after its final chunk, or
+        finished — and nothing on a steady step. Every other lane's
+        recurrent state, tail and position are left as they are: a lane
+        between two chunks of its prompt carries its state on, an idle
+        one costs no state traffic."""
+        self.ssm_lane_updates_total += len(lanes)
+        named = tuple(lanes)
+        if named != self._live_lanes:
+            mask = np.zeros((self.num_slots,), bool)
+            mask[lanes] = True
+            self._cache = self._cache._replace(live=self._put(mask))
+            self._live_lanes = named
 
     def _block_round(self, lanes: list[int], parent: float) -> None:
         """One forward over every lane's block (``sdar.block_step``),

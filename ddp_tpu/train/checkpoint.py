@@ -247,6 +247,20 @@ def save_lm_spec(directory: str, spec: Any) -> str:
     return path
 
 
+def save_params_with_spec(directory: str, spec: Any, params: Any, *,
+                          epoch: int = 0) -> None:
+    """A parameter tree as a checkpoint inference tooling restores
+    (``epoch_N/`` with an empty optimizer state) plus its ``lm_spec.json``
+    sidecar: what the serving-only models' ``save_checkpoint`` write."""
+    mgr = CheckpointManager(directory, async_save=False)
+    mgr.save(epoch, TrainState(
+        step=np.zeros((), np.int32), params=params, opt_state={},
+        model_state={},
+    ))
+    mgr.close()
+    save_lm_spec(directory, spec)
+
+
 def load_lm_spec_fields(directory: str) -> dict:
     """Read the sidecar → field dict ({} when absent or unreadable).
 
@@ -266,7 +280,11 @@ def load_lm_spec_fields(directory: str) -> dict:
         return {}
     if not isinstance(fields, dict):
         return {}
-    return {k: v for k, v in fields.items() if k in LMSpec._fields}
+    # a JSON array is a spec's tuple (``layer_types``): a spec is hashable
+    return {
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in fields.items() if k in LMSpec._fields
+    }
 
 
 def derive_spec_with_sidecar(

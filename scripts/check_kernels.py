@@ -50,6 +50,10 @@ TOL = {
     # error is relative to the output's largest magnitude, a few times
     # bfloat16's 2^-8.
     "moe_rel": 2e-2,
+    # The state update is float32 elementwise arithmetic on the VPU and
+    # one sum over 128 sublanes: float32 reassociation on values of
+    # magnitude ~10.
+    "ssm": 1e-4,
 }
 
 
@@ -331,6 +335,87 @@ def check_moe(N: int, d: int, f: int, E: int, top_k: int) -> dict:
     }
 
 
+def check_decode_packed(S: int, H: int, H_kv: int, Dh: int, L: int,
+                        depth: int, scale: float) -> dict:
+    """Flash-decode over rows stored with their kv heads side by side
+    on lanes (``[depth, S, L, H_kv * Dh]``, a head size under 128) and
+    a softmax scale that is not ``Dh ** -0.5``, against the reference
+    on the same rows viewed ``[S, L, H_kv, Dh]``; the last layer."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_tpu.ops.decode import packed_decode_attention
+
+    kq, kk, kv = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(kq, (S, H, Dh), jnp.float32)
+    k = jax.random.normal(kk, (depth, S, L, H_kv * Dh), jnp.float32)
+    v = jax.random.normal(kv, (depth, S, L, H_kv * Dh), jnp.float32)
+    pos = jnp.asarray(
+        np.resize([0, 127, 128, L // 2, L - 1, 1, L // 3, L - 2], S),
+        jnp.int32,
+    ) % L
+
+    def call(impl):
+        return jax.jit(lambda q, k, v: packed_decode_attention(
+            q, k, v, pos, layer=depth - 1, impl=impl, scale=scale))(q, k, v)
+
+    out = call("flash")
+    with jax.default_matmul_precision("highest"):
+        ref = call("reference")
+    err = _max_err(out, ref)
+    return {
+        "max_abs_err": err,
+        "tol": TOL["decode"],
+        "xla_default_max_abs_err": _max_err(call("reference"), ref),
+        "ok": bool(jnp.isfinite(out).all()) and err <= TOL["decode"],
+    }
+
+
+def check_ssm_update(S: int, H: int, P: int, N: int, layers: int,
+                     live_every: int) -> dict:
+    """``ssm_state_update`` against ``jnp`` on the stored ``[layers, S,
+    N, H * P]`` state, the middle layer, with every ``live_every``-th
+    lane idle: live lanes to float32 rounding, idle lanes and the other
+    layers bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_tpu.ops import ssm
+
+    k = jax.random.split(jax.random.key(3), 7)
+    state = jax.random.normal(k[0], (layers, S, N, H * P), jnp.float32)
+    args = (jax.random.normal(k[1], (S, H, P)),
+            jax.nn.softplus(jax.random.normal(k[2], (S, H)) - 2.0),
+            -jnp.exp(jax.random.uniform(k[3], (H,), minval=0.0, maxval=2.7)),
+            jax.random.normal(k[4], (S, N)), jax.random.normal(k[5], (S, N)),
+            jnp.ones((H,)))
+    live = jnp.asarray(np.arange(S) % live_every != live_every - 1)
+    layer = layers // 2
+
+    def call(impl):
+        return jax.jit(lambda s, *a: ssm.ssm_state_update(
+            s, layer, *a, live, impl=impl))(state, *args)
+
+    got_s, got_y = call("pallas")
+    want_s, want_y = call("jnp")
+    idle = ~np.asarray(live)
+    untouched = (
+        bool(jnp.array_equal(got_s[layer][idle], state[layer][idle]))
+        and bool(jnp.array_equal(got_s[:layer], state[:layer]))
+        and bool(jnp.array_equal(got_s[layer + 1:], state[layer + 1:]))
+    )
+    err = max(_max_err(got_s, want_s), _max_err(got_y, want_y))
+    return {
+        "max_abs_err": err, "tol": TOL["ssm"],
+        "idle_lanes_and_other_layers_bit_equal": untouched,
+        "live_lanes": int(live.sum()),
+        "ok": bool(jnp.isfinite(got_y).all()) and untouched
+        and err <= TOL["ssm"],
+    }
+
+
 def cases(tiny: bool, every: bool):
     if tiny:
         flash = dict(B=1, T=128, H=2, D=128, block=64)
@@ -381,6 +466,18 @@ def cases(tiny: bool, every: bool):
         moe = (dict(N=16, d=128, f=128, E=8, top_k=2) if tiny
                else dict(N=128, d=2048, f=768, E=128, top_k=8))
         yield "moe_grouped_bf16", lambda: check_moe(**moe)
+        # A hybrid of state-space and attention layers (models/
+        # granite_hybrid.py) at the benchmark's widths: 4 queries a kv
+        # head of 64 at softmax scale 1/64, two kv heads to a 128-lane
+        # group of the stored rows; the state update of 64 lanes of 64
+        # heads x 64 channels x 128 state dimensions, every fourth idle.
+        packed = (dict(S=2, H=8, H_kv=2, Dh=64, L=256, depth=2) if tiny
+                  else dict(S=64, H=32, H_kv=8, Dh=64, L=2048, depth=2))
+        yield "decode_fp32_g4_dh64_packed", lambda: check_decode_packed(
+            **packed, scale=1 / 64)
+        upd = (dict(S=3, H=2, P=64, N=16, layers=3, live_every=2) if tiny
+               else dict(S=64, H=64, P=64, N=128, layers=3, live_every=4))
+        yield "ssm_state_update_fp32", lambda: check_ssm_update(**upd)
 
 
 def main() -> int:

@@ -122,6 +122,63 @@ def test_flash_training_kernels_compile_at_the_cells_shape(one_chip, case):
         assert kernel in text
 
 
+# ---- the hybrid model's kernels at granite-4.0-h-micro's widths ----------
+
+
+def test_state_update_kernel_compiles_in_place_at_published_widths(
+        one_chip, as_on_tpu):
+    """64 lanes of 64 heads x 64 channels x 128 state dimensions, 36
+    layers stored: one ``ssm_state_update`` call whose output IS the
+    stored buffer (4.8 GB never copied: the program's temporaries stay
+    under a lane's worth)."""
+    from ddp_tpu.ops import ssm
+
+    S, H, P, N, layers = 64, 64, 64, 128, 36
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda s, x, dt, A, B, C, D, live: ssm.ssm_state_update(
+            s, 17, x, dt, A, B, C, D, live, impl="pallas"),
+        donate_argnums=(0,),
+    ).lower(
+        _shape((layers, S, N, H * P), f32, one_chip),
+        _shape((S, H, P), f32, one_chip), _shape((S, H), f32, one_chip),
+        _shape((H,), f32, one_chip), _shape((S, N), f32, one_chip),
+        _shape((S, N), f32, one_chip), _shape((H,), f32, one_chip),
+        _shape((S,), jnp.bool_, one_chip),
+    ).compile()
+    assert "ssm_state_update" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == layers * S * N * H * P * 4
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+def test_decode_kernel_compiles_with_heads_packed_on_lanes(
+        one_chip, as_on_tpu):
+    """4 queries a kv head of 64 at softmax scale 1/64 on rows stored
+    ``[4, 64, 2048, 8 * 64]``, and one lane of them read for a prefill
+    chunk: both take the stored buffer as it is (no copy of it among the
+    temporaries)."""
+    from ddp_tpu.ops.decode import packed_decode_attention, read_lane
+
+    S, H, Hkv, Dh, L, depth = 64, 32, 8, 64, 2048, 4
+    kv = _shape((depth, S, L, Hkv * Dh), jnp.float32, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, pos: packed_decode_attention(
+            q, k, v, pos, layer=depth - 1, impl="flash", scale=1 / 64)
+    ).lower(
+        _shape((S, H, Dh), jnp.float32, one_chip), kv, kv,
+        _shape((S,), jnp.int32, one_chip),
+    ).compile()
+    assert "flash_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    lane = jax.jit(
+        lambda k, slot: read_lane(k, 2, slot, impl="pallas").reshape(
+            L, Hkv, Dh)
+    ).lower(kv, _shape((), jnp.int32, one_chip)).compile()
+    assert "read_lane" in lane.as_text()
+    assert lane.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
 @pytest.fixture(scope="module")
 def ddp4_schedule(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
