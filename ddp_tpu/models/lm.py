@@ -296,6 +296,19 @@ def _sharded_lm(
             q, k, v, axis_name="seq", strategy=spec.strategy, causal=True
         )
 
+    local = best_attention(causal=True)
+
+    def from_projection(qkv, heads):
+        # A ``seq`` axis of one member holds the whole sequence: the
+        # ring has no hop and Ulysses nothing to exchange, so the local
+        # attention may read the fused projection itself (inside
+        # ``shard_map`` the axis size is a Python int).
+        if lax.psum(1, "seq") != 1:
+            return None
+        return local.from_projection(qkv, heads)
+
+    attention.from_projection = from_projection
+
     return CausalLM(
         vocab_size=spec.vocab_size,
         total_len=spec.total_len,

@@ -151,6 +151,7 @@ class MultiHeadAttention(nn.Module):
             v = qkv[..., g + 1, :]
             k = jnp.repeat(k, g, axis=2)
             v = jnp.repeat(v, g, axis=2)
+            out = fn(q, k, v)  # [B, T, H_local, D]
         else:
             heads_local = self.num_heads // self.tp_size
             if self.tp_size > 1 and self.tp_inner_vjp:
@@ -165,9 +166,18 @@ class MultiHeadAttention(nn.Module):
             # member 0 "all of Q plus half of K" under TP.) generate.py
             # mirrors this layout.
             qkv = nn.Dense(3 * C // self.tp_size, name="qkv")(x)
-            qkv = qkv.reshape(B, T, heads_local, 3, head_dim)
-            q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
-        out = fn(q, k, v)  # [B, T, H_local, D]
+            # An attention that can read q, k and v where this matmul
+            # wrote them says so (``from_projection``, ops/attention.py:
+            # the flash kernels, heads of whole 128-lane groups): it
+            # takes the [B, T, H_local·3·D] array whole and returns
+            # [B, T, H_local·D], what ``proj`` reads, and nothing is
+            # sliced, transposed or stacked on the way there or back.
+            # None: not at this shape; q, k, v are strided slices.
+            whole = getattr(fn, "from_projection", None)
+            out = whole(qkv, heads_local) if whole else None
+            if out is None:
+                qkv = qkv.reshape(B, T, heads_local, 3, head_dim)
+                out = fn(qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2])
         out = out.reshape(B, T, C // self.tp_size)
         if self.tp_size > 1:
             return RowParallelDense(

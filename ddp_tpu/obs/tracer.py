@@ -90,10 +90,14 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # ops/flash.py — one per traced ``pallas_call`` of a flash training
     # kernel (trace time, zero duration; a compiled step leaves none):
     # the grid steps a (batch·head) visits, how many of them run the
-    # masked program, how many are dead (visited, nothing computed).
-    # ``kernel`` and ``operand_dtype`` are names, not numbers
+    # masked program, how many are dead (visited, nothing computed),
+    # and where its operands lie: ``projection`` (the fused qkv matmul's
+    # output, read and written in place), ``heads_last`` ([B, T, H·D])
+    # or ``transposed`` ([B·H, T, D] copies: heads narrower than 128
+    # lanes). ``kernel``, ``operand_dtype`` and ``operand_layout`` are
+    # names, not numbers
     "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
-                   "dead", "operand_dtype"),
+                   "dead", "operand_dtype", "operand_layout"),
     # ops/ssm.py — one per traced ``pallas_call`` of the state update
     # (trace time, zero duration): lanes and heads of a lane a grid step
     # holds. ``kernel`` and ``state_dtype`` are names

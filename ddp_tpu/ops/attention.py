@@ -59,15 +59,34 @@ def best_attention(*, causal: bool = False, block_q: int = 512,
     path otherwise (short sequences, where the kernel's per-block
     overhead loses to one fused einsum chain, and every non-TPU
     platform). The model factories (vit/lm/seq/moe) call this when no
-    explicit ``attention_fn`` is given.
+    explicit ``attention_fn`` is given. The fn also carries
+    ``from_projection(qkv, heads)``, which a caller holding the fused
+    projection tries first (``models/vit.py::MultiHeadAttention``).
     """
-    from ddp_tpu.ops.flash import flash_attention
+    from ddp_tpu.ops.flash import (
+        LANES,
+        flash_attention,
+        flash_attention_projection,
+    )
 
     def fn(q, k, v):
         if use_flash(k.shape[1]):
             return flash_attention(q, k, v, causal, block_q, block_k, False)
         return dot_product_attention(q, k, v, causal=causal)
 
+    def from_projection(qkv, heads):
+        """Attention of a fused head-major projection ``qkv``
+        [B, T, heads·3·D] (models/vit.py) → [B, T, heads·D], where the
+        flash kernels can read it as it lies: ``use_flash`` lengths and
+        heads of whole 128-lane groups. None otherwise: the caller
+        slices q, k, v out and calls ``fn``."""
+        head_dim = qkv.shape[2] // (3 * heads)
+        if use_flash(qkv.shape[1]) and head_dim % LANES == 0:
+            return flash_attention_projection(
+                qkv, heads, causal, block_q, block_k, False)
+        return None
+
+    fn.from_projection = from_projection
     return fn
 
 
@@ -86,7 +105,9 @@ def gspmd_flash_attention(mesh, *, causal: bool = False, block_q: int = 512,
     head_dim whole per shard. Below ``FLASH_MIN_LEN`` keys it returns
     the dense path exactly like ``best_attention`` (and always does on
     non-TPU platforms unless ``interpret`` forces the kernel for
-    tests), so short-sequence models are untouched.
+    tests), so short-sequence models are untouched. The island's specs
+    are written for [B, T, H, D], so it keeps the separate-operand
+    entry and offers no ``from_projection``: q, k, v arrive sliced.
     """
     from ddp_tpu.runtime.mesh import data_axes
 

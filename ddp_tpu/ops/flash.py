@@ -52,10 +52,37 @@ merge by the standard (out, lse) log-space combine, and the custom VJP
 routes the lse cotangent through the same blockwise backward (the
 ``delta − dlse`` fold below).
 
-Layout notes (Mosaic constraints): per-row statistics (LSE, delta)
+Where the operands lie (PR 33). A kernel reads an operand where its
+producer wrote it and writes a result where its consumer reads it: an
+operand is an array [B, T, columns] and a rule that maps a head to a
+D-wide column block (``_Operand``), and the grid's first dimension
+still runs over (batch, head) — ``_spec`` is the one index rule of all
+three kernels. Two entries share them. ``flash_attention_projection``
+takes the fused head-major projection [B, T, H·3·D] as
+``models/vit.py``'s ``qkv`` matmul wrote it (one array seen three
+times, stride 3, offsets 0, 1, 2), writes ``out`` as [B, T, H·D], which
+is what ``proj`` reads, and its backward writes dq, dk, dv into ONE
+[B, T, H·3·D] array, the projection's cotangent (``flash_dq`` writes
+its column blocks of it, ``flash_dkv`` takes it aliased and writes a
+head's dk | dv beside them). ``flash_attention`` /
+``flash_attention_with_lse`` take separate
+[B, T, H, D] operands (the ring's hops, GQA after its repeat), which
+are [B, T, H·D] by a free reshape. Nothing is sliced, transposed,
+stacked or broadcast in XLA around the kernels on either. That needs a
+head of whole 128-lane groups; a narrower head (ViT-Tiny's 64) is no
+lane-aligned column block and goes through both entries as transposed
+[B·H, T, D] copies (no cell runs that: ``FLASH_MIN_LEN`` keeps the
+image models on the dense path). Each ``flash.plan`` record says
+which: ``projection``, ``heads_last`` or ``transposed``.
+
+Layout notes (Mosaic constraints): per-row statistics (LSE, delta')
 travel as [B·H, T, LANES] fp32 broadcast across a 128-lane minor
 dimension — a [.., T, 1] layout would be lane-padded to 128 in VMEM
-anyway, and 2-D [B·H, T] blocks of one row are not tileable. Scratch
+anyway, and 2-D [B·H, T] blocks of one row are not tileable. They stay
+so from kernel to kernel: the custom VJP's residual is the LSE as
+``flash_fwd`` wrote it, and delta' = rowsum(dO ∘ O) − dLSE is made by
+``flash_dq`` on the first step of each q block, from the dO and O
+blocks it holds, and handed to ``flash_dkv``. Scratch
 accumulators persist across the steps of an output block and flush on
 its last one (``pl.when`` on the step's flags), the scheme of
 jax.experimental.pallas.ops.tpu.flash_attention. The live-pair tables
@@ -74,6 +101,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -295,16 +323,22 @@ def _saved_lse(lse, empty_rows):
 
 
 def _dq_kernel(
-    qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-    dq_ref, dq_acc,
-    *, scale, causal, block_q, block_k, T_total, S_total,
+    qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+    *rest, scale, causal, block_q, block_k, T_total, S_total,
 ):
     """Grid (B·H, live pairs by q block): dQ accumulates over the
     streamed KV blocks of each q block.
 
-    ``dl_ref`` holds delta' = rowsum(dO ∘ O) − dLSE; with P recomputed
-    as exp(S − LSE), dS = P ∘ (dO·Vᵀ − delta') and dQ = scale · dS·K.
+    The first step of a q block also makes its rows of delta' =
+    rowsum(dO ∘ O) − dLSE from the dO and O blocks it holds, lane-
+    broadcast into ``dl_ref``: an OUTPUT ([B·H, T, LANES], what
+    ``flash_dkv`` reads) that this kernel reads back on every step of
+    the block. ``rest`` is ``dq_ref, dl_ref`` and the accumulator, led
+    by ``dlse_ref`` (lane-broadcast like the LSE) where the caller
+    differentiates the LSE output too. With P recomputed as
+    exp(S − LSE), dS = P ∘ (dO·Vᵀ − delta') and dQ = scale · dS·K.
     """
+    *dlse_ref, dq_ref, dl_ref, dq_acc = rest
     step = pl.program_id(1)
     flags = flags_ref[step]
     q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
@@ -313,6 +347,11 @@ def _dq_kernel(
     @pl.when(flags & _FIRST != 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        delta = (
+            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        ).sum(axis=-1, keepdims=True)
+        dl = jnp.broadcast_to(delta, dl_ref.shape[1:])
+        dl_ref[0] = dl - dlse_ref[0][0] if dlse_ref else dl
 
     def _compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale
@@ -337,11 +376,17 @@ def _dq_kernel(
 
 def _dkv_kernel(
     kj_ref, qi_ref, flags_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref,
-    dk_ref, dv_ref, dk_acc, dv_acc,
-    *, scale, causal, block_q, block_k, T_total, S_total,
+    *rest, scale, causal, block_q, block_k, T_total, S_total, joined=False,
 ):
     """Grid (B·H, live pairs by k block): dK/dV accumulate over the
     streamed Q blocks of each k block.
+
+    ``rest`` is ``dk_ref, dv_ref`` and the two accumulators, or, when
+    ``joined`` (the fused projection), the cotangent array itself
+    (aliased to the output, in no memory the kernel reads), ``dkv_ref``
+    and the accumulators: the flush then writes the head's
+    [block_k, 2·D] dk | dv columns of the projection's cotangent in
+    one block.
 
     Where the q block fills whole lane groups the tile is built KEYS
     FIRST, Sᵀ = K·Qᵀ [block_k, block_q]: then Pᵀ and dSᵀ enter the two
@@ -353,6 +398,7 @@ def _dkv_kernel(
     q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
     empty_rows = bool(causal) and T_total > S_total
     keys_first = block_q % LANES == 0
+    *out_refs, dk_acc, dv_acc = rest
 
     @pl.when(flags & _FIRST != 0)
     def _init():
@@ -386,8 +432,15 @@ def _dkv_kernel(
 
     @pl.when(flags & _LAST != 0)
     def _flush():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        D = dk_acc.shape[1]
+        if joined:
+            _, dkv_ref = out_refs
+            dkv_ref[0, :, :D] = dk_acc[...].astype(dkv_ref.dtype)
+            dkv_ref[0, :, D:] = dv_acc[...].astype(dkv_ref.dtype)
+        else:
+            dk_ref, dv_ref = out_refs
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def pick_block(n: int, requested: int, dtype) -> int:
@@ -420,32 +473,84 @@ def _pick_blocks(T, S, block_q, block_k, dtype):
 
 
 def _to_bh(x):
-    """[B, T, H, D] → [B·H, T, D]: one grid row per (batch, head)."""
+    """[B, T, H, D] → [B·H, T, D]: one grid row per (batch, head). Only
+    where a head is not whole 128-lane groups (``_operand``)."""
     B, T, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+
+
+class _Operand(NamedTuple):
+    """What a kernel reads or writes: ``array`` [B', T, columns] as its
+    producer wrote it, and the rule that finds a head's D-wide column
+    block in it — head ``h`` of the ``heads`` a row holds lies at block
+    ``h * stride + offset``. The fused projection is one array seen
+    three times (stride 3, offsets 0, 1, 2); [B, T, H, D] is [B, T, H·D]
+    by a free reshape (stride 1, offset 0); the transposed [B·H, T, D]
+    holds one head a row."""
+
+    array: jax.Array
+    heads: int
+    stride: int = 1
+    offset: int = 0
+
+
+def _layout(D):
+    """How separate [B, T, H, D] operands reach the kernels. A D-wide
+    column block of [B, T, H·D] is lane-aligned only where D is whole
+    128-lane groups; a narrower head goes as a transposed copy."""
+    return "transposed" if D % LANES else "heads_last"
+
+
+def _operand(x):
+    """[B, T, H, D] as the kernels take it (``_layout``)."""
+    B, T, H, D = x.shape
+    if D % LANES:
+        return _Operand(_to_bh(x), 1)
+    return _Operand(x.reshape(B, T, H * D), H)
+
+
+def _from_operand(x, B, H):
+    """A result in ``_operand``'s layout back as [B, T, H, D]."""
+    T, D = x.shape[1], x.shape[2] * x.shape[0] // (B * H)
+    if D % LANES:
+        return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return x.reshape(B, T, H, D)
 
 
 def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _outer_block(b, n, outer, inner, flags):
-    """Index map of an operand blocked like the OUTPUT (the q block in
-    forward and dQ, the k block in dK/dV): the step's table entry."""
-    return (b, outer[n], 0)
+_OUTER, _INNER = 0, 1
 
 
-def _inner_block(b, n, outer, inner, flags):
-    """Index map of an operand streamed under an output block."""
-    return (b, inner[n], 0)
+def _spec(rows, width, table, heads=1, stride=1, offset=0):
+    """BlockSpec of a [rows, width] block of a [B', T, columns] array,
+    under THE index rule of the three kernels: grid row ``bh`` is head
+    ``bh % heads`` of batch row ``bh // heads``, the row block is the
+    step's entry in ``table`` — ``_OUTER`` for an operand blocked like
+    the output (the q block in forward and dQ, the k block in dK/dV),
+    ``_INNER`` for one streamed under it — and the column block is the
+    head's (``_Operand``). The defaults are a [B·H, T, width] array."""
+
+    def index(bh, n, *tables):
+        return (lax.div(bh, heads), tables[table][n],
+                lax.rem(bh, heads) * stride + offset)
+
+    return pl.BlockSpec((1, rows, width), index, memory_space=pltpu.VMEM)
 
 
-def _plan(kernel, T, S, block_q, block_k, causal, *, by_key=False):
+def _operand_spec(rows, D, table, operand):
+    return _spec(rows, D, table, *operand[1:])
+
+
+def _plan(kernel, T, S, block_q, block_k, causal, layout, *, by_key=False):
     """The live-pair tables of one ``pallas_call``, and its
     ``flash.plan`` record in the tracer's ring (trace time: a compiled
     step leaves none): the grid steps a (batch·head) visits, how many
-    of them run the masked program, how many are dead, and the dtype
-    the MXU's operands are handed over in."""
+    of them run the masked program, how many are dead, the dtype the
+    MXU's operands are handed over in, and where the operands lie
+    (``projection``, ``heads_last`` or ``transposed``)."""
     tables = _live_pairs(
         _classify(T, S, block_q, block_k, causal), by_key=by_key)
     flags = tables[2]
@@ -453,40 +558,37 @@ def _plan(kernel, T, S, block_q, block_k, causal, *, by_key=False):
         "flash.plan", time.perf_counter(), 0.0,
         nums=(kernel, block_q, block_k, len(flags),
               sum(1 for f in flags if f & _DIAGONAL),
-              sum(1 for f in flags if f & _DEAD), "float32"),
+              sum(1 for f in flags if f & _DEAD), "float32", layout),
     )
     return tuple(jnp.asarray(t, jnp.int32) for t in tables)
 
 
-def _flash_forward(
-    q, k, v, *, causal: bool, block_q: int, block_k: int, interpret: bool
+def _forward_call(
+    q, k, v, D, layout, *, causal, block_q: int, block_k: int, interpret
 ):
-    """Returns (out [B,T,H,D], lse [B,T,H] fp32)."""
-    B, T, H, D = q.shape
-    S = k.shape[1]
-    block_q, block_k = _pick_blocks(T, S, block_q, block_k, q.dtype)
-    scale = D**-0.5
-    qt, kt, vt = _to_bh(q), _to_bh(k), _to_bh(v)
-    by_q = _plan("flash_fwd", T, S, block_q, block_k, causal)
-
-    kw = {"memory_space": pltpu.VMEM}
-    qmap, kmap = _outer_block, _inner_block
-    out, lse = pl.pallas_call(
+    """``flash_fwd`` on ``_Operand``s. Returns (out [B', T, heads·D] in
+    the layout of a stride-1 operand, lse [B·H, T, LANES] fp32)."""
+    Bp, T, _ = q.array.shape
+    S = k.array.shape[1]
+    heads = q.heads
+    block_q, block_k = _pick_blocks(T, S, block_q, block_k, q.array.dtype)
+    by_q = _plan("flash_fwd", T, S, block_q, block_k, causal, layout)
+    return pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            _fwd_kernel, scale=D**-0.5, causal=causal, block_q=block_q,
             block_k=block_k, T_total=T, S_total=S,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B * H, len(by_q[0])),
+            grid=(Bp * heads, len(by_q[0])),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), qmap, **kw),
-                pl.BlockSpec((1, block_k, D), kmap, **kw),
-                pl.BlockSpec((1, block_k, D), kmap, **kw),
+                _operand_spec(block_q, D, _OUTER, q),
+                _operand_spec(block_k, D, _INNER, k),
+                _operand_spec(block_k, D, _INNER, v),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), qmap, **kw),
-                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
+                _spec(block_q, D, _OUTER, heads),
+                _spec(block_q, LANES, _OUTER),
             ],
             scratch_shapes=[
                 _scratch((block_q, D)),
@@ -495,15 +597,119 @@ def _flash_forward(
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, T, heads * D), q.array.dtype),
+            jax.ShapeDtypeStruct((Bp * heads, T, LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(*by_q, qt, kt, vt)
-    out = out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
-    lse = lse[:, :, 0].reshape(B, H, T).transpose(0, 2, 1)  # [B, T, H]
-    return out, lse
+    )(*by_q, q.array, k.array, v.array)
+
+
+def _backward_calls(
+    q, k, v, g, out, lse, D, layout, *, causal, block_q, block_k, interpret,
+    dlse=None, joined: bool = False,
+):
+    """``flash_dq`` and ``flash_dkv`` on ``_Operand``s; ``out`` is the
+    forward's, ``lse`` [B·H, T, LANES] fp32 as it wrote it, ``dlse`` the
+    LSE output's cotangent in the same layout or None. Returns (dq, dk,
+    dv), each like a stride-1 operand, or with ``joined`` ONE
+    [B', T, heads·3·D] array, the fused projection's cotangent:
+    ``flash_dq`` writes a head's dq columns of it, ``flash_dkv`` the dk
+    and dv columns of the same buffer. delta' travels from ``flash_dq``,
+    which makes it, to ``flash_dkv`` as [B·H, T, LANES] fp32."""
+    Bp, T, _ = q.array.shape
+    S = k.array.shape[1]
+    heads = q.heads
+    dtype = q.array.dtype
+    block_q, block_k = _pick_blocks(T, S, block_q, block_k, dtype)
+    by_q = _plan("flash_dq", T, S, block_q, block_k, causal, layout)
+    by_k = _plan(
+        "flash_dkv", T, S, block_q, block_k, causal, layout, by_key=True)
+    common = dict(
+        scale=D**-0.5, causal=causal, block_q=block_q, block_k=block_k,
+        T_total=T, S_total=S,
+    )
+    grid_rows = Bp * heads
+    stats = [lse] if dlse is None else [lse, dlse]
+    # dq lies as q does: with ``joined`` the first of a head's three
+    # column blocks of the cotangent, whose others ``flash_dkv`` fills
+    dq_stride = 3 if joined else 1
+    dq, dl = pl.pallas_call(
+        functools.partial(_dq_kernel, **common),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(grid_rows, len(by_q[0])),
+            in_specs=[
+                _operand_spec(block_q, D, _OUTER, q),
+                _operand_spec(block_k, D, _INNER, k),
+                _operand_spec(block_k, D, _INNER, v),
+                _operand_spec(block_q, D, _OUTER, g),
+                _operand_spec(block_q, D, _OUTER, out),
+                *(_spec(block_q, LANES, _OUTER) for _ in stats),
+            ],
+            out_specs=[
+                _spec(block_q, D, _OUTER, heads, dq_stride),
+                _spec(block_q, LANES, _OUTER),
+            ],
+            scratch_shapes=[_scratch((block_q, D))],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((Bp, T, heads * dq_stride * D), dtype),
+            jax.ShapeDtypeStruct(lse.shape, jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_dq",
+    )(*by_q, q.array, k.array, v.array, g.array, out.array, *stats)
+
+    # For dK/dV the K block is the OUTER streamed dim, Q the inner.
+    in_specs = [
+        _operand_spec(block_k, D, _OUTER, k),
+        _operand_spec(block_k, D, _OUTER, v),
+        _operand_spec(block_q, D, _INNER, q),
+        _operand_spec(block_q, D, _INNER, g),
+        _spec(block_q, LANES, _INNER),
+        _spec(block_q, LANES, _INNER),
+    ]
+    aliases = {}
+    if joined:
+        # T == S: q, k and v are one array's columns, and so are their
+        # cotangents. ``flash_dq`` wrote its column blocks of that
+        # array; this call takes it as its own output (aliased, never
+        # fetched) and writes a head's dk | dv beside them: 2·D columns
+        # from column (3·head + 1)·D on, which is no whole multiple of
+        # the block's width, hence an element offset and not a block
+        # index.
+        aliases = {3 + len(in_specs): 0}  # operands count the 3 tables
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+
+        def dk_dv_columns(bh, n, *tables):
+            return (lax.div(bh, heads),
+                    pl.multiple_of(tables[_OUTER][n] * block_k, block_k),
+                    pl.multiple_of((lax.rem(bh, heads) * 3 + 1) * D, D))
+
+        out_specs = pl.BlockSpec(
+            (pl.Element(1), pl.Element(block_k), pl.Element(2 * D)),
+            dk_dv_columns, memory_space=pltpu.VMEM)
+        out_shape = jax.ShapeDtypeStruct((Bp, S, heads * 3 * D), dtype)
+    else:
+        out_specs = [_spec(block_k, D, _OUTER, heads) for _ in range(2)]
+        out_shape = [jax.ShapeDtypeStruct((Bp, S, heads * D), dtype)] * 2
+    dkv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **common, joined=joined),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(grid_rows, len(by_k[0])),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=interpret,
+        name="flash_dkv",
+    )(*by_k, k.array, v.array, q.array, g.array, lse, dl,
+      *((dq,) if joined else ()))
+    return dkv if joined else (dq, *dkv)
 
 
 def _to_lanes(x_bth):
@@ -513,84 +719,71 @@ def _to_lanes(x_bth):
     return jnp.broadcast_to(flat, (B * H, T, LANES))
 
 
-def _flash_backward(
-    q, k, v, out, lse, g, dlse, *, causal, block_q, block_k, interpret
-):
-    """Blockwise VJP: (dq, dk, dv) with O(T·D) peak memory.
+def _lse_rows(lse, B):
+    """The forward's [B·H, T, LANES] LSE as a caller takes it:
+    [B, T, H]."""
+    BH, T, _ = lse.shape
+    return lse[:, :, 0].reshape(B, BH // B, T).transpose(0, 2, 1)
 
-    ``dlse`` is the cotangent of the LSE output (zeros when the caller
-    only differentiates the attention output): dS picks up an extra
-    +P·dLSE term, folded in as delta' = rowsum(dO ∘ O) − dLSE.
-    """
-    B, T, H, D = q.shape
-    S = k.shape[1]
-    block_q, block_k = _pick_blocks(T, S, block_q, block_k, q.dtype)
-    scale = D**-0.5
-    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    dl_l = _to_lanes(delta - dlse.astype(jnp.float32))
-    lse_l = _to_lanes(lse)
-    qt, kt, vt, gt = _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g)
-    by_q = _plan("flash_dq", T, S, block_q, block_k, causal)
-    by_k = _plan("flash_dkv", T, S, block_q, block_k, causal, by_key=True)
 
-    kw = {"memory_space": pltpu.VMEM}
-    common = dict(
-        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        T_total=T, S_total=S,
-    )
-    qmap, kmap = _outer_block, _inner_block
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B * H, len(by_q[0])),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), qmap, **kw),
-                pl.BlockSpec((1, block_k, D), kmap, **kw),
-                pl.BlockSpec((1, block_k, D), kmap, **kw),
-                pl.BlockSpec((1, block_q, D), qmap, **kw),
-                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
-                pl.BlockSpec((1, block_q, LANES), qmap, **kw),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, D), qmap, **kw),
-            scratch_shapes=[_scratch((block_q, D))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-        interpret=interpret,
-        name="flash_dq",
-    )(*by_q, qt, kt, vt, gt, lse_l, dl_l)
+def _flash_forward(q, k, v, **opts):
+    """Separate [B, T, H, D] operands. Returns (out [B, T, H, D], lse
+    [B·H, T, LANES] fp32, as ``flash_fwd`` wrote it and the backward
+    kernels read it)."""
+    B, _, H, D = q.shape
+    out, lse = _forward_call(
+        _operand(q), _operand(k), _operand(v), D, _layout(D), **opts)
+    return _from_operand(out, B, H), lse
 
-    # For dK/dV the K block is the OUTER streamed dim, Q the inner.
-    kvmap, qmap2 = _outer_block, _inner_block
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B * H, len(by_k[0])),
-            in_specs=[
-                pl.BlockSpec((1, block_k, D), kvmap, **kw),
-                pl.BlockSpec((1, block_k, D), kvmap, **kw),
-                pl.BlockSpec((1, block_q, D), qmap2, **kw),
-                pl.BlockSpec((1, block_q, D), qmap2, **kw),
-                pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
-                pl.BlockSpec((1, block_q, LANES), qmap2, **kw),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, D), kvmap, **kw),
-                pl.BlockSpec((1, block_k, D), kvmap, **kw),
-            ],
-            scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
-        ],
-        interpret=interpret,
-        name="flash_dkv",
-    )(*by_k, kt, vt, qt, gt, lse_l, dl_l)
 
-    back = lambda x, T_: x.reshape(B, H, T_, D).transpose(0, 2, 1, 3)
-    return back(dq, T), back(dk, S), back(dv, S)
+def _flash_backward(q, k, v, out, lse, g, dlse=None, **opts):
+    """Blockwise VJP of ``_flash_forward``: (dq, dk, dv) with O(T·D)
+    peak memory. ``dlse`` [B, T, H] is the cotangent of the LSE output
+    (None when the caller only differentiates the attention output):
+    dS picks up an extra +P·dLSE term, folded into delta' by
+    ``flash_dq``."""
+    B, _, H, D = q.shape
+    grads = _backward_calls(
+        *(_operand(x) for x in (q, k, v, g, out)), lse, D, _layout(D),
+        dlse=None if dlse is None else _to_lanes(dlse), **opts)
+    return tuple(_from_operand(x, B, H) for x in grads)
+
+
+def _split_projection(qkv, heads):
+    """The head-major fused projection [B, T, heads·3·D] as q, k, v
+    [B, T, heads, D] (strided slices: XLA copies them)."""
+    B, T, C3 = qkv.shape
+    qkv = qkv.reshape(B, T, heads, 3, C3 // (3 * heads))
+    return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
+
+
+def _projection_forward(qkv, heads, **opts):
+    """The fused projection [B, T, heads·3·D] read where it lies.
+    Returns (out [B, T, heads·D], lse [B·H, T, LANES])."""
+    B, T, C3 = qkv.shape
+    D = C3 // (3 * heads)
+    if D % LANES:  # no lane-aligned column block: the transposed operands
+        out, lse = _flash_forward(*_split_projection(qkv, heads), **opts)
+        return out.reshape(B, T, heads * D), lse
+    q, k, v = (_Operand(qkv, heads, 3, i) for i in range(3))
+    return _forward_call(q, k, v, D, "projection", **opts)
+
+
+def _projection_backward(qkv, heads, out, lse, g, **opts):
+    """VJP of ``_projection_forward``: the projection's cotangent
+    [B, T, heads·3·D], written by the kernels as one array."""
+    B, T, C3 = qkv.shape
+    D = C3 // (3 * heads)
+    if D % LANES:
+        rows = (B, T, heads, D)
+        grads = _flash_backward(
+            *_split_projection(qkv, heads), out.reshape(rows), lse,
+            g.reshape(rows), **opts)
+        return jnp.stack(grads, axis=3).reshape(B, T, C3)
+    q, k, v = (_Operand(qkv, heads, 3, i) for i in range(3))
+    return _backward_calls(
+        q, k, v, _Operand(g, heads), _Operand(out, heads), lse, D,
+        "projection", joined=True, **opts)
 
 
 def _reference(q, k, v, causal: bool):
@@ -613,6 +806,11 @@ def _reference(q, k, v, causal: bool):
     return out.astype(dtype)
 
 
+def _opts(causal, block_q, block_k, interpret):
+    return dict(
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(
     q,
@@ -632,30 +830,60 @@ def flash_attention(
     diffusion prefill; B divides the chunk, so whole blocks stay
     inside a query tile).
     """
-    out, _ = _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    return out
+    return _fa_fwd(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
 def _fa_fwd(q, k, v, causal, block_q, block_k, interpret):
     out, lse = _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
+        q, k, v, **_opts(causal, block_q, block_k, interpret))
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, block_q, block_k, interpret, residuals, g):
-    q, k, v, out, lse = residuals
     return _flash_backward(
-        q, k, v, out, lse, g, jnp.zeros_like(lse), causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
+        *residuals, g, **_opts(causal, block_q, block_k, interpret))
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def flash_attention_projection(
+    qkv,
+    heads: int,
+    causal: bool = False,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+):
+    """``flash_attention`` of a fused, head-major projection: ``qkv``
+    [B, T, heads·3·D] with columns ordered [head, (q|k|v), D], as
+    ``models/vit.py::MultiHeadAttention``'s ``qkv`` matmul writes it.
+    Returns [B, T, heads·D], what the ``proj`` matmul reads.
+
+    Where D is whole 128-lane groups nothing is sliced, transposed or
+    stacked on the way: the kernels read a head's q, k and v column
+    blocks out of ``qkv`` itself and the backward kernels write its
+    cotangent as one array. A narrower head takes the transposed
+    operands of the separate entry, sliced here.
+    """
+    return _fap_fwd(qkv, heads, causal, block_q, block_k, interpret)[0]
+
+
+def _fap_fwd(qkv, heads, causal, block_q, block_k, interpret):
+    out, lse = _projection_forward(
+        qkv, heads, **_opts(causal, block_q, block_k, interpret))
+    return out, (qkv, out, lse)
+
+
+def _fap_bwd(heads, causal, block_q, block_k, interpret, residuals, g):
+    qkv, out, lse = residuals
+    return (_projection_backward(
+        qkv, heads, out, lse, g,
+        **_opts(causal, block_q, block_k, interpret)),)
+
+
+flash_attention_projection.defvjp(_fap_fwd, _fap_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -674,29 +902,22 @@ def flash_attention_with_lse(
     query row. Partial attention outputs over different KV blocks
     combine exactly from (out, lse) pairs — this is the per-hop
     primitive of ring attention (parallel/ring.py). Differentiable in
-    both outputs.
+    both outputs. The backward reads the LSE as ``flash_fwd`` wrote it,
+    not this output: where a caller drops it, nothing is made of it.
     """
-    return _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
+    return _fal_fwd(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
 def _fal_fwd(q, k, v, causal, block_q, block_k, interpret):
     out, lse = _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    return (out, lse), (q, k, v, out, lse)
+        q, k, v, **_opts(causal, block_q, block_k, interpret))
+    return (out, _lse_rows(lse, q.shape[0])), (q, k, v, out, lse)
 
 
 def _fal_bwd(causal, block_q, block_k, interpret, residuals, cotangents):
-    q, k, v, out, lse = residuals
     g, dlse = cotangents
     return _flash_backward(
-        q, k, v, out, lse, g, dlse, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
+        *residuals, g, dlse, **_opts(causal, block_q, block_k, interpret))
 
 
 flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)

@@ -10,7 +10,8 @@ with its reference.
                                               #   Pallas interpreter, not Mosaic)
     python scripts/check_kernels.py --time    # device ms a call of flash_fwd,
                                               #   flash_dq, flash_dkv at the train
-                                              #   cells' shape (needs the chip)
+                                              #   cells' shape, and of what XLA
+                                              #   runs AROUND them (needs the chip)
 
 On a TPU the kernels compile under Mosaic; elsewhere they run in the
 Pallas interpreter, which proves the program and nothing about the
@@ -18,14 +19,21 @@ chip. References run under ``jax.default_matmul_precision("highest")``
 on fp32 copies of the inputs. One JSON line per case —
 ``{"case", "kernel", "max_abs_err", "tol", "ok"}`` — then one summary
 line; exit status 1 when any case is outside its tolerance or failed
-to build. Only ``--time`` times anything: the three flash training
-kernels, by their names in a profiler trace of a few calls (the loop a
-change to ``ops/flash.py`` iterates in; no benchmark cell runs this).
+to build. Only ``--time`` times anything: an attention layer's forward
+and backward from the fused qkv projection to what ``proj`` reads, in a
+profiler trace of a few calls — the three flash training kernels by
+their names (``ms_per_call``) and every other device operation of the
+call (``around_ms_per_call``: the slices, transposes and stacks between
+the projection and the kernels, 6.4 ms a step in the train cells until
+PR 33) — once with q, k, v sliced out for the separate-operand entry
+and once through the fused-projection entry (the loop a change to
+``ops/flash.py`` iterates in; no benchmark cell runs this).
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -81,22 +89,30 @@ def _flash_inputs(B, T, H, D, dtype):
 
 
 def check_flash(B: int, T: int, H: int, D: int, block: int,
-                causal=True, dtype="bfloat16", backward=True) -> dict:
+                causal=True, dtype="bfloat16", backward=True,
+                projection=False) -> dict:
     """Flash forward AND backward (the trainer's causal bf16 call)
     against dense attention. ``causal`` an int > 1: the block-causal
     mask of a block-diffusion prefill. ``backward=False``: the forward
-    alone (the whole-prompt prefill's float32 call)."""
+    alone (the whole-prompt prefill's float32 call). ``projection``:
+    through the fused entry, q, k, v as column blocks of one head-major
+    [B, T, H·3·D] array (the train cells' call)."""
     import jax
     import jax.numpy as jnp
 
     from ddp_tpu.ops.attention import dot_product_attention
-    from ddp_tpu.ops.flash import flash_attention
+    from ddp_tpu.ops.flash import flash_attention, flash_attention_projection
 
     interpret = jax.default_backend() != "tpu"
     q, k, v, w = _flash_inputs(B, T, H, D, dtype)  # w: the cotangent
 
     def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, causal, block, block, interpret)
+        if projection:
+            qkv = jnp.stack((q, k, v), axis=3).reshape(B, T, H * 3 * D)
+            out = flash_attention_projection(
+                qkv, H, causal, block, block, interpret).reshape(B, T, H, D)
+        else:
+            out = flash_attention(q, k, v, causal, block, block, interpret)
         return (out.astype(jnp.float32) * w).sum(), out
 
     block_causal = int(causal)  # 1: the plain triangle
@@ -139,12 +155,13 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
 FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
-def kernel_ms_per_call(log_dir: str, calls: int) -> dict:
-    """Device ms a call of each flash kernel in the profiler trace
-    under ``log_dir``: the ``XLA Ops`` events of the first TPU whose
-    instruction carries the kernel's name (``pallas_call(name=)``),
-    summed, over ``calls``. Empty where the trace holds no such event
-    (nothing ran on a chip)."""
+def device_ms_per_call(log_dir: str, calls: int) -> tuple[dict, float]:
+    """Device ms a call in the profiler trace under ``log_dir``, from
+    the ``XLA Ops`` events of the first TPU: of each flash kernel (the
+    events whose instruction carries the kernel's name,
+    ``pallas_call(name=)``), and of every other operation together.
+    ``({}, 0.0)`` where the trace holds no kernel event (nothing ran on
+    a chip)."""
     import glob
     import re
 
@@ -156,6 +173,7 @@ def kernel_ms_per_call(log_dir: str, calls: int) -> dict:
         (p for p in jax.profiler.ProfileData.from_file(path).planes
          if p.name.startswith("/device:TPU:")), key=lambda p: p.name)
     total = dict.fromkeys(FLASH_KERNELS, 0)
+    around = 0
     for line in planes[0].lines if planes else ():
         if line.name != "XLA Ops":
             continue
@@ -167,49 +185,70 @@ def kernel_ms_per_call(log_dir: str, calls: int) -> dict:
                 ev.name)
             if m:
                 total[m.group(1)] += ev.duration_ns
+            else:
+                around += ev.duration_ns
     if not any(total.values()):
-        return {}
-    return {k: ns / 1e6 / calls for k, ns in total.items()}
+        return {}, 0.0
+    return ({k: ns / 1e6 / calls for k, ns in total.items()},
+            around / 1e6 / calls)
+
+
+TIME_ENTRIES = ("sliced", "projection")
 
 
 def time_flash(B: int, T: int, H: int, D: int, block: int,
-               calls: int = 8) -> dict:
-    """Device ms a call of ``flash_fwd``, ``flash_dq`` and ``flash_dkv``
-    (causal, bf16): ``calls`` forward-and-backward calls inside a
-    profiler session, after one that compiles."""
+               calls: int = 8, entry: str = "sliced") -> dict:
+    """Device ms a call of one attention layer's forward and backward
+    (causal, bf16) from the fused head-major projection [B, T, H·3·D]
+    to [B, T, H·D] and back to the projection's cotangent: ``calls``
+    calls inside a profiler session, after one that compiles.
+    ``entry`` ``sliced``: q, k, v sliced out as ``models/vit.py`` does
+    where the kernels cannot read the projection whole, the
+    separate-operand entry, the cotangents stacked back; ``projection``:
+    the fused entry. ``ms_per_call`` is the three kernels',
+    ``around_ms_per_call`` everything else the device ran."""
     import tempfile
 
     import jax
     import jax.numpy as jnp
 
-    from ddp_tpu.ops.flash import flash_attention
+    from ddp_tpu.ops import flash
 
     interpret = jax.default_backend() != "tpu"
-    q, k, v, w = _flash_inputs(B, T, H, D, jnp.bfloat16)
+    kq, kg = jax.random.split(jax.random.key(0))
+    qkv = jax.random.normal(kq, (B, T, H * 3 * D), jnp.bfloat16)
+    g = jax.random.normal(kg, (B, T, H * D), jnp.bfloat16)
+
+    def attend(qkv):
+        if entry == "projection":
+            return flash.flash_attention_projection(
+                qkv, H, True, block, block, interpret)
+        x = qkv.reshape(B, T, H, 3, D)
+        return flash.flash_attention(
+            x[:, :, :, 0], x[:, :, :, 1], x[:, :, :, 2], True, block, block,
+            interpret,
+        ).reshape(B, T, H * D)
 
     @jax.jit
-    def fwd_bwd(q, k, v, g):
-        out, vjp = jax.vjp(
-            lambda *a: flash_attention(*a, True, block, block, interpret),
-            q, k, v,
-        )
+    def fwd_bwd(qkv, g):
+        out, vjp = jax.vjp(attend, qkv)
         return out, vjp(g)
 
-    g = w.astype(jnp.bfloat16)
-    jax.block_until_ready(fwd_bwd(q, k, v, g))
+    jax.block_until_ready(fwd_bwd(qkv, g))
     with tempfile.TemporaryDirectory() as log_dir:
         jax.profiler.start_trace(log_dir)
         try:
             for _ in range(calls):
-                res = fwd_bwd(q, k, v, g)
+                res = fwd_bwd(qkv, g)
             jax.block_until_ready(res)
         finally:
             jax.profiler.stop_trace()
-        ms = kernel_ms_per_call(log_dir, calls)
+        ms, around = device_ms_per_call(log_dir, calls)
     return {
         "shape": dict(B=B, T=T, H=H, D=D, block=block), "calls": calls,
         "ms_per_call": ms, "ok": bool(ms),
-        **({} if ms else {"error": "no TPU in the trace: nothing timed"}),
+        **({"around_ms_per_call": around} if ms
+           else {"error": "no TPU in the trace: nothing timed"}),
     }
 
 
@@ -457,6 +496,9 @@ def cases(tiny: bool, every: bool):
         # (the whole-prompt prefill of a model that states fp32).
         cell = flash if tiny else CELL_FLASH
         yield "flash_fwd_bwd_bf16_causal_cell", lambda: check_flash(**cell)
+        yield "flash_fwd_bwd_bf16_causal_cell_projection", lambda: check_flash(
+            **cell, projection=True
+        )
         yield "flash_fwd_fp32_causal_cell", lambda: check_flash(
             **cell, dtype="float32", backward=False
         )
@@ -495,14 +537,15 @@ def main() -> int:
     kernel = pallas_kernel_mode()
     print(json.dumps({"build_info": build_info(), "kernel": kernel}), flush=True)
     if args.time:
-        rec = time_flash(**(
-            dict(B=1, T=128, H=2, D=128, block=64) if args.tiny else CELL_FLASH
-        ))
-        print(json.dumps({"case": "flash_time", "kernel": kernel, **rec}),
-              flush=True)
-        return 0 if rec["ok"] else 1
+        shape = (dict(B=1, T=128, H=2, D=128, block=64) if args.tiny
+                 else CELL_FLASH)
+        runs = [(f"flash_time_{entry}",
+                 functools.partial(time_flash, **shape, entry=entry))
+                for entry in TIME_ENTRIES]
+    else:
+        runs = cases(args.tiny, args.all)
     failed = []
-    for name, run in cases(args.tiny, args.all):
+    for name, run in runs:
         try:
             rec = run()
         except Exception:  # noqa: BLE001 — report every case, fail at the end

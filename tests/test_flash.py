@@ -318,13 +318,15 @@ def test_flash_every_block_class(shape, dtype):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_with_lse_gradients_under_a_nonzero_dlse(causal, dtype):
+@pytest.mark.parametrize("D", [16, 128])
+def test_flash_with_lse_gradients_under_a_nonzero_dlse(D, causal, dtype):
     """``flash_attention_with_lse`` differentiated in BOTH outputs (ring
-    attention's hop): the lse cotangent reaches dq and dk."""
+    attention's hop): the lse cotangent reaches dq and dk, with heads
+    of 16 (transposed operands) and of 128 (read as [B, T, H·D])."""
     from ddp_tpu.ops.flash import flash_attention_with_lse
 
-    q, k, v = (x.astype(dtype) for x in _qkv(1, 32, 2, 16, seed=23))
-    w = _qkv(1, 32, 2, 16, seed=24)[0]
+    q, k, v = (x.astype(dtype) for x in _qkv(1, 32, 2, D, seed=23))
+    w = _qkv(1, 32, 2, D, seed=24)[0]
     u = jnp.asarray(np.random.default_rng(25).normal(size=(1, 32, 2)),
                     jnp.float32)
 
@@ -350,6 +352,128 @@ def test_flash_with_lse_gradients_under_a_nonzero_dlse(causal, dtype):
     for g, r in zip(grads, ref_grads):
         np.testing.assert_allclose(
             np.asarray(g, np.float32), np.asarray(r), atol=ATOL[dtype][1])
+
+
+def _plan_layouts(since):
+    """kernel -> operand_layout of the ``flash.plan`` records after
+    ``since`` (a copy of the tracer's ring)."""
+    from ddp_tpu.obs.tracer import get_tracer
+
+    return {e[4][0]: e[4][-1] for e in get_tracer().ring()[len(since):]
+            if e[0] == "flash.plan"}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("D", [128, 64, 16])
+def test_projection_entry_and_separate_entry_agree(D, causal, dtype):
+    """The two entries of the three kernels on the same numbers: the
+    fused projection read where it lies (heads of 128: a head's q, k, v
+    are 128-lane column blocks of one array, the cotangent comes back
+    as one array) and separate [B, T, H, D] operands. Bit-equal to each
+    other — one kernel body, one index rule — and within the dense
+    reference's tolerance. Heads of 64 and 16 are not lane-aligned
+    column blocks: both entries take the transposed operands."""
+    from ddp_tpu.obs.tracer import get_tracer
+    from ddp_tpu.ops.flash import (
+        _reference, _split_projection as _split, flash_attention_projection,
+    )
+
+    B, T, H = 2, 256, 2
+    rng = np.random.default_rng(30)
+    qkv = jnp.asarray(rng.normal(size=(B, T, H * 3 * D)), dtype)
+    w = jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.float32)
+
+    def loss(attn):
+        def f(qkv):
+            out = attn(qkv)
+            return (out.astype(jnp.float32) * w).sum(), out
+        return jax.value_and_grad(f, has_aux=True)
+
+    before = get_tracer().ring()
+    (_, out), dqkv = loss(
+        lambda x: flash_attention_projection(x, H, causal, 128, 128, True)
+    )(qkv)
+    layout = "projection" if D % 128 == 0 else "transposed"
+    assert _plan_layouts(before) == dict.fromkeys(
+        ("flash_fwd", "flash_dq", "flash_dkv"), layout)
+    before = get_tracer().ring()
+    (_, out_sep), dqkv_sep = loss(
+        lambda x: flash_attention(
+            *_split(x, H), causal, 128, 128, True).reshape(B, T, H * D)
+    )(qkv)
+    layout = "heads_last" if D % 128 == 0 else "transposed"
+    assert set(_plan_layouts(before).values()) == {layout}
+    (_, ref), dref = loss(
+        lambda x: _reference(*_split(x, H), causal).reshape(B, T, H * D)
+    )(qkv.astype(jnp.float32))
+
+    assert out.dtype == dqkv.dtype == qkv.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_sep))
+    np.testing.assert_array_equal(np.asarray(dqkv), np.asarray(dqkv_sep))
+    out_tol, grad_tol = ATOL[dtype]
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=out_tol)
+    np.testing.assert_allclose(
+        np.asarray(dqkv, np.float32), np.asarray(dref), atol=grad_tol)
+
+
+def _moved_activations(jaxpr, size):
+    """The equations of ``jaxpr`` (and of what it calls, but not of a
+    ``pallas_call``'s kernel) that move an array of at least ``size``
+    elements without computing on it: (primitive, shape) pairs."""
+    moving = ("transpose", "concatenate", "pad", "slice", "dynamic_slice",
+              "gather", "scatter", "dynamic_update_slice", "copy")
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        avals = [v.aval for v in (*eqn.invars, *eqn.outvars)
+                 if hasattr(v.aval, "shape")]
+        if eqn.primitive.name in moving and any(
+                int(np.prod(a.shape)) >= size for a in avals):
+            found.append((eqn.primitive.name, avals[0].shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _moved_activations(sub, size)
+    return found
+
+
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_nothing_moves_an_activation_around_the_fused_entry(
+        head_dim, monkeypatch):
+    """The jaxpr of a ``MultiHeadAttention`` forward-and-backward at a
+    flash length with heads of 128 holds, outside the three
+    ``pallas_call``s, no transpose, concatenate, pad or slice of an
+    array of activation size: q, k, v are read where the ``qkv`` matmul
+    wrote them, ``out`` is written where ``proj`` reads it, and the
+    projection's cotangent is one array the kernels wrote (PR 33: 6.4 ms
+    of copies a step in the train cells otherwise). Heads of 64 are no
+    lane-aligned column block: they are sliced, transposed and stacked
+    as before, and the check sees it."""
+    from ddp_tpu.models.vit import MultiHeadAttention
+    from ddp_tpu.ops.attention import best_attention
+
+    # the kernel choice asks the backend; nothing is lowered or run
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T, H = 2, 1024, 2
+    C = H * head_dim
+    attn = MultiHeadAttention(
+        num_heads=H, attention_fn=best_attention(causal=True))
+    x = jax.ShapeDtypeStruct((B, T, C), jnp.bfloat16)
+    params = jax.eval_shape(attn.init, jax.random.key(0), x)
+
+    def loss(params, x):
+        return attn.apply(params, x).astype(jnp.float32).sum()
+
+    closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+    text = str(closed)
+    assert all(f"name={k}" in text
+               for k in ("flash_fwd", "flash_dq", "flash_dkv"))
+    moved = _moved_activations(closed.jaxpr, B * T * C)
+    if head_dim % 128 == 0:
+        assert moved == []
+    else:
+        assert {name for name, _ in moved} >= {"transpose", "slice"}
 
 
 def _brute_classes(T, S, bq, bk, causal):

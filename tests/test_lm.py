@@ -16,6 +16,7 @@ import pytest
 from ddp_tpu.data.sequences import synthetic_tokens
 from ddp_tpu.models.lm import (
     LMSpec,
+    LMTrainState,
     create_lm_train_state,
     dense_lm_apply,
     init_lm,
@@ -197,13 +198,14 @@ def test_train_compile_record_once_per_compile(devices, data):
     assert float(m1.loss) == float(m_ref.loss)
 
 
-def test_flash_plan_record_once_per_traced_call():
+def test_flash_plan_record_once_per_traced_call(monkeypatch):
     """Tracing a flash training kernel leaves ONE ``flash.plan`` record
     in the tracer's ring — blocks, grid steps visited a (batch·head),
-    of which masked, of which dead, operand dtype — and a call of the
-    compiled program leaves none. At the train cells' shape every
-    kernel visits the 10 live pairs of its 4 x 4 grid, 4 of them
-    diagonal, none dead."""
+    of which masked, of which dead, operand dtype, where the operands
+    lie — and a call of the compiled program leaves none. At the train
+    cells' shape every kernel visits the 10 live pairs of its 4 x 4
+    grid, 4 of them diagonal, none dead, and reads the fused projection
+    where the ``qkv`` matmul wrote it."""
     from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
     from ddp_tpu.ops.flash import flash_attention
 
@@ -230,7 +232,8 @@ def test_flash_plan_record_once_per_traced_call():
         assert dur == 0.0 and parent is None
         assert dict(zip(SPAN_NUMS["flash.plan"], nums)) == {
             "kernel": nums[0], "block_q": 16, "block_k": 16, "visited": 10,
-            "diagonal": 4, "dead": 0, "operand_dtype": "float32"}
+            "diagonal": 4, "dead": 0, "operand_dtype": "float32",
+            "operand_layout": "transposed"}
     step(q, q, q)  # compiled: nothing is traced, nothing recorded
     assert len(records(before)) == 3
     # the cells' own call (4 x 2048 tokens, 16 heads of 128, bf16, blocks
@@ -239,4 +242,24 @@ def test_flash_plan_record_once_per_traced_call():
     before = get_tracer().ring()
     jax.eval_shape(grad(512), cell, cell, cell)
     assert sorted(r[4] for r in records(before)) == [
-        (kernel, 512, 512, 10, 4, 0, "float32") for kernel in kernels]
+        (kernel, 512, 512, 10, 4, 0, "float32", "heads_last")
+        for kernel in kernels]
+    # and as the cells make it: the train step of ``_sharded_lm`` on a
+    # mesh whose ``seq`` axis has one member, one layer at the cells'
+    # widths, for a backend that is a TPU (the kernel choice asks)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = LMSpec(vocab_size=64, total_len=2048, d_model=2048, depth=1,
+                  num_heads=16)
+    tx = optax.adam(1e-4)
+    mesh = make_mesh(MeshSpec(data=1), devices=jax.devices()[:1])
+    params = jax.eval_shape(lambda: init_lm(spec))
+    state = LMTrainState(
+        step=jax.ShapeDtypeStruct((), jnp.int32), params=params,
+        opt_state=jax.eval_shape(tx.init, params))
+    step = make_lm_train_step(
+        spec, tx, mesh, compute_dtype=jnp.bfloat16, jit=False)
+    before = get_tracer().ring()
+    jax.eval_shape(step, state, jax.ShapeDtypeStruct((4, 2048), jnp.int32))
+    assert sorted(r[4] for r in records(before)) == [
+        (kernel, 512, 512, 10, 4, 0, "float32", "projection")
+        for kernel in kernels]

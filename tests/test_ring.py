@@ -63,6 +63,37 @@ def test_ring_under_data_parallel(devices):
     np.testing.assert_allclose(np.asarray(fn(q, k, v)), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("D", [128, 16])
+def test_two_member_ring_on_the_flash_hop_matches_dense(devices, D):
+    """The ring's hops on the Pallas kernels (interpreted here): K/V of
+    the other member arrive as separate [B, T_local, H, D] arrays, heads
+    of 128 read as [B, T_local, H·D] and heads of 16 transposed; the
+    (out, lse) pairs combine to dense causal attention, and so do the
+    gradients through the lse cotangent."""
+    from ddp_tpu.ops.flash import flash_attention_with_lse
+
+    mesh = Mesh(np.asarray(devices[:2]), ("seq",))
+    q, k, v = _qkv(1, 64, 2, D, seed=9)
+
+    def hop(q, k, v, causal):
+        return flash_attention_with_lse(q, k, v, causal, 16, 16, True)
+
+    ring = _seq_sharded(
+        lambda a, b, c: ring_attention(a, b, c, causal=True, block_fn=hop),
+        mesh,
+    )
+    ref = dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(
+        np.asarray(ring(q, k, v)), np.asarray(ref), atol=2e-5)
+    grads = jax.grad(lambda *a: (ring(*a) ** 2).mean(), argnums=(0, 1, 2))
+    ref_grads = jax.grad(
+        lambda *a: (dot_product_attention(*a, causal=True) ** 2).mean(),
+        argnums=(0, 1, 2),
+    )
+    for g, r in zip(grads(q, k, v), ref_grads(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+
+
 def test_ulysses_matches_dense(devices):
     mesh = Mesh(np.asarray(devices[:4]), ("seq",))
     q, k, v = _qkv(2, 32, 4, 8, seed=2)  # H=4 divisible by seq=4
