@@ -491,6 +491,18 @@ class SlotCache(NamedTuple):
     are. ``granite_hybrid.layer_rows`` maps a layer's number to its row
     in either. Models without such layers carry empty tuples, as for
     the scales.
+
+    A model with windowed layers and one shared K/V layer
+    (models/sambay.py) keeps THREE kinds of state of three sizes:
+    ``k``/``v`` ``[1, S, L, H_kv * Dh]`` are the one full layer's rows,
+    which every cross-attention layer reads; ``ring_k``/``ring_v``
+    ``[n_window, S, W, H_kv * Dh]`` hold each windowed layer's last W
+    rows, position p at row ``p mod W`` (a ring forgives nothing: a row
+    written for a padded position or an idle lane destroys a live one,
+    so every write is masked); ``ssm`` ``[n_mamba, S, N, C]`` and
+    ``conv`` ``[n_mamba, S, K-1, C]`` as above; its remaining layers
+    hold nothing. ``sambay.layer_rows`` is its map. Every other model
+    carries empty tuples for the ring.
     """
 
     k: jax.Array
@@ -501,6 +513,8 @@ class SlotCache(NamedTuple):
     ssm: Any = ()
     conv: Any = ()
     live: Any = ()
+    ring_k: Any = ()
+    ring_v: Any = ()
 
     def quantized(self) -> bool:
         return self.k.dtype == jnp.int8
@@ -515,7 +529,13 @@ def init_slot_cache(
     ``slots``."""
     kinds = spec.layer_types
     n_ssm = sum(1 for t in kinds if t == "mamba")
-    shape = (spec.depth - n_ssm, slots, spec.total_len, _kv_heads(spec),
+    n_ring = sum(1 for t in kinds if t == "window")
+    # full-length rows: every layer of a plain model; of a table, the
+    # layers that keep their own (the rest hold a ring, a state, or
+    # nothing: they read another layer's rows or none)
+    n_rows = (sum(1 for t in kinds if t in ("attention", "full"))
+              if kinds else spec.depth)
+    shape = (n_rows, slots, spec.total_len, _kv_heads(spec),
              head_dim_of(spec))
     recurrent = {}
     if kinds:
@@ -523,9 +543,16 @@ def init_slot_cache(
         # on lanes"): a minor dimension of Dh 64 has no TPU layout that
         # neither pads nor is relayouted around the kernel
         shape = (*shape[:3], shape[3] * shape[4])
-        inner = spec.mamba_n_heads * spec.mamba_d_head
-        conv_dim = inner + 2 * spec.mamba_n_groups * spec.mamba_d_state
-        recurrent = dict(
+        if spec.mamba_d_inner:  # Mamba-1: the convolution is over xs alone
+            inner = conv_dim = spec.mamba_d_inner
+        else:
+            inner = spec.mamba_n_heads * spec.mamba_d_head
+            conv_dim = inner + 2 * spec.mamba_n_groups * spec.mamba_d_state
+        if n_ring:
+            ring = (n_ring, slots, spec.sliding_window, shape[3])
+            recurrent = dict(ring_k=jnp.zeros(ring, dtype),
+                             ring_v=jnp.zeros(ring, dtype))
+        recurrent.update(
             ssm=jnp.zeros((n_ssm, slots, spec.mamba_d_state, inner),
                           jnp.float32),
             conv=jnp.zeros((n_ssm, slots, spec.mamba_d_conv - 1, conv_dim),

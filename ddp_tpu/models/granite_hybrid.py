@@ -92,6 +92,8 @@ from ddp_tpu.ops.decode import packed_decode_attention, read_lane
 BLOCK = "granite_hybrid"
 MAMBA, ATTENTION = "mamba", "attention"
 INIT_STD = 0.02
+# what the serve engine asks of a block's module (serve/engine.py)
+RECURRENT = True
 
 
 def validate(spec: LMSpec) -> None:

@@ -161,7 +161,12 @@ class LMSpec(NamedTuple):
     # ``granite_hybrid`` is models/granite_hybrid.py's (HF
     # ``granitemoehybrid``: Mamba-2 and position-free GQA layers by
     # ``layer_types``, a dense gated MLP, four scalar multipliers, tied
-    # head, one token a step). Both serving only.
+    # head, one token a step); ``sambay`` is models/sambay.py's (HF
+    # ``phi4flash``: a self-decoder of Mamba-1, windowed and full
+    # differential attention whose one full K/V layer and last state
+    # read-out feed a cross-decoder of gated memory units and
+    # cross-attention; LayerNorm with bias, tied head, no positions,
+    # one token a step). All three serving only.
     block: str = "gpt2"
     head_dim: int = 0  # 0 -> d_model // num_heads
     moe_intermediate: int = 0
@@ -204,6 +209,17 @@ class LMSpec(NamedTuple):
     logits_scaling: float = 1.0
     tie_embeddings: bool = False
     position_embedding: str = ""
+    # The ``sambay`` block. ``layer_types`` over five kinds: "mamba" |
+    # "window" | "full" | "gmu" | "cross". A Mamba-1 layer has
+    # ``mamba_d_inner`` channels with a decay of their own for each of
+    # ``mamba_d_state`` state indices, a time step projected through
+    # ``mamba_dt_rank``, and ``mamba_d_conv`` taps in front. A "window"
+    # layer attends ``sliding_window`` keys, the position's own among
+    # them. ``layer_norm_eps``: every LayerNorm's.
+    mamba_d_inner: int = 0
+    mamba_dt_rank: int = 0
+    sliding_window: int = 0
+    layer_norm_eps: float = 1e-5
 
 
 def head_dim_of(spec: LMSpec) -> int:
@@ -226,7 +242,9 @@ def derive_lm_spec(params: Any, *, num_heads: int, **overrides) -> LMSpec:
     if "embed_tokens" in params:
         # the HF-named trees: the sidecar says which block (a tree with
         # Mamba layers says so itself)
-        if overrides.get("block") == "granite_hybrid" or any(
+        if overrides.get("block") == "sambay" or "final_layernorm" in params:
+            from ddp_tpu.models.sambay import derive_spec
+        elif overrides.get("block") == "granite_hybrid" or any(
             "mamba" in layer for layer in params.get("layers", {}).values()
         ):
             from ddp_tpu.models.granite_hybrid import derive_spec

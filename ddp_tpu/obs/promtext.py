@@ -400,6 +400,21 @@ def render_serve(
          "recurrent state and convolution tail one lane holds"),
         ("kv_bytes_per_slot", "gauge",
          "K/V rows one lane holds, attention layers only"),
+        ("kv_ring_rows_attended_total", "counter",
+         "ring rows decode steps read: min(pos + 1, window) a live lane "
+         "a windowed layer"),
+        ("kv_shared_rows_attended_total", "counter",
+         "shared rows decode steps read: pos + 1 a live lane a reading "
+         "layer (the full layer and its cross-attention readers)"),
+        ("prefill_self_positions_total", "counter",
+         "real prompt positions through the layers that write a lane"),
+        ("prefill_cross_positions_total", "counter",
+         "prompt positions through the layers that only sample: one a "
+         "request where prefill stops at the shared K/V layer"),
+        ("kv_ring_bytes_per_slot", "gauge",
+         "K/V rows one lane holds in its windowed layers' rings"),
+        ("kv_shared_bytes_per_slot", "gauge",
+         "K/V rows one lane holds for the one layer others read"),
     ):
         if key in rs:
             b.add(f"ddp_tpu_serve_{key}", rs[key], metric_type=kind,

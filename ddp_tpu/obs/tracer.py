@@ -69,6 +69,11 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "serve.admit": ("admitted", "chunks"),
     "serve.prefill_chunk": ("rid", "slot", "start", "width", "final"),
     "serve.decode": ("lanes", "rows_attended"),
+    # where a lane holds K/V of more than one kind (models/sambay.py),
+    # where its ``serve.decode`` ends, of no duration and under the
+    # same parent: ring rows and shared rows that step read, summed
+    # over the reading layers, and the live lanes among its ``lanes``
+    "serve.decode_rows": ("ring_rows", "shared_rows", "live_lanes"),
     "serve.spec_verify": ("lanes", "drafted", "accepted"),
     # the dispatch of one forward over every lane's block; unmasked and
     # committed are the last FETCHED report's (a step or two behind)
@@ -99,8 +104,10 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
                    "dead", "operand_dtype", "operand_layout"),
     # ops/ssm.py — one per traced ``pallas_call`` of the state update
-    # (trace time, zero duration): lanes and heads of a lane a grid step
-    # holds. ``kernel`` and ``state_dtype`` are names
+    # (``ssm_state_update``, ``selective_state_update``) or of the
+    # prefill scan (``selective_scan``), at trace time, zero duration:
+    # lanes and heads of a lane a grid step holds (Mamba-1 has no
+    # heads: channels). ``kernel`` and ``state_dtype`` are names
     "ssm.plan": ("kernel", "lanes_per_tile", "heads_per_tile",
                  "state_dtype"),
 }

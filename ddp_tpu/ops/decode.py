@@ -481,11 +481,25 @@ def packed_decode_attention(
             f"and rows that are whole {LANES}-lane groups, got Dh {Dh}, "
             f"row width {W}, {H} query heads"
         )
+    qp = _pack_queries(q, H_kv)
+    out = _packed_call(qp, k, v, pos, layer=layer, block_k=block_k,
+                       interpret=interpret,
+                       scale=Dh**-0.5 if scale is None else scale)
+    return _unpack_outputs(out, H, Dh)
+
+
+def _packed_call(qp, k, v, pos, *, layer: int, block_k: int,
+                 interpret: bool | None, scale: float):
+    """The ``flash_decode`` call over rows stored with heads packed on
+    lanes: ``qp`` ``[S, C, R, 128]``, R queries for each of the C
+    128-lane groups of a stored row, zero outside the lanes of the head
+    a query reads -> ``[S, C, R, 128]``, each query's softmax over its
+    scores applied to the group's whole 128 lanes of V."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    S, C, R, _ = qp.shape
+    L, W = k.shape[2], k.shape[3]
     block_k = decode_block(L, 1, W, k.dtype, block_k)
-    qp = _pack_queries(q, H_kv)
-    C, R = qp.shape[1], qp.shape[2]
     vmem = {"memory_space": pltpu.VMEM}
 
     def kvmap(s, j, pos_ref):
@@ -494,11 +508,8 @@ def packed_decode_attention(
     qspec = pl.BlockSpec(
         (None, C, R, LANES), lambda s, j, pos_ref: (s, 0, 0, 0), **vmem)
     kvspec = pl.BlockSpec((None, None, block_k, W), kvmap, **vmem)
-    out = pl.pallas_call(
-        functools.partial(
-            _packed_heads_kernel,
-            scale=Dh**-0.5 if scale is None else scale, block_k=block_k,
-        ),
+    return pl.pallas_call(
+        functools.partial(_packed_heads_kernel, scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S, L // block_k),
@@ -510,7 +521,84 @@ def packed_decode_attention(
         interpret=interpret,
         name="flash_decode",
     )(pos.astype(jnp.int32), qp, k, v)
-    return _unpack_outputs(out, H, Dh)
+
+
+# ---- differential attention: two softmax maps a head pair -----------------
+#
+# Adjacent heads pair. Query pair p is heads (2p, 2p + 1) = (q1, q2); kv
+# pair g is (k1, k2) and (v1, v2), and ``V_g = [v1 | v2]`` is ``2 Dh``
+# wide; query pairs ``2g, 2g + 1`` read kv pair g. A pair's two maps are
+# ``a1 = softmax(q1 k1^T) V_g`` and ``a2 = softmax(q2 k2^T) V_g``; what
+# is done with them (the subtraction, the norm) is the model's. With
+# ``Dh`` 64 a kv pair is one 128-lane group of a stored row
+# ``[.., H_kv * Dh]``, ``[k1 | k2]`` as it lies, and the four queries of
+# the group, ``[q1 | 0]`` and ``[0 | q2]`` of either query pair, are four
+# rows of ``_packed_call``'s block-diagonal operand: the zeros cancel the
+# other key of the pair, and ``p @ V`` is already the 128-wide value.
+
+
+def diff_decode_attention_reference(q, k, v, pos, *, scale: float | None = None):
+    """``q`` ``[S, H, Dh]``, ``k``/``v`` one layer's rows ``[S, L,
+    H_kv * Dh]``, ``pos`` ``[S]``: lane s attends rows ``<= pos[s]`` ->
+    ``[S, H // 2, 2, 2 * Dh]`` float32, ``[.., 0, :]`` a pair's ``a1``
+    and ``[.., 1, :]`` its ``a2``. The two maps of a pair are formed
+    separately, in :func:`decode_attention_reference`'s arithmetic."""
+    S, H, Dh = q.shape
+    L = k.shape[1]
+    G = k.shape[2] // (2 * Dh)  # kv pairs
+    J = H // (2 * G)  # query pairs a kv pair
+    qg = q.reshape(S, G, J, 2, Dh).astype(jnp.float32)
+    kg = k.reshape(S, L, G, 2, Dh).astype(jnp.float32)
+    vg = v.reshape(S, L, G, 2 * Dh).astype(jnp.float32)
+    logits = jnp.einsum("sgjwd,slgwd->sgjwl", qg, kg) * (
+        Dh**-0.5 if scale is None else scale)
+    live = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, None, None, :]
+    w = jax.nn.softmax(jnp.where(live, logits, -jnp.inf), axis=-1)
+    a = jnp.einsum("sgjwl,slge->sgjwe", w, vg)
+    return a.reshape(S, H // 2, 2, 2 * Dh)
+
+
+def diff_decode_attention(q, k, v, pos, *, layer: int = 0,
+                          impl: str = "reference",
+                          block_k: int = DEFAULT_BLOCK_K,
+                          interpret: bool | None = None,
+                          scale: float | None = None):
+    """The two softmax maps of every head pair, one query a lane, over
+    rows stored ``[depth, S, L, H_kv * Dh]`` -> ``[S, H // 2, 2,
+    2 * Dh]`` (:func:`diff_decode_attention_reference`'s contract).
+    ``pos`` is the last attendable ROW: a full-length lane passes its
+    position, a ring of W rows ``min(pos + 1, W) - 1`` (without
+    positions the order of rows does not matter to a softmax).
+    ``"flash"`` is the ``flash_decode`` grid of
+    :func:`packed_decode_attention` (``Dh`` 64: a kv pair is a 128-lane
+    group)."""
+    S, H, Dh = q.shape
+    if impl == "auto":
+        impl = "flash" if jax.default_backend() == "tpu" else "reference"
+    if impl == "reference":
+        return diff_decode_attention_reference(q, k[layer], v[layer], pos,
+                                               scale=scale)
+    if impl != "flash":
+        raise ValueError(
+            f"unknown decode attention impl {impl!r}: expected "
+            "'auto', 'reference' or 'flash'"
+        )
+    W = k.shape[3]
+    G = W // LANES
+    if 2 * Dh != LANES or W % LANES or H % (4 * G):
+        raise ValueError(
+            f"differential flash_decode needs head pairs of {LANES} lanes "
+            f"and two query pairs a kv pair, got Dh {Dh}, row width {W}, "
+            f"{H} query heads"
+        )
+    J = H // G  # queries a kv pair
+    qg = q.reshape(S, G, J // 2, 2, Dh)
+    eye = jnp.eye(2, dtype=q.dtype)
+    qp = jnp.einsum("sgjwd,wx->sgjwxd", qg, eye).reshape(S, G, J, LANES)
+    out = _packed_call(qp, k, v, pos, layer=layer, block_k=block_k,
+                       interpret=interpret,
+                       scale=Dh**-0.5 if scale is None else scale)
+    return out.reshape(S, H // 2, 2, LANES)
 
 
 def _copy_kernel(slot_ref, src_ref, dst_ref):

@@ -1,4 +1,4 @@
-"""Selective state-space (Mamba-2) operators for serving.
+"""Selective state-space (Mamba-2 and Mamba-1) operators for serving.
 
 A Mamba-2 head ``h`` keeps a state ``S`` in ``R^{P x N}`` (P channels of
 the head, N state dimensions) and advances it one token at a time:
@@ -36,6 +36,14 @@ over sublanes; the ``[H, P, N]`` layout of the equations needs a lane
 broadcast of every per-channel scalar and a lane reduction for every
 ``y``. Same bytes either way (``N * H * P`` float32 a lane a layer).
 
+**Mamba-1** (the second half of the module) decays every (state index,
+channel) pair by its own ``exp(dt_c A_nc)``: ``dt`` one a channel, ``A``
+``[N, C]``, no heads. :func:`selective_state_update` is the decode step
+through :func:`ssm_state_update`'s kernel with the decay formed inside
+it; :func:`selective_scan` is prefill, a Pallas kernel that keeps the
+state of a tile of channels in VMEM and walks the time steps (there is
+no matmul form). Same layout, same rules for idle lanes and padding.
+
 The state is float32 throughout. ``ssd_scan``'s einsums take their
 operands in ``dtype`` (the model passes its weights' dtype: bfloat16 on
 the chip, float32 at ``highest`` in tests) and accumulate in float32;
@@ -59,6 +67,7 @@ from ddp_tpu.obs.tracer import get_tracer
 # float32 is 1 MB in and 1 MB out, double-buffered 4 MB of a v5e's
 # 16 MB of scoped VMEM, beside 2 MB of temporaries.
 DEFAULT_CHANNELS_PER_TILE = 2048
+_TILE_STATE_ROWS = 128  # the N the default was sized at
 
 
 def resolve_impl(impl: str) -> str:
@@ -216,13 +225,21 @@ def state_update_reference(state, layer: int, x, dt, A, B, C, D, live):
 
 
 def _update_kernel(order_ref, n_ref, s_ref, da_ref, dtx_ref, b_ref, c_ref,
-                   o_ref, y_ref):
+                   *rest, per_element: bool):
+    """``per_element``: ``da_ref`` holds ``dt`` ``[1, cb]`` and the
+    operand after ``c_ref`` is ``A`` ``[N, cb]``; the decay of every
+    (state index, channel) pair is formed here (Mamba-1). Otherwise
+    ``da_ref`` is the channels' decay as it stands (Mamba-2: one a
+    head, widened outside)."""
+    *a_ref, o_ref, y_ref = rest
     j, c = pl.program_id(0), pl.program_id(1)
     n = n_ref[0]
 
     @pl.when(j < n)
     def _live():
-        new = s_ref[...] * da_ref[...] + b_ref[...] * dtx_ref[...]
+        decay = jnp.exp(da_ref[...] * a_ref[0][...]) if per_element \
+            else da_ref[...]
+        new = s_ref[...] * decay + b_ref[...] * dtx_ref[...]
         o_ref[...] = new
         y_ref[...] = jnp.sum(new * c_ref[...], axis=0, keepdims=True)
 
@@ -249,28 +266,24 @@ def channels_per_tile(channels: int, want: int | None = None) -> int:
     )
 
 
-def state_update_pallas(state, layer: int, x, dt, A, B, C, D, live, *,
-                        lanes=None, tile: int | None = None,
-                        interpret: bool | None = None):
-    """The kernel. Grid ``(S, H*P / tile)``: step ``(j, c)`` holds
+def _update_call(state, layer: int, da, dtx, B, C, live, *, name: str,
+                 A=None, lanes=None, tile: int | None = None,
+                 interpret: bool | None = None):
+    """The kernel. Grid ``(S, channels / tile)``: step ``(j, c)`` holds
     channels ``c`` of the ``j``-th LIVE lane's state, ``[N, tile]``;
     the steps after the last live lane repeat its last block, which
-    Pallas neither fetches nor writes again, and compute nothing."""
+    Pallas neither fetches nor writes again, and compute nothing.
+    ``da``, ``dtx`` ``[S, channels]`` row vectors, ``B``, ``C``
+    ``[S, N]``; with ``A`` ``[N, channels]`` (the same for every lane,
+    fetched a tile at a time) ``da`` is ``dt`` and the decay is formed
+    in the kernel. Returns (state, ``y`` ``[S, channels]``)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    f32 = lambda a: a.astype(jnp.float32)
-    x, dt, A, B, C, D = f32(x), f32(dt), f32(A), f32(B), f32(C), f32(D)
-    S, H, P = x.shape
+    S = da.shape[0]
     N, HP = state.shape[2], state.shape[3]
     cb = channels_per_tile(HP, tile)
     nc = HP // cb
     order, n_live = lanes if lanes is not None else live_lanes(live)
-    da, dtx, skip = _terms(x, dt, A, D)
-    # Trace time, as ``flash.plan``: a compiled step leaves none.
-    get_tracer().complete(
-        "ssm.plan", time.perf_counter(), 0.0,
-        nums=("ssm_state_update", 1, max(1, cb // P), str(state.dtype)),
-    )
 
     def lane(j, order_ref, n_ref):
         return order_ref[jnp.clip(jnp.minimum(j, n_ref[0] - 1), 0, S - 1)]
@@ -291,12 +304,20 @@ def state_update_pallas(state, layer: int, x, dt, A, B, C, D, live, *,
     state_spec = pl.BlockSpec((None, None, N, cb), state_map, **vmem)
     row_spec = pl.BlockSpec((None, 1, cb), row_map, **vmem)
     col_spec = pl.BlockSpec((None, N, 1), col_map, **vmem)
+    in_specs = [state_spec, row_spec, row_spec, col_spec, col_spec]
+    args = [state, da[:, None, :], dtx[:, None, :], B[:, :, None],
+            C[:, :, None]]
+    if A is not None:
+        in_specs.append(pl.BlockSpec(
+            (N, cb), lambda j, c, order_ref, n_ref: (0, chan(j, c, n_ref)),
+            **vmem))
+        args.append(A)
     state, y = pl.pallas_call(
-        _update_kernel,
+        functools.partial(_update_kernel, per_element=A is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S, nc),
-            in_specs=[state_spec, row_spec, row_spec, col_spec, col_spec],
+            in_specs=in_specs,
             out_specs=[state_spec, row_spec],
         ),
         out_shape=[
@@ -306,9 +327,29 @@ def state_update_pallas(state, layer: int, x, dt, A, B, C, D, live, *,
         # operand 2 (after the two prefetched scalars) is the state
         input_output_aliases={2: 0},
         interpret=interpret,
-        name="ssm_state_update",
-    )(order, n_live, state, da[:, None, :], dtx[:, None, :],
-      B[:, :, None], C[:, :, None])
+        name=name,
+    )(order, n_live, *args)
+    return state, y.reshape(S, HP)
+
+
+def state_update_pallas(state, layer: int, x, dt, A, B, C, D, live, *,
+                        lanes=None, tile: int | None = None,
+                        interpret: bool | None = None):
+    """Mamba-2's update through :func:`_update_call`: one decay a
+    head, widened to its channels outside the kernel."""
+    f32 = lambda a: a.astype(jnp.float32)
+    x, dt, A, B, C, D = f32(x), f32(dt), f32(A), f32(B), f32(C), f32(D)
+    S, H, P = x.shape
+    da, dtx, skip = _terms(x, dt, A, D)
+    state, y = _update_call(
+        state, layer, da, dtx, B, C, live, name="ssm_state_update",
+        lanes=lanes, tile=tile, interpret=interpret)
+    # Trace time, as ``flash.plan``: a compiled step leaves none.
+    cb = channels_per_tile(H * P, tile)
+    get_tracer().complete(
+        "ssm.plan", time.perf_counter(), 0.0,
+        nums=("ssm_state_update", 1, max(1, cb // P), str(state.dtype)),
+    )
     y = y.reshape(S, H, P) + skip
     return state, jnp.where(live[:, None, None], y, 0.0)
 
@@ -334,3 +375,176 @@ def ssm_state_update(state, layer: int, x, dt, A, B, C, D, live, *,
             interpret=interpret,
         )
     return state_update_reference(state, layer, x, dt, A, B, C, D, live)
+
+
+# ---- Mamba-1: a decay for every (state index, channel) pair -------------
+#
+# ``h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] B_t[n] x_t[c]``,
+# ``y_t[c] = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]``: ``dt`` one a channel,
+# ``A`` ``[N, C]`` stored as the state is laid out (state index on
+# sublanes, channels on lanes). There are no heads, and the decay cannot
+# be widened from a per-head scalar outside the kernel without writing an
+# array the size of the state: it is formed inside, from ``dt`` and a
+# tile of ``A``.
+
+
+def selective_update_reference(state, layer: int, x, dt, A, B, C, D, live):
+    """Plain ``jax.numpy``: what the kernel is pinned against."""
+    f32 = lambda a: a.astype(jnp.float32)
+    x, dt, A, B, C, D = f32(x), f32(dt), f32(A), f32(B), f32(C), f32(D)
+    old = state[layer]  # [S, N, C]
+    new = (old * jnp.exp(dt[:, None, :] * A[None])
+           + B[:, :, None] * (dt * x)[:, None, :])
+    y = jnp.sum(new * C[:, :, None], axis=1) + x * D[None, :]
+    keep = live[:, None, None]
+    state = state.at[layer].set(jnp.where(keep, new, old))
+    return state, jnp.where(live[:, None], y, 0.0)
+
+
+def selective_state_update(state, layer: int, x, dt, A, B, C, D, live, *,
+                           impl: str = "auto", lanes=None,
+                           tile: int | None = None,
+                           interpret: bool | None = None):
+    """Advance the live lanes of ``layer`` one token, Mamba-1.
+
+    ``state`` ``[layers, S, N, C]`` float32, the whole stored buffer
+    (donated; ``layer`` static); ``x``, ``dt`` ``[S, C]`` (``dt`` after
+    softplus), ``A`` ``[N, C]`` (negative), ``B``, ``C`` ``[S, N]``,
+    ``D`` ``[C]``, ``live`` ``[S]`` bool. Returns (the buffer with the
+    live lanes' states of ``layer`` advanced and every other byte as it
+    was, ``y`` ``[S, C]`` float32 with the ``D`` term, zero on an idle
+    lane). On the chip :func:`ssm_state_update`'s kernel under the name
+    ``selective_state_update``: same grid over live lanes and channel
+    tiles, same in-place write."""
+    if resolve_impl(impl) != "pallas":
+        return selective_update_reference(state, layer, x, dt, A, B, C, D,
+                                          live)
+    f32 = lambda a: a.astype(jnp.float32)
+    x, dt, A, B, C, D = f32(x), f32(dt), f32(A), f32(B), f32(C), f32(D)
+    # The same BYTES a grid step as the default holds at N 128: with 16
+    # state indices a lane's whole [16, 5120] layer (328 KB) is one step,
+    # where tiles of 1280 took 4 steps of 82 KB and the steps' own cost
+    # (~0.4 us) was two thirds of a call (PERF.md section 6, PR 36).
+    tile = tile or (DEFAULT_CHANNELS_PER_TILE * _TILE_STATE_ROWS
+                    // state.shape[2])
+    state, y = _update_call(
+        state, layer, dt, dt * x, B, C, live, A=A,
+        name="selective_state_update", lanes=lanes, tile=tile,
+        interpret=interpret)
+    get_tracer().complete(
+        "ssm.plan", time.perf_counter(), 0.0,
+        nums=("selective_state_update", 1,
+              channels_per_tile(x.shape[1], tile), str(state.dtype)),
+    )
+    return state, jnp.where(live[:, None], y + x * D[None, :], 0.0)
+
+
+def selective_scan_reference(x, dt, A, B, C, state):
+    """The recurrence one token after another (``lax.scan``)."""
+
+    def step(h, t):
+        x_t, dt_t, B_t, C_t = t
+        h = h * jnp.exp(dt_t[None, :] * A) + B_t[:, None] * (dt_t * x_t)[None]
+        return h, jnp.sum(h * C_t[:, None], axis=0)
+
+    state, y = lax.scan(step, state, (x, dt, B, C))
+    return y, state
+
+
+# Time steps a grid step of the scan holds, and the steps unrolled
+# between two aligned stores of ``y``: [8, tile] float32 is one row of
+# vregs.
+SCAN_TIME_BLOCK = 128
+_SCAN_UNROLL = 8
+
+
+def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s_ref, y_ref, o_ref,
+                 h_ref):
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _start():
+        h_ref[...] = s_ref[...]
+
+    A = a_ref[...]
+
+    def group(g, h):
+        rows = pl.ds(pl.multiple_of(g * _SCAN_UNROLL, _SCAN_UNROLL),
+                     _SCAN_UNROLL)
+        dt, dtx = dt_ref[rows, :], dt_ref[rows, :] * x_ref[rows, :]
+        ys = []
+        for i in range(_SCAN_UNROLL):
+            r = g * _SCAN_UNROLL + i
+            h = h * jnp.exp(dt[i:i + 1] * A) + b_ref[r] * dtx[i:i + 1]
+            ys.append(jnp.sum(h * c_ref[r], axis=0, keepdims=True))
+        y_ref[rows, :] = jnp.concatenate(ys, axis=0)
+        return h
+
+    h_ref[...] = lax.fori_loop(0, x_ref.shape[0] // _SCAN_UNROLL, group,
+                               h_ref[...])
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _end():
+        o_ref[...] = h_ref[...]
+
+
+def selective_scan_pallas(x, dt, A, B, C, state, *, tile: int | None = None,
+                          interpret: bool | None = None):
+    """Grid ``(channels / tile, T / SCAN_TIME_BLOCK)``, time innermost:
+    the state of a tile of channels stays in VMEM while the kernel
+    walks the time steps; ``x``, ``dt``, ``B``, ``C`` are read once and
+    ``y`` written once."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    T, Cn = x.shape
+    N = A.shape[0]
+    tb = min(SCAN_TIME_BLOCK, T + -T % _SCAN_UNROLL)
+    pad = -T % tb
+    if pad:
+        zero = lambda a: jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+        x, dt, B, C = zero(x), zero(dt), zero(B), zero(C)
+    cb = channels_per_tile(Cn, tile or 1024)
+    get_tracer().complete(
+        "ssm.plan", time.perf_counter(), 0.0,
+        nums=("selective_scan", 1, cb, str(state.dtype)),
+    )
+    vmem = {"memory_space": pltpu.VMEM}
+    row = pl.BlockSpec((tb, cb), lambda c, t: (t, c), **vmem)
+    col = pl.BlockSpec((tb, N, 1), lambda c, t: (t, 0, 0), **vmem)
+    tile_spec = pl.BlockSpec((N, cb), lambda c, t: (0, c), **vmem)
+    y, state = pl.pallas_call(
+        _scan_kernel,
+        grid=(Cn // cb, (T + pad) // tb),
+        in_specs=[row, row, col, col, tile_spec, tile_spec],
+        out_specs=[row, tile_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((T + pad, Cn), jnp.float32),
+            jax.ShapeDtypeStruct((N, Cn), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((N, cb), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="selective_scan",
+    )(x, dt, B[:, :, None], C[:, :, None], A, state)
+    return y[:T], state
+
+
+@jax.named_scope("selective_scan")
+def selective_scan(x, dt, A, B, C, state, *, impl: str = "auto",
+                   tile: int | None = None, interpret: bool | None = None):
+    """The Mamba-1 recurrence over ``T`` tokens of one lane.
+
+    ``x``, ``dt`` ``[T, C]`` (``dt`` after softplus; 0 at a position
+    that must not move the state), ``A`` ``[N, C]`` (negative), ``B``,
+    ``C`` ``[T, N]``, ``state`` ``[N, C]`` float32 the state before the
+    first token -> (``y`` ``[T, C]`` WITHOUT the ``D`` term, the state
+    after the last token). There is no matmul form: the decay differs
+    by state index. ``"pallas"`` is :func:`selective_scan_pallas`;
+    ``"jnp"`` a ``lax.scan`` of ``T`` dependent steps."""
+    f32 = lambda a: a.astype(jnp.float32)
+    x, dt, A, B, C, state = f32(x), f32(dt), f32(A), f32(B), f32(C), f32(state)
+    if resolve_impl(impl) == "pallas":
+        return selective_scan_pallas(x, dt, A, B, C, state, tile=tile,
+                                     interpret=interpret)
+    return selective_scan_reference(x, dt, A, B, C, state)

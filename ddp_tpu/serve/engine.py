@@ -52,6 +52,7 @@ compiled shape, so per-request values would recompile per mix.
 
 from __future__ import annotations
 
+import importlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -88,12 +89,33 @@ from ddp_tpu.serve.scheduler import (
 )
 from ddp_tpu.utils.metrics import MetricsWriter, StatSummary
 
-# The block whose lanes hold recurrent state beside K/V rows
-# (models/granite_hybrid.py, imported where a spec asks for it), and
-# why each knob below has no meaning yet for such a lane: its history
-# is a state, not rows that can be paged, shared, rolled back or
-# re-quantized.
-_HYBRID = "granite_hybrid"
+# The blocks that bring their own one-token programs, by the module
+# that holds each (imported where a spec asks for it, so that no other
+# model's start-up pays). What the engine asks of such a module:
+# ``validate(spec)``; ``prefill_chunk`` and ``slot_decode_sample_step``
+# under the GPT-2 path's signatures; ``RECURRENT``, whether a lane
+# holds state that a step it was not owed would spoil, so that a decode
+# step advances only the lanes ``cache.live`` names; and, where a lane
+# holds K/V of more than one kind, ``attended_rows(spec, rows)``, what
+# a decode step reads of each. The step loop holds three jitted
+# callables and no model's name.
+_BLOCK_MODULES = {
+    "granite_hybrid": "ddp_tpu.models.granite_hybrid",
+    "sambay": "ddp_tpu.models.sambay",
+}
+
+
+def block_module(spec: LMSpec):
+    """The module of ``spec.block``'s one-token programs; None for the
+    blocks the engine holds itself (``gpt2``, and ``qwen3_moe`` by
+    blocks)."""
+    name = _BLOCK_MODULES.get(spec.block)
+    return importlib.import_module(name) if name else None
+
+
+# Why each knob below has no meaning yet for a recurrent lane: its
+# history is a state, not rows that can be paged, shared, rolled back
+# or re-quantized.
 RECURRENT_REFUSALS = {
     "page_size": "pages and the radix prefix cache share K/V rows "
     "between lanes; a recurrent state at a page boundary would have to "
@@ -401,25 +423,25 @@ def resolve_engine_knobs(
     # How the model generates is its spec's to say, no flag's. Block
     # diffusion (models/sdar.py): a lane holds a block of ``block_len``
     # positions and a step is one forward over it; a block that
-    # generates by blocks needs a block length. The hybrid block
-    # (models/granite_hybrid.py) needs none: it decodes one token a
+    # generates by blocks needs a block length. A block with a module
+    # of its own (``block_module``) needs none: it decodes one token a
     # step like ``gpt2``, with recurrent state beside its K/V rows.
     block_len = int(spec.block_length)
-    recurrent = spec.block == _HYBRID
+    module = block_module(spec)
+    recurrent = bool(module and module.RECURRENT)
+    if module:
+        module.validate(spec)
     if recurrent:
-        from ddp_tpu.models import granite_hybrid
-
-        granite_hybrid.validate(spec)
         for knob, given in (
             ("page_size", paged), ("kv_dtype", kv_dtype != "fp32"),
             ("spec_tokens", spec_tokens),
         ):
             if given:
                 raise ValueError(
-                    f"{knob} does not apply to the {_HYBRID} block: "
+                    f"{knob} does not apply to the {spec.block} block: "
                     f"{RECURRENT_REFUSALS[knob]}"
                 )
-    elif block_len or spec.block != "gpt2":
+    elif module is None and (block_len or spec.block != "gpt2"):
         _sdar.validate(spec)
         if paged or kv_dtype != "fp32" or spec_tokens:
             raise ValueError(
@@ -612,8 +634,8 @@ class ServeEngine:
         # > 0, a decoding lane holds a block of this many positions and
         # a step runs one forward over it (``_block_round``).
         self.block_len = knobs["block_len"]
-        # A lane holds recurrent state beside its K/V rows
-        # (models/granite_hybrid.py). A decode step then advances only
+        # A lane holds recurrent state beside its K/V rows (the
+        # spec's block says so). A decode step then advances only
         # the lanes ``cache.live`` names; the engine owns that mask and
         # uploads it when the decoding set changes (as it does a paged
         # table), never on a steady step.
@@ -842,15 +864,17 @@ class ServeEngine:
         # per-engine. Each gets a name of its own, which is how the
         # profiler's trace tells the programs apart
         # (``jit_serve_decode``, ``jit_serve_prefill_first``, ...).
-        # The one-token programs are the spec's block's: the hybrid
-        # block brings its own chunk and decode functions under the
-        # GPT-2 path's signatures, so everything below, and the step
-        # loop, holds three jitted callables and asks no more.
-        if self.recurrent:
-            from ddp_tpu.models import granite_hybrid
-
-            _prefill_chunk = granite_hybrid.prefill_chunk
-            _decode_sample = granite_hybrid.slot_decode_sample_step
+        # The one-token programs are the spec's block's: a block with
+        # a module of its own brings its chunk and decode functions
+        # under the GPT-2 path's signatures, so everything below, and
+        # the step loop, holds three jitted callables and asks no more.
+        module = block_module(spec)
+        # K/V of more than one kind in a lane: what a decode step
+        # reads of each is the module's to count.
+        self._attended_rows = getattr(module, "attended_rows", None)
+        if module:
+            _prefill_chunk = module.prefill_chunk
+            _decode_sample = module.slot_decode_sample_step
         else:
             _prefill_chunk, _decode_sample = _gpt2_chunk, _gpt2_decode
 
@@ -1023,6 +1047,15 @@ class ServeEngine:
         self.ssm_lane_updates_total = 0
         self.ssm_prefill_tokens_total = 0
         self.ssm_state_resets_total = 0
+        # A lane with a ring and shared rows (``attended_rows``): rows
+        # a decode step read of each kind, summed over live lanes,
+        # reading layers and steps; and a prompt's real positions
+        # through the layers that write a lane and through those that
+        # only sample (whichever program ran them).
+        self.kv_ring_rows_attended_total = 0
+        self.kv_shared_rows_attended_total = 0
+        self.prefill_self_positions_total = 0
+        self.prefill_cross_positions_total = 0
         # Engine-lifetime speculative tallies (the /stats + bench
         # acceptance-rate source); zero-cost when speculation is off.
         self.spec_drafted_total = 0
@@ -1325,6 +1358,14 @@ class ServeEngine:
             leaves += [self._cache.k_scale, self._cache.v_scale]
         return sum(int(x.nbytes) for x in leaves) // self.num_slots
 
+    def ring_bytes_per_slot(self) -> int:
+        """K/V rows one lane holds in rings (windowed layers); 0 for a
+        model without them."""
+        leaves = (getattr(self._cache, "ring_k", ()),
+                  getattr(self._cache, "ring_v", ()))
+        return sum(int(x.nbytes) for x in leaves
+                   if hasattr(x, "nbytes")) // self.num_slots
+
     def state_bytes_per_slot(self) -> int:
         """Recurrent state per lane (every Mamba layer's state and
         convolution tail); 0 for a model without such layers."""
@@ -1336,7 +1377,8 @@ class ServeEngine:
     def cache_bytes_per_slot(self) -> int:
         """HBM one decode lane holds, K/V and recurrent state both —
         and with it how many ``slots`` a chip holds."""
-        return self.kv_bytes_per_slot() + self.state_bytes_per_slot()
+        return (self.kv_bytes_per_slot() + self.ring_bytes_per_slot()
+                + self.state_bytes_per_slot())
 
     def recurrent_stats(self) -> dict:
         """The recurrent lanes' counters and gauges (``/stats``'s
@@ -1348,6 +1390,18 @@ class ServeEngine:
             "ssm_state_resets_total": self.ssm_state_resets_total,
             "ssm_state_bytes_per_slot": self.state_bytes_per_slot(),
             "kv_bytes_per_slot": self.kv_bytes_per_slot(),
+            **({
+                "kv_ring_rows_attended_total":
+                    self.kv_ring_rows_attended_total,
+                "kv_shared_rows_attended_total":
+                    self.kv_shared_rows_attended_total,
+                "prefill_self_positions_total":
+                    self.prefill_self_positions_total,
+                "prefill_cross_positions_total":
+                    self.prefill_cross_positions_total,
+                "kv_ring_bytes_per_slot": self.ring_bytes_per_slot(),
+                "kv_shared_bytes_per_slot": self.kv_bytes_per_slot(),
+            } if self._attended_rows else {}),
         }
 
     def page_stats(self) -> Optional[dict]:
@@ -1386,7 +1440,7 @@ class ServeEngine:
         """
         if self.recurrent:
             raise ValueError(
-                f"export_prefix does not apply to the {_HYBRID} block: "
+                f"export_prefix does not apply to the {self.spec.block} block: "
                 f"{RECURRENT_REFUSALS['export_prefix']}"
             )
         if not self.paged:
@@ -1458,7 +1512,7 @@ class ServeEngine:
         if self.recurrent:
             raise PageWireError(
                 SHAPE_MISMATCH,
-                f"install_prefix does not apply to the {_HYBRID} block: "
+                f"install_prefix does not apply to the {self.spec.block} block: "
                 f"{RECURRENT_REFUSALS['install_prefix']}",
             )
         if not self.paged:
@@ -1991,6 +2045,11 @@ class ServeEngine:
                 # a first chunk starts the lane's state from zero
                 self.ssm_state_resets_total += start == 0
                 self.ssm_prefill_tokens_total += live
+            if self._attended_rows:
+                # the final chunk's last position alone goes on through
+                # the layers that write nothing into a lane
+                self.prefill_self_positions_total += live
+                self.prefill_cross_positions_total += bool(final)
             if self._reqtrace is not None:
                 tr = self._reqtrace.get(req.rid)
                 if tr is not None:
@@ -2038,6 +2097,15 @@ class ServeEngine:
             )
             self.kv_rows_attended_total += rows
             self.kv_rows_lane_total += self.num_slots * self.spec.total_len
+            if self._attended_rows:
+                # over the LIVE lanes: a lane that has its last token
+                # and waits to be retired is not advanced
+                ring, shared = self._attended_rows(self.spec, [
+                    len(self._slots[i].request.prompt)
+                    + self._slots[i].emitted for i in emit_lanes
+                ])
+                self.kv_ring_rows_attended_total += ring
+                self.kv_shared_rows_attended_total += shared
             if self.recurrent:
                 self._name_live_lanes(emit_lanes)
             with tracer.span(
@@ -2049,6 +2117,11 @@ class ServeEngine:
                     self._sample_steps, self._temps, self._top_ps,
                 )
             t0 = span.t0
+            if self._attended_rows:
+                tracer.complete(
+                    "serve.decode_rows", time.perf_counter(), 0.0,
+                    parent=parent, nums=(ring, shared, len(emit_lanes)),
+                )
             device_work = True
             for i in emit_lanes:
                 self._slots[i].emitted += 1

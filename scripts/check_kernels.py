@@ -455,6 +455,125 @@ def check_ssm_update(S: int, H: int, P: int, N: int, layers: int,
     }
 
 
+def check_decode_diff(S: int, H: int, H_kv: int, L: int, depth: int,
+                      ring: bool) -> dict:
+    """The two softmax maps of every head pair (differential
+    attention, heads of 64) through ``flash_decode`` on rows stored
+    ``[depth, S, L, H_kv * 64]`` against the reference that forms each
+    map separately; the last layer. ``ring``: ``L`` is a window's ring
+    and the position passed is the count of valid rows less one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_tpu.ops.decode import diff_decode_attention
+
+    Dh = 64
+    kq, kk, kv = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(kq, (S, H, Dh), jnp.float32)
+    k = jax.random.normal(kk, (depth, S, L, H_kv * Dh), jnp.float32)
+    v = jax.random.normal(kv, (depth, S, L, H_kv * Dh), jnp.float32)
+    pos = np.resize([0, 127, 128, L // 2, L - 1, 1, 5 * L // 3, 3 * L], S)
+    pos = jnp.asarray(np.minimum(pos, L - 1) if ring else pos % L, jnp.int32)
+
+    def call(impl):
+        return jax.jit(lambda q, k, v: diff_decode_attention(
+            q, k, v, pos, layer=depth - 1, impl=impl))(q, k, v)
+
+    out = call("flash")
+    with jax.default_matmul_precision("highest"):
+        ref = call("reference")
+    err = _max_err(out, ref)
+    return {
+        "max_abs_err": err, "tol": TOL["decode"],
+        "ok": bool(jnp.isfinite(out).all()) and err <= TOL["decode"],
+    }
+
+
+def check_selective_update(S: int, C: int, N: int, layers: int,
+                           live_every: int) -> dict:
+    """``selective_state_update`` (a decay for every state index and
+    channel, formed in the kernel) against ``jnp`` on the stored
+    ``[layers, S, N, C]`` state, the middle layer, with every
+    ``live_every``-th lane idle: live lanes to float32 rounding, idle
+    lanes and the other layers bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_tpu.ops import ssm
+
+    k = jax.random.split(jax.random.key(7), 6)
+    state = jax.random.normal(k[0], (layers, S, N, C), jnp.float32)
+    args = (jax.random.normal(k[1], (S, C)),
+            jax.nn.softplus(jax.random.normal(k[2], (S, C)) - 2.0),
+            -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, C)),
+            jax.random.normal(k[3], (S, N)), jax.random.normal(k[4], (S, N)),
+            jnp.ones((C,)))
+    live = jnp.asarray(np.arange(S) % live_every != live_every - 1)
+    layer = layers // 2
+
+    def call(impl):
+        return jax.jit(lambda s, *a: ssm.selective_state_update(
+            s, layer, *a, live, impl=impl))(state, *args)
+
+    got_s, got_y = call("pallas")
+    want_s, want_y = call("jnp")
+    idle = ~np.asarray(live)
+    untouched = (
+        bool(jnp.array_equal(got_s[layer][idle], state[layer][idle]))
+        and bool(jnp.array_equal(got_s[:layer], state[:layer]))
+        and bool(jnp.array_equal(got_s[layer + 1:], state[layer + 1:]))
+    )
+    err = max(_max_err(got_s, want_s), _max_err(got_y, want_y))
+    return {
+        "max_abs_err": err, "tol": TOL["ssm"],
+        "idle_lanes_and_other_layers_bit_equal": untouched,
+        "live_lanes": int(live.sum()),
+        "ok": bool(jnp.isfinite(got_y).all()) and untouched
+        and err <= TOL["ssm"],
+    }
+
+
+def check_selective_scan(T: int, C: int, N: int, real: int) -> dict:
+    """``selective_scan`` over ``T`` positions of which ``real`` are
+    real (``dt`` 0 after them) from a non-zero carried state, against
+    the sequential ``lax.scan``; and the device ms a call."""
+    import time as _time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops import ssm
+
+    k = jax.random.split(jax.random.key(9), 6)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (T, C)) - 2.0)
+    args = (jax.random.normal(k[0], (T, C)),
+            jnp.where((jnp.arange(T) < real)[:, None], dt, 0.0),
+            -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, C)),
+            jax.random.normal(k[2], (T, N)), jax.random.normal(k[3], (T, N)),
+            jax.random.normal(k[4], (N, C)))
+
+    def call(impl):
+        return jax.jit(lambda *a: ssm.selective_scan(*a, impl=impl))
+
+    fn = call("pallas")
+    got_y, got_s = jax.block_until_ready(fn(*args))
+    t = _time.perf_counter()
+    for _ in range(10):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    wall_ms = (_time.perf_counter() - t) * 100.0
+    want_y, want_s = call("jnp")(*args)
+    # 512 dependent steps of a sum of 16 products: rounding accumulates
+    tol = 10 * TOL["ssm"]
+    err = max(_max_err(got_s, want_s), _max_err(got_y[:real], want_y[:real]))
+    on_chip = jax.default_backend() == "tpu"  # a CPU's time is no reading
+    return {"max_abs_err": err, "tol": tol,
+            "wall_ms_per_call": wall_ms if on_chip else None,
+            "ok": bool(jnp.isfinite(got_y).all()) and err <= tol}
+
+
 def cases(tiny: bool, every: bool):
     if tiny:
         flash = dict(B=1, T=128, H=2, D=128, block=64)
@@ -520,6 +639,25 @@ def cases(tiny: bool, every: bool):
         upd = (dict(S=3, H=2, P=64, N=16, layers=3, live_every=2) if tiny
                else dict(S=64, H=64, P=64, N=128, layers=3, live_every=4))
         yield "ssm_state_update_fp32", lambda: check_ssm_update(**upd)
+        # The SambaY decoder (models/sambay.py) at the benchmark's
+        # widths: the two maps of 20 head pairs over 10 kv pairs, on a
+        # window's ring of 512 rows (8 layers stored) and on the shared
+        # rows of 4096; the per-element update of 64 lanes of [16,
+        # 5120], every fourth idle; the prefill scan of a chunk of 512
+        # with a padded end.
+        diff = (dict(S=2, H=8, H_kv=4, depth=2) if tiny
+                else dict(S=64, H=40, H_kv=20, depth=2))
+        yield "decode_fp32_diff_dh64_ring", lambda: check_decode_diff(
+            **diff, L=256 if tiny else 512, ring=True)
+        yield "decode_fp32_diff_dh64_shared", lambda: check_decode_diff(
+            **dict(diff, depth=1), L=256 if tiny else 4096, ring=False)
+        sel = (dict(S=3, C=256, N=4, layers=3, live_every=2) if tiny
+               else dict(S=64, C=5120, N=16, layers=3, live_every=4))
+        yield "selective_state_update_fp32", lambda: check_selective_update(
+            **sel)
+        scan = (dict(T=40, C=256, N=4, real=33) if tiny
+                else dict(T=512, C=5120, N=16, real=389))
+        yield "selective_scan_fp32", lambda: check_selective_scan(**scan)
 
 
 def main() -> int:

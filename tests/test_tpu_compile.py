@@ -209,6 +209,67 @@ def test_decode_kernel_compiles_with_heads_packed_on_lanes(
     assert lane.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
+# ---- the SambaY decoder's kernels at Phi-4-mini-flash-reasoning's widths ----
+
+
+def test_selective_kernels_compile_at_published_widths(one_chip, as_on_tpu):
+    """64 lanes of ``[16, 5120]`` state, 9 layers stored: one
+    ``selective_state_update`` call whose output IS the stored buffer,
+    the decay formed in the kernel from ``dt`` and a tile of ``A``; and
+    the prefill scan of a chunk of 512 with the state of a tile of
+    channels held in VMEM."""
+    from ddp_tpu.ops import ssm
+
+    S, C, N, layers, T = 64, 5120, 16, 9, 512
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda s, x, dt, A, B, Cc, D, live: ssm.selective_state_update(
+            s, 4, x, dt, A, B, Cc, D, live, impl="pallas"),
+        donate_argnums=(0,),
+    ).lower(
+        _shape((layers, S, N, C), f32, one_chip),
+        _shape((S, C), f32, one_chip), _shape((S, C), f32, one_chip),
+        _shape((N, C), f32, one_chip), _shape((S, N), f32, one_chip),
+        _shape((S, N), f32, one_chip), _shape((C,), f32, one_chip),
+        _shape((S,), jnp.bool_, one_chip),
+    ).compile()
+    assert "selective_state_update" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == layers * S * N * C * 4
+    assert mem.temp_size_in_bytes < 16 * 2**20
+    scan = jax.jit(
+        lambda x, dt, A, B, Cc, s: ssm.selective_scan(
+            x, dt, A, B, Cc, s, impl="pallas")
+    ).lower(
+        _shape((T, C), f32, one_chip), _shape((T, C), f32, one_chip),
+        _shape((N, C), f32, one_chip), _shape((T, N), f32, one_chip),
+        _shape((T, N), f32, one_chip), _shape((N, C), f32, one_chip),
+    ).compile()
+    assert "selective_scan" in scan.as_text()
+    assert scan.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
+@pytest.mark.parametrize("L,depth", [(512, 8), (4096, 1)])
+def test_differential_decode_kernel_compiles_over_ring_and_shared_rows(
+        one_chip, as_on_tpu, L, depth):
+    """The two maps of 20 head pairs over 10 kv pairs of 128 lanes on a
+    window's ring (8 layers of 512 rows) and on the shared rows (one
+    layer of 4096): ``flash_decode`` takes the stored buffer as it is."""
+    from ddp_tpu.ops.decode import diff_decode_attention
+
+    S, H, Hkv, Dh = 64, 40, 20, 64
+    kv = _shape((depth, S, L, Hkv * Dh), jnp.float32, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, pos: diff_decode_attention(
+            q, k, v, pos, layer=depth - 1, impl="flash")
+    ).lower(
+        _shape((S, H, Dh), jnp.float32, one_chip), kv, kv,
+        _shape((S,), jnp.int32, one_chip),
+    ).compile()
+    assert "flash_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
 @pytest.fixture(scope="module")
 def ddp4_schedule(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
