@@ -110,6 +110,17 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # heads: channels). ``kernel`` and ``state_dtype`` are names
     "ssm.plan": ("kernel", "lanes_per_tile", "heads_per_tile",
                  "state_dtype"),
+    # ops/decode.py — one per traced ``flash_decode`` call over rows
+    # stored with heads packed on lanes (``_packed_call``), at trace
+    # time, zero duration: the kernel's form (``walk``: a name; the
+    # kernel copies a lane's live rows itself), grid steps a lane, rows
+    # an absorb takes and rows a copy holds, copies in flight a stream,
+    # the stored row's width and the lane's length, and whether a
+    # lane's last copy is cut to its live rows' tiles or fetched in
+    # whole absorbs (0: ``ops.decode.fetched_rows``)
+    "decode.plan": ("form", "steps_per_lane", "absorb_rows", "copy_rows",
+                    "copies_in_flight", "row_width", "lane_rows",
+                    "last_copy_cut"),
 }
 # Spans in which the host WAITS for the device (the blocking token
 # fetch): host time, but not host work.

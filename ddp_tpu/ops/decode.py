@@ -34,6 +34,12 @@ sibling:
   dequantize at the compute site — the kernel widens int8 blocks in
   VMEM, so HBM reads stay quarter-width (the whole point of
   quantizing: decode is cache-bandwidth bound).
+- :func:`packed_decode_attention`, :func:`diff_decode_attention` — rows
+  stored with kv heads packed on lanes (head sizes under 128) go
+  through a ``flash_decode`` call of ONE grid step a lane whose kernel
+  walks the lane's live rows itself (``_walk_kernel``: double-buffered
+  copies it starts, :func:`fetched_rows` of them, the same absorbs):
+  a lane costs by the rows it holds, not by its length.
 - :func:`shard_decode_attention` — mesh composition: the compiled
   Mosaic call has no partitioning rule (same wall as
   ``ops/attention.gspmd_flash_attention``), so TP serving routes the
@@ -56,6 +62,7 @@ elementwise tolerance, the same contract ops/flash.py tests use.
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +70,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddp_tpu.obs.tracer import get_tracer
 from ddp_tpu.ops.flash import pick_block
 
 # Per-row stats ride broadcast across the minor 128-lane dim (the
@@ -408,6 +416,12 @@ def _per_head_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, scale, block_k):
 # and ``p @ V`` gives ``[P G, 128]`` whose diagonal blocks are the
 # heads' outputs. The MXU multiplies P times the zeros; at a few rows a
 # dot that is nothing beside the block's DMA.
+#
+# This call's grid is ``(S,)``: one step a lane, and the kernel walks the
+# lane's live rows itself (``_walk_kernel``). On a grid of ``(S, L //
+# block_k)`` a dead step fetched and computed nothing yet cost its
+# ~0.3 us, 21 of a lane's 32 at 1,350 rows of 4,096: a third of the
+# call. ``flash_decode_attention`` above keeps the grid skeleton.
 
 
 def _pack_queries(q, H_kv: int):
@@ -430,21 +444,117 @@ def _unpack_outputs(o, H: int, Dh: int):
     ).reshape(S, H, Dh)
 
 
-def _packed_heads_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                         m_ref, l_ref, *, scale, block_k):
-    """Per 128-lane group of the stored rows: its P kv heads' queries
-    (block-diagonal) against the group's ``[block_k, 128]`` keys."""
+def copy_rows(L: int, W: int, block_k: int) -> int:
+    """Rows one copy of the walk holds: whole absorbs of ``block_k``
+    rows, as many as tile the lane in EQUAL copies under the VMEM cap
+    :func:`decode_block` applies (``_MAX_BLOCK_BYTES`` of float32 a
+    stream and slot): 256 rows of 1,280 lanes in lanes of 512 or 4,096,
+    1,024 rows of 512 lanes. A copy is awaited whole and absorbed while
+    the next one flies, so copies of one size keep the two in step: a
+    full ring of 512 rows read as 384 + 128 took 0.664 ms a call on a
+    v5e where 256 + 256 took 0.466 (``scripts/check_kernels.py
+    --time``)."""
+    cap = min(L, max(32, _MAX_BLOCK_BYTES // (W * 4)))
+    return max(n for n in range(block_k, cap + 1, block_k) if L % n == 0)
 
-    def body(j, pos):
-        for c in range(q_ref.shape[0]):
-            lanes = slice(c * LANES, (c + 1) * LANES)
-            kb = k_ref[:, lanes].astype(jnp.float32)  # [block_k, 128]
-            vb = v_ref[:, lanes].astype(jnp.float32)
-            q = q_ref[c].astype(jnp.float32) * scale  # [P G, 128]
-            _absorb_block(c, q, kb, vb, j, pos, block_k,
-                          acc_ref, m_ref, l_ref)
 
-    _online_softmax_grid(pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
+def fetched_rows(pos, L: int, block_k: int = DEFAULT_BLOCK_K):
+    """Rows of a lane at ``pos`` the walk copies out of HBM: its live
+    blocks of ``block_k`` rows (the absorb's), whatever the lane's
+    length or the copy's. A plain function of its arguments, as
+    :func:`live_block`: the kernel sizes its copies with it on the
+    scalar-prefetched position, a reader sets it beside the rows
+    attended (``pos + 1``) to get the kernel's waste. Cut to the live
+    rows' 8-row tiles a lane's last copy took 4% fewer bytes and 0.2%
+    less time on a v5e (the call is bound by its absorbs, not its
+    bytes) and three times the DMAs to trace and lower: dropped."""
+    return (jnp.clip(pos, 0, L - 1) // block_k + 1) * block_k
+
+
+def _walk_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+                 turn_ref, acc_ref, m_ref, l_ref, *, scale, block_k):
+    """One grid step a lane: the lane's live rows come through two
+    VMEM slots a stream in copies this kernel starts itself, and each
+    ``block_k`` rows of a landed copy are absorbed per 128-lane group
+    (its P kv heads' queries, block-diagonal, against the group's
+    ``[block_k, 128]`` keys), blocks in ascending order.
+
+    A copy's length is known only here (the lane's position), and a
+    DMA's is static: a copy of ``n`` blocks is the binary pieces of
+    ``n``, each started and awaited under the bit that calls for it.
+    While a lane's last copy is awaited and absorbed the NEXT lane's
+    first flies into the other slot (scratch, semaphores and the
+    slot's turn persist across grid steps), so only lane 0 waits
+    exposed."""
+    s = pl.program_id(0)
+    S = pl.num_programs(0)
+    layer = pos_ref[pos_ref.shape[0] - 1]  # behind the S positions
+    L = k_hbm.shape[2]
+    rows = kbuf.shape[1]  # a copy's
+    blocks = rows // block_k
+    bits = range(blocks.bit_length() - 1, -1, -1)
+
+    def copies(lane, c, slot, act):
+        """``act`` on every DMA of copy ``c`` of ``lane``."""
+        left = fetched_rows(pos_ref[lane], L, block_k) // block_k - c * blocks
+        n = jnp.minimum(left, blocks)
+        for b in bits:
+            @pl.when((n >> b) & 1 == 1)
+            def _piece(b=b):
+                at = pl.multiple_of(((n >> (b + 1)) << (b + 1)) * block_k,
+                                    block_k)
+                size = block_k << b
+                for x, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                    act(pltpu.make_async_copy(
+                        hbm.at[layer, lane, pl.ds(c * rows + at, size)],
+                        buf.at[slot, pl.ds(at, size)], sems.at[x, slot]))
+
+    start = lambda dma: dma.start()
+    wait = lambda dma: dma.wait()
+
+    @pl.when(s == 0)
+    def _first():
+        turn_ref[0] = 0
+        copies(0, 0, 0, start)
+
+    pos = pos_ref[s]
+    last = jnp.clip(pos, 0, L - 1)
+    n_copies = last // rows + 1
+    turn = turn_ref[0]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def walk(c, _):
+        slot = (turn + c) % 2
+        more = c + 1 < n_copies  # of this lane; else the next lane's first
+
+        @pl.when(more | (s + 1 < S))
+        def _ahead():
+            copies(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
+                   1 - slot, start)
+
+        copies(s, c, slot, wait)
+        first = c * blocks
+
+        def absorb(i, _):
+            at = pl.multiple_of(i * block_k, block_k)
+            for g in range(q_ref.shape[0]):
+                lanes = slice(g * LANES, (g + 1) * LANES)
+                kb = kbuf[slot, pl.ds(at, block_k), lanes].astype(jnp.float32)
+                vb = vbuf[slot, pl.ds(at, block_k), lanes].astype(jnp.float32)
+                q = q_ref[g].astype(jnp.float32) * scale  # [P G, 128]
+                # A slot's blocks past the lane's last live one were
+                # not copied (they are another lane's): never absorbed.
+                _absorb_block(g, q, kb, vb, first + i, pos, block_k,
+                              acc_ref, m_ref, l_ref)
+
+        lax.fori_loop(
+            0, jnp.minimum(last // block_k + 1 - first, blocks), absorb, None)
+
+    lax.fori_loop(0, n_copies, walk, None)
+    turn_ref[0] = (turn + n_copies) % 2
+    o_ref[...] = (acc_ref[...] / l_ref[...][..., :1]).astype(o_ref.dtype)
 
 
 def packed_decode_attention(
@@ -460,9 +570,9 @@ def packed_decode_attention(
     ``layer`` Python-static; ``pos``, ``scale`` and ``impl`` as
     :func:`decode_attention`'s. ``"reference"`` is
     :func:`decode_attention_reference` on the layer's rows viewed
-    ``[S, L, H_kv, Dh]``; ``"flash"`` the same ``flash_decode`` grid,
-    banded read and online softmax over ``[block_k, H_kv * Dh]`` blocks
-    (the note above)."""
+    ``[S, L, H_kv, Dh]``; ``"flash"`` a ``flash_decode`` call of one
+    grid step a lane, the same banded read and online softmax over
+    ``[block_k, H_kv * Dh]`` blocks (the note above)."""
     S, H, Dh = q.shape
     L, W = k.shape[2], k.shape[3]
     H_kv = W // Dh
@@ -494,33 +604,66 @@ def _packed_call(qp, k, v, pos, *, layer: int, block_k: int,
     lanes: ``qp`` ``[S, C, R, 128]``, R queries for each of the C
     128-lane groups of a stored row, zero outside the lanes of the head
     a query reads -> ``[S, C, R, 128]``, each query's softmax over its
-    scores applied to the group's whole 128 lanes of V."""
+    scores applied to the group's whole 128 lanes of V.
+
+    The grid is ``(S,)``: K and V stay where they lie in HBM and
+    :func:`_walk_kernel` copies a lane's :func:`fetched_rows` itself,
+    :func:`copy_rows` at a time, so a lane costs by the rows it holds
+    and not by its length. ``block_k`` is the rows an absorb takes
+    (:func:`decode_block`'s). Each traced call leaves a ``decode.plan``
+    record in the tracer's ring (trace time: a compiled step leaves
+    none)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    S, C, R, _ = qp.shape
     L, W = k.shape[2], k.shape[3]
     block_k = decode_block(L, 1, W, k.dtype, block_k)
-    vmem = {"memory_space": pltpu.VMEM}
+    rows = copy_rows(L, W, block_k)
+    get_tracer().complete(
+        "decode.plan", time.perf_counter(), 0.0,
+        nums=("walk", 1, block_k, rows, 2, W, L, 0),
+    )
+    # The layer rides scalar prefetch behind the positions, so the
+    # reading layers of one stored buffer are ONE program: a step's 8
+    # ring calls (and its 8 calls on the shared rows) are traced,
+    # lowered and compiled once, not once a layer.
+    at = jnp.concatenate([pos.astype(jnp.int32), jnp.full((1,), layer, jnp.int32)])
+    return _walk_call(at, qp, k, v, block_k=block_k, rows=rows,
+                      interpret=interpret, scale=scale)
 
-    def kvmap(s, j, pos_ref):
-        return (layer, s, live_block(j, pos_ref[s], block_k), 0)
 
+@functools.partial(
+    jax.jit, static_argnames=("block_k", "rows", "interpret", "scale"))
+def _walk_call(at, qp, k, v, *, block_k, rows, interpret, scale):
+    """:func:`_packed_call`'s ``pallas_call``; ``at`` is the lanes'
+    positions and, last, the layer to read."""
+    S, C, R, _ = qp.shape
+    W = k.shape[3]
     qspec = pl.BlockSpec(
-        (None, C, R, LANES), lambda s, j, pos_ref: (s, 0, 0, 0), **vmem)
-    kvspec = pl.BlockSpec((None, None, block_k, W), kvmap, **vmem)
+        (None, C, R, LANES), lambda s, at_ref: (s, 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    rows_in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_packed_heads_kernel, scale=scale, block_k=block_k),
+        functools.partial(_walk_kernel, scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(S, L // block_k),
-            in_specs=[qspec, kvspec, kvspec],
+            grid=(S,),
+            in_specs=[qspec, rows_in_hbm, rows_in_hbm],
             out_specs=qspec,
-            scratch_shapes=[pltpu.VMEM((C, R, LANES), jnp.float32)] * 3,
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, W), k.dtype),
+                pltpu.VMEM((2, rows, W), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # [stream, slot]
+                pltpu.SMEM((1,), jnp.int32),  # the slot a lane starts in
+                *[pltpu.VMEM((C, R, LANES), jnp.float32)] * 3,
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, C, R, LANES), jnp.float32),
+        # a lane hands the next its first copy and the slot's turn
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="flash_decode",
-    )(pos.astype(jnp.int32), qp, k, v)
+    )(at, qp, k, v)
 
 
 # ---- differential attention: two softmax maps a head pair -----------------
@@ -569,7 +712,7 @@ def diff_decode_attention(q, k, v, pos, *, layer: int = 0,
     ``pos`` is the last attendable ROW: a full-length lane passes its
     position, a ring of W rows ``min(pos + 1, W) - 1`` (without
     positions the order of rows does not matter to a softmax).
-    ``"flash"`` is the ``flash_decode`` grid of
+    ``"flash"`` is the ``flash_decode`` call of
     :func:`packed_decode_attention` (``Dh`` 64: a kv pair is a 128-lane
     group)."""
     S, H, Dh = q.shape

@@ -11,7 +11,8 @@ with its reference.
     python scripts/check_kernels.py --time    # device ms a call of flash_fwd,
                                               #   flash_dq, flash_dkv at the train
                                               #   cells' shape, and of what XLA
-                                              #   runs AROUND them (needs the chip)
+                                              #   runs AROUND them; of flash_decode
+                                              #   at the serve cells' (needs the chip)
 
 On a TPU the kernels compile under Mosaic; elsewhere they run in the
 Pallas interpreter, which proves the program and nothing about the
@@ -27,7 +28,11 @@ call (``around_ms_per_call``: the slices, transposes and stacks between
 the projection and the kernels, 6.4 ms a step in the train cells until
 PR 33) — once with q, k, v sliced out for the separate-operand entry
 and once through the fused-projection entry (the loop a change to
-``ops/flash.py`` iterates in; no benchmark cell runs this).
+``ops/flash.py`` iterates in; no benchmark cell runs this). Then the
+serve cells' ``flash_decode`` calls over rows with heads packed on lanes,
+at the positions their lanes hold (``DECODE_CELLS``): ms a call, rows
+attended and fetched, and the attended rows' bytes a second over the
+HBM's (the loop a change to ``ops/decode.py``'s walk iterates in).
 """
 
 from __future__ import annotations
@@ -155,9 +160,10 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
 FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
-def device_ms_per_call(log_dir: str, calls: int) -> tuple[dict, float]:
+def device_ms_per_call(log_dir: str, calls: int,
+                       kernels=FLASH_KERNELS) -> tuple[dict, float]:
     """Device ms a call in the profiler trace under ``log_dir``, from
-    the ``XLA Ops`` events of the first TPU: of each flash kernel (the
+    the ``XLA Ops`` events of the first TPU: of each of ``kernels`` (the
     events whose instruction carries the kernel's name,
     ``pallas_call(name=)``), and of every other operation together.
     ``({}, 0.0)`` where the trace holds no kernel event (nothing ran on
@@ -172,7 +178,9 @@ def device_ms_per_call(log_dir: str, calls: int) -> tuple[dict, float]:
     planes = sorted(
         (p for p in jax.profiler.ProfileData.from_file(path).planes
          if p.name.startswith("/device:TPU:")), key=lambda p: p.name)
-    total = dict.fromkeys(FLASH_KERNELS, 0)
+    total = dict.fromkeys(kernels, 0)
+    named = re.compile(
+        r"%?[\w\-]*?(" + "|".join(kernels) + r")(?![a-z])[\w\-.]* = ")
     around = 0
     for line in planes[0].lines if planes else ():
         if line.name != "XLA Ops":
@@ -180,9 +188,7 @@ def device_ms_per_call(log_dir: str, calls: int) -> tuple[dict, float]:
         for ev in line.events:
             # "%flash_fwd.3 = (bf16[...]) custom-call(...)"; under a
             # vjp the instruction is "%transpose_jvp_flash_dq__.1"
-            m = re.match(
-                r"%?[\w\-]*?(flash_(?:fwd|dq|dkv))(?![a-z])[\w\-.]* = ",
-                ev.name)
+            m = named.match(ev.name)
             if m:
                 total[m.group(1)] += ev.duration_ns
             else:
@@ -250,6 +256,120 @@ def time_flash(B: int, T: int, H: int, D: int, block: int,
         **({"around_ms_per_call": around} if ms
            else {"error": "no TPU in the trace: nothing timed"}),
     }
+
+
+# HBM bytes a second of the chip ``--time`` is read on (TPU v5e; Google
+# Cloud's "TPU v5e" page): a decode call's share of it is the bytes of
+# the rows it ATTENDS (K and V, once) over its device time.
+HBM_BYTES_PER_S = 819e9
+
+
+def lane_positions(S: int, L: int, mean_rows: int):
+    """``S`` lane positions as a serve cell past its knee holds them:
+    spread evenly from a quarter to seven quarters of ``mean_rows`` rows
+    and dealt so that neighbours differ; ``mean_rows`` at ``L`` or above
+    is a ring that has wrapped (every lane at its last row)."""
+    import numpy as np
+
+    if mean_rows >= L:
+        return np.full(S, L - 1, np.int32)
+    rows = np.linspace(mean_rows / 4, 7 * mean_rows / 4, S).round()
+    return (np.random.default_rng(0).permutation(rows) - 1).astype(np.int32)
+
+
+def _decode_call(kind: str, S: int, L: int, depth: int, mean_rows: int):
+    """The serve cells' ``flash_decode`` calls at their widths: ``diff``
+    the SambaY decoder's (20 head pairs over 10 kv pairs, rows of 1,280),
+    ``packed`` the hybrid's (4 queries a kv head of 64 at scale 1/64,
+    rows of 512), the last of ``depth`` stored layers -> (a function of
+    ``impl``, the positions, the row width)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops import decode
+
+    H, H_kv, Dh = (40, 20, 64) if kind == "diff" else (32, 8, 64)
+    kq, kk, kv = jax.random.split(jax.random.key(9), 3)
+    q = jax.random.normal(kq, (S, H, Dh), jnp.float32)
+    k = jax.random.normal(kk, (depth, S, L, H_kv * Dh), jnp.float32)
+    v = jax.random.normal(kv, (depth, S, L, H_kv * Dh), jnp.float32)
+    pos = lane_positions(S, L, mean_rows)
+    attend = (decode.diff_decode_attention if kind == "diff"
+              else functools.partial(decode.packed_decode_attention,
+                                     scale=1 / 64))
+    fn = lambda impl: jax.jit(lambda q, k, v, p: attend(
+        q, k, v, p, layer=depth - 1, impl=impl))(q, k, v, jnp.asarray(pos))
+    return fn, pos, H_kv * Dh
+
+
+def _rows(pos, L: int) -> dict:
+    from ddp_tpu.ops.decode import fetched_rows
+
+    return {"rows_attended": int((pos + 1).sum()),
+            "rows_fetched": int(fetched_rows(pos, L).sum())}
+
+
+def check_decode_cell(kind: str, S: int, L: int, depth: int,
+                      mean_rows: int) -> dict:
+    """A serve cell's ``flash_decode`` call (:func:`_decode_call`) at the
+    positions its lanes hold against the reference."""
+    import jax
+    import jax.numpy as jnp
+
+    call, pos, _ = _decode_call(kind, S, L, depth, mean_rows)
+    out = call("flash")
+    with jax.default_matmul_precision("highest"):
+        err = _max_err(out, call("reference"))
+    return {
+        "max_abs_err": err, "tol": TOL["decode"], **_rows(pos, L),
+        "ok": bool(jnp.isfinite(out).all()) and err <= TOL["decode"],
+    }
+
+
+def time_decode(kind: str, S: int, L: int, depth: int, mean_rows: int,
+                calls: int = 8) -> dict:
+    """Device ms a call of a serve cell's ``flash_decode``
+    (:func:`_decode_call`): ``calls`` calls inside a profiler session,
+    after one that compiles; the rows it attends and fetches, and the
+    attended rows' bytes (K and V) a second over the HBM's."""
+    import tempfile
+
+    import jax
+
+    call, pos, W = _decode_call(kind, S, L, depth, mean_rows)
+    jax.block_until_ready(call("flash"))
+    with tempfile.TemporaryDirectory() as log_dir:
+        jax.profiler.start_trace(log_dir)
+        try:
+            for _ in range(calls):
+                out = call("flash")
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        ms, _ = device_ms_per_call(log_dir, calls, ("flash_decode",))
+    rows = _rows(pos, L)
+    rec = {"shape": dict(S=S, L=L, W=W, depth=depth, mean_rows=mean_rows),
+           "calls": calls, "ms_per_call": ms, **rows, "ok": bool(ms)}
+    if not ms:
+        return {**rec, "error": "no TPU in the trace: nothing timed"}
+    needed = rows["rows_attended"] * W * 4 * 2
+    return {**rec, "hbm_share_pct": round(
+        100 * needed / HBM_BYTES_PER_S / (ms["flash_decode"] / 1e3), 2)}
+
+
+# The serve cells' flash_decode calls (PERF.md section 5): the SambaY
+# cell's shared rows at a mean of 1,350 of 4,096 and its wrapped rings,
+# the hybrid cell's rows at ~320 of 2,048; 64 lanes each.
+DECODE_CELLS = {
+    "diff_shared": dict(kind="diff", S=64, L=4096, depth=1, mean_rows=1350),
+    "diff_ring": dict(kind="diff", S=64, L=512, depth=8, mean_rows=512),
+    "packed": dict(kind="packed", S=64, L=2048, depth=4, mean_rows=320),
+}
+DECODE_CELLS_TINY = {
+    "diff_shared": dict(kind="diff", S=3, L=512, depth=1, mean_rows=170),
+    "diff_ring": dict(kind="diff", S=3, L=128, depth=2, mean_rows=128),
+    "packed": dict(kind="packed", S=3, L=256, depth=2, mean_rows=40),
+}
 
 
 def check_decode(
@@ -658,6 +778,10 @@ def cases(tiny: bool, every: bool):
         scan = (dict(T=40, C=256, N=4, real=33) if tiny
                 else dict(T=512, C=5120, N=16, real=389))
         yield "selective_scan_fp32", lambda: check_selective_scan(**scan)
+        # Both cells' decode calls at the positions their lanes hold.
+        for name, cell in (DECODE_CELLS_TINY if tiny else DECODE_CELLS).items():
+            yield f"decode_fp32_{name}_cell_positions", functools.partial(
+                check_decode_cell, **cell)
 
 
 def main() -> int:
@@ -680,6 +804,9 @@ def main() -> int:
         runs = [(f"flash_time_{entry}",
                  functools.partial(time_flash, **shape, entry=entry))
                 for entry in TIME_ENTRIES]
+        runs += [(f"decode_time_{name}", functools.partial(time_decode, **cell))
+                 for name, cell in (DECODE_CELLS_TINY if args.tiny
+                                    else DECODE_CELLS).items()]
     else:
         runs = cases(args.tiny, args.all)
     failed = []

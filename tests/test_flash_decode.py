@@ -23,10 +23,14 @@ item 2), layered:
   shard) and matches the unsharded op bitwise-tolerably.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ddp_tpu.models.generate import (
     generate,
@@ -34,12 +38,20 @@ from ddp_tpu.models.generate import (
     slot_decode_step,
 )
 from ddp_tpu.models.lm import LMSpec, init_lm
+from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+from ddp_tpu.ops import decode as decode_ops
 from ddp_tpu.ops.decode import (
+    copy_rows,
     decode_attention,
     decode_attention_reference,
+    decode_block,
     dequantize_kv,
+    diff_decode_attention,
+    diff_decode_attention_reference,
+    fetched_rows,
     flash_decode_attention,
     live_block,
+    packed_decode_attention,
     quantize_kv,
     shard_decode_attention,
 )
@@ -581,3 +593,247 @@ class TestMeshComposition:
             np.asarray(decode_attention(q, k, v, pos, impl="reference")),
             atol=1e-6, rtol=1e-6,
         )
+
+
+# ---- heads packed on lanes: the kernel walks a lane's live rows itself ----
+#
+# ``_packed_call`` (``packed_decode_attention``, ``diff_decode_attention``)
+# at the two serve cells' row widths, lanes and lengths cut down. The
+# parent's form of the same call, a grid over every block of a lane
+# with Pallas fetching the live ones, is kept HERE as the reference the
+# walk is bit-equal to: same absorbs, same order, same masks.
+
+# (kind, row width, lane length): the SambaY cell's shared rows and its
+# window's ring (copies of 256 rows), the hybrid cell's rows (1,024).
+_WALKS = {
+    "diff_shared": ("diff", 1280, 1024),
+    "diff_ring": ("diff", 1280, 512),
+    "packed": ("packed", 512, 2048),
+}
+_BLOCK = decode_ops.DEFAULT_BLOCK_K
+
+
+def _edge_positions(W, L, block_k=_BLOCK):
+    """0, a tile's edge, an absorb's, a copy's, the lane's end: mixed in
+    one call, a long lane before a short one and after it."""
+    rows = copy_rows(L, W, block_k)
+    edges = {0, 7, 8, block_k - 1, block_k, block_k + 1, rows - 1, rows,
+             rows + 1, 2 * rows - 1, 2 * rows, L - block_k - 1, L - 1}
+    pos = sorted(p for p in edges if 0 <= p < L)
+    return pos[1::2] + pos[::2][::-1]
+
+
+def _walk_inputs(kind, W, L, pos, depth=2, seed=0):
+    Dh = 64
+    H_kv = W // Dh
+    H = 2 * H_kv if kind == "diff" else 4 * H_kv
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    S = len(pos)
+    q = jax.random.normal(kq, (S, H, Dh), jnp.float32)
+    k = jax.random.normal(kk, (depth, S, L, W), jnp.float32)
+    v = jax.random.normal(kv, (depth, S, L, W), jnp.float32)
+    return q, k, v, jnp.asarray(pos, jnp.int32)
+
+
+def _walk(kind, q, k, v, pos, **kw):
+    if kind == "diff":
+        return diff_decode_attention(q, k, v, pos, impl="flash", **kw)
+    return packed_decode_attention(q, k, v, pos, impl="flash",
+                                   scale=1 / 64, **kw)
+
+
+def _walk_reference(kind, q, k, v, pos, layer):
+    if kind == "diff":
+        return diff_decode_attention_reference(q, k[layer], v[layer], pos)
+    S, L, W = k.shape[1:]
+    heads = lambda c: c[layer].reshape(S, L, W // 64, 64)
+    return decode_attention_reference(q, heads(k), heads(v), pos,
+                                      scale=1 / 64)
+
+
+def _grid_packed_call(qp, k, v, pos, *, layer, block_k, interpret, scale):
+    """``_packed_call`` as the parent commit had it: grid ``(S, L //
+    block_k)``, K and V blocks fetched by Pallas under ``live_block``,
+    the shared grid skeleton around the same absorbs."""
+    S, C, R, _ = qp.shape
+    L, W = k.shape[2], k.shape[3]
+    block_k = decode_block(L, 1, W, k.dtype, block_k)
+
+    def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+        def body(j, p):
+            for c in range(C):
+                lanes = slice(c * 128, (c + 1) * 128)
+                decode_ops._absorb_block(
+                    c, q_ref[c].astype(jnp.float32) * scale,
+                    k_ref[:, lanes].astype(jnp.float32),
+                    v_ref[:, lanes].astype(jnp.float32),
+                    j, p, block_k, acc_ref, m_ref, l_ref)
+
+        decode_ops._online_softmax_grid(
+            pos_ref, o_ref, acc_ref, m_ref, l_ref, block_k, body)
+
+    vmem = {"memory_space": pltpu.VMEM}
+    qspec = pl.BlockSpec(
+        (None, C, R, 128), lambda s, j, pos_ref: (s, 0, 0, 0), **vmem)
+    kvspec = pl.BlockSpec(
+        (None, None, block_k, W),
+        lambda s, j, pos_ref: (
+            layer, s, live_block(j, pos_ref[s], block_k), 0), **vmem)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, L // block_k),
+            in_specs=[qspec, kvspec, kvspec], out_specs=qspec,
+            scratch_shapes=[pltpu.VMEM((C, R, 128), jnp.float32)] * 3),
+        out_shape=jax.ShapeDtypeStruct((S, C, R, 128), jnp.float32),
+        interpret=interpret, name="flash_decode",
+    )(pos.astype(jnp.int32), qp, k, v)
+
+
+class TestWalk:
+    @pytest.mark.parametrize("mode", ["interpret", "tpu_interpret"])
+    @pytest.mark.parametrize("case", _WALKS)
+    def test_matches_reference_at_every_edge(self, case, mode):
+        """Every position where a tile, an absorb, a copy or the lane
+        ends, mixed in one call (so a lane's first copy is started by
+        the lane before it, into the slot that lane left free), in both
+        of Pallas's interpreters."""
+        kind, W, L = _WALKS[case]
+        q, k, v, pos = _walk_inputs(kind, W, L, _edge_positions(W, L))
+        interpret = True if mode == "interpret" else pltpu.InterpretParams()
+        got = _walk(kind, q, k, v, pos, layer=1, interpret=interpret)
+        want = _walk_reference(kind, q, k, v, pos, 1)
+        assert float(jnp.abs(got.reshape(want.shape) - want).max()) < 2e-5
+
+    @pytest.mark.parametrize("written", [300, 511, 512, 5000])
+    def test_a_ring_before_and_after_it_wraps(self, written):
+        """A window's ring of 512 rows is handed the count of valid
+        rows less one: a ring that has not wrapped reads its first
+        rows, one that has reads them all."""
+        kind, W, L = _WALKS["diff_ring"]
+        last = [min(written, L) - 1, 0, min(written + 7, L) - 1]
+        q, k, v, pos = _walk_inputs(kind, W, L, last, seed=written)
+        got = _walk(kind, q, k, v, pos, layer=0, interpret=True)
+        want = _walk_reference(kind, q, k, v, pos, 0)
+        assert float(jnp.abs(got - want).max()) < 2e-5
+
+    @pytest.mark.parametrize("block_k", [32, 128])
+    @pytest.mark.parametrize("case", _WALKS)
+    def test_bit_equal_to_the_grid_form(self, case, block_k, monkeypatch):
+        """The same absorbs in the same order under the same masks: the
+        walk's output is the parent's grid form's, bit for bit."""
+        kind, W, L = _WALKS[case]
+        L = min(L, 1024)
+        q, k, v, pos = _walk_inputs(
+            kind, W, L, _edge_positions(W, L, block_k), seed=block_k)
+        got = _walk(kind, q, k, v, pos, layer=1, block_k=block_k,
+                    interpret=True)
+        monkeypatch.setattr(decode_ops, "_packed_call", _grid_packed_call)
+        grid = _walk(kind, q, k, v, pos, layer=1, block_k=block_k,
+                     interpret=True)
+        assert jnp.array_equal(got, grid)
+
+    @pytest.mark.parametrize("fill", [float("nan"), 3e38])
+    @pytest.mark.parametrize("case", _WALKS)
+    def test_rows_past_pos_reach_nothing(self, case, fill):
+        """Rows past a lane's position hold ``fill`` in HBM, and so does
+        EVERY row of the lanes in between (whose outputs are not read):
+        a short lane's slot then holds ``fill`` behind the blocks its
+        last copy brought (stale VMEM, not only unattended HBM). Output
+        and statistics of the lanes read are the clean cache's."""
+        kind, W, L = _WALKS[case]
+        rows = copy_rows(L, W, _BLOCK)
+        # lanes 1, 3, 5 are filled to the brim and fill both slots
+        pos = [3, L - 1, 0, L - 1, _BLOCK + 5, min(2 * rows, L) - 1, rows + 2, 9]
+        read = np.asarray([0, 2, 4, 6, 7])
+        q, k, v, pos = _walk_inputs(kind, W, L, pos, seed=3)
+        want = _walk_reference(kind, q, k, v, pos, 1)
+        dead = jnp.arange(L)[None, :, None] > pos[:, None, None]
+        dead = dead.at[jnp.asarray([1, 3, 5])].set(True)
+        k, v = (x.at[1].set(jnp.where(dead, fill, x[1])) for x in (k, v))
+        got = _walk(kind, q, k, v, pos, layer=1, interpret=True)
+        got = np.asarray(got.reshape(want.shape))[read]
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, np.asarray(want)[read],
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("pos", [
+        [0], [7], [8], [127], [128], [255], [256], [1023], [5, 600, 0, 1000],
+    ])
+    def test_fetched_rows_are_the_copies_the_kernel_starts(
+            self, pos, monkeypatch):
+        """Every DMA the interpreted kernel starts is counted by its
+        rows: a lane's are its live blocks of 128 rows, whatever a copy
+        holds, once for K and once for V."""
+        kind, W, L = _WALKS["diff_shared"]
+        started = []
+        make = pltpu.make_async_copy
+
+        class Counted:
+            def __init__(self, src, dst, sem):
+                self.dma, self.rows = make(src, dst, sem), dst.shape[0]
+
+            def start(self):
+                jax.debug.callback(lambda: started.append(self.rows))
+                self.dma.start()
+
+            def wait(self):
+                self.dma.wait()
+
+        monkeypatch.setattr(pltpu, "make_async_copy", Counted)
+        q, k, v, pos = _walk_inputs(kind, W, L, pos, depth=1)
+        # the call's program is traced once a shape: not one traced
+        # before the count, and not the counted one after it
+        decode_ops._walk_call.clear_cache()
+        try:
+            jax.block_until_ready(_walk(kind, q, k, v, pos, interpret=True))
+            jax.effects_barrier()
+        finally:
+            decode_ops._walk_call.clear_cache()
+        want = fetched_rows(pos, L)
+        assert sum(started) == 2 * int(want.sum())
+        assert [int(r) for r in want] == [(p // 128 + 1) * 128 for p in pos]
+        assert int(fetched_rows(jnp.int32(L + 5), L)) == L  # never past the lane
+
+    @pytest.mark.parametrize("case", _WALKS)
+    def test_leaves_its_plan_record(self, case):
+        """One ``decode.plan`` a traced call: the walk, one grid step a
+        lane, rows an absorb and a copy, two copies in flight a stream,
+        the row width and lane length, the last copy not cut."""
+        kind, W, L = _WALKS[case]
+        q, k, v, pos = _walk_inputs(kind, W, L, [3, L - 1], depth=1)
+        ring = get_tracer().ring
+        before = sum(e[0] == "decode.plan" for e in ring())
+        jax.make_jaxpr(functools.partial(_walk, kind, interpret=True))(
+            q, k, v, pos)
+        plans = [e[4] for e in ring() if e[0] == "decode.plan"]
+        assert len(plans) == before + 1
+        rows = {1280: 256, 512: 1024}[W]
+        assert plans[-1] == ("walk", 1, 128, rows, 2, W, L, 0)
+        assert len(SPAN_NUMS["decode.plan"]) == len(plans[-1])
+
+    def test_the_reading_layers_of_a_buffer_are_one_program(self):
+        """The layer rides scalar prefetch: a step's calls on one stored
+        buffer trace (and lower, and compile) the kernel once, whatever
+        layer each reads, and still read their own layer."""
+        kind, W, L = _WALKS["diff_ring"]
+        q, k, v, pos = _walk_inputs(kind, W, L, [3, L - 1], depth=3)
+        step = lambda q, k, v, p: [
+            _walk(kind, q, k, v, p, layer=i, interpret=True)
+            for i in range(3)]
+        calls = [e for e in jax.make_jaxpr(step)(q, k, v, pos).eqns
+                 if e.params.get("name") == "_walk_call"]
+        assert len(calls) == 3
+        assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+        for i, got in enumerate(jax.jit(step)(q, k, v, pos)):
+            want = _walk_reference(kind, q, k, v, pos, i)
+            assert float(jnp.abs(got - want).max()) < 2e-5
+
+    def test_a_lane_shorter_than_an_absorb_is_one_copy(self):
+        """A lane of 16 rows: the absorb, the copy and the lane are one
+        block, fetched whole."""
+        q, k, v, pos = _walk_inputs("packed", 512, 16, [3, 15, 0], depth=1)
+        got = _walk("packed", q, k, v, pos, interpret=True)
+        want = _walk_reference("packed", q, k, v, pos, 0)
+        assert float(jnp.abs(got - want).max()) < 2e-5
+        assert [int(r) for r in fetched_rows(pos, 16, 16)] == [16, 16, 16]

@@ -443,6 +443,33 @@ def test_a_step_leaves_idle_lanes_bit_for_bit(params, impl):
     assert after.pos.tolist() == [14, 8, 0]
 
 
+def test_a_decode_step_through_the_kernel_reads_ring_and_rows_alike():
+    """Heads of 64 (a kv pair is a 128-lane group, what ``flash_decode``
+    takes): one step over lanes whose rings have not wrapped, have just
+    wrapped and wrapped long ago, one of them idle, through the kernel
+    that walks a lane's live rows and through the reference: the same
+    logits and the same lanes, to rounding (a layer writes rows formed
+    from the attention below it)."""
+    spec = SPEC._replace(head_dim=64, d_model=128, mamba_d_inner=256)
+    tree = sy.init_params(spec, seed=5, dtype=jnp.float32)
+    cache = init_slot_cache(spec, 4)
+    keys = iter(jax.random.split(jax.random.key(8), 8))
+    fill = lambda a: jax.random.normal(next(keys), a.shape, a.dtype)
+    cache = cache._replace(
+        k=fill(cache.k), v=fill(cache.v), ring_k=fill(cache.ring_k),
+        ring_v=fill(cache.ring_v), ssm=fill(cache.ssm), conv=fill(cache.conv),
+        pos=jnp.asarray([3, W - 1, W, 47], jnp.int32),
+        live=jnp.asarray([True, True, False, True]))
+    toks = jnp.asarray([5, 6, 7, 8], jnp.int32)
+    step = lambda impl: jax.jit(lambda c: sy.slot_decode_step(
+        spec, tree, c, toks, attn_impl=impl, ssm_impl="jnp"))(cache)
+    (want, want_c), (got, got_c) = step("reference"), step("flash")
+    live = np.asarray(cache.live)
+    assert float(jnp.abs(got - want)[live].max()) < TOL
+    for a, b in zip(jax.tree.leaves(got_c), jax.tree.leaves(want_c)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=TOL)
+
+
 # ---- the engine ---------------------------------------------------------------------
 
 

@@ -270,6 +270,50 @@ def test_differential_decode_kernel_compiles_over_ring_and_shared_rows(
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
+@pytest.mark.parametrize("block,config,arguments_gb,temporaries_gb", [
+    # as PR 36 and PR 32 compiled them with the grid form: the walk's
+    # four copy slots are VMEM, not HBM
+    ("sambay", "phi-4-mini-flash-reasoning-serve.json", 13.30, 0.23),
+    ("granite_hybrid", "granite-4.0-h-micro-serve.json", 13.48, 0.09),
+])
+def test_the_serve_cells_decode_programs_still_fit(
+        one_chip, as_on_tpu, block, config, arguments_gb, temporaries_gb):
+    """The whole decode program of the SambaY and the hybrid cell at
+    the benchmark's sizes (64 lanes; bfloat16 weights, float32 rows and
+    state), every ``flash_decode`` call the walk: arguments and
+    temporaries as they were, inside one chip's 16 GB."""
+    import importlib
+    import json
+
+    from benchmarks.drivers import granite_serve, sambay_serve
+    from ddp_tpu.models.generate import init_slot_cache
+
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "benchmarks", "configs", config)) as f:
+        cfg = json.load(f)
+    spec = {"sambay": sambay_serve,
+            "granite_hybrid": granite_serve}[block].lm_spec(cfg)
+    model = importlib.import_module(f"ddp_tpu.models.{block}")
+    S = cfg["engine"]["slots"]
+    described = lambda make: jax.tree.map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), jax.eval_shape(make))
+    lanes = lambda dtype: _shape((S,), dtype, one_chip)
+    compiled = jax.jit(
+        lambda p, c, *a: model.slot_decode_sample_step(
+            spec, p, c, *a, attn_impl="flash"),
+        donate_argnums=(1,),
+    ).lower(
+        described(lambda: model.init_params(spec)),
+        described(lambda: init_slot_cache(spec, S)),
+        lanes(jnp.int32), lanes(jnp.uint32), lanes(jnp.int32),
+        lanes(jnp.float32), lanes(jnp.float32),
+    ).compile()
+    assert "flash_decode" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes / 1e9 - arguments_gb) < 0.01
+    assert abs(mem.temp_size_in_bytes / 1e9 - temporaries_gb) < 0.01
+
+
 @pytest.fixture(scope="module")
 def ddp4_schedule(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
