@@ -16,8 +16,12 @@ deliberate TPU idiom, not behavior:
 
 from __future__ import annotations
 
-import flax.linen as nn
 import jax.numpy as jnp
+
+from ddp_tpu.obs.tracer import importing
+
+with importing("flax"):
+    import flax.linen as nn
 
 
 class SimpleCNN(nn.Module):

@@ -22,9 +22,13 @@ from typing import Any, Callable, NamedTuple, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ddp_tpu.obs.tracer import importing
+
+with importing("optax"):
+    import optax
 
 from ddp_tpu.models.vit import EncoderBlock
 from ddp_tpu.ops.attention import best_attention

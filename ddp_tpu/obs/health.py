@@ -293,7 +293,6 @@ class HealthMonitor:
         if self.enabled:
             from ddp_tpu.obs.steptime import CompileCounter
 
-            CompileCounter.install()
             self._compiles = CompileCounter.count
             self._c_prev = self._compiles()
 

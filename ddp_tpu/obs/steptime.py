@@ -18,7 +18,8 @@ costing nothing when off (``StepAttributor(enabled=False)`` hands back
 the caller's iterator unchanged and ``on_step`` returns immediately).
 
 Recompiles are counted process-wide via ``jax.monitoring`` compile
-events (one ``backend_compile`` per executable built), not per-function
+events (one ``backend_compile`` per executable built or loaded: the
+tracer's one listener, ``obs/tracer.compile_count``), not per-function
 ``_cache_size()`` probes: the trainer's step may be a lambda over a
 jitted inner function, and a *process-level* counter also catches
 compiles hiding in eval, checkpoint restore, or a library call — if
@@ -32,45 +33,29 @@ import time
 from dataclasses import dataclass
 from typing import Any, Iterable, Iterator, Optional
 
-from ddp_tpu.obs.tracer import Tracer, get_tracer
-
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+from ddp_tpu.obs.tracer import Tracer, compile_count, get_tracer
 
 
 class CompileCounter:
-    """Process-wide XLA compile counter (lazy jax.monitoring listener).
-
-    ``install()`` is idempotent and only ever called from enabled
-    attribution paths, so tracing-off runs never register the listener
-    (and never import jax from this module) — part of the disabled-
-    mode-is-free pin. jax.monitoring has no unregister; the listener
-    is one integer increment per *compilation*, which is noise even if
-    it outlives the attributor that installed it.
+    """Process-wide XLA compile counter: a view of the program's one
+    ``jax.monitoring`` listener (``obs/tracer.py``, installed with the
+    process-global tracer and always on), which also keeps a record of
+    every compile by program name. One increment per *compilation*;
+    ``install()`` remains for the callers that asked for the listener
+    when it was lazy, and has nothing left to do.
     """
-
-    _count = 0
-    _installed = False
 
     @classmethod
     def install(cls) -> None:
-        if cls._installed:
-            return
-        import jax
-
-        def _on_event(name: str, *args: Any, **kw: Any) -> None:
-            if name == _COMPILE_EVENT:
-                cls._count += 1
-
-        jax.monitoring.register_event_duration_secs_listener(_on_event)
-        cls._installed = True
+        pass
 
     @classmethod
     def installed(cls) -> bool:
-        return cls._installed
+        return True
 
     @classmethod
     def count(cls) -> int:
-        return cls._count
+        return compile_count()
 
 
 @dataclass
@@ -150,8 +135,6 @@ class StepAttributor:
         self._fetch_end = 0.0
         self._compiles_at_fetch = 0
         self._xprof_seq_at_fetch = 0
-        if self.enabled:
-            CompileCounter.install()
 
     def batches(self, iterable: Iterable) -> Iterator:
         """Wrap a batch iterator, timing each ``next()``.
@@ -247,7 +230,6 @@ def dispatch_compute_split(run, *args) -> tuple[Any, float, float, int]:
     """
     import jax
 
-    CompileCounter.install()
     c0 = CompileCounter.count()
     t0 = time.perf_counter()
     result = run(*args)

@@ -24,6 +24,15 @@ flushes. This tracer is the complement, at two levels:
    tracks, the per-request async spans, and the crash-safe Perfetto
    export (temp file + ``os.replace``; the launcher path registers an
    atexit export so a watchdog abort still leaves a trace).
+3. **Phases, kept.** ``phase()`` and ``phase_complete()`` record what
+   a process does ONCE, between its start and its first productive
+   step (imports, state, warm-up, every compile by program name): the
+   same tuple, the same annotation, appended to the ring AND to a
+   bounded store of its own (``KEPT_RECORDS``) that the hot path never
+   writes, so the start is still there after an hour of serving
+   (``startup()``). Once full the store keeps what it has, the start
+   being what it is for, and counts what it refuses. ``span()`` and
+   ``complete()`` know nothing of it.
 
 ``t0`` is a ``time.perf_counter`` stamp; ``parent`` is the ``t0`` of the
 span that caused this one (None at the top); ``nums`` is a small tuple
@@ -35,11 +44,14 @@ processes merge onto one timeline (scripts/trace_merge.py).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from typing import Any, Optional
+
+from jax import monitoring
 
 # The profiler's own annotation: written into the ``.xplane.pb`` while a
 # session is open, dropped by the runtime otherwise.
@@ -54,6 +66,14 @@ RING_EVENTS_ENV = "DDP_TPU_TRACE_RING_EVENTS"
 
 DEFAULT_RING_EVENTS = 65536
 MAX_SUMMARY_NAMES = 256
+# Room for a start: JAX reports a trace of EVERY function it traces
+# (``jnp``'s own are ``jit``s too, once a layer a program), so a cell
+# of the benchmark keeps 6,700-18,100 records by its first step
+# (PERF.md section 5): under 6 MB when full.
+KEPT_RECORDS = 32768
+# A ``compile.*`` record briefer than this is counted, not listed, in
+# the ``/statusz`` view (``startup()`` returns every one).
+BRIEF_COMPILE_S = 1e-3
 
 # Canonical per-rank trace filename (the launcher writes one per rank;
 # trace_merge globs this pattern).
@@ -121,6 +141,43 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "decode.plan": ("form", "steps_per_lane", "absorb_rows", "copy_rows",
                     "copies_in_flight", "row_width", "lane_rows",
                     "last_copy_cut"),
+    # ---- the process's start: phases, kept past the ring ------------
+    # (``Tracer.phase`` / ``phase_complete``; docs/OBSERVABILITY.md
+    # "Start-up"). Names are names, not numbers.
+    # The whole import of one of the program's heavy modules (a stamp
+    # at its first line, the record at its last) or of a third-party
+    # package, around the import statement in the program's module
+    # that first needs it. They nest: take the UNION of intervals
+    "startup.import": ("module",),
+    # train/trainer.py ``Trainer.__init__`` (kind ``trainer``) and
+    # serve/engine.py ``ServeEngine.__init__`` (kind ``engine``);
+    # the phases below it carry its ``t0`` as ``parent``
+    "startup.state": ("kind",),
+    "startup.model_init": (),  # step builders and the initial state
+    # ``Trainer.train()``, no parent: the checkpoint restored, or not
+    "startup.checkpoint": (),
+    "startup.lane_cache": (),  # the engine's lanes, allocated
+    # scripts/serve.py: parameters restored or initialised
+    "startup.weights": (),
+    # serve/engine.py ``warmup()``: one ``warmup_program`` per call it
+    # makes (``parent`` the warm-up's ``t0``; ``width`` 0 for a program
+    # without one), then the wait for the device
+    "startup.warmup": ("programs",),
+    "startup.warmup_program": ("program", "width"),
+    "startup.warmup_wait": (),
+    # One record per JAX compile event, by the program's name, from the
+    # one listener below (``t0`` is the event's end less its duration).
+    # They carry no parent: start-up is one thread, and a reader gives
+    # each to the innermost kept phase whose interval contains it. An
+    # inner ``jit`` traced inside an outer one leaves a record inside
+    # the outer's: take the UNION of intervals, never the sum.
+    # ``fun_name`` is the name the program gave ``jax.jit`` (the
+    # ``jit(...)`` JAX wraps it in for lowering and the backend taken
+    # off); ``cache_hit`` is 1 where the persistent cache supplied the
+    # executable
+    "compile.trace": ("fun_name",),
+    "compile.lower": ("fun_name",),
+    "compile.backend": ("fun_name", "cache_hit"),
 }
 # Spans in which the host WAITS for the device (the blocking token
 # fetch): host time, but not host work.
@@ -163,6 +220,34 @@ class _Span:
         return False
 
 
+class _Phase(_Span):
+    """A live phase: a span that is also kept (``Tracer.phase``)."""
+
+    __slots__ = ()
+
+    def __exit__(self, *exc) -> bool:
+        t0 = self.t0
+        dur = time.perf_counter() - t0
+        self._ann.__exit__(None, None, None)
+        self._tracer.phase_complete(
+            self.name, t0, dur, self.args,
+            parent=self.parent, nums=self.nums,
+        )
+        return False
+
+
+class _Kept:
+    """The records a process keeps of its start. Bounded: once full it
+    keeps what it has and counts what it refuses."""
+
+    __slots__ = ("records", "refused", "lock")
+
+    def __init__(self):
+        self.records: list[tuple] = []
+        self.refused = 0
+        self.lock = threading.Lock()  # compiles run on any thread
+
+
 def _nums_args(name: str, parent, nums: tuple) -> Optional[dict]:
     fields = SPAN_NUMS.get(name, ())
     args = {
@@ -189,6 +274,7 @@ class Tracer:
         enabled: bool = False,
         ring_events: int = DEFAULT_RING_EVENTS,
         process_id: int = 0,
+        kept_with: Optional["Tracer"] = None,
     ):
         from collections import deque
 
@@ -199,6 +285,11 @@ class Tracer:
         self._ring: Any = deque(maxlen=self.ring_events)
         # The enabled level: full Perfetto records.
         self._events: Any = deque(maxlen=self.ring_events)
+        # The kept level. A process starts once: a tracer that replaces
+        # the process-global one, or stands beside it (``--trace_dir``,
+        # scripts/serve.py), keeps with it (``kept_with``), so that
+        # ``startup()`` reads the same from either.
+        self._kept = kept_with._kept if kept_with is not None else _Kept()
         self._lock = threading.Lock()
         self._summaries: dict[str, StatSummary] = {}
         self._dropped = 0
@@ -260,6 +351,42 @@ class Tracer:
                 "X", name, start_perf, dur_s,
                 args or _nums_args(name, parent, nums),
             )
+
+    def phase(
+        self,
+        name: str,
+        args: Optional[dict] = None,
+        *,
+        parent: Optional[float] = None,
+        nums: tuple = (),
+    ) -> _Phase:
+        """``span()`` for what the process does once on its way to its
+        first productive step: recorded like a span and also KEPT
+        (``startup()``). Not for a hot path: a kept record costs a
+        lock."""
+        return _Phase(self, name, args, parent, nums)
+
+    def phase_complete(
+        self,
+        name: str,
+        start_perf: float,
+        dur_s: float,
+        args: Optional[dict] = None,
+        *,
+        parent: Optional[float] = None,
+        nums: tuple = (),
+    ) -> None:
+        """``complete()`` for a phase: into the ring, and kept."""
+        self.complete(name, start_perf, dur_s, args, parent=parent,
+                      nums=nums)
+        kept = self._kept
+        with kept.lock:
+            if len(kept.records) < KEPT_RECORDS:
+                kept.records.append(
+                    (name, start_perf, max(0.0, dur_s), parent, nums)
+                )
+            else:
+                kept.refused += 1
 
     def async_complete(
         self,
@@ -327,6 +454,46 @@ class Tracer:
                 return list(self._ring)
             except RuntimeError:  # appended to while copied: again
                 continue
+
+    def startup(self) -> list[tuple]:
+        """The kept records, oldest first (a phase before what it
+        contains): the ring's tuples, still here when the ring has
+        long turned over."""
+        with self._kept.lock:
+            records = list(self._kept.records)
+        return sorted(records, key=lambda e: (e[1], -e[2]))
+
+    @property
+    def startup_refused(self) -> int:
+        """Records a full store refused: over 0, widen it."""
+        return self._kept.refused
+
+    def startup_snapshot(self) -> dict:
+        """JSON-ready view of the kept records for ``/statusz``: name,
+        seconds since the first record, seconds, ``nums`` under their
+        ``SPAN_NUMS`` names. JAX reports a trace of every ``jnp``
+        function a program calls, thousands of records of microseconds
+        each: the view lists the ``compile.*`` records of
+        ``BRIEF_COMPILE_S`` or more and counts the rest (``startup()`` has them all). Also
+        how many records a full store refused."""
+        records = self.startup()
+        first = records[0][1] if records else 0.0
+        listed, brief, brief_total = [], 0, 0.0
+        for name, t0, dur, _, nums in records:
+            if dur < BRIEF_COMPILE_S and name.startswith("compile."):
+                brief, brief_total = brief + 1, brief_total + dur
+                continue
+            listed.append(
+                {"name": name, "at_s": round(t0 - first, 6),
+                 "seconds": round(dur, 6),
+                 **(_nums_args(name, None, nums) or {})}
+            )
+        return {
+            "records": listed,
+            "brief_compile_records": brief,
+            "brief_compile_seconds": round(brief_total, 6),
+            "refused": self.startup_refused,
+        }
 
     def _event_dicts(self, limit: Optional[int] = None) -> list[dict]:
         if self.enabled:
@@ -434,12 +601,98 @@ _GLOBAL = Tracer()
 _GLOBAL_LOCK = threading.Lock()
 
 
+class _CompileListener:
+    """The program's ONE listener on JAX's monitoring events, installed
+    where the process-global tracer is made (JAX has no public call to
+    take a listener off, and a second would see every compile twice). For
+    each of the three duration events of a compile it keeps a record in
+    the process-global tracer, under the program's name: ``t0`` is now
+    less the duration (the event fires at the end of what it times).
+    Nothing compiles in steady state, so a steady step never gets
+    here."""
+
+    EVENTS = {
+        "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+        "/jax/core/compile/backend_compile_duration": "compile.backend",
+    }
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        # Executables built or loaded, process-wide: what
+        # ``obs/steptime.CompileCounter`` reports.
+        self.count = 0
+        self._lock = threading.Lock()
+        # The persistent cache reports a hit INSIDE the backend event
+        # it serves, on the thread that compiles.
+        self._hit = threading.local()
+
+    def install(self) -> "_CompileListener":
+        monitoring.register_event_duration_secs_listener(self.on_duration)
+        monitoring.register_event_listener(self.on_event)
+        return self
+
+    def on_event(self, event: str, **kw) -> None:
+        if event == self.CACHE_HIT:
+            self._hit.seen = True
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        name = self.EVENTS.get(event)
+        if name is None:
+            return
+        # Lowering and the backend are told ``jit(<name>)``, tracing the
+        # bare name: one program, one name.
+        fun = str(kw.get("fun_name", ""))
+        if fun.startswith("jit(") and fun.endswith(")"):
+            fun = fun[4:-1]
+        nums: tuple = (fun,)
+        if name == "compile.backend":
+            with self._lock:
+                self.count += 1
+            nums += (int(getattr(self._hit, "seen", False)),)
+            self._hit.seen = False
+        _GLOBAL.phase_complete(
+            name, time.perf_counter() - duration, duration, nums=nums
+        )
+
+
+_COMPILES = _CompileListener().install()
+
+
+def compile_count() -> int:
+    """Executables built or loaded in this process so far (one
+    ``backend_compile`` event each, whoever owned the compile)."""
+    return _COMPILES.count
+
+
 def get_tracer() -> Tracer:
     """The process-global tracer: what ``ServeEngine``, ``LMServer``,
     ``ShardedLoader`` and ``Trainer`` record into unless handed
     another. Its ring is always on; ``enabled`` only once someone
     installs an enabled one."""
     return _GLOBAL
+
+
+@contextlib.contextmanager
+def importing(module: str):
+    """Around the import statement of a third-party package that costs
+    over a quarter of a second, in the program's module that first
+    needs it: ``with importing("optax"): import optax``."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        imported(module, t0)
+
+
+def imported(module: str, t0: float) -> None:
+    """Keep the ``startup.import`` record of ``module``, whose import
+    began at ``t0`` and ends here: called at the last line of the
+    program's heavy modules with a stamp from their first
+    (docs/OBSERVABILITY.md "Start-up")."""
+    _GLOBAL.phase_complete(
+        "startup.import", t0, time.perf_counter() - t0, nums=(module,)
+    )
 
 
 def install_from_env(
@@ -461,7 +714,8 @@ def install_from_env(
     ring = int(os.environ.get(RING_EVENTS_ENV, DEFAULT_RING_EVENTS))
     with _GLOBAL_LOCK:
         tracer = Tracer(
-            enabled=True, ring_events=ring, process_id=process_id
+            enabled=True, ring_events=ring, process_id=process_id,
+            kept_with=_GLOBAL,
         )
         _GLOBAL = tracer
     if register_atexit:

@@ -67,10 +67,12 @@ import time
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ddp_tpu.obs.tracer import get_tracer
+from ddp_tpu.obs.tracer import get_tracer, importing
+
+with importing("jax.experimental.pallas"):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 from ddp_tpu.ops.flash import pick_block
 
 # Per-row stats ride broadcast across the minor 128-lane dim (the

@@ -106,10 +106,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ddp_tpu.obs.tracer import get_tracer
+from ddp_tpu.obs.tracer import get_tracer, importing
+
+with importing("jax.experimental.pallas"):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
 # Minor-most lanes of a TPU vector register; per-row stats are carried
 # broadcast across this many lanes (see module docstring).

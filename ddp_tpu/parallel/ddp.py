@@ -42,12 +42,15 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddp_tpu.obs.health import health_stats, inject_nan
-from ddp_tpu.obs.tracer import get_tracer
+from ddp_tpu.obs.tracer import get_tracer, importing
+
+with importing("optax"):
+    import optax
+
 from ddp_tpu.obs.xprof import call_signature, collective_schedule
 from ddp_tpu.parallel.common import (
     _preprocess,

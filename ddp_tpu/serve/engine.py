@@ -52,8 +52,11 @@ compiled shape, so per-request values would recompile per mix.
 
 from __future__ import annotations
 
-import importlib
 import time
+
+_IMPORT_T0 = time.perf_counter()  # → ``startup.import``, at the last line
+
+import importlib
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -78,7 +81,7 @@ from ddp_tpu.models.generate import slot_verify_step as _verify_step
 from ddp_tpu.models import sdar as _sdar
 from ddp_tpu.models.lm import LMSpec, head_dim_of
 from ddp_tpu.ops.decode import DEFAULT_BLOCK_K, decode_block
-from ddp_tpu.obs.tracer import Tracer, get_tracer
+from ddp_tpu.obs.tracer import Tracer, get_tracer, imported
 from ddp_tpu.serve.pages import PrefixCache, page_demand
 from ddp_tpu.serve.scheduler import (
     Admission,
@@ -590,6 +593,7 @@ class ServeEngine:
         recorder=None,
         model_version: Optional[str] = None,
     ):
+        t_init = time.perf_counter()  # ``startup.state``, kept at the end
         # The whole knob surface validates + resolves through the
         # module-level resolver.
         knobs = resolve_engine_knobs(
@@ -767,6 +771,7 @@ class ServeEngine:
         self._build_info = build_info()
         # {min_bucket · 2^i} ∪ {chunk}: the whole compiled-width set.
         self.buckets = self.scheduler.bucket_list()
+        t_lanes = time.perf_counter()
         self._slots = [_Slot(index=i) for i in range(slots)]
         cache_dtype = jnp.int8 if kv_dtype == "int8" else jnp.float32
         if self.paged:
@@ -806,6 +811,10 @@ class ServeEngine:
         self._sample_steps = self._put(jnp.zeros((slots,), jnp.int32))
         self._temps = self._put(jnp.zeros((slots,), jnp.float32))
         self._top_ps = self._put(jnp.ones((slots,), jnp.float32))
+        self.tracer.phase_complete(
+            "startup.lane_cache", t_lanes, time.perf_counter() - t_lanes,
+            parent=t_init,
+        )
         # Device values dispatched but not yet read back:
         # ("first", scalar, slot) | ("decode", [S] array, lanes).
         self._pending: list[tuple[str, Any, Any]] = []
@@ -1061,6 +1070,10 @@ class ServeEngine:
         self.spec_drafted_total = 0
         self.spec_accepted_total = 0
         self.accept_rate = StatSummary()
+        self.tracer.phase_complete(
+            "startup.state", t_init, time.perf_counter() - t_init,
+            nums=("engine",),
+        )
 
     def _put(self, tree):
         """Commit a pytree of arrays to the engine's device (a no-op
@@ -1213,66 +1226,109 @@ class ServeEngine:
         """
         if self.active:
             raise RuntimeError("warmup() requires an idle engine")
+        # Kept records of the process's start (obs/tracer.py): the
+        # warm-up, under it each call that builds one program (as many
+        # as ``compile_counts()`` sums to), then the wait for the
+        # device. What each call traced, lowered and loaded is in the
+        # ``compile.*`` records its interval contains.
+        tracer = self.tracer
+        with tracer.phase("startup.warmup") as warm:
+
+            def program(name, width=0):
+                return tracer.phase(
+                    "startup.warmup_program", parent=warm.t0,
+                    nums=(name, width),
+                )
+
+            last = (self._warm_blocks if self.block_len
+                    else self._warm_tokens)(program)
+            with tracer.phase("startup.warmup_wait", parent=warm.t0):
+                jax.block_until_ready(last)
+            counts = self.compile_counts()
+            warm.nums = (sum(counts.values()),)
+        return counts
+
+    def _chunk_programs(self):
+        return (("prefill_first", self._chunk_first),
+                ("prefill_chunk", self._chunk_cont))
+
+    def _warm_blocks(self, program):
+        """Block diffusion's program set, each call under
+        ``program(name, width)`` → what the last call returned."""
         # The chunk programs are warmed with what a step hands them
         # (numpy scalars, uploaded with the call): an argument of
         # another kind is another entry in the jit cache.
         czero, off, cold, whole = (
             np.int32(0), np.bool_(False), np.float32(0.0), np.float32(1.0))
-        zero = jnp.int32(0)
-        if self.block_len:
-            tail = np.zeros((self.block_len,), np.int32)
-            for fn in (self._chunk_first, self._chunk_cont):
-                for w in self.buckets:
+        tail = np.zeros((self.block_len,), np.int32)
+        for name, fn in self._chunk_programs():
+            for w in self.buckets:
+                with program(name, w):
                     self._cache, self._lanes, _ = fn(
                         self.params, self._cache, self._lanes, czero,
                         np.zeros((w,), np.int32), czero, np.int32(w),
                         off, tail, czero, czero, czero, cold, whole,
                     )
+        with program("block_step"):
             self._cache, self._lanes, report, _ = self._decode(
                 self.params, self._cache, self._lanes
             )
-            jax.block_until_ready(report)
-            return self.compile_counts()
-        for fn in (self._chunk_first, self._chunk_cont):
+        return report
+
+    def _warm_tokens(self, program):
+        """The one-token program set (and the speculative one), as
+        ``_warm_blocks``."""
+        czero, off, cold, whole = (
+            np.int32(0), np.bool_(False), np.float32(0.0), np.float32(1.0))
+        zero = jnp.int32(0)
+        for name, fn in self._chunk_programs():
             for w in self.buckets:
-                (self._cache, self._toks, self._seeds,
-                 self._sample_steps, self._temps, self._top_ps,
-                 _) = fn(
-                    self.params, self._cache, self._toks, self._seeds,
-                    self._sample_steps, self._temps, self._top_ps,
-                    czero, np.zeros((w,), np.int32), czero,
-                    np.int32(w), off, czero, cold, whole,
-                )
-        self._toks, self._cache, self._sample_steps = self._decode(
-            self.params, self._cache, self._toks, self._seeds,
-            self._sample_steps, self._temps, self._top_ps,
-        )
-        if self.spec_tokens:
-            for fn in (self._draft_chunk_first, self._draft_chunk_cont):
-                for w in self.buckets:
-                    (self._draft_cache, self._d_toks, self._d_seeds,
-                     self._d_steps, self._d_temps, self._d_top_ps,
+                with program(name, w):
+                    (self._cache, self._toks, self._seeds,
+                     self._sample_steps, self._temps, self._top_ps,
                      _) = fn(
-                        self.draft_params, self._draft_cache,
-                        self._d_toks, self._d_seeds, self._d_steps,
-                        self._d_temps, self._d_top_ps,
-                        zero, jnp.zeros((w,), jnp.int32), zero,
-                        jnp.int32(w), jnp.asarray(False), zero,
-                        jnp.float32(0.0), jnp.float32(1.0),
+                        self.params, self._cache, self._toks, self._seeds,
+                        self._sample_steps, self._temps, self._top_ps,
+                        czero, np.zeros((w,), np.int32), czero,
+                        np.int32(w), off, czero, cold, whole,
                     )
-            _, self._draft_cache = self._draft_decode(
-                self.draft_params, self._draft_cache, self._toks,
-                self._cache.pos, self._sync_pos,
+        with program("decode"):
+            self._toks, self._cache, self._sample_steps = self._decode(
+                self.params, self._cache, self._toks, self._seeds,
+                self._sample_steps, self._temps, self._top_ps,
             )
-            (self._toks, self._cache, self._sample_steps, _t, _m
-             ) = self._verify(
-                self.params, self._cache, self._toks,
-                jnp.zeros((self.num_slots, self.spec_tokens), jnp.int32),
-                self._seeds, self._sample_steps, self._temps,
-                self._top_ps,
-            )
-        jax.block_until_ready(self._toks)
-        return self.compile_counts()
+        if self.spec_tokens:
+            for name, fn in (
+                ("draft_prefill_first", self._draft_chunk_first),
+                ("draft_prefill_chunk", self._draft_chunk_cont),
+            ):
+                for w in self.buckets:
+                    with program(name, w):
+                        (self._draft_cache, self._d_toks, self._d_seeds,
+                         self._d_steps, self._d_temps, self._d_top_ps,
+                         _) = fn(
+                            self.draft_params, self._draft_cache,
+                            self._d_toks, self._d_seeds, self._d_steps,
+                            self._d_temps, self._d_top_ps,
+                            zero, jnp.zeros((w,), jnp.int32), zero,
+                            jnp.int32(w), jnp.asarray(False), zero,
+                            jnp.float32(0.0), jnp.float32(1.0),
+                        )
+            with program("draft_decode"):
+                _, self._draft_cache = self._draft_decode(
+                    self.draft_params, self._draft_cache, self._toks,
+                    self._cache.pos, self._sync_pos,
+                )
+            with program("spec_verify"):
+                (self._toks, self._cache, self._sample_steps, _t, _m
+                 ) = self._verify(
+                    self.params, self._cache, self._toks,
+                    jnp.zeros((self.num_slots, self.spec_tokens),
+                              jnp.int32),
+                    self._seeds, self._sample_steps, self._temps,
+                    self._top_ps,
+                )
+        return self._toks
 
     # ---- model lifecycle (serve/lifecycle.py) -----------------------
 
@@ -2707,3 +2763,6 @@ class ServeEngine:
                 queue_s=c.queue_s,
                 ok=c.status == COMPLETE,
             )
+
+
+imported(__name__, _IMPORT_T0)

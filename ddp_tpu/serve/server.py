@@ -25,7 +25,8 @@ every container this repo targets, and the API is three routes:
   GET  /stats      → 200 engine.stats() (TTFT/throughput summaries,
                     compile counts — the static-shape invariant is an
                     OBSERVABLE, not a comment)
-  GET  /statusz    → 200 {"ok", "stats", "trace", "build_info"} —
+  GET  /statusz    → 200 {"ok", "stats", "trace", "startup",
+                    "build_info"} —
                     stats (including mergeable summary states, SLO
                     state when --slo is set, and build provenance)
                     plus the live span-trace tail (``.trace`` is a
@@ -67,13 +68,17 @@ immediately with the scheduler's reason.
 
 from __future__ import annotations
 
+import time
+
+_IMPORT_T0 = time.perf_counter()  # → ``startup.import``, at the last line
+
 import json
 import math
 import threading
-import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ddp_tpu.obs.tracer import imported
 from ddp_tpu.serve.engine import ServeEngine
 from ddp_tpu.serve.scheduler import QUEUE_FULL
 
@@ -642,6 +647,11 @@ class LMServer:
             # StatSummary states ride along so a fleet aggregator
             # (obs/aggregate.py) merges EXACTLY instead of averaging
             # percentiles.
+            # What the process did before its first step: the kept
+            # records, still here when the ring below has turned over
+            # many times. They have a lock of their own: read outside
+            # the one the engine loop steps under.
+            startup = self.tracer.startup_snapshot()
             with self._lock:
                 return {
                     "ok": self._engine_error is None,
@@ -661,6 +671,7 @@ class LMServer:
                         else {}
                     ),
                     "trace": self.tracer.snapshot(limit=512),
+                    "startup": startup,
                 }
         return None
 
@@ -876,3 +887,6 @@ def _make_handler(server: LMServer):
             self._send(status, payload, headers)
 
     return Handler
+
+
+imported(__name__, _IMPORT_T0)

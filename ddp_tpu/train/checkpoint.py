@@ -30,6 +30,10 @@ SURVEY.md §2a #8):
 
 from __future__ import annotations
 
+import time
+
+_IMPORT_T0 = time.perf_counter()  # → ``startup.import``, at the last line
+
 import json
 import logging
 import os
@@ -38,7 +42,11 @@ from typing import Any, Sequence
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
+
+from ddp_tpu.obs.tracer import imported, importing
+
+with importing("orbax.checkpoint"):
+    import orbax.checkpoint as ocp
 
 from ddp_tpu.parallel.ddp import TrainState
 
@@ -983,3 +991,6 @@ class CheckpointManager:
         if self._pytree_mgr is not None:
             self._pytree_mgr.close()
             self._pytree_mgr = None
+
+
+imported(__name__, _IMPORT_T0)

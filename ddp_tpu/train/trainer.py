@@ -14,10 +14,13 @@ program, and the Python loop just feeds it batches and reads metrics.
 
 from __future__ import annotations
 
+import time
+
+_IMPORT_T0 = time.perf_counter()  # → ``startup.import``, at the last line
+
 import dataclasses
 import logging
 import os
-import time
 from collections import deque
 from typing import Any
 
@@ -43,8 +46,9 @@ from ddp_tpu.obs.health import (
 )
 from ddp_tpu.obs.recorder import FlightRecorder, snapshot_env
 from ddp_tpu.obs.sentry import AnomalySentry, SentryConfig
+from ddp_tpu.obs.startup import startup_line
 from ddp_tpu.obs.steptime import StepAttributor, dispatch_compute_split
-from ddp_tpu.obs.tracer import Tracer, get_tracer
+from ddp_tpu.obs.tracer import Tracer, get_tracer, imported
 from ddp_tpu.obs.xprof import DeviceMemorySampler, Xprof
 from ddp_tpu.parallel.ddp import (
     create_train_state,
@@ -133,6 +137,10 @@ class EpochStats:
 
 class Trainer:
     def __init__(self, config: TrainConfig, ctx: dist.DistContext | None = None):
+        # ``startup.state`` and the phase under it (obs/tracer.py) are
+        # kept from stamps, after the fact: the tracer they go to is
+        # made below, once the process knows its rank.
+        t_init = time.perf_counter()
         self.config = config
         self.ctx = ctx or dist.setup(
             coordinator_address=config.coordinator_address,
@@ -154,6 +162,7 @@ class Trainer:
                 enabled=True,
                 ring_events=config.trace_ring_events,
                 process_id=self.ctx.process_id,
+                kept_with=get_tracer(),
             )
             if config.trace_dir
             else get_tracer()
@@ -808,6 +817,9 @@ class Trainer:
             else config.num_workers,
             tracer=self.tracer,
         )
+        # ``startup.model_init``: step builders and the initial state,
+        # whichever family.
+        t = time.perf_counter()
 
         compute_dtype = jnp.bfloat16 if config.compute_dtype == "bfloat16" else jnp.float32
         augment_fn = get_augmentation(config.augment)
@@ -1299,13 +1311,21 @@ class Trainer:
                 self.eval_step = self._xprof.instrument(
                     self.eval_step, "eval_step"
                 )
+        self.tracer.phase_complete(
+            "startup.model_init", t, time.perf_counter() - t, parent=t_init
+        )
         # ``train.dispatch``: the host's share of one step — the call
         # of the jitted step, whatever family built it. Wrapped once,
-        # here, where ``train_step`` is final; never a sync.
+        # here, where ``train_step`` is final; never a sync. The first
+        # call traces, lowers and compiles the step: that one record is
+        # also kept, with the process's start.
         step, tracer = self.train_step, self.tracer
+        record = tracer.phase
 
         def dispatch(*args, **kwargs):
-            with tracer.span("train.dispatch"):
+            nonlocal record
+            with record("train.dispatch"):
+                record = tracer.span
                 return step(*args, **kwargs)
 
         self.train_step = dispatch
@@ -1567,6 +1587,11 @@ class Trainer:
         self._raw_eval_count = 0  # companion raw evals under EMA
         self._preempt_requested = False
         self.history: list[EpochStats] = []
+        self._startup_logged = False
+        self.tracer.phase_complete(
+            "startup.state", t_init, time.perf_counter() - t_init,
+            nums=("trainer",),
+        )
 
     # ---- the reference's epoch/batch loop (train_ddp.py:192-209) ----
 
@@ -2177,7 +2202,8 @@ class Trainer:
         # Process-start chaos (ckpt_corrupt) fires BEFORE discovery so
         # the integrity/quarantine fallback below is what it drills.
         self._chaos.on_start(cfg.checkpoint_dir)
-        self.state, start_epoch = self._restore_or_init()
+        with self.tracer.phase("startup.checkpoint"):
+            self.state, start_epoch = self._restore_or_init()
         # Integrity fallbacks during discovery (train/checkpoint.py):
         # a corrupt latest was quarantined and an earlier epoch
         # restored. Surface each as a metrics record + flight-recorder
@@ -2633,6 +2659,7 @@ class Trainer:
                             "Epoch %d Batch %d Loss %.4f",
                             epoch, batch_idx, loss,
                         )
+                        self._log_startup()
                         gn = (
                             {}
                             if metrics.grad_norm is None
@@ -2710,6 +2737,14 @@ class Trainer:
         seconds = time.perf_counter() - t0
         return self._finish_epoch(epoch, losses, n_batches, seconds)
 
+    def _log_startup(self) -> None:
+        """ONE line on what the process did before its first step
+        (obs/startup.py), once that step has run: at the first loss
+        read, or, where an epoch is one dispatch, at its end."""
+        if not self._startup_logged:
+            self._startup_logged = True
+            logger.info("%s", startup_line(self.tracer))
+
     def _finish_epoch(
         self,
         epoch: int,
@@ -2718,6 +2753,7 @@ class Trainer:
         seconds: float,
         obs_extra: dict | None = None,
     ) -> EpochStats:
+        self._log_startup()
         """Shared epoch-summary contract for the step and fast paths."""
         images = n_batches * self.global_batch_size
         stats = EpochStats(
@@ -2973,3 +3009,6 @@ class Trainer:
         if self._metrics_port is not None:
             self._metrics_port.stop()
             self._metrics_port = None
+
+
+imported(__name__, _IMPORT_T0)

@@ -24,6 +24,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -234,7 +235,8 @@ def main() -> None:
     args = p.parse_args()
 
     from ddp_tpu.models.lm import LMSpec, init_lm
-    from ddp_tpu.obs.tracer import Tracer
+    from ddp_tpu.obs.startup import startup_line
+    from ddp_tpu.obs.tracer import Tracer, get_tracer
     from ddp_tpu.obs.xprof import Xprof
     from ddp_tpu.runtime.dist import enable_compile_cache
     from ddp_tpu.serve.engine import ServeEngine
@@ -251,6 +253,7 @@ def main() -> None:
     # params, and the real tree installs through the hot-swap path.
     streaming = None
     model_version = None
+    t_weights = time.perf_counter()  # → ``startup.weights``, below
     if args.init_demo:
         spec = LMSpec(
             vocab_size=args.vocab_size, total_len=args.seq_len,
@@ -342,6 +345,13 @@ def main() -> None:
         enabled=bool(args.trace_dir),
         ring_events=args.trace_ring_events,
         process_id=args.trace_rank,
+        # One account of the process's start: the imports and compiles
+        # kept in the process-global tracer, this one's phases with them.
+        kept_with=get_tracer(),
+    )
+    # Parameters (and the draft's) restored or initialised.
+    tracer.phase_complete(
+        "startup.weights", t_weights, time.perf_counter() - t_weights
     )
     # SLO engine + flight recorder (ISSUE 11): objectives evaluated
     # live inside the serving process; breach events land in the
@@ -538,6 +548,8 @@ def main() -> None:
                 ),
                 flush=True,
             )
+            # The socket listens: what the process did on its way here.
+            print(json.dumps({"startup": startup_line(tracer)}), flush=True)
             if streaming is not None:
                 # Full residency → install through the hot-swap path
                 # (same barrier, same validation) and open the lanes.
