@@ -119,10 +119,17 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # and where its operands lie: ``projection`` (the fused qkv matmul's
     # output, read and written in place), ``heads_last`` ([B, T, H·D])
     # or ``transposed`` ([B·H, T, D] copies: heads narrower than 128
-    # lanes). ``kernel``, ``operand_dtype`` and ``operand_layout`` are
-    # names, not numbers
+    # lanes); since PR 39 the kernel's ``form`` (``grid``: a grid step
+    # a block pair; ``resident``: the backward's ONE kernel, a head's
+    # operands in VMEM and the pairs walked inside), the grid steps a
+    # (batch·head) takes, the matmuls a pair costs in this kernel (2
+    # forward; backward 5 resident, 3 + 4 over the grid form's two) and
+    # the VMEM bytes reckoned for it (0: the compiler's default limit).
+    # ``kernel``, ``operand_dtype``, ``operand_layout`` and ``form``
+    # are names, not numbers
     "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
-                   "dead", "operand_dtype", "operand_layout"),
+                   "dead", "operand_dtype", "operand_layout", "form",
+                   "grid_steps", "matmuls_per_pair", "vmem_bytes"),
     # ops/ssm.py — one per traced ``pallas_call`` of the state update
     # (``ssm_state_update``, ``selective_state_update``) or of the
     # prefill scan (``selective_scan``), at trace time, zero duration:

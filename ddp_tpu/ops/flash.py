@@ -9,16 +9,45 @@ of an output block, every matmul hits the MXU with
 the [T, S] score matrix never exists. This is the TPU-native answer to
 the fused ATen attention kernels the reference inherits invisibly from
 torch's C++ core (/root/reference/train_ddp.py:199, SURVEY.md §2b N5) —
-there the fusion lives in cuDNN/ATen; here it is an explicit trio of
-Pallas kernels.
+there the fusion lives in cuDNN/ATen; here it is explicit Pallas
+kernels.
 
 Differentiation is flash end to end: the forward kernel also emits the
-per-row log-sum-exp (LSE), and the backward runs two Pallas kernels —
-one gridded over Q blocks producing dQ, one gridded over K/V blocks
-producing dK/dV — each recomputing P = exp(S − LSE) blockwise from the
-saved residuals. Peak memory of the whole VJP is O(T·D); the round-1
-version recomputed backward through a dense O(T²) reference
-(VERDICT.md "What's missing" #1).
+per-row log-sum-exp (LSE), and the backward recomputes P = exp(S − LSE)
+blockwise from the saved residuals. Peak memory of the whole VJP is
+O(T·D); the round-1 version recomputed backward through a dense O(T²)
+reference (VERDICT.md "What's missing" #1).
+
+The backward is ONE algorithm in two forms, chosen from what the code
+can observe, the shapes (``_backward_form``; no knob, no environment
+variable, no model's name). The arithmetic of a block pair — scores,
+the class's mask, P, dP = dO·Vᵀ, dS = P ∘ (dP − delta') — exists once,
+``_pair``, and both forms call it.
+
+- **resident** (PR 39; ``_resident_kernel``, named ``flash_dkv``):
+  where a head's operands, outputs and accumulators fit the VMEM
+  budget and its live pairs unroll — the train cells' 2048 x 128, up to
+  4096 in bfloat16 — ONE ``pallas_call`` on a grid of (batch·head)
+  alone. The pipeline fetches the head's q, k, v, dO, O and LSE rows
+  once, the next head's under this head's matmuls; the kernel walks
+  the live pairs itself, keys outer, queries inner, computes S, P, dP
+  and dS once a pair and accumulates dV, dK and dQ from them: five
+  matmuls and one ``exp`` pass a pair. dQ's float32 accumulator for the
+  whole head stays in VMEM scratch from the head's first pair to its
+  last; delta' = rowsum(dO ∘ O) − dLSE is made once a head inside the
+  kernel and never leaves VMEM. The walk is unrolled from the static
+  classes: no grid step, no flags table, no ``pl.when``, no strided
+  block fetch a pair. ``vmem_limit_bytes`` is reckoned from the shapes.
+- **grid** (``flash_dq`` + ``flash_dkv``): a longer head (a ring hop at
+  32k). Two kernels, each on a (batch·head, live block pair) grid: one
+  by q block producing dQ, one by k block producing dK/dV, each
+  recomputing S, P and dP for itself: seven matmuls and two ``exp``
+  passes a pair, a grid step a pair in each, and delta' travels from
+  the first to the second through HBM.
+
+Every mask (none, causal, block-causal), the ``dlse`` cotangent,
+``T != S`` and all three operand layouts go through either form; none
+is left to the grid form alone.
 
 Causal masking skips arithmetic, grid steps and fetches (PR 31). Every
 (q block, k block) pair is classified from the static shapes
@@ -27,20 +56,25 @@ under the same ``_last_key`` the mask applies). The grid's second
 dimension runs over the LIVE pairs only, through scalar-prefetched
 (outer, inner, flags) tables (``_live_pairs``): a strictly-future pair
 is no grid step, so its blocks are never fetched — 10 steps of 16 at
-2048 x 2048 in blocks of 512, in all three kernels. Of the live pairs
+2048 x 2048 in blocks of 512, in the forward and the grid backward;
+the resident backward walks the same 10 pairs inside its one step
+(``_walk``). Of the live pairs
 only the diagonal ones run the program with the mask (iota, compare,
 select); interior pairs run the plain one, and the guards against a
 row with no visible key are built only where such a row can exist
 (``T > S``). Each traced ``pallas_call`` leaves a ``flash.plan`` record
-in the tracer's ring (``obs/tracer.py``): blocks, steps visited, of
-which masked, of which dead.
+in the tracer's ring (``obs/tracer.py``): blocks, pairs visited, of
+which masked, of which dead, the kernel's form (``grid`` or
+``resident``), its grid steps a (batch·head), the matmuls a pair costs
+in it (2 forward; 5 resident, 3 + 4 over the grid pair) and the VMEM
+bytes reckoned.
 
 What a step does NOT pay for, found on the chip (PERF.md section 6, PR
 31): a cross-lane sum (the forward's ``l`` travels as lane-partial
 sums, reduced once a q block), lane broadcasts of [block, 1] columns
 (the forward's statistics stay [block_q, LANES]), and transposes of
-the [block_q, block_k] tiles on their way to the MXU (the dK/dV kernel
-builds its tile keys first). MXU operands are float32 copies of the
+the [block_q, block_k] tiles on their way to the MXU (the dK/dV walk,
+grid or resident, builds its tile keys first). MXU operands are float32 copies of the
 blocks: at the default precision the MXU takes them in one bfloat16
 pass, bit for bit what explicit bfloat16 operands give, and the
 explicit casts measured no faster.
@@ -56,15 +90,17 @@ Where the operands lie (PR 33). A kernel reads an operand where its
 producer wrote it and writes a result where its consumer reads it: an
 operand is an array [B, T, columns] and a rule that maps a head to a
 D-wide column block (``_Operand``), and the grid's first dimension
-still runs over (batch, head) — ``_spec`` is the one index rule of all
-three kernels. Two entries share them. ``flash_attention_projection``
+still runs over (batch, head) — ``_spec`` is the one index rule of the
+grid kernels, ``_head_spec`` the same rule for a head's whole columns
+(the resident backward). Two entries share them. ``flash_attention_projection``
 takes the fused head-major projection [B, T, H·3·D] as
 ``models/vit.py``'s ``qkv`` matmul wrote it (one array seen three
 times, stride 3, offsets 0, 1, 2), writes ``out`` as [B, T, H·D], which
 is what ``proj`` reads, and its backward writes dq, dk, dv into ONE
-[B, T, H·3·D] array, the projection's cotangent (``flash_dq`` writes
-its column blocks of it, ``flash_dkv`` takes it aliased and writes a
-head's dk | dv beside them). ``flash_attention`` /
+[B, T, H·3·D] array, the projection's cotangent (resident: a head's
+[T, 3·D] dq | dk | dv columns are the kernel's one output block; grid:
+``flash_dq`` writes its column blocks of it, ``flash_dkv`` takes it
+aliased and writes a head's dk | dv beside them). ``flash_attention`` /
 ``flash_attention_with_lse`` take separate
 [B, T, H, D] operands (the ring's hops, GQA after its repeat), which
 are [B, T, H·D] by a free reshape. Nothing is sliced, transposed,
@@ -80,9 +116,12 @@ travel as [B·H, T, LANES] fp32 broadcast across a 128-lane minor
 dimension — a [.., T, 1] layout would be lane-padded to 128 in VMEM
 anyway, and 2-D [B·H, T] blocks of one row are not tileable. They stay
 so from kernel to kernel: the custom VJP's residual is the LSE as
-``flash_fwd`` wrote it, and delta' = rowsum(dO ∘ O) − dLSE is made by
-``flash_dq`` on the first step of each q block, from the dO and O
-blocks it holds, and handed to ``flash_dkv``. Scratch
+``flash_fwd`` wrote it. In the grid form delta' = rowsum(dO ∘ O) − dLSE
+is made by ``flash_dq`` on the first step of each q block, from the dO
+and O blocks it holds, and handed to ``flash_dkv`` as such an array;
+the resident kernel makes it once a head and keeps the head's LSE and
+delta' in scratch as its tiles meet them (a [1, T] row keys first).
+The grid kernels' scratch
 accumulators persist across the steps of an output block and flush on
 its last one (``pl.when`` on the step's flags), the scheme of
 jax.experimental.pallas.ops.tpu.flash_attention. The live-pair tables
@@ -92,7 +131,8 @@ most (4,096 at 32k x 32k in blocks of 512).
 ``interpret=True`` runs the kernels on CPU for tests — the same
 program the TPU compiles, minus Mosaic. On-chip agreement with the
 dense reference is checked by ``scripts/check_kernels.py`` (run by
-``chip_smoke.py``; ``--time`` prints the three kernels' device ms a
+``chip_smoke.py``; both forms of the backward through both entries;
+``--time`` prints the forward's and each form's backward's device ms a
 call at the train cells' shape); the tolerances it found are in
 CHANGES.md.
 """
@@ -324,12 +364,40 @@ def _saved_lse(lse, empty_rows):
     return jnp.where(jnp.isfinite(lse), lse, 0.5 * jnp.finfo(jnp.float32).max)
 
 
+def _pair(q, k, v, do, lse, dl, q_start, k_start, *, masked, keys_first,
+          causal, block_q, block_k, T_total, S_total):
+    """THE arithmetic of one live (q block, k block) pair of the
+    backward, the one form all three backward kernels run: the scores
+    recomputed from ``q`` (which carries the softmax scale), the mask
+    of a diagonal pair, P = exp(S − LSE) from the forward's saved LSE,
+    dP = dO·Vᵀ and dS = P ∘ (dP − delta'). Returns (P, dS) as
+    [block_q, block_k] tiles, or KEYS FIRST, [block_k, block_q]: then
+    Pᵀ and dSᵀ enter the dK/dV matmuls as they stand (queries first,
+    each would be transposed on its way to the MXU) and ``lse`` and
+    ``dl`` are [1, block_q] rows that meet the tile along the sublanes,
+    where queries first takes [block_q, 1] columns."""
+    swap = (lambda a, b: (b, a)) if keys_first else (lambda a, b: (a, b))
+    s = _dot(*swap(q, k), (1, 1))
+    if masked:
+        s = _causal_mask(
+            s, q_start, k_start, block_q, block_k, S_total, T_total,
+            causal, keys_first,
+        )
+    # masked: exp(-inf) = 0
+    empty_rows = bool(causal) and T_total > S_total
+    p = jnp.exp(s - _saved_lse(lse, empty_rows))
+    dp = _dot(*swap(do, v), (1, 1))
+    return p, p * (dp - dl)
+
+
 def _dq_kernel(
     qi_ref, kj_ref, flags_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-    *rest, scale, causal, block_q, block_k, T_total, S_total,
+    *rest, scale, **geometry,
 ):
     """Grid (B·H, live pairs by q block): dQ accumulates over the
-    streamed KV blocks of each q block.
+    streamed KV blocks of each q block — with ``_dkv_kernel`` the GRID
+    form of the backward, for a head that does not fit the VMEM
+    (``_backward_form``).
 
     The first step of a q block also makes its rows of delta' =
     rowsum(dO ∘ O) − dLSE from the dO and O blocks it holds, lane-
@@ -337,51 +405,49 @@ def _dq_kernel(
     ``flash_dkv`` reads) that this kernel reads back on every step of
     the block. ``rest`` is ``dq_ref, dl_ref`` and the accumulator, led
     by ``dlse_ref`` (lane-broadcast like the LSE) where the caller
-    differentiates the LSE output too. With P recomputed as
-    exp(S − LSE), dS = P ∘ (dO·Vᵀ − delta') and dQ = scale · dS·K.
+    differentiates the LSE output too. dQ = scale · dS·K (``_pair``).
     """
     *dlse_ref, dq_ref, dl_ref, dq_acc = rest
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
     step = pl.program_id(1)
     flags = flags_ref[step]
     q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
-    empty_rows = bool(causal) and T_total > S_total
 
     @pl.when(flags & _FIRST != 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-        delta = (
-            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        ).sum(axis=-1, keepdims=True)
-        dl = jnp.broadcast_to(delta, dl_ref.shape[1:])
-        dl_ref[0] = dl - dlse_ref[0][0] if dlse_ref else dl
+        dl_ref[0] = _delta(do_ref[0], o_ref[0], *(r[0] for r in dlse_ref))
 
     def _compute(masked):
         q = q_ref[0].astype(jnp.float32) * scale
-        s = _dot(q, k_ref[0], (1, 1))  # [block_q, block_k]
-        if masked:
-            s = _causal_mask(
-                s, q_start, k_start, block_q, block_k, S_total, T_total,
-                causal,
-            )
-        # masked: exp(-inf) = 0
-        p = jnp.exp(s - _saved_lse(_row_stat(lse_ref), empty_rows))
-        dp = _dot(do_ref[0], v_ref[0], (1, 1))
-        ds = p * (dp - _row_stat(dl_ref))
+        _, ds = _pair(
+            q, k_ref[0], v_ref[0], do_ref[0], _row_stat(lse_ref),
+            _row_stat(dl_ref), q_start, k_start, masked=masked,
+            keys_first=False, **geometry)
         dq_acc[...] = dq_acc[...] + _dot(ds, k_ref[0], (1, 0))
 
-    _by_class(flags, causal, _compute)
+    _by_class(flags, geometry["causal"], _compute)
 
     @pl.when(flags & _LAST != 0)
     def _flush():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
+def _delta(do, o, dlse=None):
+    """delta' = rowsum(dO ∘ O) − dLSE of a block's rows, float32,
+    broadcast over [rows, LANES] (``dlse`` lies so already)."""
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        axis=-1, keepdims=True)
+    dl = jnp.broadcast_to(delta, (delta.shape[0], LANES))
+    return dl if dlse is None else dl - dlse
+
+
 def _dkv_kernel(
     kj_ref, qi_ref, flags_ref, k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref,
-    *rest, scale, causal, block_q, block_k, T_total, S_total, joined=False,
+    *rest, scale, joined=False, **geometry,
 ):
     """Grid (B·H, live pairs by k block): dK/dV accumulate over the
-    streamed Q blocks of each k block.
+    streamed Q blocks of each k block (the grid form's second kernel).
 
     ``rest`` is ``dk_ref, dv_ref`` and the two accumulators, or, when
     ``joined`` (the fused projection), the cotangent array itself
@@ -391,14 +457,11 @@ def _dkv_kernel(
     one block.
 
     Where the q block fills whole lane groups the tile is built KEYS
-    FIRST, Sᵀ = K·Qᵀ [block_k, block_q]: then Pᵀ and dSᵀ enter the two
-    accumulating matmuls as they stand (queries first, each would be
-    transposed on its way to the MXU) and the row statistics are
-    [1, block_q] rows that meet the tile along the sublanes."""
+    FIRST (``_pair``)."""
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
     step = pl.program_id(1)
     flags = flags_ref[step]
     q_start, k_start = qi_ref[step] * block_q, kj_ref[step] * block_k
-    empty_rows = bool(causal) and T_total > S_total
     keys_first = block_q % LANES == 0
     *out_refs, dk_acc, dv_acc = rest
 
@@ -410,27 +473,17 @@ def _dkv_kernel(
     def _compute(masked):
         # q carries the scale, and so does the dK it accumulates into
         q = q_ref[0].astype(jnp.float32) * scale
-        if keys_first:
-            stat = lambda ref: ref[0].T[:1]  # [1, block_q]
-            swap = lambda a, b: (b, a)
-            over_queries = 1
-        else:
-            stat = _row_stat  # [block_q, 1]
-            swap = lambda a, b: (a, b)
-            over_queries = 0
-        s = _dot(*swap(q, k_ref[0]), (1, 1))
-        if masked:
-            s = _causal_mask(
-                s, q_start, k_start, block_q, block_k, S_total, T_total,
-                causal, keys_first,
-            )
-        p = jnp.exp(s - _saved_lse(stat(lse_ref), empty_rows))
+        # [1, block_q] rows keys first, else [block_q, 1] columns
+        stat = (lambda ref: ref[0].T[:1]) if keys_first else _row_stat
+        p, ds = _pair(
+            q, k_ref[0], v_ref[0], do_ref[0], stat(lse_ref), stat(dl_ref),
+            q_start, k_start, masked=masked, keys_first=keys_first,
+            **geometry)
+        over_queries = int(keys_first)
         dv_acc[...] = dv_acc[...] + _dot(p, do_ref[0], (over_queries, 0))
-        dp = _dot(*swap(do_ref[0], v_ref[0]), (1, 1))
-        ds = p * (dp - stat(dl_ref))
         dk_acc[...] = dk_acc[...] + _dot(ds, q, (over_queries, 0))
 
-    _by_class(flags, causal, _compute)
+    _by_class(flags, geometry["causal"], _compute)
 
     @pl.when(flags & _LAST != 0)
     def _flush():
@@ -443,6 +496,88 @@ def _dkv_kernel(
             dk_ref, dv_ref = out_refs
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _resident_kernel(
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
+    scale, walk, joined=False, **geometry,
+):
+    """Grid (B·H,): the whole backward of one head, its operands
+    RESIDENT in VMEM (the resident form, ``_backward_form``). The
+    pipeline fetches a head's q, k, v, dO, O and LSE rows once, the next
+    head's under this head's matmuls, and the kernel walks the head's
+    live block pairs itself, keys outer, queries inner, both ascending
+    (``_dkv_kernel``'s walk): per pair ``_pair`` once, then dV += Pᵀ·dO,
+    dK += dSᵀ·Q and dQ += dS·K: FIVE matmuls and one ``exp`` pass where
+    the grid form's two kernels run seven and two. No grid step, flags
+    table or strided block fetch is paid a pair, and delta' is made
+    once a head and never leaves VMEM.
+
+    ``walk`` (``_walk``) is static, an entry a k block: the first live q
+    block and the first interior one. The walk is UNROLLED: every
+    pair's class and every slice is static (no ``pl.when``, no table),
+    and the compiler schedules across pairs (on the chip 1.17 ms a call
+    at the train cells' shape against 1.53 for the same walk as two
+    loops: PERF.md section 6, PR 39). dQ's float32 accumulator for the
+    whole head stays in scratch from the head's first pair to its last
+    and takes the k blocks in ascending order.
+
+    ``rest``: ``dlse_ref`` where the LSE output is differentiated, the
+    outputs — ``dq, dk, dv``, or with ``joined`` the head's
+    [T, 3·D] dq | dk | dv columns of the projection's cotangent as ONE
+    block — and the scratch: dQ's accumulator [T, D] and the head's LSE
+    and delta' as the tiles meet them (``_pair``: a [1, T] row keys
+    first, else a [T, 1] column)."""
+    *refs, dq_acc, lse_s, dl_s = rest
+    outs = refs[-1:] if joined else refs[-3:]
+    dlse_ref = refs[:-len(outs)]
+    block_q, block_k = geometry["block_q"], geometry["block_k"]
+    n_q = geometry["T_total"] // block_q
+    D = dq_acc.shape[1]
+    keys_first = block_q % LANES == 0
+    over_queries = int(keys_first)
+
+    def put(which, at, x):  # 0, 1, 2: dq, dk, dv
+        if joined:
+            outs[0][0, at, which * D:(which + 1) * D] = x.astype(
+                outs[0].dtype)
+        else:
+            outs[which][0, at] = x.astype(outs[which].dtype)
+
+    def stat_at(at):  # a q block's place in the head's statistics
+        return (slice(None), at) if keys_first else (at, slice(None))
+
+    def as_met(x):  # [block_q, LANES] lane-broadcast -> as a tile meets it
+        return x.T[:1] if keys_first else x[:, :1]
+
+    q_blocks = [pl.ds(i * block_q, block_q) for i in range(n_q)]
+    for at in q_blocks:
+        dl_s[stat_at(at)] = as_met(_delta(
+            do_ref[0, at], o_ref[0, at], *(r[0, at] for r in dlse_ref)))
+        lse_s[stat_at(at)] = as_met(lse_ref[0, at])
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    for j, (lo, mid) in enumerate(walk):
+        at_k = pl.ds(j * block_k, block_k)
+        k, v = k_ref[0, at_k], v_ref[0, at_k]
+        dk = dv = jnp.zeros((block_k, D), jnp.float32)
+        for i in range(lo, n_q):
+            at = q_blocks[i]
+            # q carries the scale, and so does the dK it accumulates into
+            q = q_ref[0, at].astype(jnp.float32) * scale
+            do = do_ref[0, at]
+            p, ds = _pair(
+                q, k, v, do, lse_s[stat_at(at)], dl_s[stat_at(at)],
+                i * block_q, j * block_k, masked=i < mid,
+                keys_first=keys_first, **geometry)
+            dv = dv + _dot(p, do, (over_queries, 0))
+            dk = dk + _dot(ds, q, (over_queries, 0))
+            dq_acc[at] = dq_acc[at] + _dot(ds, k, (1 - over_queries, 0))
+        put(1, at_k, dk)
+        put(2, at_k, dv)
+
+    for at in q_blocks:
+        put(0, at, dq_acc[at] * scale)
 
 
 def pick_block(n: int, requested: int, dtype) -> int:
@@ -546,23 +681,58 @@ def _operand_spec(rows, D, table, operand):
     return _spec(rows, D, table, *operand[1:])
 
 
-def _plan(kernel, T, S, block_q, block_k, causal, layout, *, by_key=False):
-    """The live-pair tables of one ``pallas_call``, and its
-    ``flash.plan`` record in the tracer's ring (trace time: a compiled
-    step leaves none): the grid steps a (batch·head) visits, how many
-    of them run the masked program, how many are dead, the dtype the
-    MXU's operands are handed over in, and where the operands lie
-    (``projection``, ``heads_last`` or ``transposed``)."""
+def _record_plan(kernel, block_q, block_k, classes, layout, form, steps,
+                 matmuls, vmem_bytes=0):
+    """One ``pallas_call``'s ``flash.plan`` record in the tracer's ring
+    (trace time: a compiled step leaves none): the block pairs a
+    (batch·head) visits (``classes``, one each), how many of them run
+    the masked program, how many are dead, the dtype the MXU's operands
+    are handed over in, where the operands lie (``projection``, ``heads_last`` or
+    ``transposed``), the kernel's form (``grid``: a grid step a pair;
+    ``resident``: the head in VMEM, the kernel walks the pairs), the
+    grid steps a (batch·head) takes, the matmuls a pair costs in this
+    kernel (forward 2; backward 5 resident, 3 + 4 over the grid form's
+    two kernels) and the VMEM bytes reckoned for it (0: the compiler's
+    default limit stands)."""
+    get_tracer().complete(
+        "flash.plan", time.perf_counter(), 0.0,
+        nums=(kernel, block_q, block_k, len(classes),
+              sum(1 for c in classes if c == _DIAGONAL),
+              sum(1 for c in classes if c == _DEAD), "float32", layout,
+              form, steps, matmuls, vmem_bytes),
+    )
+
+
+def _plan(kernel, T, S, block_q, block_k, causal, layout, matmuls, *,
+          by_key=False):
+    """The live-pair tables of one grid ``pallas_call``, and its
+    ``flash.plan`` record."""
     tables = _live_pairs(
         _classify(T, S, block_q, block_k, causal), by_key=by_key)
     flags = tables[2]
-    get_tracer().complete(
-        "flash.plan", time.perf_counter(), 0.0,
-        nums=(kernel, block_q, block_k, len(flags),
-              sum(1 for f in flags if f & _DIAGONAL),
-              sum(1 for f in flags if f & _DEAD), "float32", layout),
-    )
+    _record_plan(
+        kernel, block_q, block_k,
+        [f & (_DIAGONAL | _DEAD) for f in flags], layout, "grid",
+        len(flags), matmuls)
     return tuple(jnp.asarray(t, jnp.int32) for t in tables)
+
+
+def _walk(classes):
+    """The resident kernel's walk of ``classes`` (``_classify``), an
+    entry a k block: ``(lo, mid)``, the first live q block and the
+    first interior one. Later rows see more keys, so down a k block's
+    column the pairs are dead, then diagonal, then interior: q blocks
+    ``lo`` to ``mid`` run the masked program, ``mid`` on the plain
+    one."""
+    walk = []
+    for column in zip(*classes):
+        dead = sum(1 for c in column if c == _DEAD)
+        diagonal = sum(1 for c in column if c == _DIAGONAL)
+        assert list(column) == (
+            [_DEAD] * dead + [_DIAGONAL] * diagonal
+            + [0] * (len(column) - dead - diagonal)), column
+        walk.append((dead, dead + diagonal))
+    return tuple(walk)
 
 
 def _forward_call(
@@ -574,7 +744,7 @@ def _forward_call(
     S = k.array.shape[1]
     heads = q.heads
     block_q, block_k = _pick_blocks(T, S, block_q, block_k, q.array.dtype)
-    by_q = _plan("flash_fwd", T, S, block_q, block_k, causal, layout)
+    by_q = _plan("flash_fwd", T, S, block_q, block_k, causal, layout, 2)
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=D**-0.5, causal=causal, block_q=block_q,
@@ -607,26 +777,172 @@ def _forward_call(
     )(*by_q, q.array, k.array, v.array)
 
 
+# What the resident backward may ask of the VMEM: three quarters of
+# the 128 MiB a TensorCore of a TPU v4, v5e, v5p or v6e holds. The rest
+# is the compiler's.
+_VMEM_BUDGET = 96 * 1024 * 1024
+# The live block pairs its unrolled walk may hold: a non-causal head of
+# 4096 in blocks of 512. The program grows with the pairs and Mosaic's
+# time to compile it faster (for a described v5e, causal in blocks of
+# 512: 10 pairs at 2048 4 s, 36 at 4096 19 s, 136 at 8192 98 s).
+_UNROLL_PAIRS = 64
+
+
+def _backward_form(T, S, D, dtype, block_q, block_k, causal, *, dlse=False,
+                   budget=_VMEM_BUDGET):
+    """How the backward of one (batch·head) of ``T`` queries and ``S``
+    keys runs, from what the code can observe, its shapes: -> (form,
+    VMEM bytes reckoned). ``resident`` (``_resident_kernel``) where the
+    head's operands, outputs and accumulators fit ``budget`` and its
+    live block pairs unroll (``_UNROLL_PAIRS``), else ``grid``
+    (``flash_dq`` + ``flash_dkv``, a block pair a grid step: a ring hop
+    at 32k). Reckoned: q, dO, O [T, D], k, v [S, D] and the LSE rows
+    [T, LANES] float32 (and dLSE), twice, because the pipeline fetches
+    the next head under this one; dq, dk, dv, twice; dQ's float32
+    accumulator [T, D], a k block's dK and dV, the head's statistics;
+    and the float32 tiles of a pair — scores, P, dP, dS and the
+    compiler's copies of them [block_q, block_k], the blocks'
+    [block, D] copies — half again on top for what the compiler keeps
+    of its own. A minor dimension under 128 lanes is padded to 128."""
+    block_q, block_k = _pick_blocks(T, S, block_q, block_k, dtype)
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-D // LANES) * LANES
+    operands = (3 * T + 2 * S) * lanes * item + (1 + dlse) * T * LANES * 4
+    outputs = (T + 2 * S) * lanes * item
+    scratch = (T + 2 * block_k) * lanes * 4 + 2 * T * 4 * (
+        LANES if block_q % LANES else 8)
+    tiles = 6 * block_q * block_k * 4 + 6 * max(block_q, block_k) * lanes * 4
+    reckoned = (2 * operands + 2 * outputs + scratch + tiles) * 3 // 2
+    pairs = sum(c != _DEAD for row in _classify(T, S, block_q, block_k, causal)
+                for c in row)
+    fits = reckoned <= budget and pairs <= _UNROLL_PAIRS
+    return ("resident" if fits else "grid"), reckoned
+
+
 def _backward_calls(
+    q, k, v, g, out, lse, D, layout, *, dlse=None, **opts,
+):
+    """The backward on ``_Operand``s, in the form ``_backward_form``
+    finds for the shapes: ONE kernel over a resident head
+    (``_backward_resident``), or the grid pair (``_backward_grid``).
+    One algorithm, ``_pair``, under both; every mask, ``dlse``,
+    ``T != S`` and all three layouts go through either.
+
+    ``out`` is the forward's, ``lse`` [B·H, T, LANES] fp32 as it wrote
+    it, ``dlse`` the LSE output's cotangent in the same layout or None.
+    Returns (dq, dk, dv), each like a stride-1 operand, or with
+    ``joined`` ONE [B', T, heads·3·D] array, the fused projection's
+    cotangent."""
+    form, _ = _backward_form(
+        q.array.shape[1], k.array.shape[1], D, q.array.dtype,
+        opts["block_q"], opts["block_k"], opts["causal"],
+        dlse=dlse is not None)
+    call = _backward_resident if form == "resident" else _backward_grid
+    return call(q, k, v, g, out, lse, D, layout, dlse=dlse, **opts)
+
+
+def _head_spec(rows, width, heads=1, stride=1, offset=0):
+    """BlockSpec of a head's WHOLE [rows, width] columns of a
+    [B', rows, columns] array under ``_spec``'s rule for the head: the
+    resident kernel's operands, on a grid of (batch·head) alone."""
+
+    def index(bh):
+        return (lax.div(bh, heads), 0, lax.rem(bh, heads) * stride + offset)
+
+    return pl.BlockSpec((1, rows, width), index, memory_space=pltpu.VMEM)
+
+
+def _backward_resident(
     q, k, v, g, out, lse, D, layout, *, causal, block_q, block_k, interpret,
     dlse=None, joined: bool = False,
 ):
-    """``flash_dq`` and ``flash_dkv`` on ``_Operand``s; ``out`` is the
-    forward's, ``lse`` [B·H, T, LANES] fp32 as it wrote it, ``dlse`` the
-    LSE output's cotangent in the same layout or None. Returns (dq, dk,
-    dv), each like a stride-1 operand, or with ``joined`` ONE
-    [B', T, heads·3·D] array, the fused projection's cotangent:
-    ``flash_dq`` writes a head's dq columns of it, ``flash_dkv`` the dk
-    and dv columns of the same buffer. delta' travels from ``flash_dq``,
-    which makes it, to ``flash_dkv`` as [B·H, T, LANES] fp32."""
+    """``flash_dkv``, the resident form: one call, one grid step a
+    (batch·head) (``_resident_kernel``; it keeps the name of the kernel
+    whose walk it is, which now also accumulates dQ). With ``joined``
+    its output block is a head's [T, 3·D] dq | dk | dv columns of the
+    projection's cotangent: no second call, no aliasing. The call
+    itself is a jitted function of its shapes (``_resident_call``): a
+    model's layers are ONE program, traced and lowered once and not
+    once a layer (its unrolled walk is a longer trace than a grid
+    kernel's two bodies)."""
+    T, S = q.array.shape[1], k.array.shape[1]
+    dtype = q.array.dtype
+    _, vmem_bytes = _backward_form(
+        T, S, D, dtype, block_q, block_k, causal, dlse=dlse is not None)
+    block_q, block_k = _pick_blocks(T, S, block_q, block_k, dtype)
+    classes = _classify(T, S, block_q, block_k, causal)
+    _record_plan(
+        "flash_dkv", block_q, block_k,
+        [c for row in classes for c in row if c != _DEAD], layout,
+        "resident", 1, 5, vmem_bytes)
+    operands = (q, k, v, g, out)
+    return _resident_call(
+        *(x.array for x in operands), lse, *(() if dlse is None else (dlse,)),
+        rules=tuple(tuple(x[1:]) for x in operands), D=D, causal=causal,
+        walk=_walk(classes), block_q=block_q, block_k=block_k,
+        interpret=interpret, joined=joined, vmem_bytes=vmem_bytes)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "rules", "D", "causal", "walk", "block_q", "block_k", "interpret",
+    "joined", "vmem_bytes"))
+def _resident_call(q, k, v, g, out, *stats, rules, D, causal, walk, block_q,
+                   block_k, interpret, joined, vmem_bytes):
+    """The resident backward's ``pallas_call`` on the operands' arrays;
+    ``rules`` are their (heads, stride, offset) (``_Operand``), ``stats``
+    the LSE rows and, where given, the dLSE rows, ``walk`` the head's
+    (``_walk``)."""
+    Bp, T, _ = q.shape
+    S = k.shape[1]
+    heads = rules[0][0]
+    if joined:
+        out_specs = _head_spec(T, 3 * D, heads)
+        out_shape = jax.ShapeDtypeStruct((Bp, T, heads * 3 * D), q.dtype)
+    else:
+        out_specs = [_head_spec(n, D, heads) for n in (T, S, S)]
+        out_shape = [jax.ShapeDtypeStruct((Bp, n, heads * D), q.dtype)
+                     for n in (T, S, S)]
+    # a row keys first (``_pair``), else a column
+    stat_shape = (1, T) if block_q % LANES == 0 else (T, 1)
+    return pl.pallas_call(
+        functools.partial(
+            _resident_kernel, scale=D**-0.5, joined=joined, causal=causal,
+            walk=walk, block_q=block_q, block_k=block_k, T_total=T,
+            S_total=S),
+        grid=(Bp * heads,),
+        in_specs=[
+            *(_head_spec(n, D, *rule)
+              for n, rule in zip((T, S, S, T, T), rules)),
+            *(_head_spec(T, LANES) for _ in stats),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=[
+            _scratch((T, D)), _scratch(stat_shape), _scratch(stat_shape)],
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
+        interpret=interpret,
+        name="flash_dkv",
+    )(q, k, v, g, out, *stats)
+
+
+def _backward_grid(
+    q, k, v, g, out, lse, D, layout, *, causal, block_q, block_k, interpret,
+    dlse=None, joined: bool = False,
+):
+    """``flash_dq`` and ``flash_dkv``, the grid form: a live block pair
+    a grid step in each, 3 + 4 matmuls a pair between them. With
+    ``joined`` ``flash_dq`` writes a head's dq columns of the
+    projection's cotangent, ``flash_dkv`` the dk and dv columns of the
+    same buffer. delta' travels from ``flash_dq``, which makes it, to
+    ``flash_dkv`` as [B·H, T, LANES] fp32."""
     Bp, T, _ = q.array.shape
     S = k.array.shape[1]
     heads = q.heads
     dtype = q.array.dtype
     block_q, block_k = _pick_blocks(T, S, block_q, block_k, dtype)
-    by_q = _plan("flash_dq", T, S, block_q, block_k, causal, layout)
+    by_q = _plan("flash_dq", T, S, block_q, block_k, causal, layout, 3)
     by_k = _plan(
-        "flash_dkv", T, S, block_q, block_k, causal, layout, by_key=True)
+        "flash_dkv", T, S, block_q, block_k, causal, layout, 4, by_key=True)
     common = dict(
         scale=D**-0.5, causal=causal, block_q=block_q, block_k=block_k,
         T_total=T, S_total=S,

@@ -8,11 +8,14 @@ with its reference.
                                               #   block-diffusion MoE model's kernels
     python scripts/check_kernels.py --tiny    # small shapes (CPU rehearsal: the
                                               #   Pallas interpreter, not Mosaic)
-    python scripts/check_kernels.py --time    # device ms a call of flash_fwd,
-                                              #   flash_dq, flash_dkv at the train
-                                              #   cells' shape, and of what XLA
-                                              #   runs AROUND them; of flash_decode
-                                              #   at the serve cells' (needs the chip)
+    python scripts/check_kernels.py --all --only flash   # the cases so named
+    python scripts/check_kernels.py --time    # device ms a call of flash_fwd and
+                                              #   of the backward by form (resident:
+                                              #   flash_dkv alone; grid: flash_dq +
+                                              #   flash_dkv) at the train cells'
+                                              #   shape, and of what XLA runs AROUND
+                                              #   them; of flash_decode at the serve
+                                              #   cells' (needs the chip)
 
 On a TPU the kernels compile under Mosaic; elsewhere they run in the
 Pallas interpreter, which proves the program and nothing about the
@@ -22,13 +25,16 @@ on fp32 copies of the inputs. One JSON line per case —
 line; exit status 1 when any case is outside its tolerance or failed
 to build. Only ``--time`` times anything: an attention layer's forward
 and backward from the fused qkv projection to what ``proj`` reads, in a
-profiler trace of a few calls — the three flash training kernels by
-their names (``ms_per_call``) and every other device operation of the
-call (``around_ms_per_call``: the slices, transposes and stacks between
+profiler trace of a few calls — the flash training kernels by their
+names (``ms_per_call``; ``backward_ms_per_call`` is ``flash_dq`` +
+``flash_dkv``) and every other device operation of the call
+(``around_ms_per_call``: the slices, transposes and stacks between
 the projection and the kernels, 6.4 ms a step in the train cells until
-PR 33) — once with q, k, v sliced out for the separate-operand entry
-and once through the fused-projection entry (the loop a change to
-``ops/flash.py`` iterates in; no benchmark cell runs this). Then the
+PR 33) — with q, k, v sliced out for the separate-operand entry and
+through the fused-projection entry, each with the backward in the form
+the program chooses for the shape (``resident``, PR 39: one kernel over
+a head held in VMEM) and held to the ``grid`` form (the loop a change
+to ``ops/flash.py`` iterates in; no benchmark cell runs this). Then the
 serve cells' ``flash_decode`` calls over rows with heads packed on lanes,
 at the positions their lanes hold (``DECODE_CELLS``): ms a call, rows
 attended and fetched, and the attended rows' bytes a second over the
@@ -38,10 +44,12 @@ HBM's (the loop a change to ``ops/decode.py``'s walk iterates in).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import os
 import sys
+import time
 import traceback
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -93,15 +101,48 @@ def _flash_inputs(B, T, H, D, dtype):
     return q, k, v, jax.random.normal(keys[3], (B, T, H, D), jnp.float32)
 
 
+@contextlib.contextmanager
+def backward_form(form: str | None):
+    """Hold ``ops/flash.py``'s backward to ``form`` (``grid`` or
+    ``resident``) for what is TRACED inside: the program has no such
+    switch, it chooses from the shapes (``flash._backward_form``), and
+    at the cells' shape only this script and the tests run the other
+    form. None: as the program chooses."""
+    from ddp_tpu.ops import flash
+
+    if form is None:
+        yield
+        return
+    chosen = flash._backward_form
+    flash._backward_form = lambda *a, **kw: (form, chosen(*a, **kw)[1])
+    try:
+        yield
+    finally:
+        flash._backward_form = chosen
+
+
+def traced_forms(since: float) -> list:
+    """The forms of the backward kernels traced after ``since`` (the
+    tracer's clock, ``time.perf_counter()``: its ring is bounded), from
+    their ``flash.plan`` records."""
+    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+
+    form = SPAN_NUMS["flash.plan"].index("form")
+    return sorted({e[4][form] for e in get_tracer().ring()
+                   if e[0] == "flash.plan" and e[1] >= since
+                   and e[4][0] != "flash_fwd"})
+
+
 def check_flash(B: int, T: int, H: int, D: int, block: int,
                 causal=True, dtype="bfloat16", backward=True,
-                projection=False) -> dict:
+                projection=False, form: str | None = None) -> dict:
     """Flash forward AND backward (the trainer's causal bf16 call)
     against dense attention. ``causal`` an int > 1: the block-causal
     mask of a block-diffusion prefill. ``backward=False``: the forward
     alone (the whole-prompt prefill's float32 call). ``projection``:
     through the fused entry, q, k, v as column blocks of one head-major
-    [B, T, H·3·D] array (the train cells' call)."""
+    [B, T, H·3·D] array (the train cells' call). ``form``: the backward
+    held to it (:func:`backward_form`); the record says which ran."""
     import jax
     import jax.numpy as jnp
 
@@ -135,9 +176,12 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
             "max_abs_err": err, "tol": TOL["flash"],
             "ok": bool(jnp.isfinite(out).all()) and err <= TOL["flash"],
         }
-    (_, out), grads = jax.jit(
-        jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True)
-    )(q, k, v)
+    before = time.perf_counter()
+    with backward_form(form):
+        (_, out), grads = jax.jit(
+            jax.value_and_grad(flash_loss, argnums=(0, 1, 2), has_aux=True)
+        )(q, k, v)
+    forms = traced_forms(before)
     with jax.default_matmul_precision("highest"):
         (_, ref), ref_grads = jax.jit(
             jax.value_and_grad(dense_loss, argnums=(0, 1, 2), has_aux=True)
@@ -153,7 +197,58 @@ def check_flash(B: int, T: int, H: int, D: int, block: int,
         "tol": TOL["flash"],
         "grad_max_abs_err": grad_err,
         "grad_tol": TOL["flash_grad"],
-        "ok": finite and err <= TOL["flash"] and grad_err <= TOL["flash_grad"],
+        "backward_form": forms,
+        "ok": finite and err <= TOL["flash"] and grad_err <= TOL["flash_grad"]
+        and form in (None, *forms),
+    }
+
+
+def check_backward_forms(B: int, T: int, H: int, D: int, block: int) -> dict:
+    """The two forms of the backward on the same numbers where the
+    cells' call does not go: ``flash_attention_with_lse`` (a ring hop)
+    with BOTH outputs differentiated, causal over half as many queries
+    as keys. The resident form's dq, dk, dv against the grid form's
+    (one pair function under both: the difference is the order the
+    MXU's float32 sums run in) and against dense attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.flash import _lse_rows, _reference, flash_attention_with_lse
+
+    interpret = jax.default_backend() != "tpu"
+    q, k, v, w = _flash_inputs(B, T, H, D, "bfloat16")
+    q, w = q[:, T // 2:], w[:, T // 2:]
+    u = jax.random.normal(jax.random.key(7), (B, T // 2, H), jnp.float32)
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            return (out.astype(jnp.float32) * w).sum() + (lse * u).sum()
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2)))
+
+    def dense(q, k, v):
+        scale = D ** -0.5
+        s = jnp.einsum("bthd,bshd->bhts", q, k) * scale
+        rows = jnp.arange(T // 2)[:, None] + T // 2
+        s = jnp.where(rows >= jnp.arange(T)[None], s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        return _reference(q, k, v, True), lse.transpose(0, 2, 1)
+
+    flash = lambda q, k, v: flash_attention_with_lse(
+        q, k, v, True, block, block, interpret)
+    grads = {}
+    for form in ("resident", "grid"):
+        with backward_form(form):
+            grads[form] = loss(flash)(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        ref = loss(dense)(*(x.astype(jnp.float32) for x in (q, k, v)))
+    between = max(_max_err(a, b)
+                  for a, b in zip(grads["resident"], grads["grid"]))
+    err = max(_max_err(g, r) for g, r in zip(grads["resident"], ref))
+    return {
+        "max_abs_err": between, "tol": TOL["flash_grad"],
+        "grad_max_abs_err": err, "grad_tol": TOL["flash_grad"],
+        "ok": between <= TOL["flash_grad"] and err <= TOL["flash_grad"],
     }
 
 
@@ -200,10 +295,12 @@ def device_ms_per_call(log_dir: str, calls: int,
 
 
 TIME_ENTRIES = ("sliced", "projection")
+TIME_FORMS = (None, "grid")  # as the program chooses; held to the grid
 
 
 def time_flash(B: int, T: int, H: int, D: int, block: int,
-               calls: int = 8, entry: str = "sliced") -> dict:
+               calls: int = 8, entry: str = "sliced",
+               form: str | None = None) -> dict:
     """Device ms a call of one attention layer's forward and backward
     (causal, bf16) from the fused head-major projection [B, T, H·3·D]
     to [B, T, H·D] and back to the projection's cotangent: ``calls``
@@ -211,7 +308,10 @@ def time_flash(B: int, T: int, H: int, D: int, block: int,
     ``entry`` ``sliced``: q, k, v sliced out as ``models/vit.py`` does
     where the kernels cannot read the projection whole, the
     separate-operand entry, the cotangents stacked back; ``projection``:
-    the fused entry. ``ms_per_call`` is the three kernels',
+    the fused entry. ``form``: the backward held to it
+    (:func:`backward_form`). ``ms_per_call`` is the kernels' by name,
+    ``backward_ms_per_call`` the backward's (``flash_dq`` +
+    ``flash_dkv``: the resident form runs no ``flash_dq``),
     ``around_ms_per_call`` everything else the device ran."""
     import tempfile
 
@@ -240,7 +340,10 @@ def time_flash(B: int, T: int, H: int, D: int, block: int,
         out, vjp = jax.vjp(attend, qkv)
         return out, vjp(g)
 
-    jax.block_until_ready(fwd_bwd(qkv, g))
+    before = time.perf_counter()
+    with backward_form(form):
+        jax.block_until_ready(fwd_bwd(qkv, g))
+    forms = traced_forms(before)
     with tempfile.TemporaryDirectory() as log_dir:
         jax.profiler.start_trace(log_dir)
         try:
@@ -252,8 +355,9 @@ def time_flash(B: int, T: int, H: int, D: int, block: int,
         ms, around = device_ms_per_call(log_dir, calls)
     return {
         "shape": dict(B=B, T=T, H=H, D=D, block=block), "calls": calls,
-        "ms_per_call": ms, "ok": bool(ms),
-        **({"around_ms_per_call": around} if ms
+        "backward_form": forms, "ms_per_call": ms, "ok": bool(ms),
+        **({"backward_ms_per_call": ms["flash_dq"] + ms["flash_dkv"],
+            "around_ms_per_call": around} if ms
            else {"error": "no TPU in the trace: nothing timed"}),
     }
 
@@ -738,6 +842,17 @@ def cases(tiny: bool, every: bool):
         yield "flash_fwd_bwd_bf16_causal_cell_projection", lambda: check_flash(
             **cell, projection=True
         )
+        # ... whose backward is ONE kernel over a resident head since PR
+        # 39; the grid pair a longer head falls back to, through both
+        # entries at the same shape; and the resident form where the
+        # LSE is differentiated over fewer queries than keys.
+        yield "flash_fwd_bwd_bf16_causal_cell_grid", lambda: check_flash(
+            **cell, form="grid"
+        )
+        yield "flash_fwd_bwd_bf16_causal_cell_projection_grid", (
+            lambda: check_flash(**cell, projection=True, form="grid"))
+        yield "flash_backward_forms_agree_lse_rectangular", (
+            lambda: check_backward_forms(**dict(cell, B=1, H=2)))
         yield "flash_fwd_fp32_causal_cell", lambda: check_flash(
             **cell, dtype="float32", backward=False
         )
@@ -789,6 +904,8 @@ def main() -> int:
     p.add_argument("--all", action="store_true")
     p.add_argument("--tiny", action="store_true")
     p.add_argument("--time", action="store_true")
+    p.add_argument("--only", default="", metavar="PREFIX",
+                   help="run only the cases whose name starts with PREFIX")
     args = p.parse_args()
 
     from ddp_tpu.obs.recorder import build_info
@@ -801,9 +918,10 @@ def main() -> int:
     if args.time:
         shape = (dict(B=1, T=128, H=2, D=128, block=64) if args.tiny
                  else CELL_FLASH)
-        runs = [(f"flash_time_{entry}",
-                 functools.partial(time_flash, **shape, entry=entry))
-                for entry in TIME_ENTRIES]
+        runs = [(f"flash_time_{entry}" + (f"_{form}" if form else ""),
+                 functools.partial(time_flash, **shape, entry=entry,
+                                   form=form))
+                for entry in TIME_ENTRIES for form in TIME_FORMS]
         runs += [(f"decode_time_{name}", functools.partial(time_decode, **cell))
                  for name, cell in (DECODE_CELLS_TINY if args.tiny
                                     else DECODE_CELLS).items()]
@@ -811,6 +929,8 @@ def main() -> int:
         runs = cases(args.tiny, args.all)
     failed = []
     for name, run in runs:
+        if not name.startswith(args.only):
+            continue
         try:
             rec = run()
         except Exception:  # noqa: BLE001 — report every case, fail at the end

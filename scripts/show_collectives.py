@@ -9,7 +9,8 @@ half a minute, no chip time) and the entry computation of
 reduce's position, bytes, whether it is a plain ``all-reduce`` (the
 TensorCore waits for it wherever it stands) or an asynchronous
 start/done pair, and what compute stands between the pair, against the
-positions of the backward kernels (``flash_dq`` / ``flash_dkv``).
+positions of the backward kernels (``flash_dkv``; with ``flash_dq`` where
+a head does not fit the VMEM).
 
     JAX_PLATFORMS=cpu python scripts/show_collectives.py            # cgpt1.3b-train-ddp4's step
     JAX_PLATFORMS=cpu python scripts/show_collectives.py --plain    # without ddp.overlap_compile_options

@@ -364,5 +364,20 @@ def mnist_synthetic():
 
 
 @pytest.fixture()
+def backward_over_budget(monkeypatch):
+    """``ops/flash.py``'s planning function handed a VMEM budget no head
+    fits, so every backward traced under it takes the GRID form
+    (``flash_dq`` + ``flash_dkv``), as a ring hop at 32k does. The
+    budget is the planning function's argument: the program has no
+    switch."""
+    from ddp_tpu.ops import flash
+
+    chosen = flash._backward_form
+    monkeypatch.setattr(
+        flash, "_backward_form",
+        lambda *a, **kw: chosen(*a, **kw, budget=1024))
+
+
+@pytest.fixture()
 def tmp_ckpt_dir(tmp_path):
     return str(tmp_path / "checkpoints")

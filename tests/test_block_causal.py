@@ -58,8 +58,20 @@ def test_a_chunks_traced_offset_places_its_blocks():
     assert float(jnp.abs(got[:, 4:] - full).max()) < 1e-5
 
 
+@pytest.mark.parametrize("form", ["resident", "grid"])
 @pytest.mark.parametrize("T,S,bq,bk", [(64, 64, 16, 16), (32, 64, 16, 32)])
-def test_flash_kernels_take_the_block_causal_mask(T, S, bq, bk):
+def test_flash_kernels_take_the_block_causal_mask(
+        request, T, S, bq, bk, form):
+    """Forward and backward under the block-causal mask, the backward
+    as ONE kernel over a resident head (what the program chooses here)
+    and as the grid pair (a VMEM budget the head does not fit, handed
+    to the planning function): one pair function, one mask."""
+    from ddp_tpu.ops import flash
+
+    if form == "grid":
+        request.getfixturevalue("backward_over_budget")
+    assert flash._backward_form(
+        T, S, 32, "float32", bq, bk, 4)[0] == form
     q, k, v = _qkv(T, S, seed=1)
     out = flash_attention(q, k, v, 4, bq, bk, True)
     assert float(jnp.abs(out - _reference(q, k, v, 4)).max()) < 1e-5

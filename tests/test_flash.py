@@ -357,9 +357,10 @@ def test_flash_with_lse_gradients_under_a_nonzero_dlse(D, causal, dtype):
 def _plan_layouts(since):
     """kernel -> operand_layout of the ``flash.plan`` records after
     ``since`` (a copy of the tracer's ring)."""
-    from ddp_tpu.obs.tracer import get_tracer
+    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
 
-    return {e[4][0]: e[4][-1] for e in get_tracer().ring()[len(since):]
+    layout = SPAN_NUMS["flash.plan"].index("operand_layout")
+    return {e[4][0]: e[4][layout] for e in get_tracer().ring()[len(since):]
             if e[0] == "flash.plan"}
 
 
@@ -367,8 +368,9 @@ def _plan_layouts(since):
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("D", [128, 64, 16])
 def test_projection_entry_and_separate_entry_agree(D, causal, dtype):
-    """The two entries of the three kernels on the same numbers: the
-    fused projection read where it lies (heads of 128: a head's q, k, v
+    """The two entries of the kernels (the forward and, at this size,
+    the resident backward under ``flash_dkv``'s name) on the same
+    numbers: the fused projection read where it lies (heads of 128: a head's q, k, v
     are 128-lane column blocks of one array, the cotangent comes back
     as one array) and separate [B, T, H, D] operands. Bit-equal to each
     other — one kernel body, one index rule — and within the dense
@@ -396,7 +398,7 @@ def test_projection_entry_and_separate_entry_agree(D, causal, dtype):
     )(qkv)
     layout = "projection" if D % 128 == 0 else "transposed"
     assert _plan_layouts(before) == dict.fromkeys(
-        ("flash_fwd", "flash_dq", "flash_dkv"), layout)
+        ("flash_fwd", "flash_dkv"), layout)
     before = get_tracer().ring()
     (_, out_sep), dqkv_sep = loss(
         lambda x: flash_attention(
@@ -442,8 +444,9 @@ def _moved_activations(jaxpr, size):
 def test_nothing_moves_an_activation_around_the_fused_entry(
         head_dim, monkeypatch):
     """The jaxpr of a ``MultiHeadAttention`` forward-and-backward at a
-    flash length with heads of 128 holds, outside the three
-    ``pallas_call``s, no transpose, concatenate, pad or slice of an
+    flash length with heads of 128 holds, outside the two
+    ``pallas_call``s (the backward is ONE since PR 39, no ``flash_dq``
+    beside it), no transpose, concatenate, pad or slice of an
     array of activation size: q, k, v are read where the ``qkv`` matmul
     wrote them, ``out`` is written where ``proj`` reads it, and the
     projection's cotangent is one array the kernels wrote (PR 33: 6.4 ms
@@ -467,8 +470,8 @@ def test_nothing_moves_an_activation_around_the_fused_entry(
 
     closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
     text = str(closed)
-    assert all(f"name={k}" in text
-               for k in ("flash_fwd", "flash_dq", "flash_dkv"))
+    assert all(f"name={k}" in text for k in ("flash_fwd", "flash_dkv"))
+    assert "name=flash_dq" not in text
     moved = _moved_activations(closed.jaxpr, B * T * C)
     if head_dim % 128 == 0:
         assert moved == []
@@ -530,3 +533,213 @@ def test_block_classifier_and_live_pair_tables(shape):
         closes = np.r_[np.diff(outer) > 0, True]
         np.testing.assert_array_equal(flags & _FIRST != 0, opens)
         np.testing.assert_array_equal(flags & _LAST != 0, closes)
+
+
+# ---- the backward's two forms (PR 39) --------------------------------
+#
+# ONE kernel over a head held in VMEM (``resident``: the pairs walked
+# inside, five matmuls a pair) where the head fits, the ``flash_dq`` +
+# ``flash_dkv`` grid pair where it does not. One pair function under
+# both, so they must agree wherever both can run.
+
+FORM_ENTRIES = {
+    # entry: (head width, block): keys first wherever the block is whole
+    # lane groups; a head narrower than 128 goes as transposed copies
+    "projection": (128, 128),
+    "heads_last": (128, 128),
+    "transposed": (64, 128),
+    "transposed_small_blocks": (16, 16),
+}
+FORM_MASKS = {"none": False, "causal": True, "block_causal_4": 4}
+# (T over S in blocks, whether the LSE output is differentiated too)
+FORM_SHAPES = {"T_eq_S": (2, 2, False), "T_lt_S_dlse": (1, 2, True),
+               "T_gt_S": (3, 2, False)}
+FORM_CASES = [
+    (entry, mask, shape) for entry in FORM_ENTRIES for mask in FORM_MASKS
+    for shape in FORM_SHAPES
+    # the fused projection holds q, k and v of ONE length
+    if entry != "projection" or shape == "T_eq_S"]
+
+
+def _dense_lse(q, k, causal):
+    """The LSE rows [B, T, H] of dense attention under ``_last_key``'s
+    end-anchored mask (every row sees a key: T <= S)."""
+    from ddp_tpu.ops.flash import _last_key
+
+    T, S = q.shape[1], k.shape[1]
+    s = jnp.einsum("bthd,bshd->bhts", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        rows = _last_key(jnp.arange(T)[:, None] + (S - T), causal)
+        s = jnp.where(rows >= jnp.arange(S)[None, :], s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1).transpose(0, 2, 1)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("entry,mask,shape", FORM_CASES)
+def test_backward_forms_agree(entry, mask, shape, dtype):
+    """The resident form's dq, dk, dv equal the grid form's and lie
+    within the file's tolerance of the dense reference's: through the
+    fused projection (one [B, T, H·3·D] cotangent), [B, T, H·D] operands
+    and transposed copies; unmasked, causal and block-causal; with as
+    many queries as keys, with fewer and the LSE output differentiated
+    (a ring hop), and with more (rows that see no key)."""
+    from ddp_tpu.ops import flash as F
+
+    D, block = FORM_ENTRIES[entry]
+    causal = FORM_MASKS[mask]
+    n_q, n_k, with_dlse = FORM_SHAPES[shape]
+    B, H, T, S = 1, 2, n_q * block, n_k * block
+    opts = dict(causal=causal, block_q=block, block_k=block, interpret=True)
+    rng = np.random.default_rng(39)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k, v, w = normal(B, T, H, D), normal(B, S, H, D), normal(B, S, H, D), \
+        normal(B, T, H, D)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    u = normal(B, T, H) if with_dlse else None
+
+    if entry == "projection":
+        qkv = jnp.stack((q, k, v), axis=3).reshape(B, T, H * 3 * D)
+        out, lse = F._projection_forward(qkv, H, **opts)
+        operands = [*(F._Operand(qkv, H, 3, i) for i in range(3)),
+                    F._Operand(w.astype(dtype).reshape(B, T, H * D), H),
+                    F._Operand(out, H)]
+        split = lambda dqkv: F._split_projection(dqkv, H)
+        kwargs = dict(joined=True)
+        layout = "projection"
+    else:
+        out, lse = F._flash_forward(q, k, v, **opts)
+        operands = [F._operand(x) for x in (q, k, v, w.astype(dtype), out)]
+        split = lambda grads: [F._from_operand(g, B, H) for g in grads]
+        kwargs = dict(dlse=None if u is None else F._to_lanes(u))
+        layout = F._layout(D)
+    before = _mark()
+    resident = split(F._backward_resident(
+        *operands, lse, D, layout, **kwargs, **opts))
+    grid = split(F._backward_grid(*operands, lse, D, layout, **kwargs, **opts))
+    assert [(r[0], r[7], r[8]) for r in _plans(before)] == [
+        ("flash_dkv", layout, "resident"), ("flash_dq", layout, "grid"),
+        ("flash_dkv", layout, "grid")]
+
+    def loss(q, k, v):
+        # the cotangent the kernels were handed, rounded as they saw it
+        total = (_dense_safe(q, k, v, causal)
+                 * w.astype(dtype).astype(jnp.float32)).sum()
+        if u is None:
+            return total
+        return total + (_dense_lse(q, k, causal) * u).sum()
+
+    dense = jax.grad(loss, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for got, same, want in zip(resident, grid, dense):
+        assert got.dtype == jnp.dtype(dtype)
+        # one pair function, the k blocks in one order: only the order
+        # of a dot's own float32 sums could differ
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(same, np.float32),
+            atol=1e-6 if dtype == "float32" else 2 ** -7)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want),
+            atol=ATOL[dtype][1])
+
+
+def _mark():
+    """A mark in the tracer's clock: ``_plans`` takes what came after
+    (the ring is bounded and full late in a worker's run, so a length
+    taken before marks nothing)."""
+    import time
+
+    return time.perf_counter()
+
+
+def _plans(since):
+    """The ``flash.plan`` records (their values) stamped after
+    ``since``."""
+    from ddp_tpu.obs.tracer import get_tracer
+
+    return [e[4] for e in get_tracer().ring()
+            if e[0] == "flash.plan" and e[1] >= since]
+
+
+@pytest.mark.parametrize("case", [
+    "the_train_cells", "the_train_cells_float32", "a_small_budget",
+    "ring_hop_32k", "head_of_8k", "too_many_pairs_to_unroll"])
+def test_backward_form_from_the_shapes(case):
+    """The form is chosen from what the code can observe — lengths, head
+    width, dtype, blocks, mask — against a VMEM budget that is an
+    ARGUMENT of the planning function: resident at the train cells'
+    shape, the grid under a budget the same head does not fit, for a
+    ring hop at 32k, and where the unrolled walk would hold more pairs
+    than ``_UNROLL_PAIRS``."""
+    from ddp_tpu.ops.flash import _UNROLL_PAIRS, _VMEM_BUDGET, _backward_form
+
+    cell = (2048, 2048, 128, "bfloat16", 512, 512, True)
+    args, kwargs, want = {
+        "the_train_cells": (cell, {}, "resident"),
+        "the_train_cells_float32": (
+            (2048, 2048, 128, "float32", 512, 512, True), {}, "resident"),
+        "a_small_budget": (cell, dict(budget=16 * 2 ** 20), "grid"),
+        "ring_hop_32k": (
+            (32768, 32768, 128, "bfloat16", 512, 512, False), {}, "grid"),
+        "head_of_8k": (
+            (8192, 8192, 128, "bfloat16", 512, 512, True), {}, "grid"),
+        "too_many_pairs_to_unroll": (
+            (2048, 2048, 128, "bfloat16", 128, 128, True), {}, "grid"),
+    }[case]
+    form, vmem = _backward_form(*args, **kwargs)
+    assert form == want
+    if case.startswith("the_train_cells"):
+        # 11-15 MB of operands, outputs and accumulators, a pair's tiles
+        # and the compiler's half again: well inside the budget
+        assert 16 * 2 ** 20 < vmem < _VMEM_BUDGET // 2
+    if case == "head_of_8k":  # fits the VMEM; its 136 pairs do not unroll
+        assert vmem < _VMEM_BUDGET and 136 > _UNROLL_PAIRS
+    if case == "ring_hop_32k":
+        assert vmem > _VMEM_BUDGET
+
+
+def test_a_head_over_the_budget_runs_the_grid_pair(request):
+    """``_backward_calls`` follows the planning function: the same call
+    leaves one resident ``flash_dkv`` record, and under a budget the
+    head does not fit (given to the planning function: the program has
+    no switch) a ``flash_dq`` and a ``flash_dkv`` record of the grid
+    form, with the same gradients."""
+    q, k, v = _qkv(1, 64, 2, 16, seed=31)
+    grad = lambda: jax.grad(
+        lambda *a: flash_attention(*a, True, 16, 16, True).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    before = _mark()
+    resident = grad()
+    assert [(r[0], r[8], r[9], r[10]) for r in _plans(before)] == [
+        ("flash_fwd", "grid", 10, 2), ("flash_dkv", "resident", 1, 5)]
+    request.getfixturevalue("backward_over_budget")
+    before = _mark()
+    grid = grad()
+    assert [(r[0], r[8], r[9], r[10]) for r in _plans(before)] == [
+        ("flash_fwd", "grid", 10, 2), ("flash_dq", "grid", 10, 3),
+        ("flash_dkv", "grid", 10, 4)]
+    for a, b in zip(resident, grid):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+@pytest.mark.parametrize("shape", [*SHAPES, "the_train_cells"])
+def test_the_resident_walk_visits_every_live_pair_once(shape):
+    """``_walk``: down a k block's column the pairs are dead, then
+    diagonal, then interior, so (first live, first interior) says every
+    pair's class; walked keys outer, queries inner, it is ``_live_pairs``
+    by key without the flags."""
+    from ddp_tpu.ops.flash import (
+        _DEAD, _DIAGONAL, _classify, _live_pairs, _walk,
+    )
+
+    T, S, bq, bk, causal = {
+        **SHAPES, "the_train_cells": (2048, 2048, 512, 512, True)}[shape]
+    classes = _classify(T, S, bq, bk, causal)
+    walk = _walk(classes)
+    assert len(walk) == S // bk
+    walked = [(j, i, _DIAGONAL if i < mid else 0)
+              for j, (lo, mid) in enumerate(walk) for i in range(lo, T // bq)]
+    outer, inner, flags = _live_pairs(classes, by_key=True)
+    assert walked == [(o, n, f & _DIAGONAL)
+                      for o, n, f in zip(outer, inner, flags) if not f & _DEAD]
+    if shape == "the_train_cells":
+        assert walk == ((0, 1), (1, 2), (2, 3), (3, 4))

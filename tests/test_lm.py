@@ -200,12 +200,16 @@ def test_train_compile_record_once_per_compile(devices, data):
 
 def test_flash_plan_record_once_per_traced_call(monkeypatch):
     """Tracing a flash training kernel leaves ONE ``flash.plan`` record
-    in the tracer's ring — blocks, grid steps visited a (batch·head),
+    in the tracer's ring — blocks, block pairs visited a (batch·head),
     of which masked, of which dead, operand dtype, where the operands
-    lie — and a call of the compiled program leaves none. At the train
-    cells' shape every kernel visits the 10 live pairs of its 4 x 4
-    grid, 4 of them diagonal, none dead, and reads the fused projection
-    where the ``qkv`` matmul wrote it."""
+    lie, the kernel's form, its grid steps a (batch·head), the matmuls a
+    pair costs in it and the VMEM bytes reckoned — and a call of the
+    compiled program leaves none. At the train cells' shape every
+    kernel visits the 10 live pairs of its 4 x 4 grid, 4 of them
+    diagonal, none dead, and reads the fused projection where the
+    ``qkv`` matmul wrote it; the backward is ONE kernel, ``flash_dkv``
+    in its resident form (PR 39): one grid step a (batch·head), five
+    matmuls a pair, no ``flash_dq``."""
     from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
     from ddp_tpu.ops.flash import flash_attention
 
@@ -221,29 +225,38 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
             argnums=(0, 1, 2),
         ))
 
-    kernels = ["flash_dkv", "flash_dq", "flash_fwd"]
+    from ddp_tpu.ops.flash import _backward_form
+
     q = jnp.ones((1, 64, 2, 16), jnp.float32)
     step = grad(16)
     before = get_tracer().ring()
     step(q, q, q)
     recs = records(before)
-    assert sorted(r[4][0] for r in recs) == kernels
+    small = _backward_form(64, 64, 16, "float32", 16, 16, True)[1]
+    forms = {"flash_fwd": ("grid", 10, 2, 0),
+             "flash_dkv": ("resident", 1, 5, small)}
+    assert sorted(r[4][0] for r in recs) == sorted(forms)
     for name, t0, dur, parent, nums in recs:
         assert dur == 0.0 and parent is None
         assert dict(zip(SPAN_NUMS["flash.plan"], nums)) == {
             "kernel": nums[0], "block_q": 16, "block_k": 16, "visited": 10,
             "diagonal": 4, "dead": 0, "operand_dtype": "float32",
-            "operand_layout": "transposed"}
+            "operand_layout": "transposed", **dict(zip(
+                ("form", "grid_steps", "matmuls_per_pair", "vmem_bytes"),
+                forms[nums[0]]))}
     step(q, q, q)  # compiled: nothing is traced, nothing recorded
-    assert len(records(before)) == 3
+    assert len(records(before)) == 2
     # the cells' own call (4 x 2048 tokens, 16 heads of 128, bf16, blocks
     # of 512), traced and not run
     cell = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16)
     before = get_tracer().ring()
     jax.eval_shape(grad(512), cell, cell, cell)
+    vmem = _backward_form(2048, 2048, 128, "bfloat16", 512, 512, True)[1]
+    forms = {"flash_dkv": ("resident", 1, 5, vmem),
+             "flash_fwd": ("grid", 10, 2, 0)}
     assert sorted(r[4] for r in records(before)) == [
-        (kernel, 512, 512, 10, 4, 0, "float32", "heads_last")
-        for kernel in kernels]
+        (kernel, 512, 512, 10, 4, 0, "float32", "heads_last", *form)
+        for kernel, form in forms.items()]
     # and as the cells make it: the train step of ``_sharded_lm`` on a
     # mesh whose ``seq`` axis has one member, one layer at the cells'
     # widths, for a backend that is a TPU (the kernel choice asks)
@@ -261,5 +274,5 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
     before = get_tracer().ring()
     jax.eval_shape(step, state, jax.ShapeDtypeStruct((4, 2048), jnp.int32))
     assert sorted(r[4] for r in records(before)) == [
-        (kernel, 512, 512, 10, 4, 0, "float32", "projection")
-        for kernel in kernels]
+        (kernel, 512, 512, 10, 4, 0, "float32", "projection", *form)
+        for kernel, form in forms.items()]

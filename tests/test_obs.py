@@ -689,12 +689,13 @@ def test_spans_land_in_the_profilers_trace(tmp_path):
     assert s0 <= r0 and r0 + rd <= s0 + sd  # nested on that clock too
 
 
-def test_programs_and_kernels_carry_their_names():
+def test_programs_and_kernels_carry_their_names(request):
     """What the device trace tells programs and kernels apart by, read
     from the lowered text: the engine's programs are modules of their
-    own, the Pallas kernels carry ``flash_fwd`` / ``flash_dq`` /
-    ``flash_dkv`` / ``flash_decode``, the optimizer update, the cache
-    update and sampling their scopes."""
+    own, the Pallas kernels carry ``flash_fwd`` / ``flash_dkv`` (the
+    backward's ONE kernel where a head fits the VMEM; with ``flash_dq``
+    the grid pair where it does not) / ``flash_decode``, the optimizer
+    update, the cache update and sampling their scopes."""
     import re
 
     import jax
@@ -718,12 +719,19 @@ def test_programs_and_kernels_carry_their_names():
             f"module @jit_{name} ")
 
     q = jnp.zeros((1, 64, 2, 32), jnp.float32)
-    grad = jax.jit(jax.grad(
+    grad = lambda: jax.jit(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True, 32, 32, True).sum(),
         argnums=(0, 1, 2),
     )).lower(q, q, q).as_text(debug_info=True)
+    resident = grad()
+    for kernel in ("flash_fwd", "flash_dkv"):
+        assert re.search(rf"\b{kernel}\b", resident), kernel
+    assert not re.search(r"\bflash_dq\b", resident)
+    # a head over the VMEM budget: the grid pair, under both names
+    request.getfixturevalue("backward_over_budget")
+    over = grad()
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert re.search(rf"\b{kernel}\b", grad), kernel
+        assert re.search(rf"\b{kernel}\b", over), kernel
 
 
 def test_train_step_scopes_the_optimizer_update():

@@ -6,6 +6,8 @@ mesh axis. Exactness is the whole contract — ring and Ulysses must
 match the dense kernel to fp32 tolerance on the gathered sequence.
 """
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -63,15 +65,25 @@ def test_ring_under_data_parallel(devices):
     np.testing.assert_allclose(np.asarray(fn(q, k, v)), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("form", ["resident", "grid"])
 @pytest.mark.parametrize("D", [128, 16])
-def test_two_member_ring_on_the_flash_hop_matches_dense(devices, D):
+def test_two_member_ring_on_the_flash_hop_matches_dense(
+        devices, request, D, form):
     """The ring's hops on the Pallas kernels (interpreted here): K/V of
     the other member arrive as separate [B, T_local, H, D] arrays, heads
     of 128 read as [B, T_local, H·D] and heads of 16 transposed; the
     (out, lse) pairs combine to dense causal attention, and so do the
-    gradients through the lse cotangent."""
+    gradients through the lse cotangent. ``resident``: each hop's
+    backward is the ONE kernel over a head held in VMEM (what the
+    program chooses for a hop this short; the causal hop and the
+    non-causal one, each with its ``dlse``); ``grid``: the kernel pair a
+    hop at 32k runs, here under a VMEM budget a head does not fit."""
+    from ddp_tpu.ops import flash
     from ddp_tpu.ops.flash import flash_attention_with_lse
 
+    if form == "grid":
+        request.getfixturevalue("backward_over_budget")
+    before = time.perf_counter()  # the ring is bounded: mark its clock
     mesh = Mesh(np.asarray(devices[:2]), ("seq",))
     q, k, v = _qkv(1, 64, 2, D, seed=9)
 
@@ -92,6 +104,12 @@ def test_two_member_ring_on_the_flash_hop_matches_dense(devices, D):
     )
     for g, r in zip(grads(q, k, v), ref_grads(q, k, v)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+    backward = {(e[4][0], e[4][8]) for e in flash.get_tracer().ring()
+                if e[0] == "flash.plan" and e[1] >= before
+                and e[4][0] != "flash_fwd"}
+    assert backward == {
+        "resident": {("flash_dkv", "resident")},
+        "grid": {("flash_dq", "grid"), ("flash_dkv", "grid")}}[form]
 
 
 def test_ulysses_matches_dense(devices):

@@ -94,16 +94,24 @@ def test_decode_kernel_compiles_with_a_blocks_queries_folded(
 # ---- the LM train step's gradient all-reduces (parallel/ddp.py) ---------
 
 
+@pytest.mark.parametrize("form", ["resident", "grid"])
 @pytest.mark.parametrize("case", [
     "train_cells_bf16", "prefill_fp32", "more_queries_than_keys",
     "block_causal_4"])
-def test_flash_training_kernels_compile_at_the_cells_shape(one_chip, case):
-    """The flash trio under Mosaic with its live-pair tables in scalar
-    memory: the train cells' call (4 x 2048 tokens, 16 heads of 128,
-    bf16, blocks of 512), float32 inputs (float32 MXU operands), rows
-    that see no key, and the block-causal mask."""
-    from ddp_tpu.ops.flash import flash_attention
+def test_flash_training_kernels_compile_at_the_cells_shape(
+        one_chip, request, case, form):
+    """The flash kernels under Mosaic: the train cells' call (4 x 2048
+    tokens, 16 heads of 128, bf16, blocks of 512), float32 inputs
+    (float32 MXU operands), rows that see no key, and the block-causal
+    mask. ``resident``, what the program chooses at these shapes: the
+    forward on its grid and ONE backward kernel over a head held in
+    VMEM, its walk unrolled; ``grid``: the backward's grid pair with
+    its live-pair tables in scalar memory, as a head over the VMEM
+    budget runs it (the budget handed to the planning function)."""
+    from ddp_tpu.ops import flash
 
+    if form == "grid":
+        request.getfixturevalue("backward_over_budget")
     dtype, T, S, causal = {
         "train_cells_bf16": (jnp.bfloat16, 2048, 2048, True),
         "prefill_fp32": (jnp.float32, 2048, 2048, True),
@@ -113,26 +121,22 @@ def test_flash_training_kernels_compile_at_the_cells_shape(one_chip, case):
     q = _shape((4, T, 16, 128), dtype, one_chip)
     kv = _shape((4, S, 16, 128), dtype, one_chip)
     text = jax.jit(jax.grad(
-        lambda q, k, v: flash_attention(
+        lambda q, k, v: flash.flash_attention(
             q, k, v, causal, 512, 512, False).astype(jnp.float32).sum(),
         argnums=(0, 1, 2),
     )).lower(q, kv, kv).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+    kernels = {"resident": ("flash_fwd", "flash_dkv"),
+               "grid": ("flash_fwd", "flash_dq", "flash_dkv")}[form]
+    assert text.count("tpu_custom_call") >= len(kernels)
+    for kernel in kernels:
         assert kernel in text
+    assert ("flash_dq" in text) == (form == "grid")
 
 
-def test_flash_kernels_compile_on_the_fused_projection(one_chip):
-    """The train cells' own call: q, k, v are 128-lane column blocks of
-    the ``qkv`` matmul's [4, 2048, 16·3·128] output, strided fetches
-    Mosaic takes as they are, and the cotangent comes back as one array.
-    The compiled forward-and-backward is the three kernels: XLA adds no
-    copy or transpose of an operand (33.5 MB each) around them."""
-    import re
-
+def _projection_fwd_bwd(B, T, H, D, one_chip):
+    """The compiled text of ``flash_attention_projection``'s forward and
+    backward at [B, T, H·3·D] bf16 for the described chip."""
     from ddp_tpu.ops.flash import flash_attention_projection
-
-    B, T, H, D = 4, 2048, 16, 128
 
     def fwd_bwd(qkv, g):
         out, vjp = jax.vjp(
@@ -140,16 +144,55 @@ def test_flash_kernels_compile_on_the_fused_projection(one_chip):
             qkv)
         return out, vjp(g)
 
-    text = jax.jit(fwd_bwd).lower(
+    return jax.jit(fwd_bwd).lower(
         _shape((B, T, H * 3 * D), jnp.bfloat16, one_chip),
         _shape((B, T, H * D), jnp.bfloat16, one_chip),
     ).compile().as_text()
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+
+
+def test_flash_kernels_compile_on_the_fused_projection(one_chip):
+    """The train cells' own call: q, k, v are 128-lane column blocks of
+    the ``qkv`` matmul's [4, 2048, 16·3·128] output, strided fetches
+    Mosaic takes as they are, and the cotangent comes back as one array.
+    The compiled forward-and-backward is TWO kernels since PR 39 (the
+    resident backward writes a head's dq | dk | dv columns itself): XLA
+    adds no copy or transpose of an operand (33.5 MB each) around them."""
+    import re
+
+    from ddp_tpu.ops.flash import _backward_form
+
+    text = _projection_fwd_bwd(4, 2048, 16, 128, one_chip)
+    for kernel in ("flash_fwd", "flash_dkv"):
         assert kernel in text
+    assert "flash_dq" not in text
     moved = re.findall(
         r"= (\w+\[[\d,]+\])\S* (?:copy|transpose|concatenate|pad|slice)\(",
         text)
     assert moved == []
+    form, vmem = _backward_form(2048, 2048, 128, jnp.bfloat16, 512, 512, True)
+    print(f"resident backward at the cells' shape: vmem_limit_bytes {vmem} "
+          f"({vmem / 2 ** 20:.1f} MiB)")
+    assert form == "resident"
+
+
+def test_resident_backward_compiles_at_the_largest_head_it_admits(one_chip):
+    """The longest causal head in blocks of 512 whose backward the
+    planning function still holds resident: 4096 (36 live pairs
+    unrolled, 45 MiB of VMEM asked for); at 8192 the operands would
+    still fit the budget but the 136 pairs do not unroll, and the grid
+    pair runs."""
+    from ddp_tpu.ops.flash import _backward_form
+
+    cell = (128, jnp.bfloat16, 512, 512, True)
+    longest = max(T for T in (2048, 4096, 8192, 16384)
+                  if _backward_form(T, T, *cell)[0] == "resident")
+    assert longest == 4096
+    _, vmem = _backward_form(longest, longest, *cell)
+    print(f"resident backward at T {longest}: vmem_limit_bytes {vmem} "
+          f"({vmem / 2 ** 20:.1f} MiB)")
+    text = _projection_fwd_bwd(1, longest, 2, 128, one_chip)
+    assert "flash_dkv" in text and "flash_dq" not in text
+    assert "flash_dq" in _projection_fwd_bwd(1, 2 * longest, 2, 128, one_chip)
 
 
 # ---- the hybrid model's kernels at granite-4.0-h-micro's widths ----------
