@@ -17,6 +17,7 @@ weight-tying trick; halves the embedding parameters).
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ddp_tpu.obs.tracer import importing
+from ddp_tpu.obs.tracer import get_tracer, importing
 
 with importing("optax"):
     import optax
@@ -82,7 +83,10 @@ class CausalLM(nn.Module):
     num_kv_heads: int = 0  # GQA — see models/vit.py MultiHeadAttention
 
     @nn.compact
-    def __call__(self, tokens, pos_offset=0):
+    def __call__(self, tokens, pos_offset=0, head: bool = True):
+        """``head=False`` stops in front of the tied head: ``(ln_final's
+        float32 output, the embedding)``, for a caller that wants the
+        loss and not the logits (``ops/lm_head.head_loss``)."""
         embed = self.param(
             "embed",
             nn.initializers.normal(stddev=0.02),
@@ -131,6 +135,8 @@ class CausalLM(nn.Module):
                     name=f"block{i + 1}",
                 )(x)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+        if not head:
+            return x, embed
         # Tied head: logits through the embedding transpose.
         return (x @ embed.T.astype(x.dtype)).astype(jnp.float32)
 
@@ -455,11 +461,19 @@ def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
     baxes = _batch_axes(mesh)
     xspec = P(baxes, "seq")
 
-    def forward(params, tokens, want_aux: bool = True):
+    def forward(params, tokens, want_aux: bool = True, head: bool = True):
         """→ (logits sharded like the tokens, replicated MoE aux loss
         scalar — 0.0 for dense specs or ``want_aux=False``, which also
         skips the aux collection and its cross-device mean: eval has
-        no use for the routing penalty)."""
+        no use for the routing penalty). ``head=False``: in the logits'
+        place ``(ln_final's output, sharded like the tokens, every
+        device's compute-dtype embedding)``, what the fused head of
+        ``_make_sharded_token_metrics`` takes. The embeddings are
+        stacked on a leading axis sharded over the WHOLE mesh, so each
+        device hands its own copy to its own loss shard and takes that
+        shard's cotangent back as it is: a replicated (``P()``) output
+        would be summed over the mesh at the boundary, a second
+        all-reduce of the largest gradient."""
         pspecs = seq_param_specs(params, mesh)
         collect_aux = bool(spec.num_experts) and want_aux
 
@@ -474,7 +488,7 @@ def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
             if collect_aux:
                 logits, variables = model.apply(
                     {"params": params}, tok_shard, pos_offset=offset,
-                    mutable=["losses"],
+                    head=head, mutable=["losses"],
                 )
                 leaves = jax.tree.leaves(variables.get("losses", {}))
                 aux = (
@@ -485,16 +499,21 @@ def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
                 aux = lax.pmean(aux, mesh.axis_names)
             else:
                 logits = model.apply(
-                    {"params": params}, tok_shard, pos_offset=offset
+                    {"params": params}, tok_shard, pos_offset=offset,
+                    head=head,
                 )
                 aux = jnp.float32(0.0)
+            if not head:
+                hidden, embed = logits
+                logits = hidden, embed[None]
             return logits, aux
 
         return jax.shard_map(
             per_shard_forward,
             mesh=mesh,
             in_specs=(pspecs, xspec),
-            out_specs=(xspec, P()),
+            out_specs=(
+                xspec if head else (xspec, P(mesh.axis_names)), P()),
             check_vma=False,
         )(params, tokens)
 
@@ -533,8 +552,19 @@ def _make_sharded_token_metrics(
     very last global position is weight-0, exactly as in
     ``next_token_loss``. Returns ``(mean loss, correct count)``
     replicated; weights sum to B·(T−1).
+
+    The form follows what the loss needs, no knob. Integer targets
+    without label smoothing need a row's log-sum-exp, its target's
+    logit and its arg-max, not the logits: the returned function then
+    takes ``sharded_forward(..., head=False)``'s ``(hidden, embeddings)``
+    in the logits' place and runs head and loss as ONE operation
+    (``ops/lm_head.head_loss``; its ``fused`` attribute says so).
+    Label smoothing sums every log-probability and keeps the plain path
+    over logits. Either way each traced call leaves one ``lm.head_plan``
+    record.
     """
     from ddp_tpu.models.seq_transformer import _batch_axes
+    from ddp_tpu.ops.lm_head import head_loss, padded_vocab
 
     baxes = _batch_axes(mesh)
     xspec = P(baxes, "seq")
@@ -545,6 +575,8 @@ def _make_sharded_token_metrics(
     # transpose treat the P() scalar outputs as replicated (same reason
     # the forward's aux output pmeans over every mesh axis).
     rep_axes = tuple(a for a in mesh.axis_names if a not in red_axes)
+
+    fused = not label_smoothing
 
     def body(logits, tok_shard):
         T_l = tok_shard.shape[1]
@@ -562,11 +594,24 @@ def _make_sharded_token_metrics(
         weights = jnp.where(
             (jnp.arange(T_l) == T_l - 1) & on_last_shard, 0.0, 1.0
         )[None, :].astype(jnp.float32)  # [1, T_l], broadcasts over B
-        logits32 = logits.astype(jnp.float32)
-        per_tok = _per_token_nll(logits32, targets, label_smoothing)
-        loss_sum = (per_tok * weights).sum()
-        pred = jnp.argmax(logits32, -1)
-        correct = ((pred == targets).astype(jnp.float32) * weights).sum()
+        vocab = spec.vocab_size
+        # Trace time, as ``flash.plan``: a compiled step leaves none.
+        get_tracer().complete(
+            "lm.head_plan", time.perf_counter(), 0.0,
+            nums=("fused" if fused else "plain", targets.size, vocab,
+                  padded_vocab(vocab) if fused else vocab, targets.size),
+        )
+        if fused:
+            hidden, embeds = logits
+            loss_sum, correct = head_loss(
+                hidden, embeds[0], targets, weights)
+        else:
+            logits32 = logits.astype(jnp.float32)
+            per_tok = _per_token_nll(logits32, targets, label_smoothing)
+            loss_sum = (per_tok * weights).sum()
+            pred = jnp.argmax(logits32, -1)
+            correct = (
+                (pred == targets).astype(jnp.float32) * weights).sum()
         if red_axes:
             loss_sum, correct = lax.psum((loss_sum, correct), red_axes)
         # The weight total is static — B_global·(T_global−1) — so divide
@@ -582,13 +627,16 @@ def _make_sharded_token_metrics(
             loss, correct = lax.pmean((loss, correct), rep_axes)
         return loss, correct
 
-    return jax.shard_map(
+    metrics = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(xspec, xspec),
+        in_specs=(
+            (xspec, P(mesh.axis_names)) if fused else xspec, xspec),
         out_specs=(P(), P()),
         check_vma=False,
     )
+    metrics.fused = fused
+    return metrics
 
 
 def make_lm_eval_step(
@@ -732,7 +780,8 @@ def make_lm_train_step(
     )
 
     def loss_and_logits(params, tokens):
-        logits, aux = sharded_forward(params, tokens)
+        logits, aux = sharded_forward(
+            params, tokens, head=not token_metrics.fused)
         loss, correct = token_metrics(logits, tokens)
         if spec.num_experts:
             loss = loss + spec.aux_loss_weight * aux

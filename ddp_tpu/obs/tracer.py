@@ -130,6 +130,15 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "flash.plan": ("kernel", "block_q", "block_k", "visited", "diagonal",
                    "dead", "operand_dtype", "operand_layout", "form",
                    "grid_steps", "matmuls_per_pair", "vmem_bytes"),
+    # models/lm.py — one per traced call of the train step's next-token
+    # loss (``_make_sharded_token_metrics``; trace time, zero duration):
+    # the form (``fused``: head and loss ONE operation over bf16
+    # operands, ``ops/lm_head.head_loss``; ``plain``: cross-entropy and
+    # arg-max over the logits the head returned; a name), the rows and
+    # the vocabulary of a shard, the vocabulary as the operation's
+    # matmuls see it (padded to 128 when fused) and the rows it takes at
+    # a time (all of them: nothing yet holds a block)
+    "lm.head_plan": ("form", "rows", "vocab", "padded_vocab", "block_rows"),
     # ops/ssm.py — one per traced ``pallas_call`` of the state update
     # (``ssm_state_update``, ``selective_state_update``) or of the
     # prefill scan (``selective_scan``), at trace time, zero duration:

@@ -7,6 +7,9 @@ one bit-close across shard boundaries, and the dp×sp train step must
 learn next-token prediction on deterministic progressions.
 """
 
+import functools
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -116,6 +119,18 @@ def test_remat_variant_runs(devices):
     assert np.isfinite(float(m.loss))
 
 
+def _records_since(name, since):
+    """The ring's records of one name stamped at or after ``since`` (a
+    ``time.perf_counter`` reading): chosen by the record's own start,
+    not by its place in the ring, which turns over once earlier files
+    in the same worker have filled it (the compile records do, since
+    PR 38), after which "everything past the old length" is empty."""
+    from ddp_tpu.obs.tracer import get_tracer
+
+    return [e for e in get_tracer().ring()
+            if e[0] == name and e[1] >= since]
+
+
 # ---- the compile options of the data-parallel step (parallel/ddp.py) ----
 
 
@@ -160,18 +175,15 @@ def test_train_compile_record_once_per_compile(devices, data):
     reduces of the compiled step, how many are asynchronous, how many
     start before the last backward kernel — and a call that hits the
     compiled step leaves none."""
-    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+    from ddp_tpu.obs.tracer import SPAN_NUMS
 
-    def records(since):
-        return [e for e in get_tracer().ring()[len(since):]
-                if e[0] == "train.compile"]
-
+    records = functools.partial(_records_since, "train.compile")
     mesh = make_mesh(MeshSpec(data=data), devices=devices[:data])
     tx = optax.adam(1e-3)
     step = make_lm_train_step(SPEC, tx, mesh, donate=False)
     state = create_lm_train_state(SPEC, tx, mesh, seed=0)
     toks = jnp.asarray(synthetic_tokens(4, total_len=64, vocab_size=32))
-    before = get_tracer().ring()
+    before = time.perf_counter()
     state1, m1 = step(state, toks)
     assert len(records(before)) == 1
     state2, _ = step(state1, toks)  # same signature: no compile
@@ -210,13 +222,10 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
     ``qkv`` matmul wrote it; the backward is ONE kernel, ``flash_dkv``
     in its resident form (PR 39): one grid step a (batch·head), five
     matmuls a pair, no ``flash_dq``."""
-    from ddp_tpu.obs.tracer import SPAN_NUMS, get_tracer
+    from ddp_tpu.obs.tracer import SPAN_NUMS
     from ddp_tpu.ops.flash import flash_attention
 
-    def records(since):
-        return [e for e in get_tracer().ring()[len(since):]
-                if e[0] == "flash.plan"]
-
+    records = functools.partial(_records_since, "flash.plan")
     def grad(block):
         return jax.jit(jax.grad(
             lambda q, k, v: flash_attention(
@@ -229,7 +238,7 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
 
     q = jnp.ones((1, 64, 2, 16), jnp.float32)
     step = grad(16)
-    before = get_tracer().ring()
+    before = time.perf_counter()
     step(q, q, q)
     recs = records(before)
     small = _backward_form(64, 64, 16, "float32", 16, 16, True)[1]
@@ -249,7 +258,7 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
     # the cells' own call (4 x 2048 tokens, 16 heads of 128, bf16, blocks
     # of 512), traced and not run
     cell = jax.ShapeDtypeStruct((4, 2048, 16, 128), jnp.bfloat16)
-    before = get_tracer().ring()
+    before = time.perf_counter()
     jax.eval_shape(grad(512), cell, cell, cell)
     vmem = _backward_form(2048, 2048, 128, "bfloat16", 512, 512, True)[1]
     forms = {"flash_dkv": ("resident", 1, 5, vmem),
@@ -271,7 +280,7 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
         opt_state=jax.eval_shape(tx.init, params))
     step = make_lm_train_step(
         spec, tx, mesh, compute_dtype=jnp.bfloat16, jit=False)
-    before = get_tracer().ring()
+    before = time.perf_counter()
     jax.eval_shape(step, state, jax.ShapeDtypeStruct((4, 2048), jnp.int32))
     assert sorted(r[4] for r in records(before)) == [
         (kernel, 512, 512, 10, 4, 0, "float32", "projection", *form)

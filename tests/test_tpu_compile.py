@@ -427,6 +427,48 @@ def test_one_chip_step_compiles_to_the_same_program(topo):
     assert _program(ours) == _program(bare)
 
 
+def test_one_chip_step_runs_head_and_loss_as_one_operation(topo):
+    """The train cells' vocabulary (50257, which 128 does not divide) on
+    one chip: since PR 42 no matmul of the compiled step takes a float32
+    ``[rows, vocabulary]`` operand (the head's three take bfloat16, the
+    vocabulary padded to 50304 and minor), and the exponentials over
+    the logits are taken ONCE, by the pass that writes the logits'
+    gradient for both gradient matmuls to read (the parent took them
+    three times: for the loss, and inside dX's and dW's own fusions)."""
+    import re
+
+    from show_collectives import compile_lm_step
+
+    text = compile_lm_step(
+        topo.devices[:1], mesh_axes={"data": 1}, d_model=512, depth=2,
+        num_heads=4, seq_len=1024, rows_per_chip=2).as_text()
+    bodies = re.split(r"\n(?=%\S+ \(|ENTRY )", text)
+    vocab = r"\[(?:\d+,)*(?:50257|50304)(?:,\d+)*\]"
+    matmuls = [b for b in bodies if " convolution(" in b]
+    assert len(matmuls) >= 3
+    wide = [line.strip()[:160] for b in matmuls for line in b.split("\n")
+            if " parameter(" in line and re.search(r"= f32" + vocab, line)]
+    assert wide == []
+    narrow = [line for b in matmuls for line in b.split("\n")
+              if " parameter(" in line
+              and re.search(r"= bf16\[2048,50304\]", line)]
+    assert len(narrow) == 2  # dX and dW read the ONE bfloat16 array
+    exps = [line for line in text.split("\n")
+            if " exponential(" in line and re.search(r"= \w+" + vocab, line)]
+    assert len(exps) == 1
+    # and the schedule is still the one that keeps each layer's weight
+    # gradient (fused with its Adam update) beside its backward, not the
+    # depth-first one that puts them all off to the end of the step
+    # (``ops/lm_head._head_loss_bwd``): block2's ``mlp1`` has both its
+    # gradient matmuls behind it before block1's backward begins
+    entry = text[text.index("\nENTRY"):].split("\n")
+    at = lambda name: [i for i, line in enumerate(entry) if re.search(
+        r'kind=kOutput.*op_name="[^"]*transpose\(jvp\(\)\)/CausalLM/'
+        + name + '/dot_general"', line)]
+    assert len(at("block2/mlp1")) == 2
+    assert max(at("block2/mlp1")) < min(at("block1/mlp2"))
+
+
 def _program(text: str) -> str:
     """A module's computations without what names the Python call stack
     they were traced under (the tables at its top, each ``metadata``)."""
