@@ -503,6 +503,19 @@ class SlotCache(NamedTuple):
     ``conv`` ``[n_mamba, S, K-1, C]`` as above; its remaining layers
     hold nothing. ``sambay.layer_rows`` is its map. Every other model
     carries empty tuples for the ring.
+
+    A model with latent attention over selected keys
+    (models/glm_dsa.py) keeps a FOURTH kind and no ``k``/``v`` rows at
+    all (they are empty): a position of a layer is ONE ``latent`` row
+    shared by all heads (the normed key/value latent beside the rotated
+    rope key, 512 + 64 at the published size, stored padded to whole
+    groups of 128 lanes: :func:`latent_row_width`) and one ``index_k`` row
+    (the indexer's key), each a tuple of one ``[S, L, W]`` array a
+    layer, so a layer is read without being sliced out of a stack.
+    ``sel`` ``[layers, S, K]`` int32 is what the last decode step
+    selected in each lane (-1 past a young lane's rows): read by the
+    engine for a request that asks. Every other model carries empty
+    tuples for all three.
     """
 
     k: jax.Array
@@ -515,9 +528,23 @@ class SlotCache(NamedTuple):
     live: Any = ()
     ring_k: Any = ()
     ring_v: Any = ()
+    latent: Any = ()
+    index_k: Any = ()
+    sel: Any = ()
 
     def quantized(self) -> bool:
         return self.k.dtype == jnp.int8
+
+
+def latent_row_width(spec: LMSpec) -> int:
+    """Columns of a stored latent row: the latent and the rope key
+    (512 + 64 at the published size) padded with zeros to whole groups
+    of 128 lanes (640). A minor dimension that is no multiple of 128
+    has no TPU layout that is neither padded nor transposed: compiled
+    for a v5e, the 576-wide buffer was laid out ``[S][576][L]`` and
+    copied whole (321 MB) into row order and back around every layer's
+    row write, 14 copies a decode step."""
+    return -(-(spec.kv_lora_rank + spec.qk_rope_head_dim) // 128) * 128
 
 
 def init_slot_cache(
@@ -527,6 +554,18 @@ def init_slot_cache(
     plus per-(position, head) fp32 scales — cache HBM per slot drops
     to ~(1 + 4/Dh)/8 of the fp32 layout, so a chip holds more
     ``slots``."""
+    if spec.kv_lora_rank:
+        # latent rows and indexer keys, no K/V rows: see SlotCache
+        L, n = spec.total_len, spec.depth
+        rows = lambda w: tuple(
+            jnp.zeros((slots, L, w), dtype) for _ in range(n))
+        none = lambda: jnp.zeros((0, slots, 0, 0), dtype)
+        return SlotCache(
+            k=none(), v=none(), pos=jnp.zeros((slots,), jnp.int32),
+            latent=rows(latent_row_width(spec)),
+            index_k=rows(spec.index_head_dim),
+            sel=jnp.full((n, slots, min(spec.index_topk, L)), -1, jnp.int32),
+        )
     kinds = spec.layer_types
     n_ssm = sum(1 for t in kinds if t == "mamba")
     n_ring = sum(1 for t in kinds if t == "window")

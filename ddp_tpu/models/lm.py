@@ -176,7 +176,11 @@ class LMSpec(NamedTuple):
     # differential attention whose one full K/V layer and last state
     # read-out feed a cross-decoder of gated memory units and
     # cross-attention; LayerNorm with bias, tied head, no positions,
-    # one token a step). All three serving only.
+    # one token a step); ``glm_dsa`` is models/glm_dsa.py's (HF
+    # ``glm_moe_dsa``: latent attention over the keys an indexer
+    # selects, leading dense layers, then sigmoid-routed experts of
+    # which this process holds a share, beside a shared one; untied
+    # head, one token a step). All four serving only.
     block: str = "gpt2"
     head_dim: int = 0  # 0 -> d_model // num_heads
     moe_intermediate: int = 0
@@ -230,6 +234,33 @@ class LMSpec(NamedTuple):
     mamba_dt_rank: int = 0
     sliding_window: int = 0
     layer_norm_eps: float = 1e-5
+    # The ``glm_dsa`` block, under the source's names. Latent attention:
+    # the query passes a latent of ``q_lora_rank``; a position's keys
+    # and values are ONE latent of ``kv_lora_rank`` and one rotated key
+    # of ``qk_rope_head_dim`` shared by all heads; a head's query is
+    # ``qk_nope_head_dim + qk_rope_head_dim`` wide and its value
+    # ``v_head_dim``. The indexer: ``index_n_heads`` heads of
+    # ``index_head_dim`` score every earlier position and a query
+    # attends the ``index_topk`` best. The first
+    # ``first_k_dense_replace`` layers have a dense gated MLP of
+    # ``mlp_intermediate``; the others route ``moe_top_k`` of
+    # ``n_routed_experts`` by sigmoid scores scaled by
+    # ``routed_scaling_factor``, compute the ``num_experts`` experts
+    # held HERE (numbers ``expert_offset`` onward) and
+    # ``n_shared_experts`` shared ones of ``moe_intermediate``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    first_k_dense_replace: int = 0
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    expert_offset: int = 0
 
 
 def head_dim_of(spec: LMSpec) -> int:
@@ -254,6 +285,8 @@ def derive_lm_spec(params: Any, *, num_heads: int, **overrides) -> LMSpec:
         # Mamba layers says so itself)
         if overrides.get("block") == "sambay" or "final_layernorm" in params:
             from ddp_tpu.models.sambay import derive_spec
+        elif overrides.get("block") == "glm_dsa":
+            from ddp_tpu.models.glm_dsa import derive_spec
         elif overrides.get("block") == "granite_hybrid" or any(
             "mamba" in layer for layer in params.get("layers", {}).values()
         ):

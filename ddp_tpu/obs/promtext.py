@@ -419,6 +419,27 @@ def render_serve(
         if key in rs:
             b.add(f"ddp_tpu_serve_{key}", rs[key], metric_type=kind,
                   help=help_)
+    # Latent lanes whose keys an indexer selects (models/glm_dsa.py):
+    # present only on an engine that serves such a block.
+    la = stats.get("latent_attention") or {}
+    for key, kind, help_ in (
+        ("dsa_rows_scored_total", "counter",
+         "stored rows the indexer scored: t + 1 a query at position t a "
+         "layer, over real prompt positions and live decoding lanes"),
+        ("dsa_rows_selected_total", "counter",
+         "stored rows attention read: min(t + 1, index_topk) a query a "
+         "layer"),
+        ("moe_pairs_routed_total", "counter",
+         "(token, chosen expert) pairs the routed layers' programs "
+         "made, over all the experts the router scores"),
+        ("moe_pairs_held_total", "counter",
+         "those pairs whose expert is held by this process"),
+        ("latent_bytes_per_slot", "gauge",
+         "latent and indexer rows one lane holds, all layers"),
+    ):
+        if key in la:
+            b.add(f"ddp_tpu_serve_{key}", la[key], metric_type=kind,
+                  help=help_)
     b.summary(
         "ddp_tpu_serve_ttft_seconds", stats.get("ttft_s"),
         help="submit to first token",

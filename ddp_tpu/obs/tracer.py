@@ -157,6 +157,24 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     "decode.plan": ("form", "steps_per_lane", "absorb_rows", "copy_rows",
                     "copies_in_flight", "row_width", "lane_rows",
                     "last_copy_cut"),
+    # models/glm_dsa.py — one per traced program of the block (trace
+    # time, zero duration): which program (``prefill_first`` |
+    # ``prefill_chunk`` | ``decode``: a name), its queries, the stored
+    # rows a query may score, how many it attends, the layers, the keys
+    # a chunk takes at a time (0: a decode step scores a lane in one),
+    # and the widths of a position's latent and indexer rows
+    "dsa.plan": ("program", "queries", "keys", "top_k", "layers",
+                 "key_block", "latent_row", "index_row"),
+    # where a lane's keys are selected (models/glm_dsa.py), where its
+    # ``serve.decode`` ends, of no duration and under the same parent:
+    # rows the indexer scored and rows attention read that step, summed
+    # over the live lanes and the layers, and the live lanes
+    "serve.decode_selected": ("rows_scored", "rows_selected", "live_lanes"),
+    # ... and where its ``serve.prefill_chunk`` ends: the same two
+    # counts over the chunk's REAL positions and the layers, how many
+    # those are, where the chunk starts and whether it samples
+    "serve.chunk_selected": ("rows_scored", "rows_selected", "tokens",
+                             "start", "final"),
     # ---- the process's start: phases, kept past the ring ------------
     # (``Tracer.phase`` / ``phase_complete``; docs/OBSERVABILITY.md
     # "Start-up"). Names are names, not numbers.

@@ -40,6 +40,16 @@ sibling:
   walks the lane's live rows itself (``_walk_kernel``: double-buffered
   copies it starts, :func:`fetched_rows` of them, the same absorbs):
   a lane costs by the rows it holds, not by its length.
+- :func:`index_scores`, :func:`select_rows`,
+  :func:`latent_decode_attention` — decode over a LATENT cache whose
+  keys a learned indexer selects (models/glm_dsa.py): a step first
+  scores every live row of a lane against the lane's query
+  (``sum_j w_j relu(q_j . k)`` over the indexer's heads), takes the
+  ``top_k`` best (all of them while the lane is young), then attends
+  those rows alone: all heads against ONE shared row of latent + rope
+  key, the value the row's latent part (the up-projections absorbed
+  into the query and the output by the caller). Plain ``jnp``: the
+  rows are gathered by XLA, no kernel yet.
 - :func:`shard_decode_attention` — mesh composition: the compiled
   Mosaic call has no partitioning rule (same wall as
   ``ops/attention.gspmd_flash_attention``), so TP serving routes the
@@ -958,3 +968,64 @@ def shard_decode_attention(
         )(*args)
 
     return fn
+
+
+# ---- a latent cache whose keys an indexer selects ---------------------
+#
+# models/glm_dsa.py's decode. The cache holds, a layer, ``latent``
+# ``[S, L, R + Dr]`` (a position's normed key/value latent beside its
+# rotated rope key: ONE row for all heads) and ``index_k`` ``[S, L, Di]``
+# (the indexer's key). A step selects before it attends.
+
+
+@jax.named_scope("dsa_index")
+def index_scores(qi, w, ki):
+    """The indexer's score of every stored row for each query:
+    ``I[..., l] = sum_j w[..., j] * relu(qi[..., j, :] . ki[..., l, :])``.
+    ``qi`` ``[..., Hi, Di]``, ``w`` ``[..., Hi]`` float32 (the head
+    weights, already scaled), ``ki`` ``[..., L, Di]`` as stored ->
+    ``[..., L]`` float32. Operands in the stored rows' dtype, float32
+    accumulation, ReLU and sum in float32."""
+    dots = jnp.einsum("...hd,...ld->...hl", qi.astype(ki.dtype), ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("...hl,...h->...l", jax.nn.relu(dots),
+                      w.astype(jnp.float32))
+
+
+@jax.named_scope("dsa_select")
+def select_rows(scores, pos, top_k: int):
+    """Each lane's ``top_k`` best rows among its live ones: ``scores``
+    ``[S, L]``, ``pos`` ``[S]`` (rows ``0..pos`` are live) -> (rows
+    ``[S, K]`` int32, which of them count ``[S, K]`` bool), ``K =
+    min(top_k, L)``. Ties go to the lower position; a lane with at most
+    ``K`` live rows selects them all and the rest of its ``K`` do not
+    count."""
+    L = scores.shape[-1]
+    live = jnp.arange(L, dtype=jnp.int32)[None, :] <= pos[:, None]
+    _, rows = lax.top_k(jnp.where(live, scores, -jnp.inf), min(top_k, L))
+    rows = rows.astype(jnp.int32)
+    return rows, rows <= pos[:, None]
+
+
+@jax.named_scope("mla_decode")
+def latent_decode_attention(q, latent, rows, counted, *, rank: int,
+                            scale: float):
+    """Every head's query against the SELECTED rows of its lane: ``q``
+    ``[S, H, W]`` (the key up-projection absorbed into its first
+    ``rank`` columns, the rotated rope query after, zeros up to the
+    stored width), ``latent`` ``[S, L, W]`` as stored (``R + Dr`` padded
+    to whole groups of 128 lanes), ``rows``/``counted`` from
+    :func:`select_rows` -> ``[S, H, R]`` float32: softmax over the
+    counted rows of ``q . row * scale``, times the rows' latent part.
+    The value up-projection is the caller's."""
+    S, L, W = latent.shape
+    flat = latent.reshape(S * L, W)
+    at = jnp.arange(S, dtype=jnp.int32)[:, None] * L + rows
+    picked = jnp.take(flat, at, axis=0)  # [S, K, W]
+    s = jnp.einsum("shw,skw->shk", q.astype(latent.dtype), picked,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(counted[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("shk,skr->shr", p.astype(latent.dtype),
+                      picked[..., :rank],
+                      preferred_element_type=jnp.float32)

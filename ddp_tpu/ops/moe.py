@@ -10,7 +10,10 @@ times the needed work at 128 experts; here an expert touches only the
 rows routed to it:
 
 - :func:`route` — softmax over all E in fp32, the ``top_k`` largest,
-  optionally renormalised (the published layer's ``norm_topk_prob``).
+  optionally renormalised (the published layer's ``norm_topk_prob``);
+  or, ``scoring="sigmoid"``, each expert's own sigmoid, the choice made
+  on score + ``bias`` and the weight on the score alone, renormalised
+  over the chosen and scaled.
 - :func:`group_rows` — the N x top_k assignments sorted by expert and
   laid out in row TILES of ``TILE_ROWS``: every expert's group is padded to a
   whole number of tiles, so a tile belongs to exactly one expert. The
@@ -32,12 +35,23 @@ rows routed to it:
   and the weighted sum of each token's ``top_k`` rows; also returns the
   step's routing counts (rows routed, the fullest expert's rows,
   experts hit) for the engine's counters.
+- :func:`moe_share_layer` — the same for a process that holds experts
+  ``first .. first + E_held - 1`` of the ``E`` the router scores (expert
+  parallelism, one member's part): the assignments to experts held
+  elsewhere are sorted behind the held ones into tiles marked dead, so
+  they cost neither a fetch nor a matmul, and add nothing. What the
+  other members would add is theirs to add; nothing here stands in for
+  them. At widths whose gate and up matrices do not fit VMEM twice
+  over, the gate/up kernel walks the tiles once a COLUMN block of the
+  expert width (columns outermost, so a matrix block still crosses
+  HBM once an expert).
 
 Forward only: serving never differentiates it.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -56,15 +70,32 @@ TILE_ROWS = 16
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def route(logits, top_k: int, normalize: bool = True):
+def route(logits, top_k: int, normalize: bool = True, *,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
     """Router logits ``[N, E]`` -> (experts ``[N, top_k]`` int32,
     weights ``[N, top_k]`` fp32): softmax over ALL experts in fp32,
     the ``top_k`` largest (ties to the lower index), renormalised to
-    sum to one when ``normalize``."""
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = lax.top_k(p, top_k)
+    sum to one when ``normalize``.
+
+    ``scoring="sigmoid"``: the score is each expert's own sigmoid; the
+    ``top_k`` are chosen by score + ``bias`` (``[E]``, the balancing
+    bias: it moves the CHOICE and never the weight), the weights are
+    the chosen experts' scores, renormalised when ``normalize``, times
+    ``scale``."""
+    if scoring == "softmax":
+        p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        w, idx = lax.top_k(p, top_k)
+    elif scoring == "sigmoid":
+        p = jax.nn.sigmoid(logits.astype(jnp.float32))
+        chosen = p if bias is None else p + bias.astype(jnp.float32)
+        _, idx = lax.top_k(chosen, top_k)
+        w = jnp.take_along_axis(p, idx, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     if normalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scoring == "sigmoid":
+        w = w * scale
     return idx.astype(jnp.int32), w
 
 
@@ -132,10 +163,11 @@ def _silu(g):
     return g * jax.nn.sigmoid(g)
 
 
-def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref):
+def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref, *,
+                    tile_axis: int = 0):
     del te_ref
 
-    @pl.when(pl.program_id(0) < live_ref[0])
+    @pl.when(pl.program_id(tile_axis) < live_ref[0])
     def _():
         x = x_ref[...].astype(wg_ref.dtype)
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
@@ -154,10 +186,64 @@ def _down_kernel(te_ref, live_ref, h_ref, wd_ref, y_ref):
         ).astype(y_ref.dtype)
 
 
+def column_block(d: int, f: int, itemsize: int, matrices: int = 2) -> int:
+    """Columns of the expert width a grouped call takes at a time: all
+    ``f`` where ``matrices`` ``[d, f]`` blocks fit VMEM double-buffered
+    under the limit with room for the rows (the accepted shapes), else
+    the largest halving of ``f`` (a multiple of 128) that does."""
+    budget = _VMEM_LIMIT_BYTES * 3 // 4
+    fb = f
+    while (2 * matrices * d * fb * itemsize > budget and fb % 256 == 0):
+        fb //= 2
+    return fb
+
+
+def _grouped_columns_call(kernel, name, rows, weights, g: Grouped, fb,
+                          out_dtype, interpret):
+    """The gate/up call a column block at a time: grid (f / fb, tiles),
+    columns OUTERMOST, so within one column block the weight index
+    changes only where the expert does and each ``[d, fb]`` block
+    crosses HBM once; the rows cross once a column block."""
+    M, K = rows.shape
+    tm = TILE_ROWS
+    f = weights[0].shape[-1]
+    vmem = {"memory_space": pltpu.VMEM}
+    row_spec = pl.BlockSpec((tm, K), lambda c, t, te, live: (t, 0), **vmem)
+    w_specs = [
+        pl.BlockSpec((1, K, fb), lambda c, t, te, live: (te[t], 0, c),
+                     **vmem)
+        for _ in weights
+    ]
+
+    return pl.pallas_call(
+        functools.partial(kernel, tile_axis=1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(f // fb, M // tm),
+            in_specs=[row_spec, *w_specs],
+            out_specs=pl.BlockSpec(
+                (tm, fb), lambda c, t, te, live: (t, c), **vmem
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, f), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name=name,
+    )(g.tile_expert, g.live_tiles, rows, *weights)
+
+
 def _grouped_call(kernel, name, rows, weights, g: Grouped, out_cols,
                   out_dtype, interpret):
     M, K = rows.shape
     tm = TILE_ROWS
+    if len(weights) == 2:
+        fb = column_block(K, out_cols, weights[0].dtype.itemsize)
+        if fb < out_cols:
+            return _grouped_columns_call(
+                kernel, name, rows, weights, g, fb, out_dtype, interpret)
     vmem = {"memory_space": pltpu.VMEM}
     row_spec = pl.BlockSpec((tm, K), lambda t, te, live: (t, 0), **vmem)
     w_specs = [
@@ -257,6 +343,69 @@ def moe_layer(x, router_logits, w_gate, w_up, w_down, *, top_k: int,
     out = jnp.einsum("nkd,nk->nd", y[g.dest], w)
     stats = jnp.stack([
         jnp.int32(N * top_k), g.counts.max(), (g.counts > 0).sum(),
+    ]).astype(jnp.int32)
+    return out, stats
+
+
+def hold_share(idx, first: int, held: int, num_experts: int):
+    """The routed choices ``[N, top_k]`` over all ``num_experts`` ->
+    (the layout of the assignments to experts ``first .. first + held
+    - 1``, which assignments those are ``[N, top_k]`` bool). An
+    assignment to an expert held elsewhere joins one group sorted
+    BEHIND the held ones; its tiles are marked dead, so the kernels
+    neither fetch nor compute them, and their rows are never written."""
+    if not 0 <= first <= first + held <= num_experts:
+        raise ValueError(
+            f"experts {first}..{first + held - 1} are not among the "
+            f"{num_experts} the router scores")
+    local = idx - first
+    here = (local >= 0) & (local < held)
+    g = group_rows(jnp.where(here, local, held), held + 1)
+    tm = TILE_ROWS
+    live = (jnp.sum((g.counts[:held] + tm - 1) // tm)).astype(jnp.int32)
+    tiles = jnp.arange(g.tile_expert.shape[0], dtype=jnp.int32)
+    last = g.tile_expert[jnp.maximum(live - 1, 0)]
+    last = jnp.minimum(last, held - 1)
+    return g._replace(
+        tile_expert=jnp.where(tiles < live, g.tile_expert, last),
+        live_tiles=live[None], counts=g.counts[:held],
+    ), here
+
+
+@jax.named_scope("moe_share")
+def moe_share_layer(x, router_logits, w_gate, w_up, w_down, *, top_k: int,
+                    first: int = 0, normalize: bool = True,
+                    scoring: str = "softmax", bias=None, scale: float = 1.0,
+                    count=None, impl: str = "auto"):
+    """One member's part of an expert layer divided over several: ``x``
+    ``[N, d]`` and its router logits over ALL ``E`` experts ``[N, E]``,
+    the matrices of the ``E_held`` experts held here (numbers ``first``
+    onward) -> (``sum_{k chosen, held here} w_k expert_k(x)`` ``[N, d]``
+    fp32, counts ``[4]`` int32: pairs routed, pairs held here, the
+    fullest held expert's rows, held experts hit; the pairs of the
+    rows ``count`` ``[N]`` bool names where given, so that a chunk's
+    padding is not counted). The weights are normalised over all
+    ``top_k`` chosen, held or not."""
+    N, d = x.shape
+    E, held = router_logits.shape[-1], w_gate.shape[0]
+    idx, w = route(router_logits, top_k, normalize, scoring=scoring,
+                   bias=bias, scale=scale)
+    g, here = hold_share(idx, first, held, E)
+    src = jnp.concatenate(
+        [x.astype(w_gate.dtype), jnp.zeros((1, d), w_gate.dtype)]
+    )
+    rows = src[g.row_token]
+    h = grouped_matmul_gate_up(rows, w_gate, w_up, g, impl=impl)
+    y = grouped_matmul_down(h, w_down, g, impl=impl)
+    # rows of dead tiles were never written: select, do not multiply
+    kept = jnp.where(here[..., None], y[g.dest], 0.0)
+    out = jnp.einsum("nkd,nk->nd", kept, w)
+    if count is None:
+        routed, held_here = jnp.int32(N * top_k), here.sum()
+    else:
+        routed, held_here = count.sum() * top_k, (here & count[:, None]).sum()
+    stats = jnp.stack([
+        routed, held_here, g.counts.max(), (g.counts > 0).sum(),
     ]).astype(jnp.int32)
     return out, stats
 

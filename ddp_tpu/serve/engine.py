@@ -63,6 +63,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 # Aliased: ``prefill_chunk`` is also an engine CONFIG name (the chunk
@@ -92,6 +93,20 @@ from ddp_tpu.serve.scheduler import (
 )
 from ddp_tpu.utils.metrics import MetricsWriter, StatSummary
 
+
+def _abstract(args):
+    """Shapes and dtypes of a call's arguments (the arrays themselves
+    may be donated by it)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args)
+
+
+def _named_fn(name, fn):
+    """``fn`` under ``name``: what the profiler's trace and the compile
+    records call its program (``jit_<name>``)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
 # The blocks that bring their own one-token programs, by the module
 # that holds each (imported where a spec asks for it, so that no other
 # model's start-up pays). What the engine asks of such a module:
@@ -100,11 +115,17 @@ from ddp_tpu.utils.metrics import MetricsWriter, StatSummary
 # holds state that a step it was not owed would spoil, so that a decode
 # step advances only the lanes ``cache.live`` names; and, where a lane
 # holds K/V of more than one kind, ``attended_rows(spec, rows)``, what
-# a decode step reads of each. The step loop holds three jitted
-# callables and no model's name.
+# a decode step reads of each; where a lane's keys are selected,
+# ``dsa_rows(spec, first, count)``, the rows queries at those positions
+# score and attend, ``lane_dtype(params)``, what a lane's rows are
+# stored in, a lane whose cache carries ``sel`` (models/generate.
+# SlotCache), and ONE MORE output of both programs, the call's expert
+# pairs (routed, held here), fetched a step behind like the tokens. The
+# step loop holds three jitted callables and no model's name.
 _BLOCK_MODULES = {
     "granite_hybrid": "ddp_tpu.models.granite_hybrid",
     "sambay": "ddp_tpu.models.sambay",
+    "glm_dsa": "ddp_tpu.models.glm_dsa",
 }
 
 
@@ -131,6 +152,21 @@ RECURRENT_REFUSALS = {
     "recurrent state that goes with them is not kept per page",
     "install_prefix": "a frame of K/V pages carries no recurrent "
     "state to resume from",
+}
+# And for a lane of latent rows whose keys an indexer selects: it holds
+# no K/V rows at all, and its decode reads rows by index.
+LATENT_REFUSALS = {
+    "page_size": "a lane holds one latent and one indexer row a "
+    "position, not K/V rows; the selection gathers rows of a lane by "
+    "index and no page table stands between",
+    "kv_dtype": "the latent and indexer rows are stored as the weights "
+    "are; no int8 form of a latent row is defined",
+    "spec_tokens": "a verify round would have to select for several "
+    "positions of a lane in one step, and the decode selects for one",
+    "export_prefix": "a prefix here is latent and indexer rows, which "
+    "the page wire format does not carry",
+    "install_prefix": "a frame of K/V pages holds nothing a latent "
+    "lane could resume from",
 }
 
 # Completion statuses.
@@ -178,6 +214,12 @@ class Completion:
     # the lane ran for this request, ``(pos, tokens [B], mask [B])`` as
     # the forward saw them — what the benchmark hands its reference.
     block_inputs: Optional[list] = None
+    # Keys selected before they are attended, on request
+    # (``record_selection``): the rows each layer selected for the
+    # step that produced the LAST token (``[layers][K]``, -1 past a
+    # young lane's rows; its query stood at position ``len(prompt) +
+    # len(tokens) - 2``). None where no decode step ran.
+    selected_rows: Optional[list] = None
 
     @property
     def decode_tokens_per_s(self) -> float:
@@ -236,6 +278,10 @@ class _Slot:
     block_pos: int = 0
     first_tokens: int = 1
     block_log: Optional[list] = None
+    # A lane whose keys are selected, asked with ``record_selection``:
+    # what the step that produced the request's last token selected
+    # (``[layers, K]`` on the device until the request finishes).
+    selection: Any = None
 
     @property
     def free(self) -> bool:
@@ -434,7 +480,9 @@ def resolve_engine_knobs(
     recurrent = bool(module and module.RECURRENT)
     if module:
         module.validate(spec)
-    if recurrent:
+    refusals = (RECURRENT_REFUSALS if recurrent
+                else LATENT_REFUSALS if spec.kv_lora_rank else None)
+    if refusals:
         for knob, given in (
             ("page_size", paged), ("kv_dtype", kv_dtype != "fp32"),
             ("spec_tokens", spec_tokens),
@@ -442,7 +490,7 @@ def resolve_engine_knobs(
             if given:
                 raise ValueError(
                     f"{knob} does not apply to the {spec.block} block: "
-                    f"{RECURRENT_REFUSALS[knob]}"
+                    f"{refusals[knob]}"
                 )
     elif module is None and (block_len or spec.block != "gpt2"):
         _sdar.validate(spec)
@@ -774,6 +822,9 @@ class ServeEngine:
         t_lanes = time.perf_counter()
         self._slots = [_Slot(index=i) for i in range(slots)]
         cache_dtype = jnp.int8 if kv_dtype == "int8" else jnp.float32
+        lane_dtype = getattr(block_module(spec), "lane_dtype", None)
+        if lane_dtype:
+            cache_dtype = lane_dtype(params)
         if self.paged:
             self._cache = self._put(init_paged_slot_cache(
                 spec, slots,
@@ -818,6 +869,8 @@ class ServeEngine:
         # Device values dispatched but not yet read back:
         # ("first", scalar, slot) | ("decode", [S] array, lanes).
         self._pending: list[tuple[str, Any, Any]] = []
+        # name -> (program, its arguments' shapes) of what warmup() built
+        self._warmed: dict[str, tuple] = {}
         self._completed: dict[int, Completion] = {}
         self._steps = 0
         self.ttft = StatSummary()
@@ -881,15 +934,25 @@ class ServeEngine:
         # K/V of more than one kind in a lane: what a decode step
         # reads of each is the module's to count.
         self._attended_rows = getattr(module, "attended_rows", None)
+        # Keys selected before they are attended: what the indexer
+        # scored and attention read is the module's to count. What a
+        # decode step selected in ONE lane (``cache.sel``) is copied
+        # out by a small program, for a request that asked and at its
+        # last step only.
+        self._dsa_rows = getattr(module, "dsa_rows", None)
+        if self._dsa_rows:
+            self._lane_selection = jax.jit(_named_fn(
+                "serve_lane_selection",
+                lambda sel, i: lax.dynamic_index_in_dim(
+                    sel, i, axis=1, keepdims=False),
+            ))
         if module:
             _prefill_chunk = module.prefill_chunk
             _decode_sample = module.slot_decode_sample_step
         else:
             _prefill_chunk, _decode_sample = _gpt2_chunk, _gpt2_decode
 
-        def _named(name, fn):
-            fn.__name__ = fn.__qualname__ = name
-            return fn
+        _named = _named_fn
 
         def _chunk_fn(name, lane_attend, chunk_spec):
             return jax.jit(
@@ -1065,6 +1128,16 @@ class ServeEngine:
         self.kv_shared_rows_attended_total = 0
         self.prefill_self_positions_total = 0
         self.prefill_cross_positions_total = 0
+        # A lane whose keys are selected (``dsa_rows``): rows the
+        # indexer scored and rows attention read, over real positions,
+        # live lanes and layers (host arithmetic); and the expert
+        # layers' (expert, token) pairs routed and those whose expert
+        # is held here, one more output of each program, fetched a
+        # step behind (``pairs``).
+        self.dsa_rows_scored_total = 0
+        self.dsa_rows_selected_total = 0
+        self.moe_pairs_routed_total = 0
+        self.moe_pairs_held_total = 0
         # Engine-lifetime speculative tallies (the /stats + bench
         # acceptance-rate source); zero-cost when speculation is off.
         self.spec_drafted_total = 0
@@ -1095,11 +1168,14 @@ class ServeEngine:
         hops: Optional[dict] = None,
         model: Optional[str] = None,
         record_blocks: bool = False,
+        record_selection: bool = False,
     ) -> Admission:
         """Admission-checked enqueue; rejections carry a reason.
 
         ``record_blocks`` (a model that generates by blocks only):
         keep every forward's block inputs for the completion.
+        ``record_selection`` (a model that selects its keys only): keep
+        what the last token's step selected.
 
         ``trace`` is an inbound fleet trace-context line (the router's
         ``00-<trace>-<span>-<parent>``): a VALID one is adopted — the
@@ -1131,6 +1207,7 @@ class ServeEngine:
             trace_id=adopted[0] if adopted else None,
             model=model,
             record_blocks=bool(record_blocks) and bool(self.block_len),
+            record_selection=bool(record_selection) and bool(self._dsa_rows),
         )
         if not adm.accepted:
             self.reject_counts[adm.reason] = (
@@ -1193,6 +1270,8 @@ class ServeEngine:
             "block_step" if self.block_len else "decode":
                 self._decode._cache_size(),
         }
+        if self._dsa_rows:
+            counts["lane_selection"] = self._lane_selection._cache_size()
         if self.spec_tokens:
             counts.update(
                 draft_prefill_first=self._draft_chunk_first._cache_size(),
@@ -1207,7 +1286,7 @@ class ServeEngine:
         per bucket + 1 decode, doubled-chunks + draft-decode + verify
         when speculating — asserted by the static-shape tests
         (tests/test_serve.py, tests/test_flash_decode.py)."""
-        base = 2 * len(self.buckets) + 1
+        base = 2 * len(self.buckets) + 1 + bool(self._dsa_rows)
         if self.spec_tokens:
             base += 2 * len(self.buckets) + 2
         return base
@@ -1248,6 +1327,20 @@ class ServeEngine:
             warm.nums = (sum(counts.values()),)
         return counts
 
+    def program_hlo(self) -> dict[str, str]:
+        """The compiled text of each one-token program ``warmup()``
+        built, by ``serve_<program>[:<width>]``: what maps a device
+        operation's instruction name to the JAX name stack (and with it
+        the ``jax.named_scope``) it came from; the profiler's trace
+        keeps the former alone. Lowers and compiles anew (a persistent
+        compile cache answers): not for a measured window."""
+        out = {}
+        for name, (fn, args) in self._warmed.items():
+            lower = getattr(fn, "lower", None)
+            if lower is not None:
+                out[name] = lower(*args).compile().as_text()
+        return out
+
     def _chunk_programs(self):
         return (("prefill_first", self._chunk_first),
                 ("prefill_chunk", self._chunk_cont))
@@ -1283,20 +1376,26 @@ class ServeEngine:
         zero = jnp.int32(0)
         for name, fn in self._chunk_programs():
             for w in self.buckets:
+                args = (
+                    self.params, self._cache, self._toks, self._seeds,
+                    self._sample_steps, self._temps, self._top_ps,
+                    czero, np.zeros((w,), np.int32), czero,
+                    np.int32(w), off, czero, cold, whole,
+                )
+                self._warmed[f"serve_{name}:{w}"] = (fn, _abstract(args))
                 with program(name, w):
                     (self._cache, self._toks, self._seeds,
                      self._sample_steps, self._temps, self._top_ps,
-                     _) = fn(
-                        self.params, self._cache, self._toks, self._seeds,
-                        self._sample_steps, self._temps, self._top_ps,
-                        czero, np.zeros((w,), np.int32), czero,
-                        np.int32(w), off, czero, cold, whole,
-                    )
+                     *_) = fn(*args)
+        args = (self.params, self._cache, self._toks, self._seeds,
+                self._sample_steps, self._temps, self._top_ps)
+        self._warmed["serve_decode"] = (self._decode, _abstract(args))
         with program("decode"):
-            self._toks, self._cache, self._sample_steps = self._decode(
-                self.params, self._cache, self._toks, self._seeds,
-                self._sample_steps, self._temps, self._top_ps,
-            )
+            (self._toks, self._cache, self._sample_steps,
+             *_) = self._decode(*args)
+        if self._dsa_rows:
+            with program("lane_selection"):
+                self._lane_selection(self._cache.sel, np.int32(0))
         if self.spec_tokens:
             for name, fn in (
                 ("draft_prefill_first", self._draft_chunk_first),
@@ -1434,7 +1533,7 @@ class ServeEngine:
         """HBM one decode lane holds, K/V and recurrent state both —
         and with it how many ``slots`` a chip holds."""
         return (self.kv_bytes_per_slot() + self.ring_bytes_per_slot()
-                + self.state_bytes_per_slot())
+                + self.state_bytes_per_slot() + self.latent_bytes_per_slot())
 
     def recurrent_stats(self) -> dict:
         """The recurrent lanes' counters and gauges (``/stats``'s
@@ -1458,6 +1557,25 @@ class ServeEngine:
                 "kv_ring_bytes_per_slot": self.ring_bytes_per_slot(),
                 "kv_shared_bytes_per_slot": self.kv_bytes_per_slot(),
             } if self._attended_rows else {}),
+        }
+
+    def latent_bytes_per_slot(self) -> int:
+        """Latent and indexer rows one lane holds over all layers; 0
+        for a model without them."""
+        leaves = (jax.tree.leaves(getattr(self._cache, "latent", ()))
+                  + jax.tree.leaves(getattr(self._cache, "index_k", ())))
+        return sum(int(x.nbytes) for x in leaves) // self.num_slots
+
+    def latent_stats(self) -> dict:
+        """The selected-key lanes' counters and gauge (``/stats``'s
+        ``latent_attention``, ``/metricsz``'s ``ddp_tpu_serve_dsa_*``
+        and ``ddp_tpu_serve_moe_pairs_*``); plain host ints."""
+        return {
+            "dsa_rows_scored_total": self.dsa_rows_scored_total,
+            "dsa_rows_selected_total": self.dsa_rows_selected_total,
+            "moe_pairs_routed_total": self.moe_pairs_routed_total,
+            "moe_pairs_held_total": self.moe_pairs_held_total,
+            "latent_bytes_per_slot": self.latent_bytes_per_slot(),
         }
 
     def page_stats(self) -> Optional[dict]:
@@ -1494,10 +1612,11 @@ class ServeEngine:
         is a control-plane event, like the bind-time table upload) —
         the steady-state transfer invariant is untouched.
         """
-        if self.recurrent:
+        if self.recurrent or self._dsa_rows:
+            why = RECURRENT_REFUSALS if self.recurrent else LATENT_REFUSALS
             raise ValueError(
                 f"export_prefix does not apply to the {self.spec.block} block: "
-                f"{RECURRENT_REFUSALS['export_prefix']}"
+                f"{why['export_prefix']}"
             )
         if not self.paged:
             return None
@@ -1565,11 +1684,12 @@ class ServeEngine:
             PageWireError,
         )
 
-        if self.recurrent:
+        if self.recurrent or self._dsa_rows:
+            why = RECURRENT_REFUSALS if self.recurrent else LATENT_REFUSALS
             raise PageWireError(
                 SHAPE_MISMATCH,
                 f"install_prefix does not apply to the {self.spec.block} block: "
-                f"{RECURRENT_REFUSALS['install_prefix']}",
+                f"{why['install_prefix']}",
             )
         if not self.paged:
             raise PageWireError(
@@ -1745,6 +1865,11 @@ class ServeEngine:
             **(
                 {"recurrent_state": self.recurrent_stats()}
                 if self.recurrent else {}
+            ),
+            # Latent lanes whose keys are selected: absent elsewhere.
+            **(
+                {"latent_attention": self.latent_stats()}
+                if self._dsa_rows else {}
             ),
             # Paged KV + prefix index (PR 12): absent on fixed-lane
             # engines, so the default /metricsz exposition stays
@@ -2066,12 +2191,13 @@ class ServeEngine:
                 else:
                     (self._cache, self._toks, self._seeds,
                      self._sample_steps, self._temps, self._top_ps,
-                     first) = fn(
+                     first, *pairs) = fn(
                         self.params, self._cache, self._toks, self._seeds,
                         self._sample_steps, self._temps, self._top_ps,
                         slot_i, tok_buf, start_t, live_t, final_t,
                         *sampling,
                     )
+                    self._pending += [("pairs", p, None) for p in pairs]
                 if self.spec_tokens:
                     # The draft cache tracks the same token history: the
                     # same chunk ingests into its lane (never final — the
@@ -2106,6 +2232,15 @@ class ServeEngine:
                 # the layers that write nothing into a lane
                 self.prefill_self_positions_total += live
                 self.prefill_cross_positions_total += bool(final)
+            if self._dsa_rows:
+                scored, selected = self._dsa_rows(self.spec, start, live)
+                self.dsa_rows_scored_total += scored
+                self.dsa_rows_selected_total += selected
+                tracer.complete(
+                    "serve.chunk_selected", time.perf_counter(), 0.0,
+                    parent=parent,
+                    nums=(scored, selected, live, start, int(final)),
+                )
             if self._reqtrace is not None:
                 tr = self._reqtrace.get(req.rid)
                 if tr is not None:
@@ -2162,22 +2297,51 @@ class ServeEngine:
                 ])
                 self.kv_ring_rows_attended_total += ring
                 self.kv_shared_rows_attended_total += shared
+            if self._dsa_rows:
+                # over the LIVE lanes, as above: a lane at row count r
+                # queries from position r - 1
+                scored = selected = 0
+                for i in emit_lanes:
+                    a, b = self._dsa_rows(
+                        self.spec, len(self._slots[i].request.prompt)
+                        + self._slots[i].emitted - 1, 1)
+                    scored, selected = scored + a, selected + b
+                self.dsa_rows_scored_total += scored
+                self.dsa_rows_selected_total += selected
             if self.recurrent:
                 self._name_live_lanes(emit_lanes)
             with tracer.span(
                 "serve.decode", parent=parent,
                 nums=(len(decode_lanes), rows),
             ) as span, self._sanitizer.guard():
-                self._toks, self._cache, self._sample_steps = self._decode(
+                (self._toks, self._cache, self._sample_steps,
+                 *pairs) = self._decode(
                     self.params, self._cache, self._toks, self._seeds,
                     self._sample_steps, self._temps, self._top_ps,
                 )
+            self._pending += [("pairs", p, None) for p in pairs]
             t0 = span.t0
             if self._attended_rows:
                 tracer.complete(
                     "serve.decode_rows", time.perf_counter(), 0.0,
                     parent=parent, nums=(ring, shared, len(emit_lanes)),
                 )
+            if self._dsa_rows:
+                tracer.complete(
+                    "serve.decode_selected", time.perf_counter(), 0.0,
+                    parent=parent,
+                    nums=(scored, selected, len(emit_lanes)),
+                )
+                # what this step selected, for each lane that asked and
+                # whose LAST owed token it produced (a lane's later
+                # steps, owed nothing, would overwrite it): kept on the
+                # device until the request finishes
+                for i in emit_lanes:
+                    slot = self._slots[i]
+                    if (slot.request.record_selection and slot.emitted + 1
+                            == slot.request.max_new_tokens):
+                        slot.selection = self._lane_selection(
+                            self._cache.sel, np.int32(i))
             device_work = True
             for i in emit_lanes:
                 self._slots[i].emitted += 1
@@ -2547,6 +2711,13 @@ class ServeEngine:
         with self.tracer.span("serve.sample", parent=parent) as span:
             appended = 0
             for kind, arr, meta in items:
+                if kind == "pairs":
+                    # The call's (expert, token) pairs: routed, and
+                    # those whose expert is held here.
+                    routed, held = (int(x) for x in np.asarray(arr))
+                    self.moe_pairs_routed_total += routed
+                    self.moe_pairs_held_total += held
+                    continue
                 if kind in ("block", "moe"):
                     # The expert layer's routing counts of the call
                     # (rows, fullest expert summed over layers,
@@ -2608,6 +2779,10 @@ class ServeEngine:
             ),
             first_tokens=slot.first_tokens,
             block_inputs=slot.block_log,
+            selected_rows=(
+                None if slot.selection is None
+                else np.asarray(slot.selection).tolist()
+            ),
         )
         self._completed[req.rid] = c
         self._retire_times.append(now)
@@ -2647,6 +2822,7 @@ class ServeEngine:
         slot.lead = slot.block_pos = 0
         slot.first_tokens = 1
         slot.block_log = None
+        slot.selection = None
 
     def _retire_trace(self, c: Completion) -> None:
         """Close the request's trace (if tracing) and hang the digest

@@ -117,6 +117,9 @@ class Request:
     # Block diffusion only: keep every forward's block inputs for the
     # completion (what the benchmark's reference is handed).
     record_blocks: bool = False
+    # A model that selects its keys only: keep what the step that
+    # produced the last token selected, for the completion.
+    record_selection: bool = False
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now >= self.deadline
@@ -172,6 +175,7 @@ class Scheduler:
         trace_id: Optional[int] = None,
         model: Optional[str] = None,
         record_blocks: bool = False,
+        record_selection: bool = False,
     ) -> Admission:
         """Validate + enqueue → Admission (never raises on bad input).
 
@@ -233,6 +237,7 @@ class Scheduler:
             ),
             model=model,
             record_blocks=record_blocks,
+            record_selection=record_selection,
         )
         self._queue.append(req)
         return Admission(True, request=req)

@@ -302,6 +302,7 @@ class LMServer:
                 hops=hops,
                 model=model,
                 record_blocks=bool(body.get("record_blocks", False)),
+                record_selection=bool(body.get("record_selection", False)),
             )
         if not adm.accepted:
             # Only queue_full is transient (retry-after-backoff
@@ -375,6 +376,13 @@ class LMServer:
             **(
                 {"block_inputs": done.block_inputs}
                 if done.block_inputs is not None
+                else {}
+            ),
+            # A model that selects its keys, asked with
+            # ``record_selection``: the last token's step's rows.
+            **(
+                {"selected_rows": done.selected_rows}
+                if done.selected_rows is not None
                 else {}
             ),
             # Which model version served this request (absent on

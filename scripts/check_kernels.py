@@ -598,6 +598,118 @@ def check_moe(N: int, d: int, f: int, E: int, top_k: int) -> dict:
     }
 
 
+def check_moe_share(N: int, d: int, f: int, E: int, held: int, first: int,
+                    top_k: int) -> dict:
+    """One member's part of an expert layer divided over several
+    (ops/moe.moe_share_layer: sigmoid scores, a choice bias, ``held`` of
+    the ``E`` experts held here from number ``first``), the gate/up
+    kernel a column block of the expert width at a time where the
+    matrices are too wide for VMEM, against every held expert run on
+    every token in fp32 and weighted by the routed weights."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops.moe import column_block, moe_share_layer, route
+
+    ks = jax.random.split(jax.random.key(5), 6)
+    x = jax.random.normal(ks[0], (N, d), jnp.float32)
+    logits = jax.random.normal(ks[1], (N, E), jnp.float32)
+    bias = 0.3 * jax.random.normal(ks[5], (E,), jnp.float32)
+    wg, wu, wd = (
+        (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(jnp.bfloat16)
+        for k, shape in zip(ks[2:5], ((held, d, f), (held, d, f),
+                                      (held, f, d)))
+    )
+    kw = dict(top_k=top_k, scoring="sigmoid", bias=bias, scale=2.5)
+    out, stats = jax.jit(lambda *a: moe_share_layer(
+        *a, first=first, impl="pallas", **kw))(x, logits, wg, wu, wd)
+
+    def plain(x, logits, wg, wu, wd):
+        idx, w = route(logits, top_k, True, scoring="sigmoid", bias=bias,
+                       scale=2.5)
+        comb = jnp.zeros(logits.shape, jnp.float32).at[
+            jnp.arange(N)[:, None], idx].add(w)[:, first:first + held]
+        f32 = lambda a: a.astype(jnp.float32)
+        h = jax.nn.silu(jnp.einsum("nd,edf->enf", x, f32(wg))) * jnp.einsum(
+            "nd,edf->enf", x, f32(wu))
+        return jnp.einsum("ne,end->nd", comb,
+                          jnp.einsum("enf,efd->end", h, f32(wd)))
+
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(plain)(x, logits, wg, wu, wd)
+    err = _max_err(out, ref)
+    rel = err / float(jnp.abs(ref).max())
+    return {
+        "max_abs_err": err, "rel_err": rel, "tol": TOL["moe_rel"],
+        "pairs_routed": int(stats[0]), "pairs_held": int(stats[1]),
+        "experts_hit": int(stats[3]),
+        "column_block": column_block(d, f, 2),
+        "ok": bool(jnp.isfinite(out).all()) and rel <= TOL["moe_rel"]
+        and 0 < int(stats[1]) < int(stats[0]),
+    }
+
+
+def check_latent_decode(S: int, H: int, R: int, Dr: int, Hi: int, Di: int,
+                        L: int, top_k: int) -> dict:
+    """A decode step over a latent cache whose keys are selected
+    (ops/decode.py: index scores, the top-k, the gathered rows under
+    absorbed attention; plain XLA) against the same mathematics in fp32
+    under an explicit mask, lanes at positions from a few rows to the
+    lane's end."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.ops import decode as dec
+
+    ks = jax.random.split(jax.random.key(9), 5)
+    ki = jax.random.normal(ks[0], (S, L, Di), jnp.bfloat16)
+    lat = jax.random.normal(ks[1], (S, L, R + Dr), jnp.bfloat16)
+    qi = jax.random.normal(ks[2], (S, Hi, Di), jnp.float32)
+    w = jax.random.normal(ks[3], (S, Hi), jnp.float32)
+    q = jax.random.normal(ks[4], (S, H, R + Dr), jnp.float32)
+    pos = jnp.asarray([(L - 1) * (s + 1) // S for s in range(S)], jnp.int32)
+    pos = pos.at[0].set(min(5, L - 1))
+    scale = (R + Dr) ** -0.5
+
+    @jax.jit
+    def program(qi, w, ki, q, lat, pos):
+        rows, counted = dec.select_rows(dec.index_scores(qi, w, ki), pos,
+                                        top_k)
+        return dec.latent_decode_attention(q, lat, rows, counted, rank=R,
+                                           scale=scale), rows, counted
+
+    def plain(qi, w, ki, q, lat, pos):
+        f32 = lambda a: a.astype(jnp.float32)
+        qb = f32(qi.astype(jnp.bfloat16))
+        I = jnp.einsum("shl,sh->sl", jax.nn.relu(
+            jnp.einsum("shd,sld->shl", qb, f32(ki))), w)
+        live = jnp.arange(L)[None, :] <= pos[:, None]
+        I = jnp.where(live, I, -jnp.inf)
+        best = jax.lax.top_k(I, min(top_k, L))[1]
+        sel = live & jnp.zeros((S, L), bool).at[
+            jnp.arange(S)[:, None], best].set(True)
+        s = jnp.einsum("shw,slw->shl", f32(q.astype(jnp.bfloat16)),
+                       f32(lat)) * scale
+        p = jax.nn.softmax(jnp.where(sel[:, None], s, -jnp.inf), -1)
+        return jnp.einsum("shl,slr->shr", p, f32(lat)[..., :R]), sel
+
+    out, rows, counted = program(qi, w, ki, q, lat, pos)
+    with jax.default_matmul_precision("highest"):
+        ref, sel = jax.jit(plain)(qi, w, ki, q, lat, pos)
+    mine = jnp.zeros((S, L), bool).at[
+        jnp.arange(S)[:, None], rows].max(counted)
+    err = _max_err(out, ref)
+    return {
+        "max_abs_err": err, "tol": TOL["decode"],
+        "rows_selected": [int(c) for c in counted.sum(-1)],
+        "rows_that_differ": int((mine != sel).sum()),
+        # bf16 probabilities into the value product: the flash kernels'
+        # contract, not float32's
+        "ok": bool(jnp.isfinite(out).all()) and err <= 2e-2
+        and int((mine != sel).sum()) <= S,
+    }
+
+
 def check_decode_packed(S: int, H: int, H_kv: int, Dh: int, L: int,
                         depth: int, scale: float) -> dict:
     """Flash-decode over rows stored with their kv heads side by side
@@ -862,6 +974,22 @@ def cases(tiny: bool, every: bool):
         moe = (dict(N=16, d=128, f=128, E=8, top_k=2) if tiny
                else dict(N=128, d=2048, f=768, E=128, top_k=8))
         yield "moe_grouped_bf16", lambda: check_moe(**moe)
+        # One chip's share of a wide expert layer (models/glm_dsa.py) at
+        # the benchmark's widths: 16 of 256 sigmoid-routed experts of
+        # [6144, 2048], a decode step's 16 tokens and a chunk's 2,048;
+        # gate and up are walked a column block at a time. And a decode
+        # step over the latent cache: 32 index heads of 128 scoring a
+        # lane of 17,408, the top 2,048, 64 heads against one row of 576.
+        share = (dict(d=128, f=512, E=16, held=4, first=4, top_k=4) if tiny
+                 else dict(d=6144, f=2048, E=256, held=16, first=0, top_k=8))
+        for n in ((16,) if tiny else (16, 2048)):
+            yield f"moe_share_sigmoid_bf16_{n}", functools.partial(
+                check_moe_share, N=n, **share)
+        latent = (dict(S=3, H=4, R=16, Dr=8, Hi=2, Di=16, L=64, top_k=8)
+                  if tiny else dict(S=16, H=64, R=512, Dr=64, Hi=32, Di=128,
+                                    L=17408, top_k=2048))
+        yield "latent_decode_selected_rows", lambda: check_latent_decode(
+            **latent)
         # A hybrid of state-space and attention layers (models/
         # granite_hybrid.py) at the benchmark's widths: 4 queries a kv
         # head of 64 at softmax scale 1/64, two kv heads to a 128-lane

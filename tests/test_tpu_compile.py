@@ -357,6 +357,74 @@ def test_the_serve_cells_decode_programs_still_fit(
     assert abs(mem.temp_size_in_bytes / 1e9 - temporaries_gb) < 0.01
 
 
+@pytest.mark.parametrize("tokens", [16, 2048])  # a decode step, a chunk
+def test_an_expert_share_compiles_at_glm5_widths(one_chip, as_on_tpu,
+                                                 tokens):
+    """16 of 256 sigmoid-routed experts of [6144, 2048]: gate and up do
+    not fit VMEM twice over, so the gate/up call walks the tiles a
+    column block at a time (grid (2, tiles)); the down call keeps its
+    grid."""
+    from ddp_tpu.ops.moe import column_block, moe_share_layer
+
+    d, f, E, held, k = 6144, 2048, 256, 16, 8
+    assert column_block(d, f, 2) == 1024
+    bf = jnp.bfloat16
+    compiled = jax.jit(
+        lambda x, r, b, g, u, w: moe_share_layer(
+            x, r, g, u, w, top_k=k, first=0, scoring="sigmoid", bias=b,
+            scale=2.5, impl="pallas")
+    ).lower(
+        _shape((tokens, d), jnp.float32, one_chip),
+        _shape((tokens, E), jnp.float32, one_chip),
+        _shape((E,), jnp.float32, one_chip),
+        _shape((held, d, f), bf, one_chip),
+        _shape((held, d, f), bf, one_chip),
+        _shape((held, f, d), bf, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
+
+
+def test_the_glm5_cells_decode_program_fits(one_chip, as_on_tpu):
+    """The whole decode program of the GLM-5 cell at the benchmark's
+    sizes (16 lanes of 17,408 positions; bfloat16 weights and rows):
+    9.42 GB of weights and 2.99 GB of lanes as arguments, 0.09 GB of
+    temporaries (the index scores, the gathered rows, the experts'
+    tiles), inside one chip's 16 GB beside the chunk programs' 0.91 GB
+    (compiled by hand, PERF.md section 4: ~50 s each). No whole-lane
+    copy: with a latent row of 576 stored as it is the program held
+    0.60 GB of temporaries and copied 321 MB fourteen times a step."""
+    import json
+
+    from benchmarks.drivers import glm_dsa_serve
+    from ddp_tpu.models import glm_dsa
+    from ddp_tpu.models.generate import init_slot_cache
+
+    root = os.path.dirname(os.path.dirname(__file__))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm-5-serve-ep16.json")) as f:
+        cfg = json.load(f)
+    spec = glm_dsa_serve.lm_spec(cfg)
+    S = cfg["engine"]["slots"]
+    described = lambda make: jax.tree.map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), jax.eval_shape(make))
+    lanes = lambda dtype: _shape((S,), dtype, one_chip)
+    compiled = jax.jit(
+        lambda p, c, *a: glm_dsa.slot_decode_sample_step(spec, p, c, *a),
+        donate_argnums=(1,),
+    ).lower(
+        described(lambda: glm_dsa.init_params(spec)),
+        described(lambda: init_slot_cache(spec, S, jnp.bfloat16)),
+        lanes(jnp.int32), lanes(jnp.int32), lanes(jnp.int32),
+        lanes(jnp.float32), lanes(jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes / 1e9 - 12.42) < 0.02
+    assert mem.temp_size_in_bytes / 1e9 < 0.3
+
+
 @pytest.fixture(scope="module")
 def ddp4_schedule(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
