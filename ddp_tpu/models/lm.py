@@ -33,7 +33,7 @@ with importing("optax"):
 
 from ddp_tpu.models.vit import EncoderBlock
 from ddp_tpu.ops.attention import best_attention
-from ddp_tpu.parallel.ddp import StepMetrics, jit_train_step
+from ddp_tpu.parallel.ddp import StepMetrics, jit_train_step, norm_plan
 from ddp_tpu.parallel.ring import sequence_sharded_attention
 
 
@@ -81,6 +81,9 @@ class CausalLM(nn.Module):
     ep_axis: Optional[str] = None
     ep_size: int = 1
     num_kv_heads: int = 0  # GQA — see models/vit.py MultiHeadAttention
+    # The dense blocks' ``ln2`` output held once (models/vit.py
+    # EncoderBlock): ``_make_sharded_forward`` decides, from the mesh.
+    hold_norm: bool = False
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, head: bool = True):
@@ -132,6 +135,7 @@ class CausalLM(nn.Module):
                     tp_axis=self.tp_axis,
                     tp_size=self.tp_size,
                     num_kv_heads=self.num_kv_heads,
+                    hold_norm=self.hold_norm,
                     name=f"block{i + 1}",
                 )(x)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
@@ -350,7 +354,8 @@ def _dense_lm(spec: LMSpec) -> CausalLM:
 
 
 def _sharded_lm(
-    spec: LMSpec, *, tp_size: int = 1, ep_size: int = 1
+    spec: LMSpec, *, tp_size: int = 1, ep_size: int = 1,
+    hold_norm: bool = False,
 ) -> CausalLM:
     def attention(q, k, v):
         return sequence_sharded_attention(
@@ -388,6 +393,7 @@ def _sharded_lm(
         ep_size=ep_size,
         num_kv_heads=spec.num_kv_heads,
         mlp_ratio=spec.mlp_ratio,
+        hold_norm=hold_norm,
     )
 
 
@@ -480,6 +486,7 @@ def create_lm_train_state(
 
 
 def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
+    from ddp_tpu.models.moe import is_moe_block
     from ddp_tpu.models.seq_transformer import _batch_axes
     from ddp_tpu.parallel.tp import (
         ep_size as mesh_ep_size,
@@ -488,11 +495,16 @@ def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
         tp_size as mesh_tp_size,
     )
 
+    form, reason = norm_plan(mesh, remat=spec.remat)
     model = _sharded_lm(
-        spec, tp_size=mesh_tp_size(mesh), ep_size=mesh_ep_size(mesh)
+        spec, tp_size=mesh_tp_size(mesh), ep_size=mesh_ep_size(mesh),
+        hold_norm=form == "held",
     )
     baxes = _batch_axes(mesh)
     xspec = P(baxes, "seq")
+    held = sum(
+        not is_moe_block(i, spec.num_experts, spec.moe_every)
+        for i in range(spec.depth)) if form == "held" else 0
 
     def forward(params, tokens, want_aux: bool = True, head: bool = True):
         """→ (logits sharded like the tokens, replicated MoE aux loss
@@ -550,6 +562,18 @@ def _make_sharded_forward(spec: LMSpec, mesh: Mesh, compute_dtype):
             check_vma=False,
         )(params, tokens)
 
+    def record_norm_plan(tokens):
+        """One ``lm.norm_plan`` record, for a traced forward that is
+        differentiated (only there is anything held). Trace time, as
+        ``flash.plan``: a compiled step leaves none."""
+        get_tracer().complete(
+            "lm.norm_plan", time.perf_counter(), 0.0,
+            nums=(form, reason, held,
+                  held * tokens.size * spec.d_model
+                  * jnp.dtype(compute_dtype).itemsize),
+        )
+
+    forward.record_norm_plan = record_norm_plan
     return forward, xspec
 
 
@@ -813,6 +837,7 @@ def make_lm_train_step(
     )
 
     def loss_and_logits(params, tokens):
+        sharded_forward.record_norm_plan(tokens)
         logits, aux = sharded_forward(
             params, tokens, head=not token_metrics.fused)
         loss, correct = token_metrics(logits, tokens)

@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -186,6 +187,32 @@ class MultiHeadAttention(nn.Module):
         return nn.Dense(C, name="proj")(out)
 
 
+@jax.custom_vjp
+def held_once(y):
+    """``y``, an array of the program where it is differentiated: written
+    once, read by the matmul that follows and kept as that matmul's
+    residual, so its weight-gradient matmul reads it too. Without it XLA
+    fuses a LayerNorm's normalise-scale-shift-cast as a PRODUCER into
+    every consumer, and the weight-gradient matmul re-derives it from x
+    and the statistics once for every output tile (``mlp1``'s ran at
+    61% of the MXU's peak for it: PERF.md section 6, PR 45). The values
+    are the same either way; the cotangent passes as it is, so the
+    matmul that makes it keeps the LayerNorm's backward in its
+    epilogue."""
+    return y
+
+
+def _held_once_fwd(y):
+    return lax.optimization_barrier(y), None
+
+
+def _held_once_bwd(_, g):
+    return (g,)
+
+
+held_once.defvjp(_held_once_fwd, _held_once_bwd)
+
+
 class EncoderBlock(nn.Module):
     """Pre-LN block. ``deterministic`` is a module attribute (not a call
     kwarg) so ``nn.remat(EncoderBlock)`` traces only the activation —
@@ -195,7 +222,15 @@ class EncoderBlock(nn.Module):
     shard_map — attention heads and the MLP hidden dim shard over the
     ``model`` mesh axis, two psums per block (after attn/proj and
     mlp2); LayerNorms and the residual stream stay replicated
-    (parallel/tp.py has the layout + gradient-exactness story)."""
+    (parallel/tp.py has the layout + gradient-exactness story).
+
+    ``hold_norm``: ``ln2``'s output goes through ``held_once`` on its
+    way into ``mlp1``. The caller decides (``parallel/ddp.norm_plan``,
+    from the step's mesh); the default is the plain form. ``ln1`` passes
+    the same place and is NOT held: held, ``attn.qkv``'s forward ran
+    0.05 ms a call faster and its weight gradient 0.12 slower (XLA
+    tiles the ``[2048, 6144]`` result worse over a stored operand),
+    0.18% of the one-chip step end to end (PERF.md section 6, PR 45)."""
 
     num_heads: int
     mlp_dim: int
@@ -206,11 +241,18 @@ class EncoderBlock(nn.Module):
     tp_size: int = 1
     tp_inner_vjp: bool = False  # Megatron f/g — see MultiHeadAttention
     num_kv_heads: int = 0  # GQA — see MultiHeadAttention
+    hold_norm: bool = False
 
     @nn.compact
     def __call__(self, x):
         assert self.mlp_dim % self.tp_size == 0, (self.mlp_dim, self.tp_size)
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(x.dtype)
+
+        def norm(x, name, held=False):
+            # the one place ``ln1 -> attn.qkv`` and ``ln2 -> mlp1`` pass
+            y = nn.LayerNorm(dtype=jnp.float32, name=name)(x).astype(x.dtype)
+            return held_once(y) if held else y
+
+        y = norm(x, "ln1")
         y = MultiHeadAttention(
             self.num_heads,
             attention_fn=self.attention_fn,
@@ -222,7 +264,7 @@ class EncoderBlock(nn.Module):
         )(y, deterministic=self.deterministic)
         y = nn.Dropout(self.dropout_rate, deterministic=self.deterministic)(y)
         x = x + y
-        y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(x.dtype)
+        y = norm(x, "ln2", held=self.hold_norm)
         if self.tp_size > 1 and self.tp_inner_vjp:
             from ddp_tpu.parallel.tp import megatron_f
 

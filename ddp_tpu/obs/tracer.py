@@ -139,6 +139,17 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # matmuls see it (padded to 128 when fused) and the rows it takes at
     # a time (all of them: nothing yet holds a block)
     "lm.head_plan": ("form", "rows", "vocab", "padded_vocab", "block_rows"),
+    # models/lm.py — one per traced call of the train step's forward
+    # (``make_lm_train_step``; trace time, zero duration): the form of
+    # the dense blocks' ``ln2`` output (``held``: an array of the
+    # program, written once and read by ``mlp1``'s forward and by its
+    # weight-gradient matmul; ``plain``: re-derived inside each
+    # consumer's fusion, as ``ln1``'s is in either form), why
+    # (``one_device`` | ``data_parallel`` | ``sharded`` | ``remat``:
+    # ``parallel/ddp.norm_plan``, from the mesh), the LayerNorms held
+    # and their bytes a traced call.
+    # ``form`` and ``reason`` are names
+    "lm.norm_plan": ("form", "reason", "norms_held", "bytes_held"),
     # ops/ssm.py — one per traced ``pallas_call`` of the state update
     # (``ssm_state_update``, ``selective_state_update``) or of the
     # prefill scan (``selective_scan``), at trace time, zero duration:

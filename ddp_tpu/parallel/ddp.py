@@ -321,6 +321,11 @@ _OVERLAP_OPTIONS = {
 }
 
 
+def _parallel_axes(mesh: Mesh) -> list[str]:
+    """The mesh's axes of more than one member."""
+    return [a for a, n in mesh.shape.items() if n > 1]
+
+
 def overlap_compile_options(mesh: Mesh, *, zero_layout=None) -> dict[str, str]:
     """Compile options for a train step on ``mesh``, chosen from what
     the code can see: ``_OVERLAP_OPTIONS`` on a TPU backend for a PURE
@@ -333,11 +338,35 @@ def overlap_compile_options(mesh: Mesh, *, zero_layout=None) -> dict[str, str]:
     """
     if jax.default_backend() != "tpu" or zero_layout is not None:
         return {}
-    if mesh.shape.get("data", 1) <= 1:
-        return {}
-    if any(n > 1 for a, n in mesh.shape.items() if a != "data"):
+    if _parallel_axes(mesh) != ["data"]:
         return {}
     return dict(_OVERLAP_OPTIONS)
+
+
+def norm_plan(mesh: Mesh, *, remat: bool = False) -> tuple[str, str]:
+    """``(form, reason)`` of the LM blocks' ``ln2`` output in a train
+    step on ``mesh`` (``models/vit.py::EncoderBlock.hold_norm``), from
+    the same mesh as the options above and for the opposite need.
+
+    ``held`` on a mesh of ONE device: the step has no collective, its
+    matmuls should be as short as they go, and ``mlp1``'s
+    weight-gradient matmul, which re-derived LayerNorm(x) for every
+    output tile, ran at 61% of the MXU's peak where one that reads it
+    runs at 79% (PERF.md section 6, PR 45). ``plain``, the program as
+    it was, everywhere else: on a pure data-parallel mesh that slack is
+    what the gradient all-reduce rides (``_OVERLAP_OPTIONS`` fuses its
+    steps into those matmuls; 2.3 ms taken out of them came back as 2.2
+    ms waited at ``async-collective-done``, PR 44's chip runs), and no
+    cell measures the ``seq`` / ``model`` / ``fsdp`` / ``expert``
+    meshes, which keep what they have. ``remat`` recomputes the block
+    in the backward anyway and stays plain too.
+    """
+    if remat:
+        return "plain", "remat"
+    axes = _parallel_axes(mesh)
+    if not axes:
+        return "held", "one_device"
+    return "plain", "data_parallel" if axes == ["data"] else "sharded"
 
 
 class _RecordedLowering:

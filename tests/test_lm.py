@@ -285,3 +285,131 @@ def test_flash_plan_record_once_per_traced_call(monkeypatch):
     assert sorted(r[4] for r in records(before)) == [
         (kernel, 512, 512, 10, 4, 0, "float32", "projection", *form)
         for kernel, form in forms.items()]
+
+
+# ---- the form of the blocks' LayerNorm outputs (parallel/ddp.norm_plan) ----
+
+
+@pytest.mark.parametrize(
+    "axes, remat, form, reason",
+    [
+        (dict(data=1), False, "held", "one_device"),
+        (dict(data=2), False, "plain", "data_parallel"),  # forced host devices
+        (dict(data=4), False, "plain", "data_parallel"),
+        (dict(data=1, seq=2), False, "plain", "sharded"),
+        (dict(data=1, model=2), False, "plain", "sharded"),
+        (dict(data=2, seq=2), False, "plain", "sharded"),
+        (dict(data=2, fsdp=2), False, "plain", "sharded"),
+        (dict(data=1), True, "plain", "remat"),
+    ],
+)
+def test_norm_form_follows_the_mesh(devices, axes, remat, form, reason):
+    """``held`` where the step's mesh has ONE device (no collective to
+    cover), the parent's program on every other mesh, and a block that
+    is rematerialised anyway stays plain: chosen from what the code
+    sees, beside ``overlap_compile_options``. The step that
+    ``make_lm_train_step`` builds on that mesh follows it: a held norm
+    (a block's ``ln2``) is one ``optimization_barrier`` of the traced
+    forward (the fused head has two of its own in either form)."""
+    from ddp_tpu.parallel import ddp
+
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(MeshSpec(**axes), devices=devices[:n])
+    assert ddp.norm_plan(mesh, remat=remat) == (form, reason)
+    spec = SPEC._replace(remat=remat)
+    tx = optax.adam(1e-3)
+    state = jax.eval_shape(
+        lambda: create_lm_train_state(spec, tx, mesh, seed=0))
+    step = make_lm_train_step(spec, tx, mesh, jit=False)
+    toks = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+    barriers = str(jax.make_jaxpr(step)(state, toks)).count(
+        "optimization_barrier")
+    assert barriers == 2 + (spec.depth if form == "held" else 0)
+
+
+def _loss_and_grads(spec, mesh, compute_dtype):
+    from ddp_tpu.models import lm
+
+    forward, _ = lm._make_sharded_forward(spec, mesh, compute_dtype)
+    metrics = lm._make_sharded_token_metrics(spec, mesh)
+
+    def loss(params, toks):
+        logits, _ = forward(params, toks, head=not metrics.fused)
+        return metrics(logits, toks)[0]
+
+    return jax.jit(jax.value_and_grad(loss))
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+def test_held_and_plain_norms_train_alike(devices, monkeypatch, compute_dtype):
+    """The two forms are one computation: ``ln2``'s float32 output is
+    rounded to the compute dtype before ``mlp1`` in both, and held only
+    says that it is made once. On one device (where the rule says
+    ``held``; ``plain`` by steering the rule, in the test) the loss,
+    every leaf's gradient and the parameters after two Adam steps are
+    the same."""
+    from ddp_tpu.models import lm
+
+    dtype = jnp.dtype(compute_dtype).type
+    mesh = make_mesh(MeshSpec(data=1), devices=devices[:1])
+    toks = jnp.asarray(synthetic_tokens(4, total_len=64, vocab_size=32, seed=7))
+    tx = optax.adam(1e-2)
+
+    def build():
+        step = make_lm_train_step(
+            SPEC, tx, mesh, compute_dtype=dtype, donate=False)
+        return _loss_and_grads(SPEC, mesh, dtype), step
+
+    held = build()
+    monkeypatch.setattr(
+        lm, "norm_plan", lambda mesh, remat=False: ("plain", "data_parallel"))
+    plain = build()
+    state = create_lm_train_state(SPEC, tx, mesh, seed=3)
+    (loss_h, grads_h), (loss_p, grads_p) = (
+        f[0](state.params, toks) for f in (held, plain))
+    assert float(loss_h) == float(loss_p)
+    flat_p = dict(jax.tree_util.tree_leaves_with_path(grads_p))
+    for path, g in jax.tree_util.tree_leaves_with_path(grads_h):
+        np.testing.assert_array_equal(
+            np.asarray(g, np.float32), np.asarray(flat_p[path], np.float32),
+            err_msg=jax.tree_util.keystr(path))
+    states = []
+    for _, step in (held, plain):
+        s = state
+        for _ in range(2):
+            s, m = step(s, toks)
+        states.append((s, float(m.loss)))
+    assert states[0][1] == states[1][1]
+    for a, b in zip(jax.tree.leaves(states[0][0].params),
+                    jax.tree.leaves(states[1][0].params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("data, want", [
+    (1, ("held", "one_device", SPEC.depth,
+         SPEC.depth * 4 * 64 * SPEC.d_model * 2)),
+    (2, ("plain", "data_parallel", 0, 0)),
+])
+def test_norm_plan_record_once_per_traced_call(devices, data, want):
+    """Tracing the train step's forward leaves ONE ``lm.norm_plan``
+    record in the tracer's ring (the form, the reason, the LayerNorms
+    held and their bytes in the compute dtype) and a call of the
+    compiled step leaves none."""
+    from ddp_tpu.obs.tracer import SPAN_NUMS
+
+    records = functools.partial(_records_since, "lm.norm_plan")
+    mesh = make_mesh(MeshSpec(data=data), devices=devices[:data])
+    tx = optax.adam(1e-3)
+    step = make_lm_train_step(
+        SPEC, tx, mesh, compute_dtype=jnp.bfloat16, donate=False)
+    state = create_lm_train_state(SPEC, tx, mesh, seed=0)
+    toks = jnp.asarray(synthetic_tokens(4, total_len=64, vocab_size=32))
+    before = time.perf_counter()
+    state, _ = step(state, toks)
+    recs = records(before)
+    assert len(recs) == 1
+    name, t0, dur, parent, nums = recs[0]
+    assert dur == 0.0 and parent is None
+    assert len(nums) == len(SPAN_NUMS["lm.norm_plan"]) and nums == want
+    step(state, toks)  # compiled: nothing is traced, nothing recorded
+    assert len(records(before)) == 1

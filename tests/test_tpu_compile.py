@@ -10,6 +10,8 @@ TPU's compiler is installed here). Nothing runs.
   of the compiled, scheduled program are asynchronous and stand where
   compute runs under them; on one chip the step compiles to the program
   it always was (``scripts/show_collectives.py`` is the reader).
+- The form of the blocks' ``ln2`` output (PR 45): held once on one chip,
+  the parent's producer fusion on mesh ``data=4``.
 
 The topology is described inside a fixture, in this file only: one
 process at a time holds the TPU's library."""
@@ -426,14 +428,21 @@ def test_the_glm5_cells_decode_program_fits(one_chip, as_on_tpu):
 
 
 @pytest.fixture(scope="module")
-def ddp4_schedule(topo):
+def ddp4_text(topo):
     """Width 1024, depth 4 (heads of 64) on mesh data=4, compiled the
     way the program compiles it on a TPU: ~20 s."""
-    from show_collectives import compile_lm_step, schedule
+    from show_collectives import compile_lm_step
 
-    return schedule(compile_lm_step(
+    return compile_lm_step(
         topo.devices[:4], mesh_axes={"data": 4}, d_model=1024, depth=4,
-    ))
+    ).as_text()
+
+
+@pytest.fixture(scope="module")
+def ddp4_schedule(ddp4_text):
+    from ddp_tpu.obs.xprof import collective_schedule
+
+    return collective_schedule(ddp4_text)
 
 
 @pytest.mark.parametrize("what", [
@@ -535,6 +544,93 @@ def test_one_chip_step_runs_head_and_loss_as_one_operation(topo):
         + name + '/dot_general"', line)]
     assert len(at("block2/mlp1")) == 2
     assert max(at("block2/mlp1")) < min(at("block1/mlp2"))
+
+
+_SMALL = dict(d_model=512, depth=2, num_heads=4, vocab_size=1024,
+              seq_len=1024, rows_per_chip=2)
+
+
+@pytest.fixture(scope="module")
+def one_chip_text(topo):
+    """The step at width 512, depth 2, 2 x 1024 tokens, compiled for
+    one chip (~10 s)."""
+    from show_collectives import compile_lm_step
+
+    return compile_lm_step(
+        topo.devices[:1], mesh_axes={"data": 1}, **_SMALL).as_text()
+
+
+def _matmul_operands(text, scope, layer):
+    """``(result shapes, operand shapes)``, as ``dtype[dims]``, of each
+    matmul fusion of the entry computation whose ``op_name`` is
+    ``layer``'s ``dot_general`` under ``scope`` (``jvp()``: the forward;
+    ``transpose(jvp())``: the backward's two). The operands are the
+    parameters of the computation the fusion calls."""
+    import re
+
+    shape = r"\w+\[[\d,]*\]"
+    bodies = {b.split(" ", 1)[0]: b
+              for b in re.split(r"\n(?=%\S+ \(|ENTRY )", text)}
+    out = []
+    for line in text[text.index("\nENTRY"):].split("\n"):
+        if "kind=kOutput" not in line or not re.search(
+                r'op_name="[^"]*/' + re.escape(scope)
+                + r"/(?:shard_map/)?CausalLM/block\d/"
+                + layer + '/dot_general"', line):
+            continue
+        body = bodies[re.search(r"calls=(%[\w.\-]+)", line).group(1)]
+        out.append((
+            re.findall(shape, line.split(" fusion(", 1)[0]),
+            [re.search(r"= (" + shape + ")", l).group(1)
+             for l in body.split("\n") if " parameter(" in l]))
+    return out
+
+
+@pytest.mark.parametrize("scope", ["jvp()", "transpose(jvp())"])
+def test_one_chip_step_holds_mlp1s_layer_norm_once(one_chip_text, scope):
+    """On one chip (PR 45) ``mlp1``'s matmuls, forward and weight
+    gradient (fused with its Adam update), read ``ln2``'s output as the
+    ``bf16[rows, T, d_model]`` array it was written to once: no operand
+    is a ``[d_model]`` scale or shift or a ``f32[rows, T]`` statistic,
+    from which the parent's fusions re-derived it, the weight gradient's
+    once an output tile."""
+    found = _matmul_operands(one_chip_text, scope, "mlp1")
+    if scope == "jvp()":
+        found = [f for f in found if f[0] == ["bf16[2,1024,2048]"]]
+    else:  # dW + Adam: parameter, nu, mu and the step's scalar out
+        found = [f for f in found if f[0][0] == "f32[512,2048]"]
+    assert len(found) == _SMALL["depth"]
+    for _, operands in found:
+        assert "bf16[2,1024,512]" in operands
+        stray = [o for o in operands
+                 if o.endswith("[512]") or o == "f32[2,1024]"]
+        assert stray == [], (scope, operands)
+
+
+def test_one_chip_step_keeps_qkvs_layer_norm_plain(one_chip_text):
+    """``ln1 -> attn.qkv`` is NOT held, on any mesh: held, the forward
+    ran 0.05 ms a call faster and the weight gradient 0.12 slower at the
+    cells' widths (PERF.md section 6, PR 45), so ``qkv``'s forward
+    still derives the LayerNorm inside its own fusion."""
+    found = [f for f in _matmul_operands(one_chip_text, "jvp()", "attn/qkv")
+             if f[0] == ["bf16[2,1024,1536]"]]
+    assert len(found) == _SMALL["depth"]
+    for _, operands in found:
+        assert [o for o in operands if o.endswith("[512]")], operands
+
+
+@pytest.mark.parametrize("layer, columns", [("mlp1", 4096), ("attn/qkv", 3072)])
+def test_ddp4_step_keeps_the_plain_layer_norm(ddp4_text, layer, columns):
+    """On mesh ``data=4`` the step is the parent's program: the forward
+    matmul behind each LayerNorm still derives it inside its own fusion,
+    from x, the ``[d_model]`` scale and shift and the row statistics
+    (the weight-gradient matmuls' slack is what the gradient all-reduce
+    rides there: ``parallel/ddp.norm_plan``)."""
+    found = [f for f in _matmul_operands(ddp4_text, "jvp()", layer)
+             if f[0] == [f"bf16[4,2048,{columns}]"]]
+    assert len(found) == 4  # the fixture's depth
+    for _, operands in found:
+        assert [o for o in operands if o.endswith("[1024]")], operands
 
 
 def _program(text: str) -> str:
