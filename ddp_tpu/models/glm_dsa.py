@@ -29,7 +29,11 @@ at position ``t`` (H heads, R = ``kv_lora_rank``, Dn/Dr/Dv =
   softmax over ``s`` in ``S_t`` (below), ``out = concat_h(sum_s p v) @
   o_proj``. The cache holds ``[c_kv | rotated k_rope]``, R + Dr a
   position. A prefill chunk EXPANDS (``k_nope`` and ``v`` of a block of
-  keys from their latents, a per-query mask); a decode step ABSORBS
+  keys from their latents, a per-query mask: on a TPU ONE kernel a
+  layer, ops/latent_prefill.py, which holds a head's queries,
+  accumulator and statistics in VMEM, expands a block of keys once and
+  keeps its scores there, and still walks every live row of the lane;
+  the ``jnp`` walk elsewhere, :func:`chunk_form`); a decode step ABSORBS
   (``kv_b_proj``'s key half into the query, its value half into the
   output: H heads against one (R + Dr)-wide key whose first R columns
   are the value). Both are the equations above.
@@ -114,6 +118,7 @@ from ddp_tpu.ops.decode import (
     latent_decode_attention,
     select_rows,
 )
+from ddp_tpu.ops.latent_prefill import masked_walk, tiles
 from ddp_tpu.ops.moe import moe_share_layer
 
 BLOCK = "glm_dsa"
@@ -121,9 +126,13 @@ INIT_STD = 0.02
 BIAS_STD = 0.01
 # what the serve engine asks of a block's module (serve/engine.py)
 RECURRENT = False
-# Keys a prefill chunk takes at a time (scores of all heads for a block
-# of keys are the chunk's largest temporary: H x C x KEY_BLOCK fp32).
+# Keys a prefill chunk takes at a time, and the queries whose scores
+# against them the ``latent_prefill`` kernel holds at a time (one head's
+# [QUERY_TILE, KEY_BLOCK] fp32, in VMEM; the ``jnp`` walk's scores of
+# all heads for a block of keys are its largest temporary: H x C x
+# KEY_BLOCK fp32, through HBM).
 KEY_BLOCK = 512
+QUERY_TILE = 1024
 _NEG = -1e30
 
 
@@ -467,33 +476,62 @@ def _kth_largest(keys, k: int):
                          jnp.zeros((keys.shape[0],), jnp.uint32))
 
 
+def chunk_form(spec: LMSpec, queries: int, n_keys: int, row_width: int,
+               impl: str = "auto") -> tuple[str, int]:
+    """How a chunk's third pass runs, from what the code can observe ->
+    (``kernel`` | ``walk``, the kernel's query tile or 0): the
+    ``latent_prefill`` kernel (ops/latent_prefill.py) on a TPU where
+    queries, keys and widths are whole tiles, the ``jnp`` walk anywhere
+    else. ``impl`` ``pallas`` / ``jnp`` holds a test to one form."""
+    block_q = min(QUERY_TILE, queries)
+    if impl == "auto":
+        fits = tiles(
+            queries, n_keys, block_q, min(KEY_BLOCK, n_keys),
+            (spec.qk_nope_head_dim + spec.qk_rope_head_dim, spec.v_head_dim,
+             spec.kv_lora_rank, row_width))
+        impl = "pallas" if fits and jax.default_backend() == "tpu" else "jnp"
+    if impl not in ("pallas", "jnp"):
+        raise ValueError(f"unknown chunk attention impl {impl!r}: expected "
+                         "'auto', 'pallas' or 'jnp'")
+    return ("kernel", block_q) if impl == "pallas" else ("walk", 0)
+
+
 @jax.named_scope("mla_prefill")
-def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, block_of,
-                    n_blocks, n_keys: int, q_pos, *, want_mask=False):
-    """``C`` queries at positions ``q_pos`` against ``n_keys`` stored
-    rows, ``KEY_BLOCK`` at a time: ``block_of(j) -> (latent rows
-    [B, R + Dr], indexer keys [B, Di])`` of keys ``j * B ..``, for the
-    first ``n_blocks`` (traced or not) blocks; later rows are never
-    read. Three passes: the index scores of every (query, key); each
-    query's ``index_topk``-th largest score (a radix select: no sort);
-    then expanded attention under the mask ``score above the threshold,
-    or equal to it among the first ties``, by the online softmax.
-    -> ``[C, H * Dv]`` float32 (and the mask ``[C, n_keys]`` if asked)."""
+def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, latent, index_k,
+                    lane, n_blocks, q_pos, *, want_mask=False,
+                    impl: str = "auto"):
+    """``C`` queries at positions ``q_pos`` against the stored rows of
+    lane ``lane`` of ``latent`` ``[S, n_keys, W]`` and ``index_k`` ``[S,
+    n_keys, Di]``, ``KEY_BLOCK`` at a time, the first ``n_blocks``
+    (traced or not) blocks; later rows are never read. Three passes: the
+    index scores of every (query, key); each query's ``index_topk``-th
+    largest score (a radix select: no sort); then expanded attention
+    under the mask ``score above the threshold, or equal to it among the
+    first ties``, by the online softmax, in the form :func:`chunk_form`
+    finds: ONE kernel a layer that is handed the mask as int8 ``[blocks,
+    C, KEY_BLOCK]`` and keeps a block's scores in VMEM, or the ``jnp``
+    walk, whose scores of all heads for a block pass through HBM. Both
+    walk every live row. -> ``[C, H * Dv]`` float32 (and the mask ``[C,
+    n_keys]`` if asked)."""
     C, H = q_nope.shape[0], spec.num_heads
     R, Dv = spec.kv_lora_rank, spec.v_head_dim
+    n_keys = latent.shape[1]
     B = min(KEY_BLOCK, n_keys)
     K = spec.index_topk
     w_k, w_v = _kv_b(spec, p)
     cdt = w_k.dtype
     k_pos = jnp.arange(B, dtype=jnp.int32)
 
+    def block_of(buf, j):
+        return lax.dynamic_slice(
+            buf, (lane, j * B, 0), (1, B, buf.shape[2]))[0]
+
     def valid_of(j):
         return (j * B + k_pos)[None, :] <= q_pos[:, None]
 
     with jax.named_scope("dsa_index"):
         def score_block(j, keys):
-            _, ki = block_of(j)
-            s = index_scores(qi, w, ki[None])  # [C, B]
+            s = index_scores(qi, w, block_of(index_k, j)[None])  # [C, B]
             return lax.dynamic_update_slice(
                 keys, _order_keys(s, valid_of(j)), (0, j * B))
 
@@ -504,12 +542,39 @@ def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, block_of,
         above = jnp.sum(keys > kth[:, None], axis=1).astype(jnp.int32)
         ties_taken = K - above  # ties at the threshold, lowest first
 
+    def select_block(j, ties):
+        """Block ``j``'s mask ``[C, B]`` and the ties counted so far."""
+        kb = lax.dynamic_slice(keys, (0, j * B), (C, B))
+        tie = kb == kth[:, None]
+        rank = ties[:, None] + jnp.cumsum(tie, axis=1) - tie
+        sel = valid_of(j) & ((kb > kth[:, None])
+                             | (tie & (rank < ties_taken[:, None])))
+        return sel, ties + tie.sum(1).astype(jnp.int32)
+
     qn, qr = q_nope.astype(cdt), q_rope.astype(cdt)
     scale = attn_scale(spec)
+    no_ties = jnp.zeros((C,), jnp.int32)
+    form, block_q = chunk_form(spec, C, n_keys, latent.shape[2], impl)
+    if form == "kernel":
+        def mask_block(j, carry):
+            sel, ties = select_block(j, carry[0])
+            return ties, lax.dynamic_update_slice(
+                carry[1], sel.astype(jnp.int8)[None], (j, 0, 0))
+
+        _, mask = lax.fori_loop(
+            0, n_blocks, mask_block,
+            (no_ties, jnp.zeros((n_keys // B, C, B), jnp.int8)))
+        out = masked_walk(
+            jnp.concatenate([qn, qr], -1), latent, w_k, w_v, mask, n_blocks,
+            q_pos, lane=lane, rope=spec.qk_rope_head_dim, scale=scale,
+            block_q=block_q)
+        if want_mask:
+            return out, mask.transpose(1, 0, 2).reshape(C, n_keys) != 0
+        return out
 
     def attend_block(j, carry):
         m, l, acc, ties, mask = carry
-        rows, _ = block_of(j)
+        rows = block_of(latent, j)
         c, kr = rows[:, :R], rows[:, R:R + spec.qk_rope_head_dim]
         k_nope = jnp.einsum("br,rhn->bhn", c, w_k,
                             preferred_element_type=jnp.float32).astype(cdt)
@@ -519,11 +584,7 @@ def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, block_of,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("chr,br->hcb", qr, kr.astype(cdt),
                           preferred_element_type=jnp.float32)) * scale
-        kb = lax.dynamic_slice(keys, (0, j * B), (C, B))
-        tie = kb == kth[:, None]
-        rank = ties[:, None] + jnp.cumsum(tie, axis=1) - tie
-        sel = valid_of(j) & ((kb > kth[:, None])
-                             | (tie & (rank < ties_taken[:, None])))
+        sel, ties = select_block(j, ties)
         s = jnp.where(sel[None], s, _NEG)
         m_new = jnp.maximum(m, s.max(-1))
         pr = jnp.where(sel[None], jnp.exp(s - m_new[..., None]), 0.0)
@@ -533,11 +594,10 @@ def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, block_of,
             preferred_element_type=jnp.float32)
         if want_mask:
             mask = lax.dynamic_update_slice(mask, sel, (0, j * B))
-        return (m_new, l * alpha + pr.sum(-1), acc,
-                ties + tie.sum(1).astype(jnp.int32), mask)
+        return m_new, l * alpha + pr.sum(-1), acc, ties, mask
 
     init = (jnp.full((H, C), _NEG, jnp.float32), jnp.zeros((H, C)),
-            jnp.zeros((H, C, Dv)), jnp.zeros((C,), jnp.int32),
+            jnp.zeros((H, C, Dv)), no_ties,
             jnp.zeros((C, n_keys if want_mask else 0), bool))
     _, l, acc, _, mask = lax.fori_loop(0, n_blocks, attend_block, init)
     out = (acc / l[..., None]).transpose(1, 0, 2).reshape(C, H * Dv)
@@ -545,15 +605,11 @@ def chunk_attention(spec: LMSpec, p, q_nope, q_rope, qi, w, block_of,
 
 
 def _own_rows(row, ki, dtype, n_keys):
-    """``block_of`` over a run's own rows, rounded as a cache stores
-    them."""
-    row, ki = row.astype(dtype), ki.astype(dtype)
-    B = min(KEY_BLOCK, n_keys)
-    pad = -row.shape[0] % B
-    if pad:
-        row, ki = (jnp.pad(a, ((0, pad), (0, 0))) for a in (row, ki))
-    return lambda j: (lax.dynamic_slice_in_dim(row, j * B, B),
-                      lax.dynamic_slice_in_dim(ki, j * B, B))
+    """A run's own rows as one lane of stored rows ``[1, n_keys, .]``,
+    rounded as a cache stores them."""
+    pad = n_keys - row.shape[0]
+    return tuple(jnp.pad(a.astype(dtype), ((0, pad), (0, 0)))[None]
+                 for a in (row, ki))
 
 
 def dense_logits(spec: LMSpec, params, tokens, *, want_masks: bool = False):
@@ -574,8 +630,8 @@ def dense_logits(spec: LMSpec, params, tokens, *, want_masks: bool = False):
             q_nope, q_rope, row, qi, ki, w = attn_inputs(spec, p, u, pos)
             out = chunk_attention(
                 spec, p, q_nope, q_rope, qi, w,
-                _own_rows(row, ki, dtype, n_keys), n_keys // B,
-                n_keys, pos, want_mask=want_masks)
+                *_own_rows(row, ki, dtype, n_keys), 0, n_keys // B, pos,
+                want_mask=want_masks)
             if want_masks:
                 out, mask = out
                 masks.append(mask[:, :T])
@@ -595,15 +651,18 @@ def dense_logits(spec: LMSpec, params, tokens, *, want_masks: bool = False):
 # ---- lanes -----------------------------------------------------------
 
 
-def _record_plan(spec: LMSpec, program: str, queries: int, n_keys: int):
-    """Trace-time record of how a program selects and attends."""
+def _record_plan(spec: LMSpec, program: str, queries: int, n_keys: int,
+                 form: str = "gather", block_q: int = 0):
+    """Trace-time record of how a program selects and attends; last the
+    form its attention took (a chunk's :func:`chunk_form`; a decode step
+    gathers the selected rows) and the kernel's query tile."""
     get_tracer().complete(
         "dsa.plan", time.perf_counter(), 0.0,
         nums=(program, queries, n_keys, min(spec.index_topk, n_keys),
               spec.depth,
               0 if program == "decode" else min(KEY_BLOCK, n_keys),
               spec.kv_lora_rank + spec.qk_rope_head_dim,
-              spec.index_head_dim),
+              spec.index_head_dim, form, block_q),
     )
 
 
@@ -635,7 +694,7 @@ def prefill_chunk(
             f"a chunk reads stored rows {B} at a time: {n_keys} rows are "
             "no whole number of such blocks")
     _record_plan(spec, "prefill_chunk" if lane_attend else "prefill_first",
-                 C, n_keys)
+                 C, n_keys, *chunk_form(spec, C, n_keys, lat[0].shape[-1]))
 
     def attend(i, p, u):
         q_nope, q_rope, row, qi, ki, w = attn_inputs(spec, p, u, q_pos)
@@ -646,17 +705,11 @@ def prefill_chunk(
         idx[i] = lax.dynamic_update_slice(
             idx[i], ki.astype(dt)[None], (slot, start, 0))
         if lane_attend:
-            block_of = lambda j: (
-                lax.dynamic_slice(lat[i], (slot, j * B, 0),
-                                  (1, B, lat[i].shape[2]))[0],
-                lax.dynamic_slice(idx[i], (slot, j * B, 0),
-                                  (1, B, idx[i].shape[2]))[0])
-            n_blocks = (start + C + B - 1) // B
+            stored = lat[i], idx[i], slot, (start + C + B - 1) // B
         else:
-            block_of = _own_rows(row, ki, dt, n_keys)
-            n_blocks = n_keys // B
-        return chunk_attention(spec, p, q_nope, q_rope, qi, w, block_of,
-                               n_blocks, n_keys, q_pos)
+            stored = *_own_rows(row, ki, dt, n_keys), 0, n_keys // B
+        return chunk_attention(spec, p, q_nope, q_rope, qi, w, *stored,
+                               q_pos)
 
     x, pairs = forward_layers(spec, params, _embed(params, chunk), attend,
                               real)

@@ -173,9 +173,13 @@ SPAN_NUMS: dict[str, tuple[str, ...]] = {
     # ``prefill_chunk`` | ``decode``: a name), its queries, the stored
     # rows a query may score, how many it attends, the layers, the keys
     # a chunk takes at a time (0: a decode step scores a lane in one),
-    # and the widths of a position's latent and indexer rows
+    # the widths of a position's latent and indexer rows, and (PR 46)
+    # the form its attention took (``kernel``: ops/latent_prefill.py |
+    # ``walk``: the ``jnp`` walk | ``gather``: a decode step; a name)
+    # with the queries the kernel scores at a time (0: no kernel)
     "dsa.plan": ("program", "queries", "keys", "top_k", "layers",
-                 "key_block", "latent_row", "index_row"),
+                 "key_block", "latent_row", "index_row", "attn_form",
+                 "query_tile"),
     # where a lane's keys are selected (models/glm_dsa.py), where its
     # ``serve.decode`` ends, of no duration and under the same parent:
     # rows the indexer scored and rows attention read that step, summed

@@ -255,16 +255,11 @@ def check_backward_forms(B: int, T: int, H: int, D: int, block: int) -> dict:
 FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
-def device_ms_per_call(log_dir: str, calls: int,
-                       kernels=FLASH_KERNELS) -> tuple[dict, float]:
-    """Device ms a call in the profiler trace under ``log_dir``, from
-    the ``XLA Ops`` events of the first TPU: of each of ``kernels`` (the
-    events whose instruction carries the kernel's name,
-    ``pallas_call(name=)``), and of every other operation together.
-    ``({}, 0.0)`` where the trace holds no kernel event (nothing ran on
-    a chip)."""
+def first_tpu_lines(log_dir: str):
+    """The lines (``XLA Ops``, ``XLA Modules``, ...) of the first TPU's
+    plane in the newest profiler trace under ``log_dir``; none where
+    nothing ran on a chip."""
     import glob
-    import re
 
     import jax
 
@@ -273,11 +268,24 @@ def device_ms_per_call(log_dir: str, calls: int,
     planes = sorted(
         (p for p in jax.profiler.ProfileData.from_file(path).planes
          if p.name.startswith("/device:TPU:")), key=lambda p: p.name)
+    return planes[0].lines if planes else ()
+
+
+def device_ms_per_call(log_dir: str, calls: int,
+                       kernels=FLASH_KERNELS) -> tuple[dict, float]:
+    """Device ms a call in the profiler trace under ``log_dir``, from
+    the ``XLA Ops`` events of the first TPU: of each of ``kernels`` (the
+    events whose instruction carries the kernel's name,
+    ``pallas_call(name=)``), and of every other operation together.
+    ``({}, 0.0)`` where the trace holds no kernel event (nothing ran on
+    a chip)."""
+    import re
+
     total = dict.fromkeys(kernels, 0)
     named = re.compile(
         r"%?[\w\-]*?(" + "|".join(kernels) + r")(?![a-z])[\w\-.]* = ")
     around = 0
-    for line in planes[0].lines if planes else ():
+    for line in first_tpu_lines(log_dir):
         if line.name != "XLA Ops":
             continue
         for ev in line.events:
@@ -710,6 +718,131 @@ def check_latent_decode(S: int, H: int, R: int, Dr: int, Hi: int, Di: int,
     }
 
 
+# A GLM-5 prefill chunk's attention (PERF.md section 5): 2,048 queries
+# of 64 heads deep in a lane of 17,408 rows stored 640 wide, 14 blocks of
+# 512 keys live, 2,048 keys a query selected by 32 index heads of 128.
+LATENT_PREFILL = dict(C=2048, H=64, R=512, Dn=192, Dr=64, Dv=256, W=640,
+                      Hi=32, Di=128, S=2, L=17408, top_k=2048,
+                      live_blocks=14, key_block=512, query_tile=1024)
+LATENT_PREFILL_TINY = dict(C=64, H=4, R=16, Dn=8, Dr=8, Dv=8, W=128, Hi=2,
+                           Di=16, S=2, L=128, top_k=8, live_blocks=6,
+                           key_block=16, query_tile=32)
+
+
+def _latent_prefill_call(C, H, R, Dn, Dr, Dv, W, Hi, Di, S, L, top_k,
+                         live_blocks, key_block, query_tile):
+    """``glm_dsa.chunk_attention`` on the last lane of a stored buffer,
+    the chunk's queries the last ``C`` positions of the live blocks, the
+    rows above them NaN (never read) -> a function of ``impl`` that
+    returns (output ``[C, H * Dv]``, mask ``[C, L]``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_tpu.models import glm_dsa as gd
+    from ddp_tpu.models.lm import LMSpec
+
+    spec = LMSpec(
+        vocab_size=8, total_len=L, num_heads=H, block="glm_dsa",
+        kv_lora_rank=R, qk_nope_head_dim=Dn, qk_rope_head_dim=Dr,
+        v_head_dim=Dv, index_n_heads=Hi, index_head_dim=Di, index_topk=top_k)
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.key(11), 7)
+    live = live_blocks * key_block
+    rows = jnp.arange(L)[None, :, None]
+    stored = lambda key, width, real: jnp.where(
+        rows < live,
+        jnp.pad(jax.random.normal(key, (S, L, real), bf),
+                ((0, 0), (0, 0), (0, width - real))), jnp.nan)
+    latent, index_k = stored(ks[0], W, R + Dr), stored(ks[1], Di, Di)
+    p = {"kv_b_proj": (jax.random.normal(ks[2], (R, H * (Dn + Dv)))
+                       * R ** -0.5).astype(bf)}
+    q_nope = jax.random.normal(ks[3], (C, H, Dn))
+    q_rope = jax.random.normal(ks[4], (C, H, Dr))
+    qi = jax.random.normal(ks[5], (C, Hi, Di))
+    w = jax.random.normal(ks[6], (C, Hi))
+    q_pos = live - C + jnp.arange(C, dtype=jnp.int32)
+
+    def call(impl):
+        old = gd.KEY_BLOCK, gd.QUERY_TILE
+        gd.KEY_BLOCK, gd.QUERY_TILE = key_block, query_tile
+        try:
+            return jax.jit(lambda *a: gd.chunk_attention(
+                spec, p, *a, want_mask=True, impl=impl))(
+                    q_nope, q_rope, qi, w, latent, index_k, S - 1,
+                    jnp.asarray(live_blocks, jnp.int32), q_pos)
+        finally:
+            gd.KEY_BLOCK, gd.QUERY_TILE = old
+
+    return call
+
+
+def check_latent_prefill(**shape) -> dict:
+    """The ``latent_prefill`` kernel inside a chunk's three passes
+    against the ``jnp`` walk of the same passes: the same operands, the
+    same selection (the masks must be equal), the online softmax's
+    sums in another order."""
+    import jax.numpy as jnp
+
+    call = _latent_prefill_call(**shape)
+    out, mask = call("pallas")
+    ref, ref_mask = call("jnp")
+    err = _max_err(out, ref)
+    differ = int((mask != ref_mask).sum())
+    return {
+        "max_abs_err": err, "tol": TOL["decode"], "masks_differ": differ,
+        "pairs_selected": int(mask.sum()),
+        "ok": bool(jnp.isfinite(out).all()) and err <= TOL["decode"]
+        and differ == 0,
+    }
+
+
+def time_latent_prefill(calls: int = 4, forms=("pallas", "jnp"),
+                        **shape) -> dict:
+    """Device ms a call of a chunk's three passes in both forms
+    (``calls`` executions inside a profiler session, after one that
+    compiles): the whole program's (the ``XLA Modules`` line), and of it
+    the ``latent_prefill`` kernel's, and the eight dearest operations by
+    instruction name. The forms share passes one and two, so the
+    programs' difference is the third pass's."""
+    import tempfile
+
+    import jax
+
+    call = _latent_prefill_call(**shape)
+    rec = {"shape": shape, "calls": calls}
+    for impl in forms:
+        jax.block_until_ready(call(impl))
+        with tempfile.TemporaryDirectory() as log_dir:
+            jax.profiler.start_trace(log_dir)
+            try:
+                for _ in range(calls):
+                    out = call(impl)
+                jax.block_until_ready(out)
+            finally:
+                jax.profiler.stop_trace()
+            kernel, _ = device_ms_per_call(log_dir, calls, ("latent_prefill",))
+            program, ops = 0, {}
+            for line in first_tpu_lines(log_dir):
+                for ev in line.events:
+                    if line.name == "XLA Modules":
+                        program += ev.duration_ns
+                    elif line.name == "XLA Ops":
+                        # "%fusion.12 = ..." -> "fusion"; a loop's body
+                        # is there beside the loop
+                        op = ev.name.split(" = ")[0].lstrip("%").split(".")[0]
+                        if op not in ("while", "conditional", "call"):
+                            ops[op] = ops.get(op, 0) + ev.duration_ns
+        rec[f"{impl}_program_ms_per_call"] = program / 1e6 / calls
+        rec[f"{impl}_kernel_ms_per_call"] = kernel.get("latent_prefill", 0.0)
+        rec[f"{impl}_ops_ms_per_call"] = {
+            op: round(ns / 1e6 / calls, 3) for op, ns in sorted(
+                ops.items(), key=lambda kv: -kv[1])[:8]}
+    rec["ok"] = bool(rec["pallas_kernel_ms_per_call"])
+    if not rec["ok"]:
+        rec["error"] = "no TPU in the trace: nothing timed"
+    return rec
+
+
 def check_decode_packed(S: int, H: int, H_kv: int, Dh: int, L: int,
                         depth: int, scale: float) -> dict:
     """Flash-decode over rows stored with their kv heads side by side
@@ -990,6 +1123,11 @@ def cases(tiny: bool, every: bool):
                                     L=17408, top_k=2048))
         yield "latent_decode_selected_rows", lambda: check_latent_decode(
             **latent)
+        # ... and a prefill chunk's attention there, the kernel against
+        # the jnp walk, masks compared bit for bit.
+        yield "latent_prefill_masked_walk", functools.partial(
+            check_latent_prefill,
+            **(LATENT_PREFILL_TINY if tiny else LATENT_PREFILL))
         # A hybrid of state-space and attention layers (models/
         # granite_hybrid.py) at the benchmark's widths: 4 queries a kv
         # head of 64 at softmax scale 1/64, two kv heads to a 128-lane
@@ -1053,6 +1191,9 @@ def main() -> int:
         runs += [(f"decode_time_{name}", functools.partial(time_decode, **cell))
                  for name, cell in (DECODE_CELLS_TINY if args.tiny
                                     else DECODE_CELLS).items()]
+        runs.append(("latent_prefill_time_masked_walk", functools.partial(
+            time_latent_prefill,
+            **(LATENT_PREFILL_TINY if args.tiny else LATENT_PREFILL))))
     else:
         runs = cases(args.tiny, args.all)
     failed = []

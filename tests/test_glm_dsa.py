@@ -366,6 +366,96 @@ def test_absorbed_decode_equals_the_expanded_chunk(params):
     assert float(jnp.abs(logits[0] - whole[0, 40]).max()) < TOL
 
 
+def _walk_case(case: str, params):
+    """One chunk of 32 queries (two tiles of 16) of 4 heads against lane
+    1 of a 64-row buffer (four blocks of 16 keys) -> (chunk_attention's
+    arguments after ``p``, the rows of the output that count)."""
+    p = params["layers"]["1"]["self_attn"]
+    C, start, rows = 32, {"ties": 32, "young": 0, "dead_blocks": 16,
+                          "padding": 16}[case], 32
+    live = start + C  # the rows the lane holds once the chunk is written
+    q_pos = start + jnp.arange(C, dtype=jnp.int32)
+    u = jax.random.normal(jax.random.key(5), (L, SPEC.d_model))
+    _, _, row, _, ki, _ = gd.attn_inputs(SPEC, p, u, jnp.arange(L))
+    q_nope, q_rope, _, qi, _, w = gd.attn_inputs(SPEC, p, u[start:live],
+                                                 q_pos)
+    if case == "ties":
+        # I(t, s) = a_s: five rows above all, then EIGHT equal rows
+        # 14..21 over the boundary of blocks 0 and 1 (a query takes the
+        # three lowest: 14, 15 and 16), the rest distinct and below
+        a = 0.5 + 0.001 * jnp.arange(L)
+        a = a.at[:5].set(10.0).at[14:22].set(5.0)
+        ki = jnp.zeros_like(ki).at[:, 0].set(a)
+        qi = jnp.zeros_like(qi).at[:, 0, 0].set(1.0)
+        w = jnp.zeros_like(w).at[:, 0].set(1.0)
+    if case == "padding":
+        # the chunk holds 21 real positions: the rows its padding wrote
+        # are in the lane, above every real query
+        rows = 21
+        pad = jnp.arange(L)[:, None] >= start + rows
+        row, ki = jnp.where(pad, 50.0, row), jnp.where(pad, 50.0, ki)
+    above = jnp.arange(L)[:, None] >= live
+    fill = jnp.nan if case == "dead_blocks" else 0.0
+    lanes = lambda a, width: jnp.stack([
+        jnp.full((L, width), 7.0),  # another request's lane
+        gd._stored(jnp.where(above, fill, a), width)])
+    return (q_nope, q_rope, qi, w, lanes(row, 128), lanes(ki, ki.shape[1]),
+            1, live // 16, q_pos), rows
+
+
+def _plain_chunk(p, q_nope, q_rope, qi, w, latent, index_k, lane, n_blocks,
+                 q_pos):
+    """Every row of the live blocks expanded, one explicit mask from a
+    STABLE sort of the index scores (ties to the lower position), a
+    plain softmax; float32 at the highest precision."""
+    R, Dr = SPEC.kv_lora_rank, SPEC.qk_rope_head_dim
+    n = n_blocks * 16
+    lat, ik = latent[lane, :n], index_k[lane, :n]
+    scores = np.asarray(dec.index_scores(qi, w, ik[None]))
+    mask = np.zeros((len(q_pos), n), bool)
+    for c, t in enumerate(np.asarray(q_pos)):
+        best = np.argsort(-scores[c, :t + 1], kind="stable")[:K]
+        mask[c, best] = True
+    w_k, w_v = gd._kv_b(SPEC, p)
+    with jax.default_matmul_precision("highest"):
+        s = (jnp.einsum("chn,br,rhn->hcb", q_nope, lat[:, :R], w_k)
+             + jnp.einsum("chr,br->hcb", q_rope, lat[:, R:R + Dr]))
+        pr = jax.nn.softmax(
+            jnp.where(mask[None], s * gd.attn_scale(SPEC), -jnp.inf), -1)
+        out = jnp.einsum("hcb,br,rhv->chv", pr, lat[:, :R], w_v)
+    return out.reshape(len(q_pos), -1), mask
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+@pytest.mark.parametrize("case", ["ties", "young", "dead_blocks", "padding"])
+def test_a_chunks_walk_attends_what_a_plain_mask_says_in_both_forms(
+        params, monkeypatch, case, impl):
+    """The chunk's third pass as the ``jnp`` walk and as the
+    ``latent_prefill`` kernel (under the Pallas interpreter), each held
+    to the plain form: the SAME mask bit for bit, the outputs to
+    float32's order of sums. Equal scores at the threshold go to the
+    lower position across a block boundary; a query below ``index_topk``
+    takes every row up to itself; the blocks above the live ones hold
+    NaN and are never read; rows a chunk's padding wrote are above every
+    real query."""
+    monkeypatch.setattr(gd, "QUERY_TILE", 16)
+    args, rows = _walk_case(case, params)
+    p = params["layers"]["1"]["self_attn"]
+    assert gd.chunk_form(SPEC, 32, L, 128, impl) == (
+        ("kernel", 16) if impl == "pallas" else ("walk", 0))
+    out, mask = jax.jit(lambda *a: gd.chunk_attention(
+        SPEC, p, *a, want_mask=True, impl=impl))(*args)
+    want, plain = _plain_chunk(p, *args)
+    n = plain.shape[1]
+    assert (np.asarray(mask)[:, :n] == plain).all()
+    assert not np.asarray(mask)[:, n:].any()
+    assert float(jnp.abs(out - want)[:rows].max()) < TOL
+    if case == "ties":  # positions 32..63 all take 0..4 and 14, 15, 16
+        assert sorted(np.flatnonzero(plain[0])) == [0, 1, 2, 3, 4, 14, 15, 16]
+    if case == "young":
+        assert (plain[:K] == np.tri(K, n, dtype=bool)).all()
+
+
 def test_a_reused_lane_reads_as_a_fresh_one(params, ref_out):
     """A lane that held a longer request: its stale latent AND indexer
     rows lie above the new request's positions and are never selected."""

@@ -387,15 +387,11 @@ def test_an_expert_share_compiles_at_glm5_widths(one_chip, as_on_tpu,
     assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
 
 
-def test_the_glm5_cells_decode_program_fits(one_chip, as_on_tpu):
-    """The whole decode program of the GLM-5 cell at the benchmark's
-    sizes (16 lanes of 17,408 positions; bfloat16 weights and rows):
-    9.42 GB of weights and 2.99 GB of lanes as arguments, 0.09 GB of
-    temporaries (the index scores, the gathered rows, the experts'
-    tiles), inside one chip's 16 GB beside the chunk programs' 0.91 GB
-    (compiled by hand, PERF.md section 4: ~50 s each). No whole-lane
-    copy: with a latent row of 576 stored as it is the program held
-    0.60 GB of temporaries and copied 321 MB fourteen times a step."""
+def _glm5_cell(one_chip):
+    """The GLM-5 cell at the benchmark's sizes, described -> (spec, a
+    chunk's width, what both its programs take first: parameters, the
+    16 lanes' cache and the lanes' tokens, seeds, steps, temperatures and
+    top-ps)."""
     import json
 
     from benchmarks.drivers import glm_dsa_serve
@@ -411,20 +407,71 @@ def test_the_glm5_cells_decode_program_fits(one_chip, as_on_tpu):
     described = lambda make: jax.tree.map(
         lambda a: _shape(a.shape, a.dtype, one_chip), jax.eval_shape(make))
     lanes = lambda dtype: _shape((S,), dtype, one_chip)
-    compiled = jax.jit(
-        lambda p, c, *a: glm_dsa.slot_decode_sample_step(spec, p, c, *a),
-        donate_argnums=(1,),
-    ).lower(
+    return spec, cfg["engine"]["prefill_chunk"], (
         described(lambda: glm_dsa.init_params(spec)),
         described(lambda: init_slot_cache(spec, S, jnp.bfloat16)),
         lanes(jnp.int32), lanes(jnp.int32), lanes(jnp.int32),
-        lanes(jnp.float32), lanes(jnp.float32),
-    ).compile()
+        lanes(jnp.float32), lanes(jnp.float32))
+
+
+def test_the_glm5_cells_decode_program_fits(one_chip, as_on_tpu):
+    """The whole decode program of the GLM-5 cell at the benchmark's
+    sizes (16 lanes of 17,408 positions; bfloat16 weights and rows):
+    9.42 GB of weights and 2.99 GB of lanes as arguments, 0.09 GB of
+    temporaries (the index scores, the gathered rows, the experts'
+    tiles), inside one chip's 16 GB beside the chunk programs' 0.91 GB
+    (compiled by hand, PERF.md section 4: ~50 s each). No whole-lane
+    copy: with a latent row of 576 stored as it is the program held
+    0.60 GB of temporaries and copied 321 MB fourteen times a step."""
+    from ddp_tpu.models import glm_dsa
+
+    spec, _, state = _glm5_cell(one_chip)
+    compiled = jax.jit(
+        lambda p, c, *a: glm_dsa.slot_decode_sample_step(spec, p, c, *a),
+        donate_argnums=(1,),
+    ).lower(*state).compile()
     text = compiled.as_text()
     assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
     mem = compiled.memory_analysis()
     assert abs(mem.argument_size_in_bytes / 1e9 - 12.42) < 0.02
     assert mem.temp_size_in_bytes / 1e9 < 0.3
+
+
+def test_the_glm5_cells_chunk_program_attends_through_the_kernel(
+        one_chip, as_on_tpu):
+    """The cell's 2,048-wide ``prefill_chunk`` program (PR 46): its
+    third pass is the ``latent_prefill`` kernel, found under the
+    ``mla_prefill`` scope by the name the profiler gives its events
+    (what ``_gd_common.scope_map`` joins on); no score array of all
+    heads for a block of keys exists, and the temporaries lie under the
+    0.91 GB the ``jnp`` walk's program held."""
+    from benchmarks.layer_metrics._gd_common import scope_map
+    from ddp_tpu.models import glm_dsa
+    from ddp_tpu.obs.tracer import get_tracer
+
+    spec, C, state = _glm5_cell(one_chip)
+    form = ("kernel", glm_dsa.QUERY_TILE)
+    assert glm_dsa.chunk_form(spec, C, spec.total_len, 640) == form
+    one = lambda dtype: _shape((), dtype, one_chip)
+    compiled = jax.jit(
+        lambda p, c, *a: glm_dsa.prefill_chunk(spec, p, c, *a),
+        donate_argnums=(1,),
+    ).lower(
+        *state, one(jnp.int32), _shape((C,), jnp.int32, one_chip),
+        one(jnp.int32), one(jnp.int32), one(jnp.bool_), one(jnp.int32),
+        one(jnp.float32), one(jnp.float32),
+    ).compile()
+    plan = [e[4] for e in get_tracer().ring() if e[0] == "dsa.plan"][-1]
+    assert plan[0] == "prefill_chunk" and plan[-2:] == form
+    text = compiled.as_text()
+    scopes = scope_map(text)
+    kernels = [inst for inst in scopes if inst.startswith("latent_prefill")]
+    assert len(kernels) == spec.depth
+    assert all(scopes[k] == "mla_prefill" for k in kernels)
+    assert "f32[64,2048,512]" not in text
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes / 1e9 - 12.42) < 0.02
+    assert mem.temp_size_in_bytes / 1e9 < 0.91
 
 
 @pytest.fixture(scope="module")
